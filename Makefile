@@ -3,7 +3,7 @@
 
 PYTHON ?= python
 
-.PHONY: all native unit-test unit-test-fast unit-test-slow engine-test rag-test chaos kvq wquant kvpool kvtier lora structured obs devprof slo itl fleet autoscale spec qos asyncloop prefill overlap bench serve manager epp clean
+.PHONY: all native unit-test unit-test-fast unit-test-slow engine-test rag-test chaos kvq wquant kvpool kvtier lora structured obs devprof slo itl fleet autoscale spec qos asyncloop prefill overlap bench chip-smoke serve manager epp clean
 
 all: native
 
@@ -177,6 +177,13 @@ prefill:
 bench:
 	$(PYTHON) bench.py
 
+# the real server at a real model's widths on the chip (one chip
+# process at a time; needs a TPU).  Rehearse on the CPU first:
+#   JAX_PLATFORMS=cpu python chip_smoke.py --model tiny-llama-test \
+#     --expect-platform cpu
+chip-smoke:
+	$(PYTHON) chip_smoke.py
+
 serve:
 	$(PYTHON) -m kaito_tpu.engine.server --model $${MODEL:-tiny-llama-test}
 
@@ -198,3 +205,4 @@ docker-manager:
 
 clean:
 	$(MAKE) -C kaito_tpu/native clean
+	rm -rf .jax_cache

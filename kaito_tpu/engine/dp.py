@@ -162,14 +162,15 @@ class DataParallelEngine:
                adapter: str = "",
                timeout_s: Optional[float] = None,
                trace_id: Optional[str] = None,
-               tenant: str = "", priority: str = "") -> Request:
+               tenant: str = "", priority: str = "",
+               pool_blocks: Optional[list] = None) -> Request:
         if export_kv:
             raise RuntimeError("P/D KV export requires data_parallel=1")
         eng = self._pick()
         req = eng.submit(prompt_tokens, params, req_id=req_id,
                          adapter=adapter, timeout_s=timeout_s,
                          trace_id=trace_id, tenant=tenant,
-                         priority=priority)
+                         priority=priority, pool_blocks=pool_blocks)
         req._dp_group = eng
         return req
 

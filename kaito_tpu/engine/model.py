@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from kaito_tpu.engine import attention as attn
 from kaito_tpu.engine import nn
@@ -104,6 +105,14 @@ class TransformerLM:
         # projections (attention-out, MLP-down) route through the
         # pipelined ring — prefill/CP/PP paths never read this.
         self.overlap = None
+        # (Mesh, head axis|None) => the Pallas attention kernels run
+        # under shard_map, one call per head shard.  JAX refuses to
+        # partition a Mosaic call by itself ("Mosaic kernels cannot be
+        # automatically partitioned"), so the engine sets this on every
+        # mesh; heads are independent, so the per-shard
+        # call is the same kernel at H/tp and Hkv/tp heads.  A None
+        # axis (heads do not divide) runs every head on every device.
+        self.head_shard = None
         self.moe_impl = "dense"     # "dense" | "ragged" (grouped matmul)
         self.groups = _layer_groups(arch)
         self.vocab_padded = -(-arch.vocab_size // VOCAB_ALIGN) * VOCAB_ALIGN
@@ -410,6 +419,22 @@ class TransformerLM:
     # Layer body (shared by prefill and decode via mode switch)
     # ------------------------------------------------------------------
 
+    def _pallas_attention(self, kernel, args, head_dims, out_head_dim):
+        """Call a Pallas attention kernel on ``args``, per head shard
+        when the model sits on a mesh (see ``head_shard``).
+        ``head_dims[i]`` is the (kv-)head dimension of ``args[i]``, or
+        None for an operand every shard needs whole."""
+        if self.head_shard is None:
+            return kernel(*args)
+        mesh, axis = self.head_shard
+
+        def spec(dim):
+            return P() if dim is None else P(*([None] * dim), axis)
+
+        return jax.shard_map(
+            kernel, mesh=mesh, in_specs=tuple(spec(d) for d in head_dims),
+            out_specs=spec(out_head_dim), check_vma=False)(*args)
+
     def _attn_qkv(self, x: jax.Array, p: dict, positions: jax.Array,
                   window: Optional[jax.Array], lora: Optional[dict] = None,
                   lora_ids: Optional[jax.Array] = None, overlap=None):
@@ -579,10 +604,12 @@ class TransformerLM:
                     flash_prefill_packed)
 
                 win = window if window is not None else jnp.int32(_BIG_WINDOW)
-                out = flash_prefill_packed(
-                    q, k_new, v_new, seg_ids, positions,
-                    jnp.asarray(win, jnp.int32), scale=self._scale,
-                    softcap=a.attn_logit_softcap)
+                out = self._pallas_attention(
+                    partial(flash_prefill_packed, scale=self._scale,
+                            softcap=a.attn_logit_softcap),
+                    (q, k_new, v_new, seg_ids, positions,
+                     jnp.asarray(win, jnp.int32)),
+                    (2, 2, 2, None, None, None), 2)
             else:
                 out = attn.packed_prefill_attention(
                     q, k_new, v_new, seg_ids, positions, scale=self._scale,
@@ -613,9 +640,12 @@ class TransformerLM:
                     flash_prefill_attention)
 
                 win = window if window is not None else jnp.int32(_BIG_WINDOW)
-                out = flash_prefill_attention(
-                    q, k_new, v_new, true_lens, jnp.asarray(win, jnp.int32),
-                    scale=self._scale, softcap=a.attn_logit_softcap)
+                out = self._pallas_attention(
+                    partial(flash_prefill_attention, scale=self._scale,
+                            softcap=a.attn_logit_softcap),
+                    (q, k_new, v_new, true_lens,
+                     jnp.asarray(win, jnp.int32)),
+                    (2, 2, 2, None, None), 2)
             else:
                 out = attn.prefill_attention(
                     q, k_new, v_new, scale=self._scale,
@@ -639,11 +669,23 @@ class TransformerLM:
                     paged_decode_attention_pallas)
 
                 win = window if window is not None else jnp.int32(_BIG_WINDOW)
-                out = paged_decode_attention_pallas(
-                    q[:, 0], ck, cv, page_tables, lengths,
-                    jnp.asarray(win, jnp.int32), scale=self._scale,
-                    softcap=a.attn_logit_softcap, layer=li,
-                    k_scale=ks, v_scale=vs)
+
+                def decode_kernel(q1, ck, cv, pt, ln, win, li, *scales):
+                    k_s, v_s = scales or (None, None)
+                    return paged_decode_attention_pallas(
+                        q1, ck, cv, pt, ln, win, scale=self._scale,
+                        softcap=a.attn_logit_softcap, layer=li,
+                        k_scale=k_s, v_scale=v_s)
+
+                # q [B, H, D]; pools [Lg, P, ps, Hkv, D]; scales [Lg, P, Hkv]
+                args = (q[:, 0], ck, cv, page_tables, lengths,
+                        jnp.asarray(win, jnp.int32), li)
+                head_dims = (1, 3, 3, None, None, None, None)
+                if ks is not None:
+                    args += (ks, vs)
+                    head_dims += (2, 2)
+                out = self._pallas_attention(decode_kernel, args,
+                                             head_dims, 1)
             else:
                 out = attn.paged_decode_attention(
                     q[:, 0], ck, cv, page_tables, lengths, scale=self._scale,

@@ -127,7 +127,11 @@ class MultiHostEngine(InferenceEngine):
     def submit(self, prompt_tokens, params, req_id=None,
                export_kv=False, adapter: str = "",
                timeout_s=None, trace_id=None,
-               tenant: str = "", priority: str = "") -> Request:
+               tenant: str = "", priority: str = "",
+               pool_blocks=None) -> Request:
+        # pool_blocks (the server's KV-pool publish hashes) are dropped:
+        # publishing gathers device pages, which the leader must not do
+        # alone while the workers sit in the step broadcast
         if not self.is_leader:
             raise RuntimeError("submit() is leader-only; workers receive "
                                "requests via the step broadcast")

@@ -49,11 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 N_BUF = 4
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernel loads against the pallas version this image ships
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _decode_kernel(
     # scalar prefetch
@@ -273,7 +268,7 @@ def paged_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_tables, lengths, jnp.reshape(window, (1,)),

@@ -3,7 +3,7 @@
 Causal self-attention over a fresh chunk without materializing the
 [T, T] score matrix: the grid tiles (batch, q-head, q-block); K/V for
 the whole chunk sit in VMEM (chunks are bounded by the engine's
-prefill buckets, so T*D stays well under the VMEM budget) and the
+prefill budget; ``_check_kv_fits_vmem`` refuses what cannot fit) and the
 kernel walks K blocks with online softmax, skipping blocks entirely
 above the causal diagonal.
 
@@ -25,10 +25,23 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernel loads against the pallas version this image ships
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+# Both kernels hold one KV head's whole K and V in VMEM, and the grid
+# pipeline double-buffers each: 4 * T * D * itemsize bytes, 1 KiB per
+# token at D=128 in bf16.  A v5e's default scoped-VMEM limit is 16 MiB:
+# 12 MiB of K/V (T=12,288) compiles there and 16 MiB is refused by
+# Mosaic ("Ran out of memory in memory space vmem"), so longer chunks
+# are refused here, by name.  The engine's fresh-prefill chunks are
+# bounded by max_prefill_tokens (512) — far below.
+_KV_VMEM_BUDGET = 12 << 20
+
+
+def _check_kv_fits_vmem(T: int, D: int, dtype) -> None:
+    need = 4 * T * D * jnp.dtype(dtype).itemsize
+    if need > _KV_VMEM_BUDGET:
+        raise ValueError(
+            f"flash prefill keeps a head's K and V in VMEM: a {T}-token "
+            f"chunk needs {need >> 20} MiB of the {_KV_VMEM_BUDGET >> 20} "
+            f"MiB budget; prefill it in smaller chunks")
 
 
 def _flash_kernel(
@@ -186,6 +199,7 @@ def flash_prefill_packed(
     if T % bq or T % bk:
         raise ValueError(f"chunk length {T} must be a multiple of the "
                          f"block sizes ({bq}, {bk})")
+    _check_kv_fits_vmem(T, D, k.dtype)
     grid = (B, H, T // bq)
 
     qt = (q * scale).astype(q.dtype).transpose(0, 2, 1, 3)
@@ -210,7 +224,7 @@ def flash_prefill_packed(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.reshape(window, (1,)), seg_ids.astype(jnp.int32),
@@ -243,6 +257,7 @@ def flash_prefill_attention(
     if T % bq or T % bk:
         raise ValueError(f"chunk length {T} must be a multiple of the "
                          f"block sizes ({bq}, {bk})")
+    _check_kv_fits_vmem(T, D, k.dtype)
     grid = (B, H, T // bq)
 
     # Head-major [B, H, T, D] layout so every block's trailing two dims
@@ -267,7 +282,7 @@ def flash_prefill_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(true_len, jnp.reshape(window, (1,)), qt, kt, vt)

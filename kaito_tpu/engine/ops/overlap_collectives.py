@@ -59,7 +59,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = [
@@ -238,8 +237,8 @@ def overlap_linear(x: jax.Array, w, mesh, *, axis_name: str = "tensor",
         return ring_all_gather(yc, axis_name=axis_name, axis_size=n)
 
     with jax.named_scope(f"comm_overlap_{mode}"):
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_spec, check_rep=False)(*operands)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_spec, check_vma=False)(*operands)
 
 
 def ag_matmul_eligible(x: jax.Array, w, n: int) -> bool:
@@ -279,5 +278,5 @@ def all_gather_matmul(x: jax.Array, w: jax.Array, mesh, *,
                                       axis_size=n)
 
     with jax.named_scope(f"comm_overlap_ag_matmul_{mode}"):
-        return shard_map(body, mesh=mesh, in_specs=(x_spec, w_spec),
-                         out_specs=out_spec, check_rep=False)(x, w)
+        return jax.shard_map(body, mesh=mesh, in_specs=(x_spec, w_spec),
+                             out_specs=out_spec, check_vma=False)(x, w)

@@ -11,21 +11,23 @@ construction, never a materialized bf16 copy of the weight.
 
 Layout contract (engine/quant.py): int4 packs ADJACENT in-row pairs
 (row 2i low nibble, row 2i+1 high nibble) and every weight chunk the
-kernel sees spans exactly one scale group, so the per-group scale
-folds POST-dot:
+kernel sees is a run of WHOLE scale groups, so each group's scale folds
+POST-dot:
 
-    acc += (x_even_chunk @ lo_nibbles + x_odd_chunk @ hi_nibbles) * s_g
+    acc += (x_even_g @ lo_nibbles_g + x_odd_g @ hi_nibbles_g) * s_g
 
 The even/odd x columns are two cheap strided slices of the (tiny)
-activation taken once outside the kernel — no in-kernel interleave or
-transpose, which Mosaic would serialize.
+activation taken once outside the kernel and laid out group-major
+``[G, rows, g/2]`` — no in-kernel interleave, transpose or sub-tile
+lane slice, which Mosaic would serialize or refuse.
 
 ``quant_linear`` is the nn.linear entry point: it picks the kernel for
 decode-shaped calls (rows <= MAX_ROWS, tileable shapes) on TPU and the
 pure-JAX unpack-then-dot fallback everywhere else (CPU tests, prefill,
 odd shapes).  KAITO_QUANT_MATMUL=auto|pallas|interpret|jax overrides
 the choice (read at trace time; 'interpret' runs the kernel in
-interpreter mode so CPU tests cover the kernel path end-to-end).
+interpreter mode so CPU tests cover the kernel path end-to-end, and is
+the only way to get the interpreter: 'pallas' off a TPU is an error).
 """
 
 from __future__ import annotations
@@ -41,19 +43,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kaito_tpu.engine.quant import dequant_weight, int4_group_size
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernel loads against the pallas version this image ships
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 # decode/verify batches are skinny (max_num_seqs, or batch * spec
 # window); anything wider is prefill-shaped and belongs on the MXU via
 # the plain dot with XLA-fused dequant
 MAX_ROWS = 256
 
-# int8 chunk: in-rows per inner grid step (int4 chunks are one scale
-# group instead, so folding stays exact per chunk)
+# int8 chunk: in-rows per inner grid step
 _INT8_CHUNK = 512
+
+# int4 chunk: scale groups per inner grid step.  The chunk's scale block
+# is [groups, tn], and Mosaic tiles the second-minor block dim by 8
+# unless it spans the array — so a chunk is 8 groups, or all of them
+# when the group count is not a multiple of 8 (bounded: the per-group
+# dots unroll)
+_INT4_GROUPS = 8
+_INT4_MAX_GROUPS = 32
 
 # layer-ahead weight prefetch (docs/multichip.md): the L+1 slab rides
 # the same grid as two extra double-buffered input streams, so its
@@ -103,7 +107,11 @@ def kernel_plan(rows: int, w: dict):
     tn = _pick_tn(N)
     if tn is None or g % 2 or K % g:
         return None
-    return {"kind": "int4", "K": K, "N": N, "tk": g, "tn": tn}
+    groups = K // g
+    ng = _INT4_GROUPS if groups % _INT4_GROUPS == 0 else groups
+    if ng > _INT4_MAX_GROUPS:
+        return None
+    return {"kind": "int4", "K": K, "N": N, "tk": ng * g, "tn": tn, "g": g}
 
 
 def _int8_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_chunks):
@@ -134,19 +142,23 @@ def _int4_kernel(xe_ref, xo_ref, w_ref, s_ref, o_ref, acc_ref, *,
     def _zero():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # unpack both nibble planes in-register ( & 0xFF kills the int8
-    # sign extension from the widening)
-    p = w_ref[:].astype(jnp.int32) & 0xFF
-    lo = ((p & 0xF) - 8).astype(xe_ref.dtype)
-    hi = (((p >> 4) & 0xF) - 8).astype(xe_ref.dtype)
-    part = jax.lax.dot_general(
-        xe_ref[:], lo, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    part += jax.lax.dot_general(
-        xo_ref[:], hi, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # chunk == one scale group, so the group scale folds post-dot
-    acc_ref[:] += part * s_ref[0].astype(jnp.float32)
+    ng, _, gq = xe_ref.shape          # groups in this chunk, packed rows each
+    acc = jnp.zeros(acc_ref.shape, jnp.float32)
+    for gi in range(ng):
+        # unpack both nibble planes in-register ( & 0xFF kills the int8
+        # sign extension from the widening)
+        p = w_ref[gi * gq:(gi + 1) * gq, :].astype(jnp.int32) & 0xFF
+        lo = ((p & 0xF) - 8).astype(xe_ref.dtype)
+        hi = (((p >> 4) & 0xF) - 8).astype(xe_ref.dtype)
+        part = jax.lax.dot_general(
+            xe_ref[gi], lo, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        part += jax.lax.dot_general(
+            xo_ref[gi], hi, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # one scale group per dot, so the group scale folds post-dot
+        acc += part * s_ref[gi:gi + 1, :].astype(jnp.float32)
+    acc_ref[:] += acc
 
     @pl.when(c == n_chunks - 1)
     def _flush():
@@ -171,7 +183,8 @@ def prefetch_ok(plan: dict, w_next: Optional[dict]) -> bool:
     else:
         if w_next["q4"].shape != (plan["K"] // 2, plan["N"]):
             return False
-        block = (tk // 2) * tn + 4 * tn     # packed slab + group scales
+        # packed slab + one f32 scale row per group
+        block = (tk // 2) * tn + 4 * (tk // plan["g"]) * tn
     return 2 * block <= _PREFETCH_VMEM_BUDGET
 
 
@@ -255,20 +268,27 @@ def quant_matmul(x: jax.Array, w: dict, w_next: Optional[dict] = None,
         kernel = functools.partial(
             _int4_kernel_pf if pf else _int4_kernel, n_chunks=n_chunks)
         # the two nibble-plane activations: even/odd in-rows of x
-        # (packed byte row i holds original rows 2i and 2i+1)
-        xe, xo = x[:, 0::2], x[:, 1::2]
+        # (packed byte row i holds original rows 2i and 2i+1), laid out
+        # group-major [G, rows, gq] so the kernel picks a group's
+        # columns by leading index
+        gq = plan["g"] // 2              # packed rows per scale group
+        ng = tk // plan["g"]             # scale groups per chunk
         tkq = tk // 2                    # packed rows per chunk
+
+        def plane(xs):
+            return xs.reshape(rows, K // plan["g"], gq).transpose(1, 0, 2)
+
         in_specs = [
-            pl.BlockSpec((rows, tkq), lambda j, c: (0, c)),
-            pl.BlockSpec((rows, tkq), lambda j, c: (0, c)),
+            pl.BlockSpec((ng, rows, gq), lambda j, c: (c, 0, 0)),
+            pl.BlockSpec((ng, rows, gq), lambda j, c: (c, 0, 0)),
             pl.BlockSpec((tkq, tn), lambda j, c: (c, j)),
-            pl.BlockSpec((1, tn), lambda j, c: (c, j)),
+            pl.BlockSpec((ng, tn), lambda j, c: (c, j)),
         ]
-        operands = (xe, xo, w["q4"], scale)
+        operands = (plane(x[:, 0::2]), plane(x[:, 1::2]), w["q4"], scale)
         if pf:
             in_specs += pf_specs + [
                 pl.BlockSpec((tkq, tn), lambda j, c: (c, j)),
-                pl.BlockSpec((1, tn), lambda j, c: (c, j)),
+                pl.BlockSpec((ng, tn), lambda j, c: (c, j)),
             ]
             operands += (flag, w_next["q4"], w_next["scale"])
 
@@ -279,7 +299,7 @@ def quant_matmul(x: jax.Array, w: dict, w_next: Optional[dict] = None,
         out_specs=pl.BlockSpec((rows, tn), lambda j, c: (0, j)),
         out_shape=jax.ShapeDtypeStruct((rows, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((rows, tn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -329,10 +349,8 @@ def _quant_linear(x: jax.Array, w: dict,
         use_kernel = jax.default_backend() == "tpu"
     plan = kernel_plan(rows, w) if use_kernel and rows > 0 else None
     if plan is not None:
-        interpret = (mode == "interpret"
-                     or jax.default_backend() != "tpu")
         w_next = prefetch if prefetch_ok(plan, prefetch) else None
         out = quant_matmul(x.reshape(rows, K), w, w_next,
-                           interpret=interpret)
+                           interpret=mode == "interpret")
         return out.reshape(*lead, out.shape[-1])
     return dequant_matmul_jax(x, w)

@@ -77,6 +77,16 @@ def token_surface_forms(tokenizer, ids, window: int = 8) -> list:
     return out
 
 
+def _device_identity() -> dict:
+    """The device as jax reports it — on /health of both the loading
+    stub and the live server."""
+    import jax
+
+    dev = jax.local_devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
+
+
 class ServerState:
     def __init__(self, engine: InferenceEngine, cfg: EngineConfig):
         self.engine = engine
@@ -260,13 +270,7 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         st = self.state
         self._intake_trace()
         if self.path == "/health":
-            body = {"status": "ok"}
-            sizing = getattr(st.engine, "sizing_report", None)
-            if sizing:
-                # self-measured HBM sizing + estimator drift: the
-                # benchmark probe folds this into status.performance
-                body["hbm_sizing"] = sizing
-            self._json(200, body)
+            self._json(200, self._health())
         elif self.path == "/metrics":
             body = st.metrics.registry.expose().encode()
             self.send_response(200)
@@ -337,6 +341,36 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         groups via `.engines`; a plain engine is its own only group."""
         return list(getattr(self.state.engine, "engines",
                             [self.state.engine]))
+
+    def _health(self) -> dict:
+        """What a client that cannot import jax needs to tell WHERE and
+        HOW this server runs: the device as jax reports it, the paths
+        the engine actually selected, and each local device's memory
+        (None fields on a backend that reports none, i.e. the CPU)."""
+        import jax
+
+        engines = self._sub_engines()
+        body = {
+            "status": "ok",
+            **_device_identity(),
+            "jax_version": jax.__version__,
+            "attention": engines[0].model.attn_impl,
+            "prefix_cache": ("native" if all(
+                e.prefix_cache is not None for e in engines) else "off"),
+            "devices": [],
+        }
+        for d in jax.local_devices():
+            stats = d.memory_stats() or {}
+            body["devices"].append({
+                "id": d.id,
+                **{k: stats.get(k) for k in (
+                    "bytes_in_use", "peak_bytes_in_use", "bytes_limit")}})
+        # self-measured HBM sizing + estimator drift (first group's:
+        # every group runs the same config): the benchmark probe folds
+        # this into status.performance
+        if engines[0].sizing_report:
+            body["hbm_sizing"] = engines[0].sizing_report
+        return body
 
     def _debug_trace(self):
         """Chrome trace-event JSON of recorded spans (Perfetto-loadable),
@@ -1947,6 +1981,7 @@ class _LoadingHandler(BaseHTTPRequestHandler):
 
     protocol_version = "HTTP/1.1"
     started: float = 0.0   # stamped by start_loading_stub's subclass
+    device: dict = {}      # likewise: where the engine is loading
 
     def log_message(self, *a):
         pass
@@ -1962,7 +1997,8 @@ class _LoadingHandler(BaseHTTPRequestHandler):
         if self.path == "/health":
             self._body(503, json.dumps(
                 {"status": "loading",
-                 "seconds": round(time.time() - self.started, 1)}).encode(),
+                 "seconds": round(time.time() - self.started, 1),
+                 **self.device}).encode(),
                 "application/json")
         elif self.path == "/metrics":
             body = ("# HELP kaito:engine_loading 1 while weights "
@@ -1987,9 +2023,11 @@ class _LoadingHandler(BaseHTTPRequestHandler):
 
 def start_loading_stub(host: str, port: int) -> ThreadingHTTPServer:
     """Serve the loading stub until the engine is constructed; caller
-    shuts it down right before binding the real server."""
+    shuts it down right before binding the real server.  The stub
+    already says which device the engine is loading onto, so a client
+    that expected another platform need not wait out the load."""
     handler = type("LoadingHandler", (_LoadingHandler,),
-                   {"started": time.time()})
+                   {"started": time.time(), "device": _device_identity()})
     stub = ThreadingHTTPServer((host, port), handler)
     threading.Thread(target=stub.serve_forever, daemon=True,
                      name="loading-stub").start()
@@ -2037,9 +2075,9 @@ def load_config_file(cfg: EngineConfig, path: str) -> EngineConfig:
 
 
 def main(argv=None):
-    from kaito_tpu.utils.platform import apply_platform_env
+    from kaito_tpu.utils.platform import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     ap = argparse.ArgumentParser(prog="kaito-tpu-serve")
     ap.add_argument("--model", default="tiny-llama-test")
     ap.add_argument("--port", type=int, default=5000)
@@ -2403,22 +2441,23 @@ def main(argv=None):
         stub.shutdown()
         stub.server_close()
     server = make_server(engine, cfg, host=args.host)
-    if cfg.flight_dir:
-        # third flight trigger: SIGTERM with requests still in flight
-        # (a drain that was going to lose work) snapshots the black box
-        # before the graceful shutdown path runs.  Raising
-        # KeyboardInterrupt re-enters the normal teardown below.
-        import signal
+    # SIGTERM (what a kubelet, or a parent that started this server,
+    # sends) takes the same graceful path as ^C: raising
+    # KeyboardInterrupt re-enters the teardown below, the engine stops
+    # and the process exits 0.  With a flight recorder, requests still
+    # in flight (a drain that was going to lose work) snapshot the
+    # black box first — the third flight trigger.
+    import signal
 
-        def _on_sigterm(signum, frame):
-            st = server.state
-            in_flight = engine.num_running + engine.num_waiting
-            if st.flight is not None and in_flight > 0:
-                st.flight.record(
-                    "sigterm", reason=f"{in_flight} request(s) in flight")
-            raise KeyboardInterrupt
+    def _on_sigterm(signum, frame):
+        st = server.state
+        in_flight = engine.num_running + engine.num_waiting
+        if st.flight is not None and in_flight > 0:
+            st.flight.record(
+                "sigterm", reason=f"{in_flight} request(s) in flight")
+        raise KeyboardInterrupt
 
-        signal.signal(signal.SIGTERM, _on_sigterm)
+    signal.signal(signal.SIGTERM, _on_sigterm)
     logger.info("serving %s on %s:%d", cfg.model, args.host, cfg.port)
     try:
         server.serve_forever()

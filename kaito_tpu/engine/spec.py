@@ -223,6 +223,10 @@ class DraftRunner:
         self.model = TransformerLM(
             self.md.arch, dtype=self.dtype,
             attn_impl=getattr(engine.model, "attn_impl", "jax"))
+        if engine.model.head_shard is not None:
+            # same mesh as the target; the draft pool below is
+            # replicated, so every device runs every draft head
+            self.model.head_shard = (self.mesh, None)
         self.params = self._init_params(cfg, engine)
         self.page_size = cfg.page_size
         self.pages_per_seq = engine.pages_per_seq

@@ -1,8 +1,9 @@
 """ctypes bindings for the native runtime components.
 
-Builds ``libkaito_native.so`` on first import when a compiler is
-available (make -C kaito_tpu/native); every consumer has a pure-Python
-fallback, so absence of a toolchain degrades gracefully.
+Builds ``libkaito_native.so`` on first use when a compiler is
+available (make -C kaito_tpu/native).  The vector index has a
+pure-Python fallback in its consumer; the prefix cache has none — the
+engine warns and serves without prefix reuse, visibly on /health.
 """
 
 from __future__ import annotations

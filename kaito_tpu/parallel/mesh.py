@@ -16,7 +16,6 @@ import os
 from typing import Optional, Sequence
 
 import jax
-import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
@@ -34,11 +33,10 @@ def build_mesh(spec: MeshSpec, devices: Optional[Sequence] = None) -> Mesh:
     if spec.num_devices != n:
         raise ValueError(
             f"mesh {spec} wants {spec.num_devices} devices, have {n}")
-    try:
-        dev_array = mesh_utils.create_device_mesh(spec.shape, devices=devices)
-    except (ValueError, AssertionError):
-        dev_array = np.asarray(devices).reshape(spec.shape)
-    return Mesh(dev_array, spec.names)
+    # on a TPU a layout mesh_utils cannot place raises: a plain reshape
+    # would ignore the ICI order (off a TPU it IS a plain reshape)
+    return Mesh(mesh_utils.create_device_mesh(spec.shape, devices=devices),
+                spec.names)
 
 
 def fit_mesh_spec(spec: MeshSpec, num_devices: int) -> MeshSpec:

@@ -50,22 +50,6 @@ from kaito_tpu.engine.model import TransformerLM
 from kaito_tpu.parallel.pipeline import split_stage_params
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-    """Partial-manual shard_map across jax versions: ``jax.shard_map``
-    (axis_names/check_vma) where it exists, else the experimental
-    entry, where manual-on-one-axis spells ``auto=`` (the complement
-    set) and replication checking spells ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
-
-
 class PipelineServeExecutor:
     """Builds stage-sharded decode/prefill step functions for the engine."""
 
@@ -268,11 +252,11 @@ class PipelineServeExecutor:
             nonlocal sharded
             if sharded is None:
                 specs = self._param_specs(params)
-                sharded = _shard_map(
+                sharded = jax.shard_map(
                     local_decode, mesh=self.mesh,
                     in_specs=(specs, P(ax), P(ax), P(), P(), P(), P(), P()),
                     out_specs=(P(ax), P(ax), P()),
-                    axis_names={ax})
+                    axis_names={ax}, check_vma=False)
             if adapter_ids is None:
                 adapter_ids = jnp.zeros(tokens.shape[:1], jnp.int32)
             k, v, logits = sharded(params, cache.k, cache.v, tokens,
@@ -348,11 +332,11 @@ class PipelineServeExecutor:
             nonlocal sharded
             if sharded is None:
                 specs = self._param_specs(params)
-                sharded = _shard_map(
+                sharded = jax.shard_map(
                     local_prefill, mesh=self.mesh,
                     in_specs=(specs, P(ax), P(ax), P(), P(), P(), P(), P()),
                     out_specs=(P(ax), P(ax), P()),
-                    axis_names={ax})
+                    axis_names={ax}, check_vma=False)
             if start_pos is None:
                 start_pos = jnp.zeros((tokens.shape[0],), jnp.int32)
             if adapter_ids is None:

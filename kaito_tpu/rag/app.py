@@ -449,9 +449,9 @@ def make_server(cfg: RAGConfig, host: str = "0.0.0.0",
 
 
 def main(argv=None):
-    from kaito_tpu.utils.platform import apply_platform_env
+    from kaito_tpu.utils.platform import enable_compile_cache
 
-    apply_platform_env()   # local JAX embedder must honor JAX_PLATFORMS
+    enable_compile_cache()
     ap = argparse.ArgumentParser(prog="kaito-tpu-rag")
     ap.add_argument("--port", type=int, default=0)
     ap.add_argument("--host", default="0.0.0.0")

@@ -166,6 +166,26 @@ MACHINE_TYPES: Mapping[str, tuple[str, int]] = {
 
 _ACCELERATOR_TO_GEN = {spec.accelerator_label: gen for gen, spec in CHIP_CATALOG.items()}
 
+# jax's Device.device_kind for each generation in the catalog
+_DEVICE_KIND_TO_GEN = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+}
+
+
+def chip_for_device_kind(device_kind: str) -> TPUChipSpec:
+    """Peaks for the device jax reports.  A device that is not in the
+    table is an error, never a default chip."""
+    try:
+        return CHIP_CATALOG[_DEVICE_KIND_TO_GEN[device_kind]]
+    except KeyError:
+        raise KeyError(
+            f"no chip spec for device kind {device_kind!r} (known: "
+            f"{', '.join(sorted(_DEVICE_KIND_TO_GEN))})") from None
+
 
 @dataclass(frozen=True)
 class TPUSliceSpec:

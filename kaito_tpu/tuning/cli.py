@@ -56,9 +56,9 @@ def parse_args(argv=None) -> TrainConfig:
 
 
 def main(argv=None):
-    from kaito_tpu.utils.platform import apply_platform_env
+    from kaito_tpu.utils.platform import enable_compile_cache
 
-    apply_platform_env()
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     cfg = parse_args(argv)
     import jax

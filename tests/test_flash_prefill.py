@@ -118,3 +118,19 @@ def test_flash_rejects_misaligned_chunk():
             q, k, v, jnp.asarray([48, 48], jnp.int32),
             jnp.asarray(BIG, jnp.int32), scale=1.0,
             block_q=32, block_k=32, interpret=True)
+
+
+def test_chunk_too_long_for_vmem_is_refused_by_name():
+    """Both kernels keep a head's whole K and V in VMEM; a chunk past
+    the budget is a ValueError here, not a Mosaic allocation failure."""
+    T = 16384                      # 16 MiB of bf16 K/V at D=128
+    q = jnp.zeros((1, T, 2, 128), jnp.bfloat16)
+    k = jnp.zeros((1, T, 1, 128), jnp.bfloat16)
+    ids = jnp.zeros((1, T), jnp.int32)
+    win = jnp.asarray(1 << 30, jnp.int32)
+    with pytest.raises(ValueError, match="VMEM"):
+        flash_prefill_attention(q, k, k, jnp.asarray([T], jnp.int32), win,
+                                scale=1.0, interpret=True)
+    with pytest.raises(ValueError, match="VMEM"):
+        flash_prefill_packed(q, k, k, ids, ids, win, scale=1.0,
+                             interpret=True)

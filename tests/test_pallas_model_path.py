@@ -46,3 +46,40 @@ def test_pallas_path_matches_jax_path():
             params, cache_b, jnp.asarray([5, 6], jnp.int32), positions, pt)
         np.testing.assert_allclose(np.asarray(pl_d), np.asarray(ref_d),
                                    rtol=3e-4, atol=3e-4)
+
+
+def _greedy_tp2(use_pallas, kv_dtype, prompts):
+    """Greedy tokens from a TP=2 engine stepped on THIS thread (the
+    interpret-mode switch is thread-local, so no engine.start())."""
+    from kaito_tpu.engine.config import EngineConfig
+    from kaito_tpu.engine.engine import InferenceEngine, SamplingParams
+
+    eng = InferenceEngine(EngineConfig(
+        model="tiny-llama-test", max_model_len=256, page_size=PS,
+        max_num_seqs=4, dtype="float32", kv_dtype=kv_dtype,
+        prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
+        tensor_parallel=2, use_pallas=use_pallas,
+        enable_prefix_caching=False, seed=0))
+    assert (eng.model.head_shard is not None) == use_pallas
+    p = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    reqs = [eng.submit(list(t), p) for t in prompts]
+    for _ in range(400):
+        if all(r.finish_reason for r in reqs):
+            break
+        eng.step()
+    assert all(r.finish_reason == "length" for r in reqs)
+    return [list(r.output_tokens) for r in reqs]
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_pallas_path_under_tp_mesh_matches_jax_path(cpu_devices, kv_dtype):
+    """On a mesh the kernels run per head shard under shard_map (a
+    Mosaic call is never auto-partitioned): packed prefill (20+33
+    tokens in one 64-token round), chunked context prefill (100 tokens)
+    and batched decode must all match the pure-JAX engine."""
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(3, 2000, size=n).tolist() for n in (20, 33, 100)]
+    want = _greedy_tp2(False, kv_dtype, prompts)
+    with pltpu.force_tpu_interpret_mode():
+        got = _greedy_tp2(True, kv_dtype, prompts)
+    assert got == want

@@ -45,13 +45,17 @@ def test_health_and_models(served):
     url, _ = served
     health = json.loads(_get(url, "/health").read())
     assert health["status"] == "ok"
-    # the engine always publishes its HBM sizing decision (source is
-    # "measured" when the backend reports memory stats, else "static";
-    # CPU test engines size from the seq cap and may omit it)
-    sizing = health.get("hbm_sizing")
-    if sizing:
-        assert sizing["source"] in ("measured", "static", "seq-cap")
-        assert sizing["pages"] >= 2
+    # the engine always publishes its HBM sizing decision ("measured"
+    # on an accelerator, "seq-cap" on the CPU, "configured" with
+    # max_pages) and says where and how it runs
+    assert health["hbm_sizing"]["source"] in ("measured", "seq-cap",
+                                              "configured")
+    assert health["hbm_sizing"]["pages"] >= 2
+    assert health["platform"] == "cpu" and health["device_count"] >= 1
+    assert health["attention"] == "jax"
+    assert health["prefix_cache"] in ("native", "off")
+    assert {"id", "bytes_in_use", "peak_bytes_in_use",
+            "bytes_limit"} <= set(health["devices"][0])
     models = json.loads(_get(url, "/v1/models").read())
     assert models["data"][0]["id"] == "tiny"
 
