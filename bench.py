@@ -455,7 +455,6 @@ def _bench_serving_once(model_name: str, on_tpu: bool, quant: str,
                         spec_temp: float = 0.0) -> dict:
     from kaito_tpu.engine.config import EngineConfig
     from kaito_tpu.engine.engine import InferenceEngine, SamplingParams
-    from kaito_tpu.utils.tracing import decode_gap_summary
 
     if on_tpu:
         prompt_len, out_toks = 128, 256
@@ -578,18 +577,11 @@ def _bench_serving_once(model_name: str, on_tpu: bool, quant: str,
         eng.stop()
         for t in threads:
             t.join(timeout=10)
-    # decode-loop bubble position (docs/decode-loop.md): how much of the
-    # decode wall clock the device spent waiting on host dispatch.
-    # Schema-stable — both columns read 0.0 when the async loop is off
-    # (no timeline record carries the dispatch_gap span).
-    idle_pct, gap_ms = decode_gap_summary(eng.timeline.records())
     out = {
         "server_tok_s": round(tok_s, 1),
         "server_tpm": round(tok_s * 60.0),
         "server_batch": max_seqs,
         "server_out_toks": out_toks,
-        "device_idle_pct": round(idle_pct, 2),
-        "dispatch_gap_ms": round(gap_ms, 3),
     }
     out.update(_devprof_pcts(eng))
     out.update(_itl_metrics(eng))

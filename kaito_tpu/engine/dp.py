@@ -100,8 +100,12 @@ class DataParallelEngine:
         for e in self.engines[1:]:
             e.step_hist = first.step_hist
             e.queue_wait_hist = first.queue_wait_hist
+            e.phase_hists = first.phase_hists
         self.step_hist = first.step_hist
         self.queue_wait_hist = first.queue_wait_hist
+        self.phase_hists = first.phase_hists
+        # handler threads use only its annotate (no shared totals)
+        self.phases = first.phases
         self._rr = 0
         self._lock = threading.Lock()
         logger.info("data-parallel serving: %d groups x %d device(s)",

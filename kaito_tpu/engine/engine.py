@@ -52,7 +52,8 @@ from kaito_tpu.estimator.estimator import PER_CHIP_OVERHEAD_BYTES, HBM_UTILIZATI
 from kaito_tpu.models.metadata import ModelMetadata
 from kaito_tpu.models.registry import get_model_by_name
 from kaito_tpu.utils.failpoints import FAILPOINTS
-from kaito_tpu.utils.tracing import RingTracer, StepTimeline, format_span_tree
+from kaito_tpu.utils.tracing import (PhaseClock, RingTracer, StepTimeline,
+                                     format_span_tree)
 
 logger = logging.getLogger(__name__)
 
@@ -703,6 +704,32 @@ class InferenceEngine:
             "Submit-to-admission queue wait", None,
             buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                      0.5, 1.0, 2.5, 5.0, 10.0, 30.0))
+        # the step split into its phases (docs/observability.md): spans
+        # on the profiler's clock, and per non-idle iteration one
+        # observation per family, zero included, so that the means are
+        # per iteration and add up to engine_step_seconds
+        self.phases = PhaseClock(jax.profiler.TraceAnnotation)
+        self.phase_hists = {
+            phase: Histogram(f"kaito:engine_{family}_seconds", help_, None,
+                             buckets=self.step_hist.buckets)
+            for phase, family, help_ in (
+                ("engine.schedule", "schedule",
+                 "Per step: deadline sweep, page reservation, admission"),
+                ("engine.decode", "decode_step",
+                 "Per step: the decode dispatch, its readback and replay"),
+                ("engine.decode.dispatch", "decode_dispatch",
+                 "Per step: decode argument build up to the jitted "
+                 "call's return"),
+                ("engine.decode.wait", "decode_wait",
+                 "Per step: blocked on the decode readback"),
+                ("engine.decode.replay", "decode_replay",
+                 "Per step: replay of the decoded tokens through _emit"),
+                ("engine.prefill", "prefill_step",
+                 "Per step: prefill dispatch, first-token sampling and "
+                 "admission to decode"),
+                ("loop_stall", "loop_stall",
+                 "Per step: wall less thread CPU time over the phases "
+                 "that never block on the device"))}
         # packed prefill (docs/prefill.md): sequences per prefill
         # dispatch and staged-to-first-dispatch wait — the two numbers
         # that say whether concurrent arrivals are actually sharing
@@ -785,7 +812,7 @@ class InferenceEngine:
         self._dev_state: dict[str, object] = {}
         self._state_dirty: set[str] = set(self._STATE_FIELDS)
         self._decode_multi_state_fns: dict[int, object] = {}
-        self._inflight: Optional[tuple] = None  # (K, toks, acts, lps)
+        self._inflight: Optional[list] = None  # [K, toks, acts, lps]
         self._last_ready_t = 0.0
         self._gap_last = 0.0
         # fused-dispatch argument caches (built for both loops): the
@@ -1940,7 +1967,8 @@ class InferenceEngine:
                 self._fail_all()
                 continue
             if not did_work:
-                self._wake.wait(timeout=0.05)
+                with self.phases.annotate("engine.idle"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
 
     def _pop_waiting(self) -> Optional[Request]:
@@ -2285,11 +2313,21 @@ class InferenceEngine:
                   c["preemptions_total"], c["requests_expired_total"],
                   c["requests_shed_total"])
         t0 = time.monotonic()
-        did = self._step_inner()
+        with self.phases.phase("engine.step", n=self._tick,
+                               rows=self.num_running):
+            did = self._step_inner()
+        seconds, stall = self.phases.flush()
         if did:
             wall = time.monotonic() - t0
             self.step_hist.observe(wall)
-            extra = {}
+            seconds["loop_stall"] = stall
+            for phase, hist in self.phase_hists.items():
+                hist.observe(seconds.get(phase, 0.0))
+            # the same seconds on the record, engine.step left out
+            # (it is the record's own dur)
+            extra = {name.removeprefix("engine."): round(sec, 6)
+                     for name, sec in seconds.items()
+                     if name != "engine.step"}
             if self.async_dispatch:
                 # per-dispatch gap span (docs/decode-loop.md): host-side
                 # idle between the previous window's readback and this
@@ -2327,51 +2365,48 @@ class InferenceEngine:
         if self.async_dispatch:
             return self._step_async()
         did0 = False
-        now = time.monotonic()
-        # deadline sweep and export-registry GC are throttled: both are
-        # O(queue+slots) walks that would otherwise tax every iteration
-        # of the hot loop
-        if now - self._last_deadline_sweep >= 0.05:
-            self._last_deadline_sweep = now
-            did0 = self._expire_deadlines()
-        if now - self._last_export_tick >= 1.0:
-            self._last_export_tick = now
-            self.kv_exports.tick()
-        # ensure BEFORE admitting: growth of running sequences must not
-        # be starved by a fresh admission grabbing the last pages (which
-        # would be preempted right back — wasted churn)
-        la = 1
-        if self.active.any():
-            la = self._decode_lookahead()
-            self._ensure_decode_pages(la)
-        did = self._admit_new() or did0
-        if self._advance_imports():
-            did = True
-        decoding = bool(self.active.any())
+        phase = self.phases.phase
+        with phase("engine.schedule"):
+            now = time.monotonic()
+            # deadline sweep and export-registry GC are throttled: both
+            # are O(queue+slots) walks that would otherwise tax every
+            # iteration of the hot loop
+            if now - self._last_deadline_sweep >= 0.05:
+                self._last_deadline_sweep = now
+                did0 = self._expire_deadlines()
+            if now - self._last_export_tick >= 1.0:
+                self._last_export_tick = now
+                self.kv_exports.tick()
+            # ensure BEFORE admitting: growth of running sequences must
+            # not be starved by a fresh admission grabbing the last
+            # pages (which would be preempted right back — wasted churn)
+            la = 1
+            if self.active.any():
+                la = self._decode_lookahead()
+                self._ensure_decode_pages(la)
+            did = self._admit_new() or did0
+            if self._advance_imports():
+                did = True
+            decoding = bool(self.active.any())
+            spec = decoding and self._spec_ok()
+            if decoding and not spec:
+                la2 = self._plan_decode(la, did)
         steps_run = 0
+        if spec:
+            with phase("engine.decode", rows=self.num_running):
+                steps_run = self._decode_speculative()
+            if not steps_run:
+                with phase("engine.schedule"):
+                    la2 = self._plan_decode(la, did)
         if decoding:
-            spec_emitted = (self._decode_speculative()
-                            if self._spec_ok() else 0)
-            if spec_emitted:
-                steps_run = spec_emitted
-                did = True
-            else:
-                # recompute after admission: ensure-pages may have
-                # preempted (queue non-empty caps K at
-                # fused_under_load), and KV-import / spill-restore
-                # admissions begin decoding immediately — their slots
-                # post-date the reservation pass, so a fused dispatch
-                # must re-reserve lookahead pages first
-                la2 = self._decode_lookahead()
-                if la2 > 1:
-                    if did or la2 > la:
-                        self._ensure_decode_pages(la2)
-                    self._decode_multi(la2)
-                    steps_run = la2
-                else:
-                    self._decode_once()
-                    steps_run = 1
-                did = True
+            if not steps_run:
+                with phase("engine.decode", k=la2, rows=self.num_running):
+                    if la2 > 1:
+                        self._decode_multi(la2)
+                    else:
+                        self._decode_once()
+                steps_run = la2
+            did = True
         self._tick += 1
         # prefill cadence counts DECODE STEPS, not scheduler iterations:
         # a fused K-step dispatch advances the clock by K, so the
@@ -2380,10 +2415,24 @@ class InferenceEngine:
         self._decode_since_prefill += steps_run
         if (not decoding) or self.cfg.prefill_interleave <= 1 \
                 or self._decode_since_prefill >= self.cfg.prefill_interleave:
-            if self._advance_prefills():
+            with phase("engine.prefill"):
+                prefilled = self._advance_prefills()
+            if prefilled:
                 did = True
                 self._decode_since_prefill = 0
         return did
+
+    def _plan_decode(self, la: int, admitted: bool) -> int:
+        """How many steps the plain decode dispatch fuses, with their
+        pages reserved.  Recomputed after admission: ensure-pages may
+        have preempted (queue non-empty caps K at fused_under_load),
+        and KV-import / spill-restore admissions begin decoding
+        immediately — their slots post-date the reservation pass, so a
+        fused dispatch must re-reserve lookahead pages first."""
+        la2 = self._decode_lookahead()
+        if la2 > 1 and (admitted or la2 > la):
+            self._ensure_decode_pages(la2)
+        return la2
 
     def _admit_new(self) -> bool:
         """Fill every free slot from the waiting queue (bookkeeping
@@ -2825,37 +2874,30 @@ class InferenceEngine:
         chunk = tokens[pos: pos + budget]
         m = len(chunk)
         bucket = self._bucket(m)
-        ctoks = np.zeros((1, bucket), np.int32)
-        ctoks[0, :m] = chunk
-        aid = jnp.asarray(self.slot_adapters[i:i + 1])
         t_first_chunk = time.monotonic()
         try:
-            FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
-            if use_cp:
-                fn = self._prefill_cp_fn(bucket)
-                self.cache, logits = fn(self.params, self.cache,
-                                        jnp.asarray(ctoks),
-                                        jnp.asarray([m], np.int32),
-                                        jnp.asarray(self.page_tables[i][None]),
-                                        aid)
-            elif pos == 0 and m == n:
-                fn = self._prefill_fn(bucket)
-                self.cache, logits = fn(self.params, self.cache,
-                                        jnp.asarray(ctoks),
-                                        jnp.asarray([m], np.int32),
-                                        jnp.asarray(self.page_tables[i][None]),
-                                        aid)
-            else:
-                # chunk attends over the paged history (cached prefix +
-                # earlier chunks) — bounds per-step latency for long
-                # prompts (the feature vLLM gives the reference)
-                fn = self._prefill_ctx_fn(bucket)
-                self.cache, logits = fn(self.params, self.cache,
-                                        jnp.asarray(ctoks),
-                                        jnp.asarray([m], np.int32),
-                                        jnp.asarray(self.page_tables[i][None]),
-                                        jnp.asarray([pos], np.int32),
-                                        aid)
+            with self.phases.phase("engine.prefill.dispatch"):
+                ctoks = np.zeros((1, bucket), np.int32)
+                ctoks[0, :m] = chunk
+                aid = jnp.asarray(self.slot_adapters[i:i + 1])
+                args = (self.params, self.cache, jnp.asarray(ctoks),
+                        jnp.asarray([m], np.int32),
+                        jnp.asarray(self.page_tables[i][None]))
+                FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
+                if use_cp:
+                    fn = self._prefill_cp_fn(bucket)
+                    self.cache, logits = fn(*args, aid)
+                elif pos == 0 and m == n:
+                    fn = self._prefill_fn(bucket)
+                    self.cache, logits = fn(*args, aid)
+                else:
+                    # chunk attends over the paged history (cached
+                    # prefix + earlier chunks) — bounds per-step latency
+                    # for long prompts (the feature vLLM gives the
+                    # reference)
+                    fn = self._prefill_ctx_fn(bucket)
+                    self.cache, logits = fn(
+                        *args, jnp.asarray([pos], np.int32), aid)
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
             self._evict_slot(i, commit=False)
@@ -2886,7 +2928,8 @@ class InferenceEngine:
                 self.counters["prompt_tokens_total"] += len(req.prompt_tokens)
                 req.prompt_counted = True
             slot.prefilling = False
-            first, first_lp = self._sample_first(i, logits)
+            with self.phases.phase("engine.prefill.wait"):
+                first, first_lp = self._sample_first(i, logits)
             # _sample_first blocked on the logits, so the elapsed time
             # covers real compute (plus scheduler interleaving — the
             # honest opportunity cost a transfer would avoid)
@@ -2977,17 +3020,19 @@ class InferenceEngine:
         for gk, rows in groups:
             t0 = time.monotonic()
             try:
-                for (i, _, _, _) in rows:
-                    FAILPOINTS.fire("engine.prefill",
-                                    req_id=self.slots[i].request.req_id)
-                if gk[0] == "seg" and len(rows) > 1:
-                    logits = self._dispatch_prefill_packed(rows)
-                elif gk[0] == "ctx":
-                    logits = self._dispatch_prefill_ctx(rows)
-                else:
-                    # single fresh prompt or MLA fresh bucket: the
-                    # serial scheduler's own jitted family, batched
-                    logits = self._dispatch_prefill_fresh(rows)
+                with self.phases.phase("engine.prefill.dispatch"):
+                    for (i, _, _, _) in rows:
+                        FAILPOINTS.fire(
+                            "engine.prefill",
+                            req_id=self.slots[i].request.req_id)
+                    if gk[0] == "seg" and len(rows) > 1:
+                        logits = self._dispatch_prefill_packed(rows)
+                    elif gk[0] == "ctx":
+                        logits = self._dispatch_prefill_ctx(rows)
+                    else:
+                        # single fresh prompt or MLA fresh bucket: the
+                        # serial scheduler's own jitted family, batched
+                        logits = self._dispatch_prefill_fresh(rows)
             except Exception as e:
                 logger.exception("prefill dispatch failed (%d slots)",
                                  len(rows))
@@ -3033,7 +3078,8 @@ class InferenceEngine:
                 rows_l = jnp.concatenate(
                     [lg[r:r + 1] for (_, _, lg, r) in completed], axis=0)
             idxs = [i for (i, _, _, _) in completed]
-            toks, lps = self._sample_first_batch(idxs, rows_l)
+            with self.phases.phase("engine.prefill.wait"):
+                toks, lps = self._sample_first_batch(idxs, rows_l)
             t_done = time.monotonic()
             for (i, n, _, _), tok, lp in zip(completed, toks, lps):
                 slot = self.slots[i]
@@ -3056,17 +3102,18 @@ class InferenceEngine:
         req = slot.request
         n = len(slot.prefill_tokens)
         bucket = self._bucket(n)
-        ctoks = np.zeros((1, bucket), np.int32)
-        ctoks[0, :n] = slot.prefill_tokens
-        aid = jnp.asarray(self.slot_adapters[i:i + 1])
         t0 = time.monotonic()
         try:
-            FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
-            fn = self._prefill_cp_fn(bucket)
-            self.cache, logits = fn(
-                self.params, self.cache, jnp.asarray(ctoks),
-                jnp.asarray([n], np.int32),
-                jnp.asarray(self.page_tables[i][None]), aid)
+            with self.phases.phase("engine.prefill.dispatch"):
+                ctoks = np.zeros((1, bucket), np.int32)
+                ctoks[0, :n] = slot.prefill_tokens
+                aid = jnp.asarray(self.slot_adapters[i:i + 1])
+                FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
+                fn = self._prefill_cp_fn(bucket)
+                self.cache, logits = fn(
+                    self.params, self.cache, jnp.asarray(ctoks),
+                    jnp.asarray([n], np.int32),
+                    jnp.asarray(self.page_tables[i][None]), aid)
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
             self._evict_slot(i, commit=False)
@@ -3094,7 +3141,8 @@ class InferenceEngine:
             self.counters["prompt_tokens_total"] += len(req.prompt_tokens)
             req.prompt_counted = True
         slot.prefilling = False
-        first, first_lp = self._sample_first(i, logits)
+        with self.phases.phase("engine.prefill.wait"):
+            first, first_lp = self._sample_first(i, logits)
         if slot.prefill_t0:
             self.pd_costs.note_prefill(n - slot.prefill_base,
                                        time.monotonic() - slot.prefill_t0)
@@ -3715,7 +3763,9 @@ class InferenceEngine:
                 st = gs.grammar.advance(st, int(p[j]))
         return row
 
-    def _decode_once(self):
+    def _launch_decode_once(self) -> list:
+        """Dispatch one decode step; returns its device outputs
+        [next_tokens, lps]."""
         counts_in, seen = self._penalty_args()
         gmask, gtrans, gstate = self._grammar_args()
         cache, sampling, counts, next_tokens, lps = self._decode_fn(
@@ -3731,18 +3781,31 @@ class InferenceEngine:
         if self.token_counts is not None:
             self.token_counts = counts
         self.counters["decode_steps_total"] += 1
+        return [next_tokens, lps]
+
+    def _decode_once(self):
+        # Releasing a device array yields the interpreter lock, and the
+        # handler threads that a replay has just woken take it: each
+        # array dies inside the phase that made it wait, never between
+        # phases — the launch's temporaries when the launch returns,
+        # the outputs at the end of the replay that read them.
+        phase = self.phases.phase
+        with phase("engine.decode.dispatch"):
+            out = self._launch_decode_once()
         # one bulk D2H + tolist per dispatch: the replay loop then works
         # on Python ints/floats instead of paying a scalar conversion
         # per token
-        toks = np.asarray(next_tokens).tolist()
-        lps = np.asarray(lps).tolist()
-        for i, slot in enumerate(self.slots):
-            if not self.active[i]:
-                continue
-            self.positions[i] += 1
-            slot.position += 1
-            self._emit(i, toks[i], logprob=lps[i])
-            self.last_tokens[i] = toks[i]
+        with phase("engine.decode.wait"):
+            toks, lps = (np.asarray(a).tolist() for a in out)
+        with phase("engine.decode.replay"):
+            for i, slot in enumerate(self.slots):
+                if not self.active[i]:
+                    continue
+                self.positions[i] += 1
+                slot.position += 1
+                self._emit(i, toks[i], logprob=lps[i])
+                self.last_tokens[i] = toks[i]
+            out.clear()
 
     def _decode_lookahead(self) -> int:
         """How many decode steps the next dispatch may fuse.  Full
@@ -3812,6 +3875,13 @@ class InferenceEngine:
         """One fused K-step decode dispatch; replay the emitted-token
         trace through the single-step _emit path (stop handling,
         eviction, streaming) on the host."""
+        with self.phases.phase("engine.decode.dispatch"):
+            win = self._launch_decode_multi(K)
+        self._retire_window(win)
+
+    def _launch_decode_multi(self, K: int) -> list:
+        """Dispatch one fused K-step window; returns it as
+        [K, toks, acts, lps], the three still on the device."""
         fn = self._decode_multi_fns.get(K)
         if fn is None:
             fn = self._decode_multi_fns[K] = self._build_decode_multi_fn(K)
@@ -3833,8 +3903,7 @@ class InferenceEngine:
         if self.token_counts is not None:
             self.token_counts = counts
         self.counters["decode_steps_total"] += K
-        self._replay_window(K, np.asarray(toks), np.asarray(acts),
-                            np.asarray(lps))
+        return [K, toks, acts, lps]
 
     def _replay_window(self, K: int, toks, acts, lps):
         """Replay one fused window's [K, S] trace through the
@@ -3919,17 +3988,21 @@ class InferenceEngine:
         self._state_dirty.clear()
         return self._dev_state
 
-    def _retire_window(self, win) -> None:
+    def _retire_window(self, win: list) -> None:
         """Block on window N's readback and replay its trace through
-        the normal _emit path.  By the time this runs, window N+1 is
-        usually already executing on device — the block overlaps its
-        compute instead of serializing with it."""
-        K, toks, acts, lps = win
-        toks = np.asarray(toks)      # blocks until the readback lands
-        acts = np.asarray(acts)
-        lps = np.asarray(lps)
+        the normal _emit path.  In the async loop window N+1 is usually
+        already executing on device by the time this runs — the block
+        overlaps its compute instead of serializing with it; the
+        synchronous fused path retires the window it just dispatched.
+        The window is taken over: ``win`` is emptied at the end of the
+        replay, so its device arrays die there (see _decode_once)."""
+        with self.phases.phase("engine.decode.wait"):
+            # blocks until the readback lands
+            host = [np.asarray(a) for a in win[1:]]
         self._last_ready_t = time.monotonic()
-        self._replay_window(K, toks, acts, lps)
+        with self.phases.phase("engine.decode.replay"):
+            self._replay_window(win[0], *host)
+            win.clear()
 
     def _drain_pipeline(self) -> None:
         """Retire any in-flight window (pipeline back to depth 1).
@@ -3988,38 +4061,41 @@ class InferenceEngine:
             # state back (double-granted budget, replayed positions).
             # Reconcile first, then upload.
             self._drain_pipeline()
-        stop_dev = self._stop_matrix()
-        state = self._device_state()
-        counts_in, seen = self._penalty_args()
-        gmask, gtrans, _ = self._grammar_args()
-        t_dispatch = time.monotonic()
-        # device-idle gap: only the unprimed case exposes latency — a
-        # primed pipeline has window N still running while we are here
-        gap = (max(0.0, t_dispatch - self._last_ready_t)
-               if self._inflight is None and self._last_ready_t else 0.0)
-        cache, sampling, counts, toks, acts, lps, carry = fn(
-            self.params, self.cache, self.sampling, counts_in, seen,
-            state["last_tokens"], state["positions"],
-            state["page_tables"], state["active"],
-            state["slot_adapters"], stop_dev, state["left"],
-            gmask, gtrans, state["gstate"])
-        self.cache = cache
-        self.sampling = sampling
-        if self.token_counts is not None:
-            self.token_counts = counts
-        nxt, pos, act, left, gst = carry
-        self._dev_state.update(last_tokens=nxt, positions=pos, active=act,
-                               left=left, gstate=gst)
-        for arr in (toks, acts, lps):
-            try:
-                arr.copy_to_host_async()
-            except Exception:      # backend without async copies
-                pass
-        self.counters["decode_steps_total"] += K
+        with self.phases.phase("engine.decode.dispatch"):
+            stop_dev = self._stop_matrix()
+            state = self._device_state()
+            counts_in, seen = self._penalty_args()
+            gmask, gtrans, _ = self._grammar_args()
+            t_dispatch = time.monotonic()
+            # device-idle gap: only the unprimed case exposes latency —
+            # a primed pipeline has window N still running while we are
+            # here
+            gap = (max(0.0, t_dispatch - self._last_ready_t)
+                   if self._inflight is None and self._last_ready_t
+                   else 0.0)
+            cache, sampling, counts, toks, acts, lps, carry = fn(
+                self.params, self.cache, self.sampling, counts_in, seen,
+                state["last_tokens"], state["positions"],
+                state["page_tables"], state["active"],
+                state["slot_adapters"], stop_dev, state["left"],
+                gmask, gtrans, state["gstate"])
+            self.cache = cache
+            self.sampling = sampling
+            if self.token_counts is not None:
+                self.token_counts = counts
+            nxt, pos, act, left, gst = carry
+            self._dev_state.update(last_tokens=nxt, positions=pos,
+                                   active=act, left=left, gstate=gst)
+            for arr in (toks, acts, lps):
+                try:
+                    arr.copy_to_host_async()
+                except Exception:      # backend without async copies
+                    pass
+            self.counters["decode_steps_total"] += K
         self._gap_last = gap
         if self.dispatch_gap_hist is not None:
             self.dispatch_gap_hist.observe(gap)
-        prev, self._inflight = self._inflight, (K, toks, acts, lps)
+        prev, self._inflight = self._inflight, [K, toks, acts, lps]
         if prev is not None:
             self._retire_window(prev)
 
@@ -4028,76 +4104,85 @@ class InferenceEngine:
         schedule, but fused dispatches go through the two-deep pipeline
         and host work for window N runs while window N+1 computes."""
         did0 = False
-        now = time.monotonic()
-        if now - self._last_deadline_sweep >= 0.05:
-            self._last_deadline_sweep = now
-            # queue expiry never touches device state; slot expiry
-            # evicts (reads written prefixes) — reconcile first
-            if self._inflight is not None and any(
-                    s.request is not None and s.request.deadline is not None
-                    for s in self.slots):
+        phase = self.phases.phase
+        # a drain inside a phase nests its own decode.wait and
+        # decode.replay spans there: the innermost span names the work
+        with phase("engine.schedule"):
+            now = time.monotonic()
+            if now - self._last_deadline_sweep >= 0.05:
+                self._last_deadline_sweep = now
+                # queue expiry never touches device state; slot expiry
+                # evicts (reads written prefixes) — reconcile first
+                if self._inflight is not None and any(
+                        s.request is not None
+                        and s.request.deadline is not None
+                        for s in self.slots):
+                    self._drain_pipeline()
+                did0 = self._expire_deadlines()
+            if now - self._last_export_tick >= 1.0:
+                self._last_export_tick = now
+                self.kv_exports.tick()
+            if self._must_drain():
                 self._drain_pipeline()
-            did0 = self._expire_deadlines()
-        if now - self._last_export_tick >= 1.0:
-            self._last_export_tick = now
-            self.kv_exports.tick()
-        if self._must_drain():
-            self._drain_pipeline()
-        pend = self._inflight[0] if self._inflight is not None else 0
-        la = 1
-        if self.active.any():
-            la = self._decode_lookahead()
-            if pend and not self._lookahead_fits(la + pend):
-                # reservation must also cover the window in flight;
-                # when the pool can't, fall back to depth 1 so
-                # _ensure_decode_pages may preempt safely
-                self._drain_pipeline()
-                pend = 0
-            self._ensure_decode_pages(la + pend)
-        did = self._admit_new() or did0
-        if self._advance_imports():
-            did = True
-        decoding = bool(self.active.any())
-        steps_run = 0
-        if decoding:
-            if self._needs_sync_decode():
-                self._drain_pipeline()
-                self._decode_once()
-                self._mark_state_dirty()
-                steps_run = 1
-            elif self._spec_ok():
-                # speculation windows depend on each window's accepted
-                # length — inherently depth-1, but it still reads the
-                # reconciled host mirrors
-                self._drain_pipeline()
-                steps_run = self._decode_speculative()
-                self._mark_state_dirty()
-            if steps_run:
-                did = True
-            elif bool(self.active.any()):
-                la2 = self._decode_lookahead()
-                pend = self._inflight[0] if self._inflight is not None \
-                    else 0
-                while la2 > 1 and not self._lookahead_fits(la2 + pend):
-                    la2 //= 2
-                if pend and not self._lookahead_fits(la2 + pend):
+            pend = self._inflight[0] if self._inflight is not None else 0
+            la = 1
+            if self.active.any():
+                la = self._decode_lookahead()
+                if pend and not self._lookahead_fits(la + pend):
+                    # reservation must also cover the window in flight;
+                    # when the pool can't, fall back to depth 1 so
+                    # _ensure_decode_pages may preempt safely
                     self._drain_pipeline()
                     pend = 0
-                if did or la2 + pend > la:
-                    self._ensure_decode_pages(la2 + pend)
-                self._decode_async(la2)
-                steps_run = la2
+                self._ensure_decode_pages(la + pend)
+            did = self._admit_new() or did0
+            if self._advance_imports():
                 did = True
+            decoding = bool(self.active.any())
+        steps_run = 0
+        if decoding:
+            with phase("engine.decode", rows=self.num_running):
+                if self._needs_sync_decode():
+                    self._drain_pipeline()
+                    self._decode_once()
+                    self._mark_state_dirty()
+                    steps_run = 1
+                elif self._spec_ok():
+                    # speculation windows depend on each window's
+                    # accepted length — inherently depth-1, but it still
+                    # reads the reconciled host mirrors
+                    self._drain_pipeline()
+                    steps_run = self._decode_speculative()
+                    self._mark_state_dirty()
+                if steps_run:
+                    did = True
+                elif bool(self.active.any()):
+                    la2 = self._decode_lookahead()
+                    pend = self._inflight[0] \
+                        if self._inflight is not None else 0
+                    while la2 > 1 and not self._lookahead_fits(la2 + pend):
+                        la2 //= 2
+                    if pend and not self._lookahead_fits(la2 + pend):
+                        self._drain_pipeline()
+                        pend = 0
+                    if did or la2 + pend > la:
+                        self._ensure_decode_pages(la2 + pend)
+                    self._decode_async(la2)
+                    steps_run = la2
+                    did = True
         elif self._inflight is not None:
             # nothing left active on the host: the trailing window may
             # still hold final tokens — retire it now
-            self._drain_pipeline()
+            with phase("engine.decode"):
+                self._drain_pipeline()
             did = True
         self._tick += 1
         self._decode_since_prefill += steps_run
         if (not decoding) or self.cfg.prefill_interleave <= 1 \
                 or self._decode_since_prefill >= self.cfg.prefill_interleave:
-            if self._advance_prefills():
+            with phase("engine.prefill"):
+                prefilled = self._advance_prefills()
+            if prefilled:
                 did = True
                 self._decode_since_prefill = 0
         return did

@@ -13,6 +13,7 @@ docs/observability.md for the full inventory.
 
 from __future__ import annotations
 
+import bisect
 import os
 import threading
 import time
@@ -151,6 +152,17 @@ class Histogram:
         self._lock = threading.Lock()
         if registry is not None:
             registry.register(self)
+
+    def observe_many(self, values: Iterable[float]) -> None:
+        """Unlabelled observations under one acquisition of the lock:
+        for a hot path that can hand its values over in bulk (one per
+        streamed token, at the end of the stream)."""
+        idxs = [(bisect.bisect_left(self.buckets, v), v) for v in values]
+        with self._lock:
+            for idx, v in idxs:
+                self._sum += v
+                self._counts[idx] += 1
+            self._total += len(idxs)
 
     def observe(self, v: float, **labels):
         idx = len(self.buckets)
@@ -350,6 +362,13 @@ class EngineMetrics:
                      0.5, 1.0))
         self.e2e_latency = Histogram(
             "kaito:e2e_request_latency_seconds", "End-to-end request latency", r)
+        # one observation per streamed token (server._stream_chunk)
+        self.stream_chunk = Histogram(
+            "kaito:http_stream_chunk_seconds",
+            "Handler-thread time per streamed token: detokenize, "
+            "serialise, write", r,
+            buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
+                     0.005, 0.01, 0.025, 0.05, 0.1, 0.25))
         # process-level gauges: fleet rollups use uptime to tell a
         # restarted replica (counters reset, uptime tiny) from a quiet
         # one, and RSS to spot a leaking replica before the OOM-killer
@@ -370,6 +389,8 @@ class EngineMetrics:
                 h = getattr(engine, attr, None)
                 if h is not None:
                     r.register(h)
+            for h in getattr(engine, "phase_hists", {}).values():
+                r.register(h)
 
             # true per-token ITL (--itl): itl_hist is None when the
             # feature is off, so neither family exists and the

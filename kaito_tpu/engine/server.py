@@ -12,6 +12,7 @@ does the work; handler threads just stream queues.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import logging
 import os
@@ -38,6 +39,15 @@ logger = logging.getLogger(__name__)
 _PROFILE_LOCK = threading.Lock()
 
 
+def _clock_sync(st) -> None:
+    """A zero-length span that carries ``time.monotonic_ns()``: emitted
+    right after a trace starts and right before it stops, it places
+    whatever was stamped with ``time.monotonic()`` (the span ring, the
+    step timeline) on the trace's clock."""
+    with st.engine.phases.annotate("clock.sync", mono_ns=time.monotonic_ns()):
+        pass
+
+
 def _profile_auto_stop(st) -> None:
     """Timer target for /start_profile {"seconds": N}: stop the trace
     unless a manual /stop_profile already did."""
@@ -47,6 +57,7 @@ def _profile_auto_stop(st) -> None:
         if not getattr(st, "_profiling", False):
             return
         try:
+            _clock_sync(st)
             jax.profiler.stop_trace()
             logger.info("profiler trace auto-stopped")
         except Exception:
@@ -493,7 +504,12 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         """vLLM-parity profiler toggles (/start_profile, /stop_profile;
         the reference wrapper exposes them when the torch profiler dir
         is set) — TPU-native shape: a jax.profiler trace (XPlane/
-        perfetto) written under KAITO_PROFILE_DIR."""
+        perfetto) written under KAITO_PROFILE_DIR.  The trace holds the
+        device's operations and the serving loop's phase spans
+        (docs/observability.md); the per-call Python tracer hooks every
+        thread and slows the host code a trace is taken to size, so it
+        is on only for a body that says ``{"python_tracer": true}``.
+        ``_clock_sync`` marks both ends."""
         import jax
 
         st = self.state
@@ -502,14 +518,19 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         n = int(self.headers.get("Content-Length", "0") or 0)
         raw = self.rfile.read(n) if n else b""
         seconds = 0.0
+        python_tracer = False
         if start and raw:
             try:
-                seconds = float((json.loads(raw) or {}).get("seconds", 0))
+                opts = json.loads(raw) or {}
+                seconds = float(opts.get("seconds", 0))
+                python_tracer = opts.get("python_tracer", False)
             except (ValueError, json.JSONDecodeError, AttributeError,
                     TypeError):
                 return self._error(400, "invalid JSON body")
             if seconds < 0:
                 return self._error(400, "'seconds' must be >= 0")
+            if not isinstance(python_tracer, bool):
+                return self._error(400, "'python_tracer' must be a boolean")
         prof_dir = os.environ.get("KAITO_PROFILE_DIR", "/tmp/kaito-profile")
         with _PROFILE_LOCK:
             active = getattr(st, "_profiling", False)
@@ -517,7 +538,11 @@ class OpenAIHandler(BaseHTTPRequestHandler):
                 if start:
                     if active:
                         return self._error(409, "profiler already running")
-                    jax.profiler.start_trace(prof_dir)
+                    options = jax.profiler.ProfileOptions()
+                    options.python_tracer_level = int(python_tracer)
+                    jax.profiler.start_trace(prof_dir,
+                                             profiler_options=options)
+                    _clock_sync(st)
                     st._profiling = True
                     if seconds:
                         # bounded capture: auto-stop after `seconds` so
@@ -545,6 +570,7 @@ class OpenAIHandler(BaseHTTPRequestHandler):
                 if timer is not None:
                     timer.cancel()
                     st._profile_timer = None
+                _clock_sync(st)
                 jax.profiler.stop_trace()
                 st._profiling = False
                 logger.info("profiler trace stopped")
@@ -1319,6 +1345,19 @@ class OpenAIHandler(BaseHTTPRequestHandler):
 
     # ---------------- generation ----------------
 
+    @contextlib.contextmanager
+    def _chunk_times(self):
+        """The seconds each streamed token took on this thread —
+        detokenize, serialise, write; the wait for the token outside —
+        handed to ``kaito:http_stream_chunk_seconds`` in one piece when
+        the stream ends: per token the loop pays two clock reads, an
+        append and its ``http.stream.chunk`` span, and no lock."""
+        seconds: list[float] = []
+        try:
+            yield seconds
+        finally:
+            self.state.metrics.stream_chunk.observe_many(seconds)
+
     def _stream_tool_calls(self, st, req, base, body, forced: bool):
         """SSE tail for chat requests with tools (the role delta is
         already sent).  Forced calls (tool_choice required/named) are
@@ -1343,14 +1382,19 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         if forced:
             parser = StreamingToolCallParser()
             sent = ""
-            for tok in req.stream():
-                ids.append(tok)
-                text = st.engine.tokenizer.decode(ids)
-                if text.endswith("�"):
-                    continue  # mid-codepoint; wait for more bytes
-                delta_text, sent = text[len(sent):], text
-                for d in parser.feed(delta_text):
-                    send({"tool_calls": [d]})
+            span = st.engine.phases.annotate
+            with self._chunk_times() as chunk_s:
+                for tok in req.stream():
+                    t0 = time.perf_counter()
+                    with span("http.stream.chunk", rid=self._rid):
+                        ids.append(tok)
+                        text = st.engine.tokenizer.decode(ids)
+                        # mid-codepoint: wait for more bytes
+                        if not text.endswith("�"):
+                            delta_text, sent = text[len(sent):], text
+                            for d in parser.feed(delta_text):
+                                send({"tool_calls": [d]})
+                    chunk_s.append(time.perf_counter() - t0)
             tail = st.engine.tokenizer.decode(ids)[len(sent):]
             for d in parser.feed(tail) + parser.finish():
                 send({"tool_calls": [d]})
@@ -1381,6 +1425,16 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             req.tenant, len(req.prompt_tokens) + len(req.output_tokens))
 
     def _completions(self, chat: bool):
+        """One completion or chat request.  ``http.request`` spans the
+        intake on this thread — parse, encode, ``submit`` — and is
+        closed by ``_serve_completion`` once the engine has the
+        request; waiting for tokens and streaming them is outside it."""
+        with contextlib.ExitStack() as intake:
+            intake.enter_context(self.state.engine.phases.annotate(
+                "http.request", rid=self._rid))
+            self._serve_completion(chat, intake)
+
+    def _serve_completion(self, chat: bool, intake: contextlib.ExitStack):
         st = self.state
         body = self._read_body()
         if body is None:
@@ -1713,6 +1767,7 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         session = self.headers.get("X-Kaito-Session", "").strip()
         if session:
             req.session = session[:128]
+        intake.close()
 
         # extra choices decode CONCURRENTLY with the first (one engine
         # request per choice, seeds offset from the pinned primary seed
@@ -1749,28 +1804,37 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             sent_text = ""
             ids: list[int] = []
             stopped = False
-            for tok in req.stream():
-                ids.append(tok)
-                text = st.engine.tokenizer.decode(ids)
-                if text.endswith("�"):
-                    continue  # mid-codepoint; wait for more bytes
-                delta = text[len(sent_text):]
-                sent_text = text
-                if stop_strs and any(s in sent_text for s in stop_strs):
-                    cut = min(sent_text.find(s) for s in stop_strs
-                              if s in sent_text)
-                    delta = sent_text[:cut][len(sent_text) - len(delta):]
-                    st.engine.abort(req)
-                    stopped = True
-                if delta:
-                    chunk = dict(base)
-                    chunk["choices"] = [{
-                        "index": 0,
-                        **({"delta": {"content": delta}} if chat else {"text": delta}),
-                        "finish_reason": None}]
-                    self._sse_send(chunk)
-                if stopped:
-                    break
+            span = st.engine.phases.annotate
+            with self._chunk_times() as chunk_s:
+                for tok in req.stream():
+                    t0 = time.perf_counter()
+                    with span("http.stream.chunk", rid=self._rid):
+                        ids.append(tok)
+                        text = st.engine.tokenizer.decode(ids)
+                        # mid-codepoint: wait for more bytes
+                        if not text.endswith("�"):
+                            delta = text[len(sent_text):]
+                            sent_text = text
+                            if stop_strs and any(s in sent_text
+                                                 for s in stop_strs):
+                                cut = min(sent_text.find(s)
+                                          for s in stop_strs
+                                          if s in sent_text)
+                                delta = sent_text[:cut][
+                                    len(sent_text) - len(delta):]
+                                st.engine.abort(req)
+                                stopped = True
+                            if delta:
+                                chunk = dict(base)
+                                chunk["choices"] = [{
+                                    "index": 0,
+                                    **({"delta": {"content": delta}}
+                                       if chat else {"text": delta}),
+                                    "finish_reason": None}]
+                                self._sse_send(chunk)
+                    chunk_s.append(time.perf_counter() - t0)
+                    if stopped:
+                        break
             # flush text withheld by the mid-codepoint guard
             if not stopped and ids:
                 tail = st.engine.tokenizer.decode(ids)[len(sent_text):]

@@ -1,7 +1,7 @@
 """Dependency-free request tracing + engine flight recorder.
 
-Two bounded recorders back the observability surface
-(docs/observability.md):
+Two bounded recorders and one phase clock back the observability
+surface (docs/observability.md):
 
 - ``RingTracer`` holds request-phase **spans** (queue wait, admission,
   prefill chunks, KV import/export, host spill/restore, decode) in a
@@ -10,8 +10,14 @@ Two bounded recorders back the observability surface
 - ``StepTimeline`` is the engine **flight recorder**: one bounded
   record per scheduler step (wall time, running/waiting, prefill vs
   decode tokens, KV page usage, preemptions, shed/expired counts).
+- ``PhaseClock`` puts the serving loop's **phases** on the profiler's
+  own clock (``jax.profiler.TraceAnnotation`` spans, so a device trace
+  shows what the host did in every device-idle gap) and sums their
+  seconds per scheduler iteration for the phase histograms and the
+  timeline record.  The annotation class is handed in by the engine:
+  this module still imports nothing outside the standard library.
 
-Both export as Chrome trace-event JSON (``/debug/trace`` and
+Both recorders export as Chrome trace-event JSON (``/debug/trace`` and
 ``/debug/timeline``) loadable directly in Perfetto / chrome://tracing.
 
 Trace identity rides the ``X-Request-Id`` header end to end: the DP
@@ -35,9 +41,8 @@ from dataclasses import dataclass, field
 from typing import Iterable, Optional
 
 __all__ = [
-    "Span", "RingTracer", "StepTimeline",
+    "Span", "RingTracer", "StepTimeline", "PhaseClock", "PhaseSpan",
     "chrome_trace", "timeline_trace", "format_span_tree",
-    "decode_gap_summary",
     "parse_traceparent", "sanitize_request_id", "make_request_id",
 ]
 
@@ -239,29 +244,79 @@ class StepTimeline:
             return len(self._records)
 
 
-def decode_gap_summary(records: Iterable[dict]) -> tuple[float, float]:
-    """``(device_idle_pct, mean_gap_ms)`` over the timeline records
-    that carry a ``dispatch_gap`` field (the async decode loop's
-    per-step dispatch-gap span, docs/decode-loop.md).
+class PhaseSpan:
+    """One phase of the clock's owner thread as a context manager: a
+    span in the profiler's trace for as long as the block runs, and its
+    ``seconds`` of wall time added to the clock's totals.  With ``cpu``
+    it also reads the thread's CPU clock, and ``stalled`` is the wall
+    time in which the thread did not run: for code that never blocks
+    on the device, time it wanted to run and could not — the
+    interpreter lock or the OS."""
 
-    ``device_idle_pct`` is total gap time over total step wall time for
-    decode steps — the fraction of the decode wall clock the device
-    spent waiting on the host.  Both are 0.0 when the async loop is off
-    (no record carries the field), so bench columns stay schema-stable
-    either way."""
-    gaps: list[float] = []
-    wall = 0.0
-    for rec in records:
-        g = rec.get("dispatch_gap")
-        if g is None or not rec.get("decode_steps", 0):
-            continue
-        gaps.append(float(g))
-        wall += float(rec.get("dur", 0.0))
-    if not gaps or wall <= 0.0:
-        return 0.0, 0.0
-    total_gap = sum(gaps)
-    return (min(100.0, 100.0 * total_gap / wall),
-            1e3 * total_gap / len(gaps))
+    __slots__ = ("name", "seconds", "stalled", "_ann", "_clock", "_cpu",
+                 "_t0", "_c0")
+
+    def __init__(self, clock, annotation, name: str, cpu: bool):
+        self.name = name
+        self.seconds = self.stalled = 0.0
+        self._ann = annotation
+        self._clock = clock
+        self._cpu = cpu
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._cpu:
+            self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        if self._cpu:
+            self.stalled = max(
+                0.0, self.seconds - (time.thread_time() - self._c0))
+        self._ann.__exit__(*exc)
+        self._clock._add(self)
+        return False
+
+
+class PhaseClock:
+    """The serving loop's phases, named once and used at every layer
+    boundary (docs/observability.md has the table of names).
+
+    ``annotate`` is ``jax.profiler.TraceAnnotation`` or anything with
+    its signature; with no trace running it costs a branch.  ``phase``
+    is for the thread that owns the clock (the engine loop): its
+    seconds add up in per-phase totals that ``flush`` hands over once
+    per iteration.  Any other thread (the HTTP handlers) opens
+    ``annotate(name, **attrs)`` itself: a span in the trace and no
+    shared state — a handler that wants seconds reads a clock."""
+
+    # phases that never block on the device: their stalled time is
+    # summed into the loop's stall
+    UNBLOCKED = frozenset(("engine.schedule", "engine.decode.dispatch",
+                           "engine.decode.replay", "engine.prefill.dispatch"))
+
+    def __init__(self, annotate):
+        self.annotate = annotate
+        self._totals: dict[str, float] = {}
+        self._stall = 0.0
+
+    def phase(self, name: str, **attrs) -> PhaseSpan:
+        return PhaseSpan(self, self.annotate(name, **attrs), name,
+                         name in self.UNBLOCKED)
+
+    def _add(self, span: PhaseSpan) -> None:
+        self._totals[span.name] = (self._totals.get(span.name, 0.0)
+                                   + span.seconds)
+        self._stall += span.stalled
+
+    def flush(self) -> tuple[dict[str, float], float]:
+        """``({phase: seconds}, loop_stall_seconds)`` since the last
+        flush; both start again from nothing."""
+        out = (self._totals, self._stall)
+        self._totals, self._stall = {}, 0.0
+        return out
 
 
 def timeline_trace(records: Iterable[dict],

@@ -20,8 +20,9 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
-from kaito_tpu.utils.tracing import (RingTracer, Span, StepTimeline,
-                                     chrome_trace, format_span_tree,
+from kaito_tpu.utils.tracing import (PhaseClock, RingTracer, Span,
+                                     StepTimeline, chrome_trace,
+                                     format_span_tree,
                                      make_request_id, parse_traceparent,
                                      sanitize_request_id, timeline_trace)
 
@@ -279,6 +280,261 @@ def test_router_counts_failures_and_retries():
     finally:
         rsrv.shutdown()
         srv.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# phase spans: the instrument, the engine loop, the SSE path, the
+# profiler endpoints (fast; the profiler itself is never started)
+# ---------------------------------------------------------------------------
+
+
+class SpanRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: the same
+    signature, and a log of (thread, depth, name, attrs, t0, t1)."""
+
+    def __init__(self):
+        self.log = []
+        self._depth = {}
+
+    def __call__(self, name, **attrs):
+        return _Recorded(self, name, attrs)
+
+    def of(self, name):
+        return [r for r in self.log if r["name"] == name]
+
+
+class _Recorded:
+    def __init__(self, rec, name, attrs):
+        self.rec = rec
+        self.row = {"name": name, "attrs": attrs,
+                    "thread": threading.get_ident()}
+
+    def __enter__(self):
+        tid = self.row["thread"]
+        self.row["depth"] = self.rec._depth.get(tid, 0)
+        self.rec._depth[tid] = self.row["depth"] + 1
+        self.rec.log.append(self.row)
+        self.row["t0"] = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.row["t1"] = time.perf_counter()
+        self.rec._depth[self.row["thread"]] -= 1
+        return False
+
+
+def test_phase_clock_sums_the_owner_thread_and_only_it():
+    rec = SpanRecorder()
+    clock = PhaseClock(rec)
+    with clock.phase("engine.schedule"):
+        time.sleep(0.02)            # blocked, not computing: a stall
+    with clock.phase("engine.decode", k=4, rows=2):
+        with clock.phase("engine.decode.wait"):
+            time.sleep(0.01)
+    with clock.phase("engine.schedule"):
+        pass
+    with clock.annotate("http.stream.chunk", rid="r1"):
+        time.sleep(0.005)
+    seconds, stall = clock.flush()
+    assert set(seconds) == {"engine.schedule", "engine.decode",
+                            "engine.decode.wait"}   # annotate adds nothing
+    assert seconds["engine.schedule"] >= 0.02
+    assert seconds["engine.decode"] >= seconds["engine.decode.wait"] >= 0.01
+    # schedule never blocks on the device, so its sleep is a stall; the
+    # wait phase blocks by design and is left out
+    assert 0.015 <= stall <= seconds["engine.schedule"]
+    assert clock.flush() == ({}, 0.0)
+    assert [r["name"] for r in rec.log] == [
+        "engine.schedule", "engine.decode", "engine.decode.wait",
+        "engine.schedule", "http.stream.chunk"]
+    assert rec.of("engine.decode")[0]["attrs"] == {"k": 4, "rows": 2}
+    assert rec.of("engine.decode.wait")[0]["depth"] == 1
+
+
+PHASE_FAMILIES = {
+    "engine.schedule": "kaito:engine_schedule_seconds",
+    "engine.decode": "kaito:engine_decode_step_seconds",
+    "engine.decode.dispatch": "kaito:engine_decode_dispatch_seconds",
+    "engine.decode.wait": "kaito:engine_decode_wait_seconds",
+    "engine.decode.replay": "kaito:engine_decode_replay_seconds",
+    "engine.prefill": "kaito:engine_prefill_step_seconds",
+    "loop_stall": "kaito:engine_loop_stall_seconds",
+}
+
+
+@pytest.fixture(scope="module")
+def phased():
+    """(engine, recorder): a tiny engine that is stepped by the test
+    or, once started, by its own thread; its spans go to the recorder."""
+    from kaito_tpu.engine.config import EngineConfig
+    from kaito_tpu.engine.engine import InferenceEngine
+
+    # prefill every iteration, so that one step can hold every phase
+    engine = InferenceEngine(EngineConfig(**E2E_CFG, prefill_interleave=1))
+    rec = engine.phases.annotate = SpanRecorder()
+    yield engine, rec
+    engine.stop()
+
+
+def _contains(outer, inner):
+    return (outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"]
+            and inner["depth"] == outer["depth"] + 1)
+
+
+def test_one_step_emits_each_phase_once_and_they_add_up(phased):
+    from kaito_tpu.engine.engine import SamplingParams
+
+    engine, rec = phased
+    assert set(engine.phase_hists) == set(PHASE_FAMILIES)
+    for phase, family in PHASE_FAMILIES.items():
+        assert engine.phase_hists[phase].name == family
+    params = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
+    engine.submit(list(range(5, 25)), params)
+    assert engine.step()                     # admits and prefills the first
+    engine.submit(list(range(7, 60)), params)
+    rec.log.clear()
+    steps0 = engine.step_hist._total
+    assert engine.step()      # admits the second, decodes the first, prefills
+    names = [r["name"] for r in rec.log]
+    want = ["engine.step", "engine.schedule", "engine.decode",
+            "engine.decode.dispatch", "engine.decode.wait",
+            "engine.decode.replay", "engine.prefill",
+            "engine.prefill.dispatch", "engine.prefill.wait"]
+    assert names == want, names             # each once, in the loop's order
+    by = {r["name"]: r for r in rec.log}
+    for parent, children in (
+            ("engine.step", ("engine.schedule", "engine.decode",
+                             "engine.prefill")),
+            ("engine.decode", ("engine.decode.dispatch",
+                               "engine.decode.wait", "engine.decode.replay")),
+            ("engine.prefill", ("engine.prefill.dispatch",
+                                "engine.prefill.wait"))):
+        for child in children:
+            assert _contains(by[parent], by[child]), (parent, child)
+    assert by["engine.step"]["attrs"].keys() == {"n", "rows"}
+    assert by["engine.decode"]["attrs"] == {"k": 1, "rows": 1}
+
+    rec_ = engine.timeline.records()[-1]
+    step_s = rec_["dur"]
+    parts = rec_["decode.dispatch"] + rec_["decode.wait"] + rec_["decode.replay"]
+    assert parts == pytest.approx(rec_["decode"], rel=0.03)
+    assert (rec_["schedule"] + rec_["decode"] + rec_["prefill"]
+            == pytest.approx(step_s, rel=0.03))
+    assert rec_["prefill.dispatch"] + rec_["prefill.wait"] <= rec_["prefill"]
+    assert 0.0 <= rec_["loop_stall"] <= step_s
+    # one observation per family per non-idle step, zero included
+    assert engine.step_hist._total == steps0 + 1
+    for hist in engine.phase_hists.values():
+        assert hist._total == engine.step_hist._total
+    assert engine.phase_hists["engine.decode"]._sum == pytest.approx(
+        sum(r.get("decode", 0.0) for r in engine.timeline.records()),
+        abs=1e-4)
+
+
+def test_an_idle_poll_observes_nothing(phased):
+    engine, rec = phased
+    while engine.step():
+        pass
+    counts = {p: h._total for p, h in engine.phase_hists.items()}
+    records = len(engine.timeline)
+    rec.log.clear()
+    assert not engine.step()
+    assert {r["name"] for r in rec.log} <= {"engine.step", "engine.schedule",
+                                            "engine.prefill"}
+    assert {p: h._total for p, h in engine.phase_hists.items()} == counts
+    assert len(engine.timeline) == records
+
+
+@pytest.fixture(scope="module")
+def phased_server(phased):
+    from kaito_tpu.engine.server import make_server
+
+    engine, rec = phased
+    engine.start()
+    server = make_server(engine, engine.cfg, host="127.0.0.1", port=0)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    yield server, f"http://127.0.0.1:{server.server_address[1]}", rec
+    server.shutdown()
+    server.server_close()
+
+
+def test_streaming_emits_request_and_chunk_spans(phased_server):
+    server, url, rec = phased_server
+    rec.log.clear()
+    chunks0 = server.state.metrics.stream_chunk._total
+    with _post(url, "/v1/completions",
+               {"prompt": "stream me", "max_tokens": 6, "temperature": 0.0,
+                "ignore_eos": True, "stream": True},
+               headers={"X-Request-Id": "phase-rid-1"}) as r:
+        body = r.read().decode()
+    assert body.rstrip().endswith("data: [DONE]")
+    request = rec.of("http.request")
+    chunks = rec.of("http.stream.chunk")
+    assert len(request) == 1 and request[0]["attrs"] == {"rid": "phase-rid-1"}
+    assert len(chunks) == 6                      # one per generated token
+    assert all(c["attrs"] == {"rid": "phase-rid-1"} for c in chunks)
+    # intake ends at submit: no chunk is inside it, and it is shorter
+    # than the request
+    assert all(c["t0"] >= request[0]["t1"] for c in chunks)
+    assert all(c["thread"] == request[0]["thread"] for c in chunks)
+    assert server.state.metrics.stream_chunk._total == chunks0 + 6
+    # the engine's own thread idles in a span of its own
+    assert rec.of("engine.idle") or rec.of("engine.step")
+
+
+def test_new_families_are_unlabelled_on_metrics(phased_server):
+    """The benchmark's scrape keeps only unlabelled samples."""
+    _, url, _ = phased_server
+    with urllib.request.urlopen(url + "/metrics", timeout=30) as r:
+        lines = r.read().decode().splitlines()
+    for family in list(PHASE_FAMILIES.values()) + [
+            "kaito:http_stream_chunk_seconds"]:
+        assert f"# TYPE {family} histogram" in lines, family
+        for suffix in ("_sum", "_count"):
+            mine = [ln for ln in lines if ln.startswith(family + suffix)]
+            assert len(mine) == 1 and "{" not in mine[0], (family, mine)
+    count = {ln.split()[0]: float(ln.split()[1]) for ln in lines
+             if ln.startswith("kaito:engine_") and "_count" in ln}
+    assert count["kaito:engine_schedule_seconds_count"] \
+        == count["kaito:engine_step_seconds_count"] > 0
+
+
+def test_profile_endpoints_sync_the_clock_and_gate_the_python_tracer(
+        phased_server, monkeypatch, tmp_path):
+    """No trace is taken: start_trace and stop_trace are recorded."""
+    import jax
+    import urllib.error
+
+    server, url, rec = phased_server
+    monkeypatch.setenv("KAITO_PROFILE_DIR", str(tmp_path))
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: calls.append(
+            ("start", d, profiler_options.python_tracer_level,
+             len(rec.of("clock.sync")))))
+    monkeypatch.setattr(
+        jax.profiler, "stop_trace",
+        lambda: calls.append(("stop", len(rec.of("clock.sync")))))
+    rec.log.clear()
+    for body, level in (({}, 0), ({"python_tracer": True}, 1),
+                        ({"python_tracer": False, "seconds": 0}, 0)):
+        calls.clear()
+        before = time.monotonic_ns()
+        _post(url, "/start_profile", body).read()
+        n = len(rec.of("clock.sync"))
+        # the mark comes right after start_trace returns ...
+        assert calls == [("start", str(tmp_path), level, n - 1)]
+        _post(url, "/stop_profile", {}).read()
+        # ... and right before stop_trace
+        assert calls[-1] == ("stop", n + 1)
+        marks = rec.of("clock.sync")[-2:]
+        assert before <= marks[0]["attrs"]["mono_ns"] \
+            <= marks[1]["attrs"]["mono_ns"] <= time.monotonic_ns()
+    with pytest.raises(urllib.error.HTTPError) as exc:
+        _post(url, "/start_profile", {"python_tracer": "yes"})
+    assert exc.value.code == 400
+    assert not server.state._profiling
 
 
 # ---------------------------------------------------------------------------
