@@ -153,13 +153,15 @@ qos:
 spec:
 	$(PYTHON) -m pytest tests/test_speculative.py tests/test_spec_draft.py -q
 
-# zero-bubble decode loop (docs/decode-loop.md): the dedicated async
-# suite, then the fused-decode engine tier once more with
-# KAITO_ASYNC_DISPATCH=1 (engines built with the default config resolve
-# the env gate) so the gated pipeline path can't rot behind its
-# off-by-default flag
+# two-deep decode dispatch loop (docs/decode-loop.md; the default on an
+# accelerator, off on the CPU backend these tests run on): the
+# sustained-admission suite and the older one, then the fused-decode
+# engine tier once more with KAITO_ASYNC_DISPATCH=1 (engines built with
+# the default config resolve the env gate), so the path a chip serves
+# through is exercised here too
 asyncloop:
-	$(PYTHON) -m pytest tests/test_async_dispatch.py -q
+	$(PYTHON) -m pytest tests/test_decode_pipeline.py \
+	  tests/test_async_dispatch.py -q
 	KAITO_ASYNC_DISPATCH=1 $(PYTHON) -m pytest \
 	  tests/test_async_dispatch.py tests/test_decode_run_ahead.py -q
 

@@ -69,12 +69,14 @@ class EngineConfig:
     # decode_run_ahead so admissions and prefill chunks keep a bounded
     # latency; 0 restores the round-2 collapse-to-single-step behavior
     fused_under_load: int = 4
-    # zero-bubble decode loop (docs/decode-loop.md): device-resident
-    # loop state plus a two-deep dispatch pipeline that overlaps host
-    # postprocess (stop replay, streaming, scheduling) with device
-    # compute.  None = follow KAITO_ASYNC_DISPATCH (off when unset);
-    # True/False force it.  Off keeps the synchronous loop
-    # byte-identical to before (no new metric families).
+    # two-deep decode dispatch (docs/decode-loop.md): device-resident
+    # loop state, and window N+1 launched before window N is read back,
+    # so the host's postprocess (stop replay, streaming, scheduling)
+    # overlaps device compute.  None = resolved by the engine: on where
+    # the backend is an accelerator, single process, no PP executor; off
+    # on the CPU backend; KAITO_ASYNC_DISPATCH=1/0 pins it.  True/False
+    # force it (PP and multi-process still keep the synchronous loop,
+    # whose /metrics exposition has none of the loop's families).
     async_dispatch: Optional[bool] = None
     # collective-compute overlap for TP decode (docs/multichip.md):
     # decompose the row-parallel projections' output all-reduce into

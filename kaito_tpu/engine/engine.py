@@ -231,6 +231,18 @@ class _Slot:
         return self.prefill_pos if self.prefilling else self.position
 
 
+@jax.jit
+def _patch_carry_row(tokens, positions, active, left, gstate, row):
+    """Write one slot's row of the decode carry (docs/decode-loop.md):
+    ``row`` is int32 [slot, last token, position, budget left, grammar
+    row]; the slot becomes active, every other row keeps what the
+    device has advanced it to."""
+    i = row[0]
+    return (tokens.at[i].set(row[1]), positions.at[i].set(row[2]),
+            active.at[i].set(True), left.at[i].set(row[3]),
+            gstate.at[i].set(row[4]))
+
+
 class InferenceEngine:
     """Synchronous engine core; the HTTP server drives it via a thread."""
 
@@ -249,8 +261,8 @@ class InferenceEngine:
     # pipeline drain-free like the rest of the loop state.
     _STATE_FIELDS = ("last_tokens", "positions", "active", "page_tables",
                      "slot_adapters", "left", "gstate")
-    _DEVICE_ADVANCED = frozenset(("last_tokens", "positions", "active",
-                                  "left", "gstate"))
+    _CARRY_FIELDS = ("last_tokens", "positions", "active", "left", "gstate")
+    _DEVICE_ADVANCED = frozenset(_CARRY_FIELDS)
 
     def __init__(
         self,
@@ -784,20 +796,35 @@ class InferenceEngine:
         self.run_ahead = max(1, int(ra))
         self._decode_multi_fns: dict[int, object] = {}
 
-        # zero-bubble decode loop (docs/decode-loop.md): device-resident
-        # loop state + a two-deep dispatch pipeline.  Off by default —
-        # the synchronous loop (and the /metrics exposition) stays
-        # byte-identical; None follows KAITO_ASYNC_DISPATCH.  PP drives
-        # decode through its own executor and multi-process engines run
-        # lockstep off the step broadcast, so both keep the sync loop.
-        ad = cfg.async_dispatch if getattr(cfg, "async_dispatch", None) \
-            is not None else (os.environ.get("KAITO_ASYNC_DISPATCH", "")
-                              in ("1", "true"))
+        # two-deep decode dispatch (docs/decode-loop.md): device-resident
+        # loop state, window N+1 launched before window N is read back.
+        # None resolves from what the engine can observe, like
+        # decode_run_ahead above: on where the backend is an accelerator
+        # (the host's turnaround is device idle there), off on the CPU
+        # backend; KAITO_ASYNC_DISPATCH=1/0 and an explicit True/False
+        # pin it.  PP drives decode through its own executor and
+        # multi-process engines run lockstep off the step broadcast, so
+        # both keep the synchronous loop whatever was asked.
+        ad = getattr(cfg, "async_dispatch", None)
+        if ad is None:
+            env = os.environ.get("KAITO_ASYNC_DISPATCH", "").strip().lower()
+            ad = (True if env in ("1", "true")
+                  else False if env in ("0", "false")
+                  else jax.default_backend() != "cpu")
         self.async_dispatch = (bool(ad) and self.pp_exec is None
                                and jax.process_count() == 1)
         self.dispatch_gap_hist = None
+        # drains of a window in flight, by what forced them; the
+        # step's own go on its timeline record
+        self.drain_counts: dict[str, int] = {}
+        self._step_drains: list[str] = []
         if self.async_dispatch:
+            self.drain_counts = dict.fromkeys(self._DRAIN_REASONS, 0)
             self.counters["h2d_uploads_total"] = 0
+            # a window is primed when another was in flight at its
+            # launch: the host's work for that one overlaps this one
+            self.counters["decode_windows_primed_total"] = 0
+            self.counters["decode_windows_unprimed_total"] = 0
             self.dispatch_gap_hist = Histogram(
                 "kaito:engine_dispatch_gap_seconds",
                 "Host-side gap between decode dispatches (device idle "
@@ -812,7 +839,10 @@ class InferenceEngine:
         self._dev_state: dict[str, object] = {}
         self._state_dirty: set[str] = set(self._STATE_FIELDS)
         self._decode_multi_state_fns: dict[int, object] = {}
-        self._inflight: Optional[list] = None  # [K, toks, acts, lps]
+        # [K, toks, acts, lps, owners]: the trace still on the device
+        # and each slot's owner (slot.seq) when the window was launched
+        self._inflight: Optional[list] = None
+        self._dirty_reason = ""
         self._last_ready_t = 0.0
         self._gap_last = 0.0
         # fused-dispatch argument caches (built for both loops): the
@@ -1931,6 +1961,13 @@ class InferenceEngine:
         self._wake.set()
         if self._thread:
             self._thread.join(timeout=30)
+        if self.async_dispatch:
+            # the labelled family is easy to lose in a scrape's
+            # reduction: the log keeps the reasons beside the windows
+            logger.info("decode windows: %d primed, %d unprimed; drains %s",
+                        self.counters["decode_windows_primed_total"],
+                        self.counters["decode_windows_unprimed_total"],
+                        {k: v for k, v in self.drain_counts.items() if v})
         if self._spill_thread is not None:
             self._spill_q.put(None)
             self._spill_thread.join(timeout=10)
@@ -2060,7 +2097,8 @@ class InferenceEngine:
             q = self._tenant_queues.get(tenant)
             return len(q) if q else 0
 
-    def _evict_slot(self, slot_idx: int, commit: bool = True):
+    def _evict_slot(self, slot_idx: int, commit: bool = True,
+                    device_done: bool = False):
         """Return a slot's pages to the pool and clear it.
 
         ``commit`` feeds the written-token prefix into the radix tree
@@ -2070,9 +2108,17 @@ class InferenceEngine:
         KV never lands (the slot retires before the next decode step
         would write it), so committing it would let a later prefix hit
         attend over a garbage page slot.
+
+        ``device_done``: the fused scan retired this slot itself (stop
+        id or spent budget), so the device carry already holds it
+        inactive and nothing the scan advances needs an upload — the
+        pipeline stays primed across the finish (docs/decode-loop.md).
         """
         slot = self.slots[slot_idx]
         req = slot.request
+        # a decoding slot only the host has finished (abort, deadline,
+        # preemption, failure) is still live in the device carry
+        device_live = bool(self.active[slot_idx]) and not device_done
         if self.prefix_cache is not None:
             # adapter KV must never enter the shared tree (it embeds the
             # adapter's k/v deltas); imports are foreign bytes
@@ -2119,7 +2165,9 @@ class InferenceEngine:
         self.active[slot_idx] = False
         self._remaining[slot_idx] = 0
         self._batch_epoch += 1
-        self._mark_state_dirty("active", "slot_adapters", "left")
+        self._mark_state_dirty("slot_adapters")
+        if device_live:
+            self._mark_state_dirty("active", "left", why="finish")
 
     def _fail_request(self, req: Request, status: int = 500,
                       etype: str = "internal_error",
@@ -2334,6 +2382,10 @@ class InferenceEngine:
                 # step's dispatch; ~0 whenever the pipeline was primed
                 extra["dispatch_gap"] = round(self._gap_last, 6)
                 self._gap_last = 0.0
+                if self._step_drains:
+                    # what took the pipeline to depth 1 in this step
+                    extra["drain"] = ",".join(self._step_drains)
+                    self._step_drains.clear()
             if self._prefill_pack_note:
                 # largest prefill pack dispatched this step — the
                 # /debug/timeline annotation for packed rounds
@@ -2452,6 +2504,13 @@ class InferenceEngine:
                     victim = (None if nxt is None
                               else self._newest_slot(below_priority=nxt))
                     if victim is not None:
+                        if self._inflight is not None:
+                            # the victim resumes from resume_tokens():
+                            # every token the device has produced for
+                            # it must be replayed first (and the drain
+                            # may free a slot by itself)
+                            self._drain_pipeline("admission")
+                            continue
                         self._preempt_slot(victim)
                         continue
                 return admitted
@@ -2628,6 +2687,7 @@ class InferenceEngine:
         decoding at the prompt boundary (no prefill compute)."""
         from kaito_tpu.engine.pd import import_kv
 
+        self._drain_pipeline("import")
         meta, payload, first = req.kv_import
         n = len(req.prompt_tokens)
         n_prompt_pages = -(-n // self.cfg.page_size)
@@ -2647,6 +2707,7 @@ class InferenceEngine:
         pages — the bytes never touch the host."""
         from kaito_tpu.engine.pd import import_arrays
 
+        self._drain_pipeline("import")
         meta, slabs, first = req.kv_device
         n = len(req.prompt_tokens)
         n_prompt_pages = -(-n // self.cfg.page_size)
@@ -3332,13 +3393,17 @@ class InferenceEngine:
         self.last_tokens[slot_idx] = first
         self._remaining[slot_idx] = slot.remaining
         self._batch_epoch += 1
-        self._mark_state_dirty("positions", "active", "last_tokens", "left")
         if req.first_token_time is None:
             req.first_token_time = time.monotonic()
         if req.params.has_penalties and self.token_counts is not None:
             self.token_counts = self.token_counts.at[
                 slot_idx, first].add(1)
         self._emit(slot_idx, first, logprob=first_lp)
+        if slot.request is req:
+            # still decoding after its first token: the device learns of
+            # the slot now (a request that ended on it never reaches
+            # the device at all)
+            self._join_device_batch(slot_idx)
 
     # ------------------------------------------------------------------
     # Page growth + preemption
@@ -3513,7 +3578,8 @@ class InferenceEngine:
         self.last_tokens[free_slot] = req.output_tokens[-1]
         self._remaining[free_slot] = slot.remaining
         self._batch_epoch += 1
-        self._mark_state_dirty("positions", "active", "last_tokens", "left")
+        self._mark_state_dirty("positions", "active", "last_tokens", "left",
+                               why="admission")
         logger.debug("restored %s: %d pages, resuming at %d",
                      req.req_id, n_pages, entry.written)
         return True
@@ -3599,6 +3665,11 @@ class InferenceEngine:
         victim = self._newest_slot(below_priority=req.priority)
         if victim is None:
             return False
+        if self._inflight is not None:
+            # as in _admit_new: replay before anybody is requeued.  The
+            # caller looks again — the drain may have freed the room
+            self._drain_pipeline("admission")
+            return True
         self._preempt_slot(victim)
         return True
 
@@ -3903,14 +3974,24 @@ class InferenceEngine:
         if self.token_counts is not None:
             self.token_counts = counts
         self.counters["decode_steps_total"] += K
-        return [K, toks, acts, lps]
+        return [K, toks, acts, lps, self._slot_owners()]
 
-    def _replay_window(self, K: int, toks, acts, lps):
+    def _slot_owners(self) -> list:
+        """Each slot's admission number (0: free), recorded with a
+        window at its launch."""
+        return [s.seq if s.request is not None else 0 for s in self.slots]
+
+    def _replay_window(self, K: int, toks, acts, lps, owners: list):
         """Replay one fused window's [K, S] trace through the
         single-step _emit path (stop handling, eviction, streaming).
         The scan already deactivated finished slots on-device, so this
         is reconciliation, not control.  One bulk tolist per array
-        keeps the K x S inner loop on Python scalars."""
+        keeps the K x S inner loop on Python scalars.
+
+        A row is replayed only into the request that owned its slot
+        when the window was launched (``owners``): a slot whose request
+        the host has retired since, or that a later admission has taken
+        over, gets none of the window's tokens (docs/decode-loop.md)."""
         toks = toks.tolist()          # [K, S]
         acts = acts.tolist()          # [K, S] — device active BEFORE step k
         lps = lps.tolist()            # [K, S]
@@ -3918,7 +3999,8 @@ class InferenceEngine:
             tk, ak, lk = toks[k], acts[k], lps[k]
             for i, slot in enumerate(self.slots):
                 # slot.request goes None when _emit retires it mid-trace
-                if not ak[i] or slot.request is None:
+                if not ak[i] or slot.request is None \
+                        or slot.seq != owners[i]:
                     continue
                 self.positions[i] += 1
                 slot.position += 1
@@ -3940,13 +4022,43 @@ class InferenceEngine:
     # those paths read resume_tokens()/host mirrors and must see every
     # emitted token.
 
-    def _mark_state_dirty(self, *names: str) -> None:
+    def _mark_state_dirty(self, *names: str, why: str = "") -> None:
         """Host mutated loop-state mirrors: re-upload them at the next
         async dispatch (no-op when the async loop is off).  With no
-        args, marks everything (full re-sync)."""
+        args, marks everything (full re-sync).  ``why`` is the drain
+        reason counted if a window in flight has to be retired for it
+        (the first one given since the last upload)."""
         if not self.async_dispatch:
             return
         self._state_dirty.update(names or self._STATE_FIELDS)
+        if why and not self._dirty_reason:
+            self._dirty_reason = why
+
+    def _join_device_batch(self, slot_idx: int) -> None:
+        """Make a slot that has just begun decoding live in the loop
+        state the next window is launched from.  With a window in
+        flight the host mirrors of the other slots lag the device, so
+        only this slot's row is written into the carry, by one small
+        program queued behind that window (and behind the prefill that
+        filled the slot's pages): nothing is drained, nothing is rolled
+        back.  The window in flight was launched with the slot
+        inactive, so its trace holds no row for it."""
+        if not self.async_dispatch:
+            return
+        st = self._dev_state
+        if self._state_dirty & self._DEVICE_ADVANCED \
+                or any(f not in st for f in self._CARRY_FIELDS):
+            # a full upload from the mirrors is already owed (after a
+            # drain, if a window is in flight): it carries this slot too
+            self._mark_state_dirty("positions", "active", "last_tokens",
+                                   "left")
+            return
+        row = np.asarray(
+            [slot_idx, self.last_tokens[slot_idx], self.positions[slot_idx],
+             self._remaining[slot_idx], self._gram_state[slot_idx]], np.int32)
+        st.update(zip(self._CARRY_FIELDS, _patch_carry_row(
+            *(st[f] for f in self._CARRY_FIELDS), row)))
+        self.counters["h2d_uploads_total"] += 1
 
     def _stop_matrix(self):
         """Device [S, _STOP_WIDTH] stop matrix, cached on the batch
@@ -3986,51 +4098,67 @@ class InferenceEngine:
                 self._dev_state[name] = jnp.asarray(src[name])
                 self.counters["h2d_uploads_total"] += 1
         self._state_dirty.clear()
+        self._dirty_reason = ""
         return self._dev_state
 
-    def _retire_window(self, win: list) -> None:
+    def _retire_window(self, win: list, drain: str = "") -> None:
         """Block on window N's readback and replay its trace through
         the normal _emit path.  In the async loop window N+1 is usually
         already executing on device by the time this runs — the block
         overlaps its compute instead of serializing with it; the
         synchronous fused path retires the window it just dispatched.
         The window is taken over: ``win`` is emptied at the end of the
-        replay, so its device arrays die there (see _decode_once)."""
-        with self.phases.phase("engine.decode.wait"):
+        replay, so its device arrays die there (see _decode_once).
+        ``drain`` names what forced a retire with nothing launched
+        behind it, on the wait's span."""
+        attrs = {"drain": drain} if drain else {}
+        with self.phases.phase("engine.decode.wait", **attrs):
             # blocks until the readback lands
-            host = [np.asarray(a) for a in win[1:]]
+            host = [np.asarray(a) for a in win[1:4]]
         self._last_ready_t = time.monotonic()
         with self.phases.phase("engine.decode.replay"):
-            self._replay_window(win[0], *host)
+            self._replay_window(win[0], *host, win[4])
             win.clear()
 
-    def _drain_pipeline(self) -> None:
+    # what can force the pipeline back to depth 1 (docs/decode-loop.md)
+    _DRAIN_REASONS = ("finish", "admission", "import", "dirty_carry",
+                      "page_pressure", "sync_decode", "speculation",
+                      "deadline", "idle")
+
+    def _drain_pipeline(self, reason: str) -> None:
         """Retire any in-flight window (pipeline back to depth 1).
         After this, host mirrors are fully reconciled and paths that
         read resume_tokens()/positions (preempt, spill, evict, abort,
-        spec) are safe."""
+        spec) are safe.  Counted by ``reason`` when a window was in
+        flight."""
         win, self._inflight = self._inflight, None
         if win is not None:
-            self._retire_window(win)
+            self.drain_counts[reason] += 1
+            self._step_drains.append(reason)
+            self._retire_window(win, drain=reason)
 
-    def _must_drain(self) -> bool:
-        """Host-side batch changes that may run this step: admission is
-        possible (waiting work with a free slot, or QoS which may
-        preempt for one), a slot is mid-prefill/import (its
-        _begin_decode mutates loop state), or an abort is pending."""
+    def _drain_reason(self) -> Optional[str]:
+        """Why the window in flight must be retired before this step
+        schedules, or None.  Admission into a free slot, prefill
+        progress and a finish the scan applied itself do not: they
+        leave the rows the device is advancing alone.  What does: a
+        finish only the host knows (it dirtied the carry, and the slot
+        must not be refilled while the device still runs it), an abort
+        waiting for the single-step path, and a chunked KV import, whose
+        slot changes hands between host and device state."""
         if self._inflight is None:
-            return False
-        if self._waiting_count > 0 and (
-                self.qos is not None
-                or any(s.request is None for s in self.slots)):
-            return True
+            return None
+        if self._state_dirty & self._DEVICE_ADVANCED:
+            return self._dirty_reason or "dirty_carry"
         for slot in self.slots:
             req = slot.request
             if req is None:
                 continue
-            if slot.prefilling or slot.importing or req.aborted:
-                return True
-        return False
+            if req.aborted:
+                return "sync_decode"
+            if slot.importing:
+                return "import"
+        return None
 
     def _needs_sync_decode(self) -> bool:
         """Conditions only the single-step host loop handles: pending
@@ -4054,13 +4182,15 @@ class InferenceEngine:
         if fn is None:
             fn = self._decode_multi_state_fns[K] = \
                 self._build_decode_multi_fn(K, with_state=True)
-        if self._inflight is not None \
-                and self._state_dirty & self._DEVICE_ADVANCED:
+        if self._state_dirty & self._DEVICE_ADVANCED:
             # the host mirrors of scan-advanced fields lag the window
             # in flight: re-uploading them now would roll the device
             # state back (double-granted budget, replayed positions).
             # Reconcile first, then upload.
-            self._drain_pipeline()
+            self._drain_pipeline(self._dirty_reason or "dirty_carry")
+        primed = self._inflight is not None
+        self.counters["decode_windows_primed_total" if primed
+                      else "decode_windows_unprimed_total"] += 1
         with self.phases.phase("engine.decode.dispatch"):
             stop_dev = self._stop_matrix()
             state = self._device_state()
@@ -4071,8 +4201,7 @@ class InferenceEngine:
             # a primed pipeline has window N still running while we are
             # here
             gap = (max(0.0, t_dispatch - self._last_ready_t)
-                   if self._inflight is None and self._last_ready_t
-                   else 0.0)
+                   if not primed and self._last_ready_t else 0.0)
             cache, sampling, counts, toks, acts, lps, carry = fn(
                 self.params, self.cache, self.sampling, counts_in, seen,
                 state["last_tokens"], state["positions"],
@@ -4095,14 +4224,18 @@ class InferenceEngine:
         self._gap_last = gap
         if self.dispatch_gap_hist is not None:
             self.dispatch_gap_hist.observe(gap)
-        prev, self._inflight = self._inflight, [K, toks, acts, lps]
+        prev, self._inflight = self._inflight, [K, toks, acts, lps,
+                                                self._slot_owners()]
         if prev is not None:
             self._retire_window(prev)
 
     def _step_async(self) -> bool:
         """The async twin of _step_inner: same decode-priority
         schedule, but fused dispatches go through the two-deep pipeline
-        and host work for window N runs while window N+1 computes."""
+        and host work for window N runs while window N+1 computes.
+        Admission into a free slot, prefill and a finish the scan
+        applied itself leave the pipeline primed; what still takes it
+        to depth 1 is named by _DRAIN_REASONS."""
         did0 = False
         phase = self.phases.phase
         # a drain inside a phase nests its own decode.wait and
@@ -4116,14 +4249,16 @@ class InferenceEngine:
                 if self._inflight is not None and any(
                         s.request is not None
                         and s.request.deadline is not None
+                        and now > s.request.deadline
                         for s in self.slots):
-                    self._drain_pipeline()
+                    self._drain_pipeline("deadline")
                 did0 = self._expire_deadlines()
             if now - self._last_export_tick >= 1.0:
                 self._last_export_tick = now
                 self.kv_exports.tick()
-            if self._must_drain():
-                self._drain_pipeline()
+            reason = self._drain_reason()
+            if reason is not None:
+                self._drain_pipeline(reason)
             pend = self._inflight[0] if self._inflight is not None else 0
             la = 1
             if self.active.any():
@@ -4132,7 +4267,7 @@ class InferenceEngine:
                     # reservation must also cover the window in flight;
                     # when the pool can't, fall back to depth 1 so
                     # _ensure_decode_pages may preempt safely
-                    self._drain_pipeline()
+                    self._drain_pipeline("page_pressure")
                     pend = 0
                 self._ensure_decode_pages(la + pend)
             did = self._admit_new() or did0
@@ -4143,7 +4278,7 @@ class InferenceEngine:
         if decoding:
             with phase("engine.decode", rows=self.num_running):
                 if self._needs_sync_decode():
-                    self._drain_pipeline()
+                    self._drain_pipeline("sync_decode")
                     self._decode_once()
                     self._mark_state_dirty()
                     steps_run = 1
@@ -4151,10 +4286,12 @@ class InferenceEngine:
                     # speculation windows depend on each window's
                     # accepted length — inherently depth-1, but it still
                     # reads the reconciled host mirrors
-                    self._drain_pipeline()
+                    self._drain_pipeline("speculation")
                     steps_run = self._decode_speculative()
                     self._mark_state_dirty()
                 if steps_run:
+                    # launched into an idle device
+                    self.counters["decode_windows_unprimed_total"] += 1
                     did = True
                 elif bool(self.active.any()):
                     la2 = self._decode_lookahead()
@@ -4163,7 +4300,7 @@ class InferenceEngine:
                     while la2 > 1 and not self._lookahead_fits(la2 + pend):
                         la2 //= 2
                     if pend and not self._lookahead_fits(la2 + pend):
-                        self._drain_pipeline()
+                        self._drain_pipeline("page_pressure")
                         pend = 0
                     if did or la2 + pend > la:
                         self._ensure_decode_pages(la2 + pend)
@@ -4174,7 +4311,7 @@ class InferenceEngine:
             # nothing left active on the host: the trailing window may
             # still hold final tokens — retire it now
             with phase("engine.decode"):
-                self._drain_pipeline()
+                self._drain_pipeline("idle")
             did = True
         self._tick += 1
         self._decode_since_prefill += steps_run
@@ -4695,7 +4832,12 @@ class InferenceEngine:
             req.out.put(None)
             if self.host_kv is not None:
                 self.host_kv.discard(req.req_id)
-            self._evict_slot(slot_idx, commit=True)
+            # a stop id or a spent budget is what the fused scan checks
+            # too (same stop matrix, same countdown): it has retired the
+            # slot on the device already.  An abort alone it cannot see.
+            self._evict_slot(slot_idx, commit=True,
+                             device_done=(token in stop_ids
+                                          or slot.remaining <= 0))
             self.counters["requests_finished_total"] += 1
 
     def _publish_prefix(self, slot_idx: int) -> None:

@@ -556,17 +556,34 @@ class EngineMetrics:
                       fn=lambda: (engine.pd_costs.snapshot().get(
                           "disk_bytes_s") or 0.0))
             if getattr(engine, "async_dispatch", False):
-                # zero-bubble decode loop (docs/decode-loop.md): the
-                # family exists ONLY with the async loop on — the
-                # dispatch-gap histogram above is gated the same way
-                # (engine attr is None when off), so the flag-off
-                # exposition stays byte-identical
+                # two-deep decode dispatch (docs/decode-loop.md): the
+                # families exist wherever that loop runs, and only
+                # there — the dispatch-gap histogram above is gated the
+                # same way (engine attr is None under the synchronous
+                # loop, whose exposition they leave byte-identical)
                 Gauge("kaito:engine_h2d_uploads_total",
                       "Loop-state arrays uploaded host-to-device at "
                       "decode dispatch (~zero per dispatch in steady "
                       "state)", r,
                       fn=lambda: engine.counters.get(
                           "h2d_uploads_total", 0))
+                Gauge("kaito:engine_decode_windows_primed_total",
+                      "Decode windows launched while another was in "
+                      "flight (its host work overlaps this one)", r,
+                      fn=lambda: engine.counters.get(
+                          "decode_windows_primed_total", 0))
+                Gauge("kaito:engine_decode_windows_unprimed_total",
+                      "Decode windows launched with none in flight "
+                      "(after a drain, or single-step and speculative "
+                      "dispatches)", r,
+                      fn=lambda: engine.counters.get(
+                          "decode_windows_unprimed_total", 0))
+                Gauge("kaito:engine_decode_drains_total",
+                      "Windows in flight retired with nothing launched "
+                      "behind them, by what forced it", r,
+                      labels=("reason",),
+                      fn=lambda: {(k,): float(v) for k, v
+                                  in dict(engine.drain_counts).items()})
             if getattr(engine, "devprof", None) is not None:
                 # sampled device-time attribution (engine/devprof.py):
                 # families exist ONLY with --devprof-interval-s > 0 —

@@ -2244,14 +2244,14 @@ def main(argv=None):
                                                "0")),
                     help="cap /debug/kv_pool adverts to the freshest N "
                          "entries per EPP scrape (0 = unlimited)")
-    ap.add_argument("--async-dispatch", action="store_true",
-                    default=os.environ.get("KAITO_ASYNC_DISPATCH", "")
-                    in ("1", "true"),
-                    help="zero-bubble decode loop (docs/decode-loop.md): "
-                         "device-resident loop state + a two-deep dispatch "
-                         "pipeline overlapping host postprocess with device "
-                         "compute (default off; off keeps the synchronous "
-                         "loop and /metrics byte-identical)")
+    ap.add_argument("--async-dispatch", action="store_true", default=None,
+                    help="force the two-deep decode dispatch loop "
+                         "(docs/decode-loop.md): device-resident loop state, "
+                         "host postprocess overlapped with device compute. "
+                         "Unset, the engine resolves it: on where the "
+                         "backend is an accelerator (single process, no "
+                         "pipeline parallelism), off on the CPU backend; "
+                         "KAITO_ASYNC_DISPATCH=1/0 pins it")
     ap.add_argument("--comm-overlap", action="store_true",
                     default=os.environ.get("KAITO_COMM_OVERLAP", "")
                     .strip().lower() not in ("", "0", "false", "off"),

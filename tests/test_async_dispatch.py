@@ -2,7 +2,7 @@
 
 The two-deep dispatch pipeline with device-resident loop state must be
 observationally identical to the synchronous loop: same tokens, same
-stop/abort/preempt behavior, same /metrics when the flag is off.  What
+stop/abort/preempt behavior, same /metrics when the loop is off.  What
 changes is WHERE the host does its postprocess (overlapped with window
 N+1's device compute) and how often loop state crosses PCIe (~never in
 steady state).
@@ -46,7 +46,10 @@ def engines():
 
 
 def test_flag_resolution():
-    """config beats env; None follows KAITO_ASYNC_DISPATCH."""
+    """config beats env; None follows KAITO_ASYNC_DISPATCH and, with
+    that unset, the backend: off on the CPU these tests run on (on
+    where it is an accelerator; tests/test_decode_pipeline.py pins the
+    environment's both values)."""
     assert _mk(True).async_dispatch is True
     assert _mk(False).async_dispatch is False
     assert _mk(None).async_dispatch is _ENV_FORCED
@@ -220,31 +223,41 @@ def test_no_retrace_and_h2d_flat_steady_state():
                     "async loop on; the flag-off exposition check needs "
                     "a true sync engine")
 def test_flag_off_byte_identical_exposition():
-    """Flag off: no async metric families, no async counters, no
-    dispatch_gap timeline field — the exposition and the flight
-    recorder are byte-identical to before the feature existed."""
+    """The synchronous loop (what an unset field resolves to on the CPU
+    backend): no async metric families, no async counters, no
+    dispatch_gap or drain timeline field — the exposition and the
+    flight recorder are byte-identical to before the feature existed."""
     from kaito_tpu.engine.metrics import EngineMetrics
 
     eng = _mk(None)
     assert eng.async_dispatch is False
     assert eng.dispatch_gap_hist is None
     assert "h2d_uploads_total" not in eng.counters
+    assert "decode_windows_primed_total" not in eng.counters
     text = EngineMetrics(engine=eng).registry.expose()
     assert "dispatch_gap" not in text
     assert "h2d_uploads" not in text
+    assert "decode_windows" not in text and "decode_drains" not in text
     eng.submit([1, 2, 3], SamplingParams(max_tokens=4, temperature=0.0,
                                          ignore_eos=True))
     for _ in range(200):
         eng.step()
         if not eng.num_running and not eng.num_waiting:
             break
-    assert all("dispatch_gap" not in r for r in eng.timeline.records())
+    assert all("dispatch_gap" not in r and "drain" not in r
+               for r in eng.timeline.records())
 
 
 def test_flag_on_exposes_gap_and_h2d_families():
+    """Wherever the two-deep loop runs its families exist, from the
+    first scrape: the gap histogram, the upload count, the primed and
+    unprimed window counts, the drains by reason."""
     from kaito_tpu.engine.metrics import EngineMetrics
 
     eng = _mk(True)
     text = EngineMetrics(engine=eng).registry.expose()
     assert "kaito:engine_dispatch_gap_seconds" in text
     assert "kaito:engine_h2d_uploads_total" in text
+    assert "kaito:engine_decode_windows_primed_total 0" in text
+    assert "kaito:engine_decode_windows_unprimed_total 0" in text
+    assert 'kaito:engine_decode_drains_total{reason="finish"} 0' in text
