@@ -19,7 +19,7 @@ from kserver import BenchError, log, run_child
 from paths import CACHE, KBENCH
 from trafficgen import ids_of, words
 
-REFERENCE = os.path.join(KBENCH, "reference")
+RUN_REFERENCE = os.path.join(KBENCH, "reference", "run_reference.py")
 
 
 def check_prompts(mix: dict, seed: int, vocab: int) -> list:
@@ -70,24 +70,31 @@ def send_checks(srv, model: str, prompts: list, n_decode: int) -> dict:
     return {"served": served, "tokens": tokens}
 
 
-def _ref_hash() -> str:
+def _ref_hash(reference_file: str) -> str:
+    """Of the reference the configuration names and the child that
+    loads it: an edit to one reference spoils only its own cache."""
     h = hashlib.sha256()
-    for name in ("dense_decoder.py", "run_reference.py"):
-        with open(os.path.join(REFERENCE, name), "rb") as f:
+    for path in (reference_file, RUN_REFERENCE):
+        with open(path, "rb") as f:
             h.update(f.read())
     return h.hexdigest()
 
 
 def expectations(cfg: dict, weight_seed: int, requests: list, *,
                  platform: str, work_dir: str, perturb: str = "") -> list:
-    """The reference's answers for ``requests`` (``tokens``, ``start``),
+    """The answers of the reference ``cfg`` names (``reference_file``,
+    from ``Manifest.config``) for ``requests`` (``tokens``, ``start``),
     from the checkout's cache where they are there, else from one
     reference child that holds the device alone.  ``platform`` is the
     one the server ran on: the child refuses any other, and an
     expectation is cached under it."""
     dtype = cfg["server"].get("config_file", {}).get("dtype", "")
+    reference = cfg["reference_file"]
+    if not os.path.isfile(reference):
+        raise BenchError(f"configuration {cfg['name']}: no reference file "
+                         f"{cfg.get('reference')!r}")
     base = json.dumps([cfg["config"], weight_seed, platform, dtype, perturb,
-                       _ref_hash()], sort_keys=True)
+                       _ref_hash(reference)], sort_keys=True)
     paths = [os.path.join(CACHE, "reference", hashlib.sha256(
         (base + json.dumps(r, sort_keys=True)).encode()).hexdigest() + ".json")
         for r in requests]
@@ -96,13 +103,12 @@ def expectations(cfg: dict, weight_seed: int, requests: list, *,
         job = os.path.join(work_dir, "reference_job.json")
         out = os.path.join(work_dir, "reference_out.json")
         with open(job, "w") as f:
-            json.dump({"config": cfg["config"], "weight_seed": weight_seed,
-                       "platform": platform, "dtype": dtype,
-                       "perturb": perturb,
+            json.dump({"reference": reference, "config": cfg["config"],
+                       "weight_seed": weight_seed, "platform": platform,
+                       "dtype": dtype, "perturb": perturb,
                        "requests": [requests[i] for i in missing]}, f)
         log(f"reference child for {len(missing)} sequence(s)")
-        rc = run_child([sys.executable,
-                        os.path.join(REFERENCE, "run_reference.py"), job, out],
+        rc = run_child([sys.executable, RUN_REFERENCE, job, out],
                        os.path.join(work_dir, "reference.log"))
         if rc != 0:
             raise BenchError(f"reference child exited {rc}; see "

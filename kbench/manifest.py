@@ -11,7 +11,7 @@ import json
 import os
 import re
 
-from paths import KBENCH, MANIFEST
+from paths import MANIFEST, ROOT
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -36,14 +36,19 @@ class Manifest:
         self.root = os.path.dirname(self.path)
         self.data = load_json(self.path)
 
-    def find(self, kind: str, name: str) -> str:
-        """``kbench/<kind>/<name>.json``: beside the manifest first (the
-        rehearsal's and the tests' own data), else beside the harness."""
-        for base in (os.path.join(self.root, "kbench"), KBENCH):
-            path = os.path.join(base, kind, name + ".json")
+    def resolve(self, rel: str) -> str:
+        """A path from the root of the repo: beside the manifest first
+        (the rehearsal's and the tests' own data), else in the harness's
+        checkout."""
+        for base in (self.root, ROOT):
+            path = os.path.join(base, rel)
             if os.path.exists(path):
                 return path
         return path
+
+    def find(self, kind: str, name: str) -> str:
+        """``kbench/<kind>/<name>.json``, by ``resolve``."""
+        return self.resolve(os.path.join("kbench", kind, name + ".json"))
 
     def cell(self, name: str) -> dict:
         for w in self.data["workloads"]:
@@ -58,8 +63,11 @@ class Manifest:
         raise KeyError(f"no config {name!r} in {self.path}")
 
     def config(self, name: str) -> dict:
+        """The configuration's file, with its ``name`` and the plain
+        reference it names as an absolute path (``reference_file``)."""
         cfg = load_json(self.config_path(name))
         cfg["name"] = name
+        cfg["reference_file"] = self.resolve(cfg.get("reference", ""))
         return cfg
 
     def traffic(self, name: str) -> dict:
@@ -129,6 +137,17 @@ def validate(m: Manifest) -> list:
                 bad.append(f"config {c['name']}: reduced names a width, {key}")
         if not os.path.exists(os.path.join(m.root, c["file"])):
             bad.append(f"config file {c['file']} does not exist")
+            continue
+        ref = load_json(os.path.join(m.root, c["file"])).get("reference")
+        if not isinstance(ref, str) or not ref.endswith(".py"):
+            bad.append(f"config {c['name']}: reference {ref!r} is not a "
+                       "Python file's path")
+        elif ref.startswith("/") or ".." in ref.split("/") or not any(
+                ref.startswith(p.rstrip("/") + "/") for p in paths):
+            bad.append(f"config {c['name']}: reference {ref} is not under "
+                       "paths")
+        elif not os.path.isfile(m.resolve(ref)):
+            bad.append(f"config {c['name']}: reference {ref} does not exist")
     used, pairs, four = set(), set(), 0
     for w in d["workloads"]:
         if set(w) != {"name", "config", "traffic", "chips", "why"}:

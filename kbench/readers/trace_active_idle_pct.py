@@ -1,7 +1,7 @@
 """Share of the device's active extent — from its first to its last
 operation in the trace — in which no operation ran on it (averaged
-over the devices used).  ``trace_idle_pct`` divides by the whole
-traced window, the profiler's own start and stop included."""
+over the devices used).  The extent, not the traced window: that one
+holds the profiler's own start and stop, and moves with the tracer."""
 
 import trace_spans
 

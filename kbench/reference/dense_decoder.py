@@ -31,6 +31,8 @@ import jax.numpy as jnp
 
 Q_BLOCK = 512
 VOCAB_BLOCK = 16384
+# what ``forward`` accepts for ``perturb``
+PERTURBATIONS = ("drop_last_layer", "head_int8", "weights_fp8")
 
 
 def _rms_norm(x, w, eps):

@@ -5,18 +5,21 @@ Runs alone on the device (the server is not up).  Makes the weights
 the server made — ``TransformerLM.init_params(PRNGKey(seed))`` in the
 served type, which is data generation; no ``apply`` path of the
 program is called — spreads them over the local devices by their
-first axis so that a model too large for one chip fits, and runs
-``dense_decoder.forward`` on the first device one layer at a time.
+first axis so that a model too large for one chip fits, and runs the
+``forward`` of the reference file the configuration names, loaded as
+a module by its path, on the first device.
 
     run_reference.py <job.json> <out.json>
 
-job: ``config`` (HF keys), ``weight_seed``, ``platform`` (the one the
-server was held to: any other is an error, never a fallback),
-``dtype`` ("" = the platform's serving default), ``perturb``,
+job: ``reference`` (the file), ``config`` (HF keys), ``weight_seed``,
+``platform`` (the one the server was held to: any other is an error,
+never a fallback), ``dtype`` ("" = the platform's serving default),
+``perturb`` (one of the module's ``PERTURBATIONS``, or ""),
 ``requests`` (each ``tokens`` and ``start``).  out: per request
 ``target``, ``top`` and the ``platform`` it was computed on.
 """
 
+import importlib.util
 import json
 import os
 import sys
@@ -25,20 +28,35 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
+def load_reference(path: str):
+    """The reference file as a module, by its path: it need not lie
+    beside this file, and nothing here knows its name."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location("kbench_reference_" + stem,
+                                                  path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def main() -> int:
     with open(sys.argv[1]) as f:
         job = json.load(f)
     sys.path.insert(0, ROOT)
-    sys.path.insert(0, HERE)
     from kaito_tpu.utils.platform import enable_compile_cache
 
     enable_compile_cache()
+    reference = load_reference(job["reference"])
+    perturb = job.get("perturb", "")
+    if perturb and perturb not in reference.PERTURBATIONS:
+        print(f"{job['reference']} knows no perturbation {perturb!r}, only "
+              f"{list(reference.PERTURBATIONS)}", file=sys.stderr)
+        return 1
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    import dense_decoder
     from kaito_tpu.engine.model import TransformerLM
     from kaito_tpu.models.autogen import arch_from_hf_config
 
@@ -66,9 +84,8 @@ def main() -> int:
 
     out = []
     for req in job["requests"]:
-        res = dense_decoder.forward(job["config"], params, req["tokens"],
-                                    req["start"], put=put,
-                                    perturb=job.get("perturb", ""))
+        res = reference.forward(job["config"], params, req["tokens"],
+                                req["start"], put=put, perturb=perturb)
         out.append(dict({k: [float(x) for x in np.asarray(v)]
                          for k, v in res.items()},
                         platform=devs[0].platform, dtype=dtype))

@@ -11,9 +11,10 @@ checkout; start ``launch_server.py``; wait for ``/health`` and refuse
 the wrong platform; warm up the shapes the mix uses and send the check
 requests (all of that is ``setup_s``); scrape ``/metrics``, run the
 load generator for ``--seconds``, drain, scrape again; with
-``--trace 1`` bracket a few seconds of the window with the server's
-profiler; stop the server (exit 0 required); run or look up the plain
-reference for the check requests and compare.  The last line of
+``--trace 1`` bracket the mix's ``trace_seconds`` of the window with
+the server's profiler (``trace_window``); stop the server (exit 0
+required); run or look up the plain reference the configuration names
+for the check requests and compare.  The last line of
 stdout is the result object; everything else goes to stderr or
 ``kbench/out/<cell>/``.
 """
@@ -32,6 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import check                                            # noqa: E402
 import clientstats                                      # noqa: E402
+import trace_spans                                      # noqa: E402
 from kserver import (BenchError, Server, build_native,  # noqa: E402
                      child_env, log, stop_child)
 from manifest import Manifest, load_json                # noqa: E402
@@ -41,6 +43,10 @@ from trafficgen import schedule, words                  # noqa: E402
 
 WARM_SEED = 0x3A97          # warm-up traffic is the same in every run
 POLL_PERIOD_S = 0.5
+# /stop_profile returns when the trace is written: about 10 s for each
+# second traced on a v5e host (31, 82 and 139 s for 3, 8 and 14 s; PR 26),
+# while the server goes on serving
+PROFILER_TIMEOUT_S = 300.0
 LATE_SHARE = 0.10           # generator lateness worth a warning, of the mean gap
 
 
@@ -118,24 +124,66 @@ def health_problems(health: dict, expect: dict, chips: int) -> list:
     return bad
 
 
-def reduce_trace(profile_dir: str, work_dir: str):
-    """The newest xplane file under ``profile_dir``, reduced in a child
-    that is held to the CPU."""
-    found = []
-    for base, _, files in os.walk(profile_dir):
-        found += [os.path.join(base, f) for f in files
-                  if f.endswith(".xplane.pb")]
-    if not found:
-        return None
-    out = os.path.join(work_dir, "trace.json")
-    with open(out, "w") as f:
-        res = subprocess.run(
-            [sys.executable, os.path.join(KBENCH, "trace_reduce.py"),
-             max(found, key=os.path.getmtime)], stdout=f,
-            env=child_env({"JAX_PLATFORMS": "cpu"}), cwd=ROOT)
-    if res.returncode != 0:
-        raise BenchError("trace reduction failed")
-    return load_json(out)
+def trace_window(post, begin_s: float, span_s: float, window_s: float,
+                 now=time.monotonic, sleep=time.sleep) -> dict:
+    """Bracket ``span_s`` seconds of the window with the server's
+    profiler.  ``post(path)`` returns the HTTP status; times are
+    seconds on ``now``'s clock, the window starting at its value at
+    the call.  The stop is counted from the *return* of
+    ``/start_profile`` (the profiler's start can hold the server for
+    seconds), so the trace is ``span_s`` long or the run fails: a start
+    that returns too late for the span to end inside the window is
+    stopped at once and named, never left as a short trace."""
+    t0 = now()
+    sleep(begin_s)
+    asked = now()
+    status = post("/start_profile")
+    started = now()
+    if status != 200:
+        raise BenchError(f"/start_profile answered {status}")
+    late = started - t0 + span_s - window_s
+    if late <= 0:
+        sleep(span_s - (now() - started))
+    stopping = now()
+    status = post("/stop_profile")
+    stopped = now()
+    if late > 0:
+        raise BenchError(
+            f"/start_profile, asked at {asked - t0:.1f}s, returned at "
+            f"{started - t0:.1f}s: {span_s:.0f}s of trace from there would "
+            f"end {late:.1f}s after the {window_s:.0f}s window")
+    if status != 200:
+        raise BenchError(f"/stop_profile answered {status}")
+    return {"start_s": started - t0, "stop_s": stopping - t0,
+            "start_took_s": started - asked, "stop_took_s": stopped - stopping}
+
+
+def reduce_trace(profile_dir: str, work_dir: str, into: dict) -> None:
+    """Both reductions of the run's trace, each in a child held to the
+    CPU, into ``into``: ``trace`` (``trace_reduce.py``), ``spans``
+    (``trace_spans.py``, which the readers then find reduced), ``cost``,
+    or ``error``.  Runs in a thread beside the reference child: the two
+    take 20 s on a 14 s trace, and need no chip."""
+    try:
+        path = trace_spans.newest_trace(profile_dir)
+        if path is None:
+            raise BenchError("the profiler wrote no trace")
+        t0 = time.monotonic()
+        out = os.path.join(work_dir, "trace.json")
+        with open(out, "w") as f:
+            res = subprocess.run(
+                [sys.executable, os.path.join(KBENCH, "trace_reduce.py"), path],
+                stdout=f, env=child_env({"JAX_PLATFORMS": "cpu"}), cwd=ROOT)
+        if res.returncode != 0:
+            raise BenchError("trace reduction failed")
+        into["trace"] = load_json(out)
+        t1 = time.monotonic()
+        into["spans"] = trace_spans.reduced_newest(into)
+        into["cost"] = {"xplane_bytes": os.path.getsize(path),
+                        "trace_reduce_s": t1 - t0,
+                        "trace_spans_s": time.monotonic() - t1}
+    except BenchError as e:
+        into["error"] = str(e)
 
 
 def peaks_for(device_kind: str) -> dict:
@@ -214,7 +262,7 @@ def run(args, t_start: float) -> int:
     reqs = schedule(mix, seed=args.seed, vocab=vocab, seconds=args.seconds,
                     rate_rps=rate, count=int(mix.get("count", 0)))
 
-    polls, traced_at, client_tokens, problems = [], [], 0, []
+    polls, traced, client_tokens, problems = [], {}, 0, []
     with Server(config_path=m.config_path(model), name=model,
                 tokenizer_dir=tok_dir, weight_seed=weight_seed,
                 work_dir=work_dir,
@@ -237,30 +285,38 @@ def run(args, t_start: float) -> int:
 
         before = srv.metrics()
 
+        def tracer():
+            # the middle of the window; loadgen.py is starting up, so its
+            # clock's zero is read from its result afterwards
+            span = float(mix.get("trace_seconds", 3.0))
+            traced["t0_unix"] = time.time()
+            try:
+                traced.update(trace_window(
+                    lambda path: srv.request(path, {}, PROFILER_TIMEOUT_S)[0],
+                    max(0.0, (args.seconds - span) / 2), span, args.seconds))
+            except (BenchError, OSError) as e:
+                traced["error"] = f"the profiler's bracket failed: {e}"
+
         def during(proc):
             t0 = time.monotonic()
+            thread = None
             if args.trace and not on_cpu:
-                # a few seconds in the middle of the window; a CPU has
-                # no device plane to trace, so the rehearsal takes none
-                span = float(mix.get("trace_seconds", 3.0))
-                begin = max(0.0, (args.seconds - span) / 2)
-
-                def toggle(path):
-                    srv.request(path, {}, 120)
-                    traced_at.append(time.time())
-
-                for delay, path in ((begin, "/start_profile"),
-                                    (begin + span, "/stop_profile")):
-                    timer = threading.Timer(delay, toggle, args=(path,))
-                    timer.daemon = True
-                    timer.start()
+                # a CPU has no device plane to trace: the rehearsal takes none
+                thread = threading.Thread(target=tracer, daemon=True)
+                thread.start()
             while proc.poll() is None and args.trace:
                 if time.monotonic() - t0 < args.seconds:
                     polls.append(srv.metrics())
                 time.sleep(POLL_PERIOD_S)
+            if thread is not None:      # the stop outlasts the window
+                thread.join(timeout=args.seconds + 2 * PROFILER_TIMEOUT_S)
+                if thread.is_alive():
+                    traced["error"] = "the profiler's bracket did not return"
 
         result = run_loadgen(make_plan(srv, model, mix, reqs, args.seconds,
                                        concurrency), work_dir, "window", during)
+        if "error" in traced:
+            raise BenchError(traced["error"])
         stats = clientstats.reduce(result)
         client_tokens += stats["tokens"]
         for _ in range(20):      # the handler counts a request after its last chunk
@@ -272,6 +328,12 @@ def run(args, t_start: float) -> int:
             time.sleep(0.1)
         health_after = srv.health()
         srv.stop()
+
+    reduced, reducing = {}, None
+    if args.trace and not on_cpu:
+        reducing = threading.Thread(target=reduce_trace,
+                                    args=(profile_dir, work_dir, reduced))
+        reducing.start()
 
     # ---- accounting: counts that repeat exactly -----------------------
     problems += health_problems(health_after, expect, chips)
@@ -318,27 +380,35 @@ def run(args, t_start: float) -> int:
     out = {"correct": not problems, "attempted": stats["attempted"],
            "failed": stats["failed"]}
     client = dict(stats, setup_s=setup_s)
+    trace = cost = None
     if args.trace:
-        trace = None if on_cpu else reduce_trace(profile_dir, work_dir)
-        if not on_cpu:
-            if not trace or trace["busy_s"] <= 0:
+        if reducing is not None:
+            reducing.join()
+            if "error" in reduced:
+                raise BenchError(reduced["error"])
+            trace = reduced["trace"]
+            if trace["busy_s"] <= 0:
                 raise BenchError("the traced run saw no operation on the device")
+            cost = dict(reduced["cost"], start_profile_s=traced["start_took_s"],
+                        stop_profile_s=traced["stop_took_s"])
+            log("the trace cost " + json.dumps(cost))
             device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
             out["breakdown"] = {
                 "device_ops": [[n, s] for n, s in sorted(
                     trace["ops"].items(), key=lambda x: -x[1])[:10]],
-                "idle_gaps": [[f"device idle at +{at:.3f}s (host activity "
-                               "not attributed: no spans in the program)", gap]
-                              for at, gap in trace["gaps"]]}
+                "idle_gaps": reduced["spans"]["idle_by_name"]}
         log("device time by program " + json.dumps(
             (trace or {}).get("modules", {})))
+        # the traced span on the load generator's clock: from the return
+        # of /start_profile to the sending of /stop_profile
+        on_gen = traced.get("t0_unix", 0.0) - result["t0_unix"]
         ctx = {"before": before, "after": after, "polls": polls,
                "client": client, "trace": trace, "config": cfg, "mix": mix,
-               # the window's raw requests with their prompt lengths, and
-               # the traced span on the load generator's clock
+               # the window's raw requests with their prompt lengths
                "requests": [dict(r, prompt_tokens=len(reqs[r["idx"]]["prompt_ids"]))
                             for r in result["requests"]],
-               "traced_s": [t - result["t0_unix"] for t in traced_at],
+               "traced_s": ([on_gen + traced["start_s"],
+                             on_gen + traced["stop_s"]] if trace else []),
                "health": health_after,
                "peaks": None if on_cpu else peaks_for(health["device_kind"])}
         out["metrics"] = layer_metrics(m, cell["name"], ctx)
@@ -355,6 +425,7 @@ def run(args, t_start: float) -> int:
     with open(os.path.join(work_dir, "report.json"), "w") as f:
         json.dump({"args": vars(args), "result": out, "client": client,
                    "check": verdict, "served": sent["served"],
+                   "trace_cost": cost,
                    "metrics_before": before, "metrics_after": after,
                    "problems": problems, "health": health_after}, f)
     print(json.dumps(out), flush=True)

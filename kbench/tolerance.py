@@ -5,14 +5,16 @@ against the clean reference and against deliberately cruder ones.
     python kbench/tolerance.py kbench/out/<cell>/report.json [...]
 
 For each report (written by ``run.py``) it regenerates the check
-prompts from the run's seed, asks the reference for its expectations —
-clean, with the last layer dropped, with the head rounded to int8 and
-with every layer's matrices rounded to float8 — and prints each
-clause's largest error.  The tolerance in a configuration's file is
+prompts from the run's seed, asks the reference the configuration
+names for its expectations — clean, and under each of that module's
+``PERTURBATIONS`` (for ``dense_decoder.py``: the last layer dropped,
+the head rounded to int8, every layer's matrices rounded to float8) —
+and prints each clause's largest error.  The tolerance in a configuration's file is
 set above every clean error and below what a wrong model gives.  Runs
 the reference child, so the device must be free.
 """
 
+import ast
 import json
 import os
 import sys
@@ -22,7 +24,18 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import check                                    # noqa: E402
 from manifest import Manifest, load_json        # noqa: E402
 
-PERTURBATIONS = ("", "drop_last_layer", "head_int8", "weights_fp8")
+
+def perturbations(reference_file: str) -> tuple:
+    """The ``PERTURBATIONS`` tuple of a reference file, read from its
+    source: importing the module would bring in JAX, and this process
+    must leave the device to the reference child."""
+    with open(reference_file) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                and getattr(node.targets[0], "id", "") == "PERTURBATIONS"):
+            return tuple(ast.literal_eval(node.value))
+    raise ValueError(f"{reference_file} exports no PERTURBATIONS")
 
 
 def main() -> int:
@@ -36,7 +49,7 @@ def main() -> int:
         prompts = check.check_prompts(mix, args["seed"],
                                       int(cfg["config"]["vocab_size"]))
         requests = check.reference_requests(prompts, report["served"])
-        for perturb in PERTURBATIONS:
+        for perturb in ("",) + perturbations(cfg["reference_file"]):
             ref = check.expectations(cfg, args["seed"] % (2 ** 31 - 1), requests,
                                      platform=args["expect_platform"],
                                      work_dir=os.path.dirname(path),

@@ -20,7 +20,9 @@ The line ``XLA Modules`` holds one event per executed program
   divided by their number, so that a loop is not counted on top of its
   body.  ``op_counts``: how often each ran, per device.  ``modules``:
   the self times summed per program.
-- ``gaps``: the longest intervals in which no op ran on device 0.
+
+The idle intervals, and what the host did in them, are
+``trace_spans.py``'s.
 
     trace_reduce.py <file.xplane.pb> [--dump]     # prints JSON
 
@@ -75,15 +77,6 @@ def _self_times(events: list) -> dict:
     return out
 
 
-def _gaps(intervals: list, top: int) -> list:
-    gaps, end = [], None
-    for s, e in sorted(intervals):
-        if end is not None and s > end:
-            gaps.append((s - end, end))
-        end = e if end is None else max(end, e)
-    return sorted(gaps, reverse=True)[:top]
-
-
 def _short(name: str) -> str:
     return name.split(" = ", 1)[0].strip()
 
@@ -102,7 +95,7 @@ def _in_modules(ops: list, modules: list) -> list:
     return out
 
 
-def reduce(path: str, top: int = 10) -> dict:
+def reduce(path: str) -> dict:
     data = load(path)
     lo, hi = float("inf"), float("-inf")
     ops, modules = {}, {}
@@ -114,15 +107,18 @@ def reduce(path: str, top: int = 10) -> dict:
             for ev in line.events:
                 if PROFILER_OWN.search(ev.name):
                     continue
+                # one rounding each, so that an operation that ends where
+                # the next begins still does so in seconds: a sum of two
+                # roundings put 0.3 s of 3 s into the wrong parent
                 s = ev.start_ns * 1e-9
-                e = s + ev.duration_ns * 1e-9
+                e = (ev.start_ns + ev.duration_ns) * 1e-9
                 lo, hi = min(lo, s), max(hi, e)
                 if keep is not None:
                     keep.setdefault(plane.name, []).append((s, e, ev.name))
     window = hi - lo if hi > lo else 0.0
     if not ops:
         return {"devices": 0, "busy_s": 0.0, "window_s": window,
-                "ops": {}, "op_counts": {}, "modules": {}, "gaps": []}
+                "ops": {}, "op_counts": {}, "modules": {}}
     busy = [_union([(s, e) for s, e, _ in evs]) for evs in ops.values()]
     by_op, by_module, counts = {}, {}, {}
     for dev, evs in ops.items():
@@ -133,12 +129,9 @@ def reduce(path: str, top: int = 10) -> dict:
             by_op[name] = by_op.get(name, 0.0) + sec / len(ops)
             prog = name.split("/", 1)[0]
             by_module[prog] = by_module.get(prog, 0.0) + sec / len(ops)
-    first = ops[sorted(ops)[0]]
     return {"devices": len(ops), "busy_s": sum(busy) / len(busy),
             "window_s": window, "ops": by_op, "op_counts": counts,
-            "modules": by_module,
-            "gaps": [[at - lo, gap]
-                     for gap, at in _gaps([(s, e) for s, e, _ in first], top)]}
+            "modules": by_module}
 
 
 def dump(path: str) -> dict:

@@ -19,9 +19,14 @@ reduction overlaps the two:
   (``engine.step``, ``engine.decode``) is ``unattributed``.
   ``idle_in_s`` is the device-idle time by the kind the engine thread
   was in, ``gaps`` the longest idle intervals with the same split.
-  Both are taken between the engine thread's first and last recorded
-  span: a span still open when the trace stops is not recorded, so
-  what the thread did at the trace's edges cannot be told.
+  ``idle_by_name`` is every idle interval under a name that says what
+  the engine thread was doing in it (``gap_name``: phases, no time of
+  day, so that one kind of gap has one name in every run), the
+  seconds of one name summed, the largest first: the ``idle_gaps`` of
+  a run's ``breakdown``.  All three are taken between the engine
+  thread's first and last recorded span: a span still open when the
+  trace stops is not recorded, so what the thread did at the trace's
+  edges cannot be told.
 - host and device clocks may differ by a small offset (in
   ``testdata/tiny.xplane.pb`` a program starts 1.2 ms before the call
   that launched it).  The program an ``engine.*.dispatch`` span
@@ -38,9 +43,10 @@ reduction overlaps the two:
     trace_spans.py <file.xplane.pb>     # prints JSON
 
 A trace of a program without the spans (the parent of the PR that
-added them) gives the extent, ``busy_s`` and ``idle_s`` and null for
-the rest.  ``reduced_newest`` is the harness's side: it runs this file
-in a child held to the CPU, as ``run.py`` runs ``trace_reduce.py``.
+added them) gives the extent, ``busy_s`` and ``idle_s``, every idle
+interval under the one name ``unattributed``, and null for the rest.
+``reduced_newest`` is the harness's side: it runs this file in a
+child held to the CPU, as ``run.py`` runs ``trace_reduce.py``.
 """
 
 import functools
@@ -68,6 +74,8 @@ KIND = {"engine.decode.replay": "replay",
         "engine.prefill.wait": "wait"}
 KINDS = ("replay", "dispatch", "schedule", "prefill", "wait", "unattributed")
 CALL = re.compile(r"^PjitFunction\((.+)\)$")
+# a phase is part of a gap's name when it covers this share of the gap
+NAMED_SHARE = 0.25
 # a program of the same name that starts this long before a call was
 # launched by an earlier call: far above any clock offset, far below
 # the time between two dispatches of one program
@@ -137,6 +145,43 @@ def split(interval: tuple, pieces: list) -> dict:
     return out
 
 
+def _phase(span: str) -> str:
+    """``engine.decode.wait`` as ``decode.wait``: the ledger keeps 64
+    characters of a name.  ``engine.idle`` stays whole."""
+    return span if span == "engine.idle" else span[len("engine."):]
+
+
+def gap_name(interval: tuple, pieces: list) -> str:
+    """What the engine thread was doing in a device-idle interval:
+    ``idle in <phases> after <phase>``.  The phases are the innermost
+    ``engine.*`` spans that each cover ``NAMED_SHARE`` of it or more
+    (the largest alone when none does), largest first; ``after`` is the
+    one the thread was in when the device ran dry, where that is none
+    of them.  Time of the thread in no span counts as ``no-span``; an
+    interval that meets no span at all is ``unattributed``."""
+    lo, hi = interval
+    sec, first = {}, None
+    for s, e, name in pieces:
+        if e <= lo:
+            continue
+        if s >= hi:
+            break
+        if s <= lo:
+            first = name
+        sec[name] = sec.get(name, 0.0) + min(e, hi) - max(s, lo)
+    if not sec:
+        return "unattributed"
+    rest = (hi - lo) - sum(sec.values())
+    if rest > 0:
+        sec["engine.no-span"] = rest
+    by_size = sorted(sec, key=lambda n: (-sec[n], n))
+    named = [n for n in by_size if sec[n] >= NAMED_SHARE * (hi - lo)]
+    named = named or by_size[:1]
+    after = (f" after {_phase(first)}"
+             if first is not None and first not in named else "")
+    return "idle in " + "+".join(_phase(n) for n in named) + after
+
+
 def launches(dispatches: list, calls: list) -> list:
     """Per dispatch span that holds a jitted call, (span start, call
     start, program name) of its last one."""
@@ -201,7 +246,7 @@ def reduce(path: str, top: int = 10) -> dict:
             else (float("-inf"), float("inf")))
     active = busy = 0.0
     idle_in = dict.fromkeys(KINDS, 0.0)
-    gaps = []
+    gaps, by_name = [], {}
     for dev in sorted(ops):
         evs = [(s + shift, e + shift) for s, e in ops[dev]]
         lo = min(s for s, _ in evs)
@@ -212,11 +257,16 @@ def reduce(path: str, top: int = 10) -> dict:
             for kind, sec in parts.items():
                 idle_in[kind] += sec
             gaps.append((gap[1] - gap[0], gap[0] - lo, parts))
+            name = gap_name(gap, pieces)
+            by_name[name] = (by_name.get(name, 0.0)
+                             + (gap[1] - gap[0]) / len(ops))
     out = {"devices": len(ops), "active_s": active / len(ops),
            "busy_s": busy / len(ops), "idle_s": (active - busy) / len(ops),
            "engine_spans": len(engine), "clock_offset_ms": None,
            "clock_shift_ms": shift * 1e3, "early_programs": None,
-           "idle_in_s": None, "gaps": None}
+           "idle_in_s": None, "gaps": None,
+           "idle_by_name": [[name, sec] for name, sec in sorted(
+               by_name.items(), key=lambda x: (-x[1], x[0]))[:top]]}
     if engine:
         out["idle_in_s"] = {k: v / len(ops) for k, v in idle_in.items()}
         out["gaps"] = [[at, sec, parts] for sec, at, parts
@@ -248,6 +298,13 @@ def _reduced(path: str, mtime: float) -> dict:
     return out
 
 
+def newest_trace(root: str):
+    """The newest ``.xplane.pb`` under ``root``, or None."""
+    found = [os.path.join(base, f) for base, _, files in os.walk(root)
+             for f in files if f.endswith(".xplane.pb")]
+    return max(found, key=os.path.getmtime) if found else None
+
+
 def reduced_newest(ctx: dict):
     """The reduction of the run's trace, or None when the run took
     none.  ``ctx`` carries no path: ``run.py`` wipes the cell's
@@ -255,12 +312,8 @@ def reduced_newest(ctx: dict):
     ``kbench/out`` is this run's."""
     if not ctx.get("trace"):
         return None
-    found = [os.path.join(base, f) for base, _, files in os.walk(OUT)
-             for f in files if f.endswith(".xplane.pb")]
-    if not found:
-        return None
-    path = max(found, key=os.path.getmtime)
-    return _reduced(path, os.path.getmtime(path))
+    path = newest_trace(OUT)
+    return _reduced(path, os.path.getmtime(path)) if path else None
 
 
 if __name__ == "__main__":

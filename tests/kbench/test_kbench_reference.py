@@ -68,7 +68,19 @@ def test_architectures_differ_as_the_configs_say():
     assert real["partial_rotary_factor"] == 0.75
 
 
-@pytest.mark.parametrize("perturb", ["drop_last_layer", "head_int8"])
+def test_the_reference_lists_the_perturbations_it_accepts():
+    """``tolerance.py`` reads the tuple from the file's source: it must
+    stay off JAX, and the device, while the reference child runs."""
+    import tolerance
+
+    path = os.path.join(KBENCH, "reference", "dense_decoder.py")
+    assert tolerance.perturbations(path) == dense_decoder.PERTURBATIONS
+    assert "" not in dense_decoder.PERTURBATIONS
+    with pytest.raises(ValueError, match="no PERTURBATIONS"):
+        tolerance.perturbations(os.path.join(KBENCH, "rooflines.py"))
+
+
+@pytest.mark.parametrize("perturb", dense_decoder.PERTURBATIONS)
 def test_a_cruder_computation_moves_the_reference(perturb):
     config = Manifest(REHEARSAL).config("tiny-tied-partial")["config"]
     params = _params(config)

@@ -128,7 +128,8 @@ def test_the_reference_child_refuses_another_platform(tmp_path):
     cfg = Manifest(REHEARSAL).config("tiny-untied")
     job = tmp_path / "job.json"
     job.write_text(json.dumps({
-        "config": cfg["config"], "weight_seed": 1, "platform": "tpu",
+        "reference": cfg["reference_file"], "config": cfg["config"],
+        "weight_seed": 1, "platform": "tpu",
         "dtype": "", "perturb": "",
         "requests": [{"tokens": [1, 2, 3], "start": 0}]}))
     res = subprocess.run(

@@ -1,6 +1,7 @@
 """The reduction from a profiler trace to device numbers."""
 
 import os
+from types import SimpleNamespace
 
 import pytest
 
@@ -25,11 +26,6 @@ def test_self_time_takes_nested_operations_out():
         [(s, e) for s, e, _ in events])
 
 
-def test_longest_gaps_first():
-    gaps = trace_reduce._gaps([(0, 1), (2, 3), (7, 8), (8.5, 9)], top=2)
-    assert gaps == [(4, 3), (1, 1)]
-
-
 @pytest.fixture(scope="module")
 def reduced():
     return trace_reduce.reduce(TRACE)
@@ -42,16 +38,37 @@ def test_recorded_tpu_trace_reduces(reduced):
     assert 0 < reduced["busy_s"] < reduced["window_s"]
     assert abs(sum(reduced["ops"].values()) - reduced["busy_s"]) \
         < 1e-6 * reduced["busy_s"] + 1e-9
-    assert len(reduced["gaps"]) >= 2            # the sleeps between calls
-    assert all(gap > 0 for _, gap in reduced["gaps"])
+    # each call is one program of a scan and its body's operations
+    assert reduced["modules"] and set(reduced["op_counts"]) == set(reduced["ops"])
+    assert all(n.split("/", 1)[0] in reduced["modules"] for n in reduced["ops"])
 
 
-def test_readers_on_the_recorded_trace(reduced):
-    from readers import trace_idle_pct
+def test_back_to_back_operations_stay_siblings_in_seconds(monkeypatch):
+    """Nanoseconds become seconds with one rounding each: an end worked
+    out as start + duration, both rounded, can pass the next start, the
+    next operation then counts as a child, and its time is never taken
+    out of the loop that holds both (0.30 s of a 3 s trace, PR 26)."""
+    import random
 
-    ctx = {"trace": reduced}
-    idle = trace_idle_pct.read(ctx)
-    assert 0 < idle < 100
-    assert abs(idle - 100.0 * (1 - reduced["busy_s"] / reduced["window_s"])) \
-        < 1e-6
-    assert trace_idle_pct.read({"trace": None}) is None
+    rng = random.Random(26)
+    at = 375904377.0                 # ns, as a real trace has them
+    inside, events = at + 1.0, []
+    for _ in range(4000):
+        dur = float(rng.randrange(200, 90000))
+        events.append(SimpleNamespace(name=f"%fusion.{len(events) % 7} = f32[] fusion()",
+                            start_ns=inside, duration_ns=dur))
+        inside += dur
+    events.append(SimpleNamespace(name="%while.1 = () while()", start_ns=at,
+                        duration_ns=inside + 1.0 - at))
+    module = SimpleNamespace(name="jit_step(123)", start_ns=at - 5.0,
+                   duration_ns=inside + 10.0 - at)
+    plane = SimpleNamespace(name="/device:TPU:0", lines=[
+        SimpleNamespace(name="XLA Modules", events=[module]),
+        SimpleNamespace(name="XLA Ops", events=events)])
+    monkeypatch.setattr(trace_reduce, "load", lambda path: SimpleNamespace(planes=[plane]))
+    got = trace_reduce.reduce("unused")
+    assert got["busy_s"] == pytest.approx((inside + 1.0 - at) * 1e-9)
+    assert sum(got["ops"].values()) == pytest.approx(got["busy_s"], rel=1e-9)
+    # the loop's own time is the two nanoseconds round its body
+    assert got["ops"]["jit_step/%while.1"] == pytest.approx(2e-9, abs=1e-12)
+    assert got["op_counts"]["jit_step/%while.1"] == 1

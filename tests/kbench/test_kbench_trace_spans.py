@@ -57,6 +57,30 @@ def test_an_idle_interval_splits_by_kind_and_adds_up():
     assert sum(trace_spans.split((40, 41), pieces).values()) == 1.0
 
 
+@pytest.mark.parametrize("interval,name", [
+    # one phase holds it all
+    ((4.5, 6.5), "idle in decode.wait"),
+    # the device ran dry in the wait; replay took the time
+    ((6.8, 9), "idle in decode.replay after decode.wait"),
+    # two phases of a quarter or more, largest first; decode's own 0.3
+    # is too small to be named, but it is where the gap began
+    ((3.2, 5), "idle in decode.wait+decode.dispatch after decode"),
+    # the thread in no span for most of it, engine.idle whole
+    ((9.5, 13), "idle in no-span+engine.idle after step"),
+    # no phase reaches a quarter: the largest alone
+    ((0, 30), "idle in no-span after step"),
+    # before the thread's first span and after its last
+    ((-5, -1), "unattributed"), ((40, 41), "unattributed")])
+def test_a_gap_is_named_by_the_phases_that_cover_it(interval, name):
+    pieces = trace_spans.innermost(LOOP)
+    assert trace_spans.gap_name(interval, pieces) == name
+    # no time of day in a name: the same gap one iteration later
+    later = [(s + 100, e + 100, n) for s, e, n in pieces]
+    moved = (interval[0] + 100, interval[1] + 100)
+    assert trace_spans.gap_name(moved, later) == name
+    assert len(name) <= 64                  # what the ledger keeps
+
+
 def test_the_clock_offset_is_the_earliest_program_against_its_call():
     dispatches = [(1.0, 1.006, "engine.decode.dispatch"),
                   (1.2, 1.202, "engine.prefill.dispatch"),
@@ -132,6 +156,24 @@ def test_recorded_spans_attribute_the_idle_time(recorded):
         assert sum(split.values()) == pytest.approx(sec, abs=1e-12)
 
 
+def test_recorded_gaps_are_summed_under_their_names(recorded):
+    named = recorded["idle_by_name"]
+    assert 1 <= len(named) <= 10
+    assert [sec for _, sec in named] == sorted(
+        (sec for _, sec in named), reverse=True)
+    names = [n for n, _ in named]
+    assert len(set(names)) == len(names)
+    # every idle interval is under some name: nothing lost in the summing
+    assert sum(sec for _, sec in named) == pytest.approx(
+        sum(recorded["idle_in_s"].values()))
+    # the loop slept 2 ms in its replay after each launch, three times,
+    # and that is the largest name; none carries a time or a digit
+    assert named[0][0] == "idle in decode.replay after decode.dispatch"
+    assert named[0][1] >= 3 * 0.002
+    assert all(n.startswith("idle in ") and not any(c.isdigit() for c in n)
+               for n in names)
+
+
 def test_recorded_clock_offset_is_applied(recorded):
     """That process's device clock ran about a millisecond early: the
     reduction finds it and moves the device's events."""
@@ -163,6 +205,8 @@ def test_a_trace_without_spans_gives_the_extent_and_nothing_else():
     assert t["devices"] == 1 and 0 < t["busy_s"] < t["active_s"]
     assert t["idle_s"] == pytest.approx(t["active_s"] - t["busy_s"])
     assert t["idle_in_s"] is None and t["gaps"] is None
+    # one name for all of its idle time, and it does not say why
+    assert t["idle_by_name"] == [["unattributed", pytest.approx(t["idle_s"])]]
     assert t["clock_offset_ms"] is None and t["clock_shift_ms"] == 0.0
     assert trace_active_idle_pct.read({"trace": None}) is None
     assert trace_idle_in_pct.read({"trace": None}, kind="replay") is None
