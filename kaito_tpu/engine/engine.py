@@ -24,6 +24,7 @@ step loop the reference leans on (SURVEY.md §3.1 "HOT LOOP").  Design:
 from __future__ import annotations
 
 import collections
+import dataclasses
 import logging
 import os
 import queue
@@ -241,6 +242,40 @@ def _patch_carry_row(tokens, positions, active, left, gstate, row):
     return (tokens.at[i].set(row[1]), positions.at[i].set(row[2]),
             active.at[i].set(True), left.at[i].set(row[3]),
             gstate.at[i].set(row[4]))
+
+
+@jax.jit
+def _first_token_step(logits, sampling, carry, rows, counts, prompt_seen,
+                      grows):
+    """What a completed prefill hands to decode, in one program
+    (docs/decode-loop.md): sample the first token of each row of
+    ``logits`` [n, V] from its slot's sampling state, and, given the
+    decode ``carry`` (_CARRY_FIELDS), join the rows to it on the device.
+    ``rows`` is int32 [n, 4 + _STOP_WIDTH]: slot, position, budget,
+    grammar row, then the slot's stop ids (-1-padded).  A row joins
+    ``active`` unless its budget is spent by this token or the token is
+    one of its stop ids: what _emit decides on the host.  ``carry``,
+    ``counts``/``prompt_seen`` ([S, V] penalty state) and ``grows``
+    ([n, V] grammar masks) may be None: each then compiles away.
+    Returns (carry, key, tokens, logprobs): key is the whole [S, 2]
+    key array with the rows' draws taken."""
+    sel = rows[:, 0]
+    sub = jax.tree.map(lambda a: a[sel], sampling)
+    if counts is not None:
+        counts, prompt_seen = counts[sel], prompt_seen[sel]
+    tok, sub = sample(logits, sub, counts, prompt_seen, grows)
+    lp = chosen_logprob(logits, tok)
+    key = sampling.key.at[sel].set(sub.key)
+    if carry is not None:
+        tokens, positions, active, left, gstate = carry
+        spent = rows[:, 2] - 1
+        hit = jnp.any(tok[:, None] == rows[:, 4:], axis=1)
+        carry = (tokens.at[sel].set(tok),
+                 positions.at[sel].set(rows[:, 1]),
+                 active.at[sel].set(~hit & (spent > 0)),
+                 left.at[sel].set(spent),
+                 gstate.at[sel].set(rows[:, 3]))
+    return carry, key, tok, lp
 
 
 class InferenceEngine:
@@ -785,7 +820,6 @@ class InferenceEngine:
 
         self._decode_fn = self._build_decode_fn()
         self._prefill_fns: dict[int, object] = {}
-        self._sample_one = jax.jit(sample)
         ra = cfg.decode_run_ahead
         if ra is None:
             # fused steps amortize per-dispatch overhead (jit-cache
@@ -818,8 +852,24 @@ class InferenceEngine:
         # step's own go on its timeline record
         self.drain_counts: dict[str, int] = {}
         self._step_drains: list[str] = []
+        # first tokens sampled and joined to the carry on the device,
+        # not yet read back: [(slot, slot.seq, prompt length), ...],
+        # the tokens and logprobs on the device, dispatch time.  Those
+        # the host had to read at once are counted by what made it
+        self._first_pending: "collections.deque[tuple]" = collections.deque()
+        self.first_token_blocking: dict[str, int] = {}
+        self.first_token_resolve_hist = None
         if self.async_dispatch:
             self.drain_counts = dict.fromkeys(self._DRAIN_REASONS, 0)
+            self.counters["first_tokens_deferred_total"] = 0
+            self.first_token_blocking = dict.fromkeys(
+                self._FIRST_TOKEN_BLOCKS, 0)
+            self.first_token_resolve_hist = Histogram(
+                "kaito:engine_first_token_resolve_seconds",
+                "Dispatch of a completed prefill's first-token program "
+                "to the token's emission", None,
+                buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                         0.25, 0.5, 1.0, 2.5))
             self.counters["h2d_uploads_total"] = 0
             # a window is primed when another was in flight at its
             # launch: the host's work for that one overlaps this one
@@ -1964,10 +2014,14 @@ class InferenceEngine:
         if self.async_dispatch:
             # the labelled family is easy to lose in a scrape's
             # reduction: the log keeps the reasons beside the windows
-            logger.info("decode windows: %d primed, %d unprimed; drains %s",
+            logger.info("decode windows: %d primed, %d unprimed; drains %s; "
+                        "first tokens: %d deferred, blocking %s",
                         self.counters["decode_windows_primed_total"],
                         self.counters["decode_windows_unprimed_total"],
-                        {k: v for k, v in self.drain_counts.items() if v})
+                        {k: v for k, v in self.drain_counts.items() if v},
+                        self.counters["first_tokens_deferred_total"],
+                        {k: v for k, v
+                         in self.first_token_blocking.items() if v})
         if self._spill_thread is not None:
             self._spill_q.put(None)
             self._spill_thread.join(timeout=10)
@@ -2286,6 +2340,7 @@ class InferenceEngine:
         # may alias donated-into-failure buffers — reset the pipeline
         # and force a full re-upload from the (authoritative) host side
         self._inflight = None
+        self._first_pending.clear()
         self._dev_state.clear()
         self._mark_state_dirty()
         self._fail_active_slots()
@@ -2983,22 +3038,7 @@ class InferenceEngine:
                            queue_wait=round(wait, 6))
         slot.prefill_pos = pos + m
         if slot.prefill_pos >= n:
-            if not req.prompt_counted:
-                # resume-after-preempt re-prefills prompt+generated; only
-                # the original prompt counts (once) toward the metric
-                self.counters["prompt_tokens_total"] += len(req.prompt_tokens)
-                req.prompt_counted = True
-            slot.prefilling = False
-            with self.phases.phase("engine.prefill.wait"):
-                first, first_lp = self._sample_first(i, logits)
-            # _sample_first blocked on the logits, so the elapsed time
-            # covers real compute (plus scheduler interleaving — the
-            # honest opportunity cost a transfer would avoid)
-            if slot.prefill_t0:
-                self.pd_costs.note_prefill(
-                    n - slot.prefill_base,
-                    time.monotonic() - slot.prefill_t0)
-            self._begin_decode(i, first, n, first_lp=first_lp)
+            self._complete_prefills([(i, n)], logits)
         return True
 
     def _advance_prefill_pack(self, pack_limit: int) -> bool:
@@ -3138,22 +3178,10 @@ class InferenceEngine:
             else:
                 rows_l = jnp.concatenate(
                     [lg[r:r + 1] for (_, _, lg, r) in completed], axis=0)
-            idxs = [i for (i, _, _, _) in completed]
-            with self.phases.phase("engine.prefill.wait"):
-                toks, lps = self._sample_first_batch(idxs, rows_l)
-            t_done = time.monotonic()
-            for (i, n, _, _), tok, lp in zip(completed, toks, lps):
-                slot = self.slots[i]
-                req = slot.request
-                if not req.prompt_counted:
-                    self.counters["prompt_tokens_total"] += \
-                        len(req.prompt_tokens)
-                    req.prompt_counted = True
-                slot.prefilling = False
-                if slot.prefill_t0:
-                    self.pd_costs.note_prefill(n - slot.prefill_base,
-                                               t_done - slot.prefill_t0)
-                self._begin_decode(i, tok, n, first_lp=lp)
+            # every sequence completing in the round, in ONE program
+            # over the gathered rows
+            self._complete_prefills([(i, n) for (i, n, _, _) in completed],
+                                    rows_l)
         return did
 
     def _dispatch_prefill_cp(self, i: int) -> bool:
@@ -3198,16 +3226,7 @@ class InferenceEngine:
                            bucket=bucket, slot=i, cp=True, pack=1,
                            queue_wait=round(wait, 6))
         slot.prefill_pos = n
-        if not req.prompt_counted:
-            self.counters["prompt_tokens_total"] += len(req.prompt_tokens)
-            req.prompt_counted = True
-        slot.prefilling = False
-        with self.phases.phase("engine.prefill.wait"):
-            first, first_lp = self._sample_first(i, logits)
-        if slot.prefill_t0:
-            self.pd_costs.note_prefill(n - slot.prefill_base,
-                                       time.monotonic() - slot.prefill_t0)
-        self._begin_decode(i, first, n, first_lp=first_lp)
+        self._complete_prefills([(i, n)], logits)
         return True
 
     def _dispatch_prefill_fresh(self, rows):
@@ -3306,81 +3325,165 @@ class InferenceEngine:
             jnp.asarray(tok_pgslot) if int8 else None, aid)
         return logits
 
-    def _sample_first_batch(self, idxs: list[int], logits
-                            ) -> tuple[list[int], list[float]]:
-        """Fused first-token sampling for every sequence completing in a
-        prefill round: ONE sampler dispatch over the gathered rows,
-        per-slot grammar rows honored (zero rows for unconstrained
-        slots are an exact no-op on the logits)."""
-        s = self.sampling
-        sel = jnp.asarray(np.asarray(idxs, np.int32))
-        sub = SamplingState(
-            temperature=s.temperature[sel], top_k=s.top_k[sel],
-            top_p=s.top_p[sel], key=s.key[sel], presence=s.presence[sel],
-            frequency=s.frequency[sel], repetition=s.repetition[sel],
-            min_p=s.min_p[sel])
-        gr = None
-        if any(self._gram_slots[i] is not None for i in idxs):
-            V = self.md.arch.vocab_size
-            rows = np.zeros((len(idxs), V), np.float32)
-            for j, i in enumerate(idxs):
-                gs = self._gram_slots[i]
-                if gs is not None:
-                    rows[j] = self._gram_row(gs)
-            gr = jnp.asarray(rows)
-        if self.token_counts is not None:
-            tok, sub = self._sample_one(
-                logits, sub, self.token_counts[sel],
-                self.prompt_seen[sel], gr)
-        elif gr is not None:
-            tok, sub = self._sample_one(logits, sub, None, None, gr)
-        else:
-            tok, sub = self._sample_one(logits, sub)
-        lps = chosen_logprob(jnp.asarray(logits), tok)
-        self.sampling = SamplingState(
-            temperature=s.temperature, top_k=s.top_k, top_p=s.top_p,
-            key=s.key.at[sel].set(sub.key),
-            presence=s.presence, frequency=s.frequency,
-            repetition=s.repetition, min_p=s.min_p)
-        return ([int(t) for t in np.asarray(tok)],
-                [float(x) for x in np.asarray(lps)])
+    # what makes the host read a completed prefill's first token back
+    # at once (docs/decode-loop.md): it must see the token before the
+    # next launch can be built
+    _FIRST_TOKEN_BLOCKS = ("grammar", "penalties", "stop_set",
+                           "speculation")
 
-    def _sample_first(self, slot_idx: int, logits) -> tuple[int, float]:
-        s = self.sampling
-        sub = SamplingState(
-            temperature=s.temperature[slot_idx:slot_idx + 1],
-            top_k=s.top_k[slot_idx:slot_idx + 1],
-            top_p=s.top_p[slot_idx:slot_idx + 1],
-            key=s.key[slot_idx:slot_idx + 1],
-            presence=s.presence[slot_idx:slot_idx + 1],
-            frequency=s.frequency[slot_idx:slot_idx + 1],
-            repetition=s.repetition[slot_idx:slot_idx + 1],
-            min_p=s.min_p[slot_idx:slot_idx + 1])
-        gs = self._gram_slots[slot_idx]
-        gr = (jnp.asarray(self._gram_row(gs))[None, :]
-              if gs is not None else None)
-        if self.token_counts is not None:
-            tok, sub = self._sample_one(
-                logits, sub, self.token_counts[slot_idx:slot_idx + 1],
-                self.prompt_seen[slot_idx:slot_idx + 1], gr)
-        elif gr is not None:
-            tok, sub = self._sample_one(logits, sub, None, None, gr)
-        else:
-            tok, sub = self._sample_one(logits, sub)
-        lp = float(chosen_logprob(jnp.asarray(logits), tok)[0])
-        self.sampling = SamplingState(
-            temperature=s.temperature, top_k=s.top_k, top_p=s.top_p,
-            key=s.key.at[slot_idx].set(sub.key[0]),
-            presence=s.presence, frequency=s.frequency,
-            repetition=s.repetition, min_p=s.min_p)
-        return int(tok[0]), lp
+    def _first_token_blocks(self, idxs: list[int]) -> Optional[str]:
+        """Why the first tokens of these slots cannot wait on the
+        device for the loop's next readback, or None.  Decided from
+        what the engine can observe: the synchronous loop has no window
+        to wait behind; speculation builds its windows from the host's
+        tokens; a grammar's automaton steps on the host, penalty counts
+        are updated from it, and a stop set wider than the device
+        matrix is checked on it."""
+        if not self.async_dispatch:
+            return "sync_loop"
+        if self.cfg.speculative_ngram > 0 or self.spec_draft is not None:
+            return "speculation"
+        for i in idxs:
+            req = self.slots[i].request
+            if self._gram_slots[i] is not None:
+                return "grammar"
+            if req.params.has_penalties:
+                return "penalties"
+            if len(self._stop_set(req)) > _STOP_WIDTH:
+                return "stop_set"
+        return None
 
-    def _begin_decode(self, slot_idx: int, first: int, n: int,
-                      first_lp: Optional[float] = None):
-        """Transition a slot to decoding after its prompt KV is in place
-        (prefill completed or KV imported) and emit the first token.
-        ``first_lp`` is None on the PD-import path (the logits never
-        existed on this engine)."""
+    def _complete_prefills(self, done: list[tuple[int, int]],
+                           logits) -> None:
+        """The prompts of ``done`` [(slot, prompt length)] are written;
+        ``logits`` [len(done), V] are their last positions'.  One
+        program samples every first token and joins the rows to the
+        decode carry on the device, queued behind the window in flight
+        and the prefill (_first_token_step).  The host does not wait
+        for it: the slots become active in its mirrors, so that the
+        next launch reserves their pages and carries their stop ids,
+        and the tokens are read back where the loop next waits anyway
+        (_resolve_first_tokens).  Where the host must see the token
+        first (_first_token_blocks) the same program runs without the
+        carry, its outputs are read at once, and each row joins the
+        carry from the host's mirrors as an imported one does."""
+        idxs = [i for i, _ in done]
+        why = self._first_token_blocks(idxs)
+        rows = np.full((len(done), 4 + _STOP_WIDTH), -1, np.int32)
+        counts = seen = grows = None
+        for j, (i, n) in enumerate(done):
+            slot = self.slots[i]
+            req = slot.request
+            if not req.prompt_counted:
+                # resume-after-preempt re-prefills prompt+generated; only
+                # the original prompt counts (once) toward the metric
+                self.counters["prompt_tokens_total"] += len(req.prompt_tokens)
+                req.prompt_counted = True
+            self._enter_decode(i, n)
+            rows[j, :4] = (i, n, slot.remaining, self._gram_state[i])
+            if why is None:
+                ids = sorted(self._stop_set(req))
+                rows[j, 4:4 + len(ids)] = ids
+            if req.params.has_penalties and self.token_counts is not None:
+                counts, seen = self.token_counts, self.prompt_seen
+            gs = self._gram_slots[i]
+            if gs is not None:
+                # zero rows for unconstrained slots are an exact no-op
+                # on the logits
+                if grows is None:
+                    grows = np.zeros((len(done), self.md.arch.vocab_size),
+                                     np.float32)
+                grows[j] = self._gram_row(gs)
+        st = self._dev_state
+        carry = None
+        if why is None and not self._state_dirty & self._DEVICE_ADVANCED \
+                and all(f in st for f in self._CARRY_FIELDS):
+            carry = tuple(st[f] for f in self._CARRY_FIELDS)
+        # the blocking path's span is the wait it ends in, as it was
+        # when the sample was read back inside it
+        with self.phases.phase("engine.prefill.dispatch" if why is None
+                               else "engine.prefill.wait"):
+            t0 = time.monotonic()
+            carry, key, tok, lp = _first_token_step(
+                jnp.asarray(logits), self.sampling, carry, jnp.asarray(rows),
+                counts, seen, None if grows is None else jnp.asarray(grows))
+            self.sampling = dataclasses.replace(self.sampling, key=key)
+            if why is not None:
+                # blocks on the logits, behind any window in flight
+                toks, lps = (np.asarray(a).tolist() for a in (tok, lp))
+        staged = [(i, self.slots[i].seq, n) for i, n in done]
+        if why is None:
+            if carry is not None:
+                st.update(zip(self._CARRY_FIELDS, carry))
+                self.counters["h2d_uploads_total"] += 1
+            else:
+                # a full upload from the mirrors is owed (after a drain,
+                # if a window is in flight) and carries these slots too;
+                # the tokens are resolved before it
+                self._mark_state_dirty("positions", "active", "last_tokens",
+                                       "left")
+            self._start_readback(tok, lp)
+            self._first_pending.append((staged, tok, lp, t0))
+            self.counters["first_tokens_deferred_total"] += len(done)
+            return
+        if why in self.first_token_blocking:
+            self.first_token_blocking[why] += len(done)
+        self._land_first_tokens(staged, toks, lps, t0, join=True)
+
+    @staticmethod
+    def _start_readback(*arrays) -> None:
+        """Start the copy to the host of arrays the loop reads later."""
+        for arr in arrays:
+            try:
+                arr.copy_to_host_async()
+            except Exception:      # backend without async copies
+                pass
+
+    def _resolve_first_tokens(self, ready_only: bool = False) -> bool:
+        """Read back the first tokens still on the device, oldest
+        first, and emit them (docs/decode-loop.md has the points this
+        is called from).  ``ready_only`` stops at the first one whose
+        program has not finished instead of waiting for it."""
+        did = False
+        while self._first_pending:
+            staged, tok, lp, t0 = self._first_pending[0]
+            if ready_only and not tok.is_ready():
+                break
+            self._first_pending.popleft()
+            with self.phases.phase("engine.prefill.resolve"):
+                toks, lps = (np.asarray(a).tolist() for a in (tok, lp))
+                self._land_first_tokens(staged, toks, lps, t0, join=False)
+            did = True
+        return did
+
+    def _land_first_tokens(self, staged: list, toks: list, lps: list,
+                           t0: float, join: bool) -> None:
+        """The host half of a completed prefill, once its first token
+        is known: cost model, time to first token, _emit, and for a
+        request that ended on it the eviction.  ``join`` writes the row
+        into the device carry from the mirrors, for a token the device
+        program did not join itself."""
+        now = time.monotonic()
+        for (i, seq, n), tok, lp in zip(staged, toks, lps):
+            slot = self.slots[i]
+            req = slot.request
+            if req is None or slot.seq != seq:
+                continue        # evicted since: nobody waits for it
+            if slot.prefill_t0:
+                # taken once the token is back, so the elapsed time
+                # covers real compute (plus scheduler interleaving — the
+                # honest opportunity cost a transfer would avoid)
+                self.pd_costs.note_prefill(n - slot.prefill_base,
+                                           now - slot.prefill_t0)
+            self._emit_first(i, tok, lp)
+            if join and slot.request is req:
+                self._join_device_batch(i)
+        if self.first_token_resolve_hist is not None:
+            self.first_token_resolve_hist.observe(time.monotonic() - t0)
+
+    def _enter_decode(self, slot_idx: int, n: int) -> None:
+        """A slot's prompt KV is in place (prefill completed or KV
+        imported): from here the host plans it as a decoding row."""
         slot = self.slots[slot_idx]
         req = slot.request
         slot.prefilling = False
@@ -3390,16 +3493,30 @@ class InferenceEngine:
                              self._capacity_tokens - n)
         self.positions[slot_idx] = n
         self.active[slot_idx] = True
-        self.last_tokens[slot_idx] = first
         self._remaining[slot_idx] = slot.remaining
         self._batch_epoch += 1
+
+    def _emit_first(self, slot_idx: int, first: int,
+                    first_lp: Optional[float]) -> None:
+        """Emit a decoding slot's first token; a request that ends on
+        it is evicted here.  ``first_lp`` is None on the PD-import path
+        (the logits never existed on this engine)."""
+        req = self.slots[slot_idx].request
+        self.last_tokens[slot_idx] = first
         if req.first_token_time is None:
             req.first_token_time = time.monotonic()
         if req.params.has_penalties and self.token_counts is not None:
             self.token_counts = self.token_counts.at[
                 slot_idx, first].add(1)
         self._emit(slot_idx, first, logprob=first_lp)
-        if slot.request is req:
+
+    def _begin_decode(self, slot_idx: int, first: int, n: int):
+        """Transition a slot whose KV was imported to decoding and emit
+        the first token that came with it."""
+        req = self.slots[slot_idx].request
+        self._enter_decode(slot_idx, n)
+        self._emit_first(slot_idx, first, None)
+        if self.slots[slot_idx].request is req:
             # still decoding after its first token: the device learns of
             # the slot now (a request that ended on it never reaches
             # the device at all)
@@ -3995,6 +4112,11 @@ class InferenceEngine:
         toks = toks.tolist()          # [K, S]
         acts = acts.tolist()          # [K, S] — device active BEFORE step k
         lps = lps.tolist()            # [K, S]
+        # slots whose first token is still on the device: the loop
+        # resolves it before it retires a window that holds the row
+        # (_retire_window), so none of these is active in this trace
+        held = {i for staged, *_ in self._first_pending
+                for i, _, _ in staged}
         for k in range(K):
             tk, ak, lk = toks[k], acts[k], lps[k]
             for i, slot in enumerate(self.slots):
@@ -4002,6 +4124,12 @@ class InferenceEngine:
                 if not ak[i] or slot.request is None \
                         or slot.seq != owners[i]:
                     continue
+                if i in held:
+                    # never a row's later tokens before its first
+                    self._resolve_first_tokens()
+                    held = ()
+                    if slot.request is None:
+                        continue
                 self.positions[i] += 1
                 slot.position += 1
                 self._emit(i, tk[i], logprob=lk[i])
@@ -4017,10 +4145,12 @@ class InferenceEngine:
     # so host postprocess (stop replay, _emit, streaming, scheduling)
     # overlaps device compute.  The scan already deactivates slots
     # in-scan on stop/budget, so the host replay is reconciliation, not
-    # control.  Any host-side batch change (admit, abort, preempt,
-    # spill, deadline eviction) drains the pipeline to depth 1 first —
-    # those paths read resume_tokens()/host mirrors and must see every
-    # emitted token.
+    # control.  What only the host knows (abort, preempt, spill,
+    # deadline eviction) drains the pipeline to depth 1 first — those
+    # paths read resume_tokens()/host mirrors and must see every
+    # emitted token; an admission into a free slot and its prefill do
+    # not, and the prefill's first token stays on the device until the
+    # loop next waits (_complete_prefills).
 
     def _mark_state_dirty(self, *names: str, why: str = "") -> None:
         """Host mutated loop-state mirrors: re-upload them at the next
@@ -4036,13 +4166,15 @@ class InferenceEngine:
 
     def _join_device_batch(self, slot_idx: int) -> None:
         """Make a slot that has just begun decoding live in the loop
-        state the next window is launched from.  With a window in
-        flight the host mirrors of the other slots lag the device, so
-        only this slot's row is written into the carry, by one small
-        program queued behind that window (and behind the prefill that
-        filled the slot's pages): nothing is drained, nothing is rolled
-        back.  The window in flight was launched with the slot
-        inactive, so its trace holds no row for it."""
+        state the next window is launched from, for a first token the
+        host knows (a KV import's, a blocking path's: a prefill's own
+        program joins the row itself, _first_token_step).  With a
+        window in flight the host mirrors of the other slots lag the
+        device, so only this slot's row is written into the carry, by
+        one small program queued behind that window: nothing is
+        drained, nothing is rolled back.  The window in flight was
+        launched with the slot inactive, so its trace holds no row for
+        it."""
         if not self.async_dispatch:
             return
         st = self._dev_state
@@ -4095,7 +4227,11 @@ class InferenceEngine:
                "gstate": self._gram_state}
         for name in self._STATE_FIELDS:
             if name in self._state_dirty or name not in self._dev_state:
-                self._dev_state[name] = jnp.asarray(src[name])
+                # a copy: the CPU backend may alias a numpy buffer
+                # instead of copying it, and the host writes its mirrors
+                # again (an admission, a completed prefill) while the
+                # window launched from this upload is still queued
+                self._dev_state[name] = jnp.asarray(src[name].copy())
                 self.counters["h2d_uploads_total"] += 1
         self._state_dirty.clear()
         self._dirty_reason = ""
@@ -4112,6 +4248,9 @@ class InferenceEngine:
         ``drain`` names what forced a retire with nothing launched
         behind it, on the wait's span."""
         attrs = {"drain": drain} if drain else {}
+        # a first token that is back already goes out before the
+        # window's tokens; none is waited for here
+        self._resolve_first_tokens(ready_only=True)
         with self.phases.phase("engine.decode.wait", **attrs):
             # blocks until the readback lands
             host = [np.asarray(a) for a in win[1:4]]
@@ -4119,6 +4258,11 @@ class InferenceEngine:
         with self.phases.phase("engine.decode.replay"):
             self._replay_window(win[0], *host, win[4])
             win.clear()
+        # the prefills completed since this window's launch are queued
+        # right behind it, and the next window behind them: their first
+        # tokens are back or nearly so, and must be out before that
+        # window's replay emits the rows' later ones
+        self._resolve_first_tokens()
 
     # what can force the pipeline back to depth 1 (docs/decode-loop.md)
     _DRAIN_REASONS = ("finish", "admission", "import", "dirty_carry",
@@ -4136,6 +4280,10 @@ class InferenceEngine:
             self.drain_counts[reason] += 1
             self._step_drains.append(reason)
             self._retire_window(win, drain=reason)
+        else:
+            # no window to wait behind: the first tokens still on the
+            # device are part of what the mirrors must hold
+            self._resolve_first_tokens()
 
     def _drain_reason(self) -> Optional[str]:
         """Why the window in flight must be retired before this step
@@ -4215,11 +4363,7 @@ class InferenceEngine:
             nxt, pos, act, left, gst = carry
             self._dev_state.update(last_tokens=nxt, positions=pos,
                                    active=act, left=left, gstate=gst)
-            for arr in (toks, acts, lps):
-                try:
-                    arr.copy_to_host_async()
-                except Exception:      # backend without async copies
-                    pass
+            self._start_readback(toks, acts, lps)
             self.counters["decode_steps_total"] += K
         self._gap_last = gap
         if self.dispatch_gap_hist is not None:
@@ -4241,6 +4385,12 @@ class InferenceEngine:
         # a drain inside a phase nests its own decode.wait and
         # decode.replay spans there: the innermost span names the work
         with phase("engine.schedule"):
+            # first tokens still on the device: with no window in
+            # flight the loop has no later wait to read them at (an
+            # engine that was idle when the prompt came); with one,
+            # only those that are back already
+            did0 = self._resolve_first_tokens(
+                ready_only=self._inflight is not None)
             now = time.monotonic()
             if now - self._last_deadline_sweep >= 0.05:
                 self._last_deadline_sweep = now
@@ -4252,7 +4402,7 @@ class InferenceEngine:
                         and now > s.request.deadline
                         for s in self.slots):
                     self._drain_pipeline("deadline")
-                did0 = self._expire_deadlines()
+                did0 = self._expire_deadlines() or did0
             if now - self._last_export_tick >= 1.0:
                 self._last_export_tick = now
                 self.kv_exports.tick()

@@ -385,7 +385,7 @@ class EngineMetrics:
             # registry rather than duplicating series
             for attr in ("step_hist", "queue_wait_hist",
                          "dispatch_gap_hist", "prefill_pack_hist",
-                         "prefill_wait_hist"):
+                         "prefill_wait_hist", "first_token_resolve_hist"):
                 h = getattr(engine, attr, None)
                 if h is not None:
                     r.register(h)
@@ -584,6 +584,23 @@ class EngineMetrics:
                       labels=("reason",),
                       fn=lambda: {(k,): float(v) for k, v
                                   in dict(engine.drain_counts).items()})
+                Gauge("kaito:engine_first_tokens_deferred_total",
+                      "First tokens sampled and joined to the decode "
+                      "carry on the device, read back where the loop "
+                      "next waited", r,
+                      fn=lambda: engine.counters.get(
+                          "first_tokens_deferred_total", 0))
+                Gauge("kaito:engine_first_tokens_blocking_total",
+                      "First tokens the host read back at once, "
+                      "behind the window in flight and the prefill", r,
+                      fn=lambda: sum(dict(
+                          engine.first_token_blocking).values()))
+                Gauge("kaito:engine_first_tokens_blocking_by_reason_total",
+                      "First tokens read back at once, by what the "
+                      "host had to see the token for", r,
+                      labels=("reason",),
+                      fn=lambda: {(k,): float(v) for k, v in dict(
+                          engine.first_token_blocking).items()})
             if getattr(engine, "devprof", None) is not None:
                 # sampled device-time attribution (engine/devprof.py):
                 # families exist ONLY with --devprof-interval-s > 0 —

@@ -12,6 +12,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @jax.tree_util.register_dataclass
@@ -47,38 +48,48 @@ class SamplingState:
 
     def reset_slot(self, i: int) -> "SamplingState":
         """Greedy/no-mask/no-penalty row without touching the PRNG key
-        (admission reseeds it): retirement stays a few tiny scatters."""
-        return SamplingState(
-            temperature=self.temperature.at[i].set(0.0),
-            top_k=self.top_k.at[i].set(0),
-            top_p=self.top_p.at[i].set(1.0),
-            key=self.key,
-            presence=self.presence.at[i].set(0.0),
-            frequency=self.frequency.at[i].set(0.0),
-            repetition=self.repetition.at[i].set(1.0),
-            min_p=self.min_p.at[i].set(0.0),
-        )
+        (admission reseeds it)."""
+        return _set_row(self, np.int32(i), None, *_ROW_DEFAULTS)
 
     def set_slot(self, i: int, *, temperature: float, top_k: int, top_p: float,
                  seed: int, presence: float = 0.0, frequency: float = 0.0,
                  repetition: float = 1.0, min_p: float = 0.0
                  ) -> "SamplingState":
         key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
-        return SamplingState(
-            temperature=self.temperature.at[i].set(temperature),
-            top_k=self.top_k.at[i].set(top_k),
-            top_p=self.top_p.at[i].set(top_p),
-            key=self.key.at[i].set(jnp.asarray(key, jnp.uint32)),
-            presence=self.presence.at[i].set(presence),
-            frequency=self.frequency.at[i].set(frequency),
-            repetition=self.repetition.at[i].set(repetition),
-            min_p=self.min_p.at[i].set(min_p),
-        )
+        return _set_row(
+            self, np.int32(i), jnp.asarray(key, jnp.uint32),
+            np.int32(top_k),
+            np.asarray([temperature, top_p, presence, frequency, repetition,
+                        min_p], np.float32))
 
     @property
     def any_penalty(self) -> jax.Array:
         return jnp.any((self.presence != 0.0) | (self.frequency != 0.0)
                        | (self.repetition != 1.0))
+
+
+# top_k and [temperature, top_p, presence, frequency, repetition, min_p]
+# of a row that samples nothing: greedy, no mask, no penalty
+_ROW_DEFAULTS = (np.int32(0),
+                 np.asarray([0.0, 1.0, 0.0, 0.0, 1.0, 0.0], np.float32))
+
+
+@jax.jit
+def _set_row(state: SamplingState, i, key, top_k, floats) -> SamplingState:
+    """Write one slot's row in ONE program (``key`` None keeps the
+    row's key).  Field by field it was some ninety small programs an
+    admission, and on a TPU the engine thread waited in them for the
+    decode window in flight (PERF.md section 6, PR 29)."""
+    return SamplingState(
+        temperature=state.temperature.at[i].set(floats[0]),
+        top_k=state.top_k.at[i].set(top_k),
+        top_p=state.top_p.at[i].set(floats[1]),
+        key=state.key if key is None else state.key.at[i].set(key),
+        presence=state.presence.at[i].set(floats[2]),
+        frequency=state.frequency.at[i].set(floats[3]),
+        repetition=state.repetition.at[i].set(floats[4]),
+        min_p=state.min_p.at[i].set(floats[5]),
+    )
 
 
 def chosen_logprob(logits: jax.Array, tokens: jax.Array) -> jax.Array:

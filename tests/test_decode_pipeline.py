@@ -98,8 +98,16 @@ def sustained():
     eng._admit = spy
     reqs = _submit_mix(eng, stop_tok)
     _run(eng, reqs)
+
+    # the same loop with every first token read back at once: the
+    # path a grammar or a penalty takes, here for the whole mix
+    blocking = _mk(True)
+    blocking._first_token_blocks = lambda idxs: "stop_set"
+    blocking_reqs = _submit_mix(blocking, stop_tok)
+    _run(blocking, blocking_reqs)
     return dict(sync=sync_reqs, eng=eng, reqs=reqs, stop_tok=stop_tok,
-                free_run=free_run,
+                free_run=free_run, blocking=blocking,
+                blocking_reqs=blocking_reqs,
                 in_flight_at_admission=in_flight_at_admission)
 
 
@@ -123,9 +131,15 @@ def test_environment_pins_an_unset_field(monkeypatch, env, want):
     assert _mk(not want).async_dispatch is (not want)
 
 
+@pytest.mark.parametrize("path", ["reqs", "blocking_reqs"],
+                         ids=["deferred", "blocking"])
 @pytest.mark.parametrize("what", ["tokens", "logprobs", "finish_reason"])
-def test_sustained_admission_is_bit_identical(sustained, what):
-    for a, b in zip(sustained["sync"], sustained["reqs"]):
+def test_sustained_admission_is_bit_identical(sustained, what, path):
+    """The synchronous loop against the two-deep loop, its first
+    tokens left on the device until the loop next waits (deferred) or
+    read back at once (blocking): one program samples them in all
+    three."""
+    for a, b in zip(sustained["sync"], sustained[path]):
         if what == "tokens":
             assert a.output_tokens == b.output_tokens
             assert len(b.output_tokens) > 0
@@ -338,19 +352,301 @@ def test_an_admission_that_preempts_still_drains():
     assert reqs[0].output_tokens == ref[0].output_tokens
 
 
-def test_a_request_that_ends_on_its_first_token_never_reaches_the_device():
-    """max_tokens=1 finishes inside _begin_decode: the carry is neither
-    patched nor dirtied, and the neighbour's window stays primed."""
+def _spy_on_windows(eng):
+    """Record every replayed window's [K, S] ``active`` trace."""
+    seen = []
+    replay = eng._replay_window
+
+    def spy(K, toks, acts, lps, owners):
+        seen.append(np.array(acts))
+        return replay(K, toks, acts, lps, owners)
+
+    eng._replay_window = spy
+    return seen
+
+
+def _first_token_of(prompt):
+    probe = _mk(False)
+    r = probe.submit(prompt, _greedy(2))
+    _run(probe, [r])
+    return r.output_tokens[0]
+
+
+@pytest.mark.parametrize("how", ["budget", "stop_id"])
+def test_a_request_that_ends_on_its_first_token_never_reaches_a_window(how):
+    """max_tokens=1, or a stop id first: the carry's program runs for
+    the row and leaves it inactive on the device, as _emit decides on
+    the host; no window ever holds the row, nothing drains, and the
+    neighbour's windows stay primed."""
+    prompt = [7, 7, 7]
+    if how == "budget":
+        params, want = _greedy(1), "length"
+    else:
+        params = _greedy(30, stop_token_ids=(_first_token_of(prompt),))
+        want = "stop"
     eng = _mk(True)
     long = eng.submit([2, 4, 6], _greedy(40))
     _decoding_with_a_window_in_flight(eng, [long])
+    windows = _spy_on_windows(eng)
     uploads = eng.counters["h2d_uploads_total"]
-    one = eng.submit([7, 7, 7], _greedy(1))
-    _run(eng, [one])
+    unprimed = eng.counters["decode_windows_unprimed_total"]
+    one = eng.submit(prompt, params)
+    slots = set()
+    for _ in range(200):
+        if one.finish_reason:
+            break
+        eng.step()
+        slots |= {i for i, s in enumerate(eng.slots) if s.request is one}
+    assert one.finish_reason == want
     assert len(one.output_tokens) == 1
+    assert eng.counters["first_tokens_deferred_total"] == 2
+    (slot,) = slots
+    # the device retired the row in the program that sampled its token
+    assert not bool(np.asarray(eng._dev_state["active"])[slot])
+    assert windows and not any(w[:, slot].any() for w in windows)
     assert not any(eng.drain_counts.values())
     assert not eng._state_dirty & eng._DEVICE_ADVANCED
-    # page table and adapter row of the slot it passed through, twice
-    assert eng.counters["h2d_uploads_total"] - uploads <= 4
+    assert eng.counters["decode_windows_unprimed_total"] == unprimed
+    # page table and adapter row of the slot it passed through, twice,
+    # and the row the program joined
+    assert eng.counters["h2d_uploads_total"] - uploads <= 5
     _run(eng, [long])
     assert len(long.output_tokens) == 40
+
+
+def _arrival_with_a_window_in_flight():
+    """Two requests decoding, a window in flight, and a third whose
+    prefill has just been dispatched: its first token is on the device
+    and the host has not seen it.  Returns the engine, the arrival and
+    its reference from the synchronous loop."""
+    ref = _mk(False)
+    want = ref.submit([5, 6, 7, 8], _greedy(20, logprobs=True))
+    _run(ref, [want])
+    eng = _mk(True)
+    olds = [eng.submit([9, 8, 7], _greedy(60)),
+            eng.submit([2, 4, 6], _greedy(60))]
+    _decoding_with_a_window_in_flight(eng, olds)
+    new = eng.submit([5, 6, 7, 8], _greedy(20, logprobs=True))
+    for _ in range(20):
+        if eng._first_pending:
+            break
+        eng.step()
+    assert eng._first_pending and eng._inflight is not None
+    assert new.output_tokens == []
+    return eng, new, want, olds
+
+
+def test_the_host_does_not_wait_for_a_first_token_at_the_prefill(sustained):
+    """No step of the deferred run spent time in prefill.wait, the
+    blocking run's steps did, and resolution has its own span."""
+    deferred = sustained["eng"].timeline.records()
+    assert not any("prefill.wait" in r for r in deferred)
+    assert sum(1 for r in deferred if "prefill.resolve" in r) >= N_REQUESTS - 4
+    blocking = sustained["blocking"].timeline.records()
+    assert sum(1 for r in blocking if "prefill.wait" in r) == N_REQUESTS
+    assert not any("prefill.resolve" in r for r in blocking)
+
+
+def test_a_pending_first_token_holds_its_slot():
+    """Between the dispatch and the resolution the slot is neither
+    free nor prefilling; the host plans it as a decoding row (pages,
+    stop ids) and the device has it in the carry with its token."""
+    eng, new, want, _ = _arrival_with_a_window_in_flight()
+    (staged, tok, lp, _t0), = eng._first_pending
+    (slot, seq, n), = staged
+    assert eng.slots[slot].request is new and eng.slots[slot].seq == seq
+    assert not eng.slots[slot].prefilling and n == 4
+    assert eng.active[slot] and eng.positions[slot] == 4
+    assert bool(np.asarray(eng._dev_state["active"])[slot])
+    assert int(np.asarray(eng._dev_state["last_tokens"])[slot]) \
+        == int(np.asarray(tok)[0]) == want.output_tokens[0]
+    assert int(np.asarray(eng._dev_state["left"])[slot]) == 19
+    assert not eng._state_dirty & eng._DEVICE_ADVANCED
+    assert new.first_token_time is None
+    _run(eng, [new])
+    assert new.output_tokens == want.output_tokens
+    assert new.output_logprobs == want.output_logprobs
+    assert eng.first_token_resolve_hist._total == 3
+
+
+def _emissions(eng, req):
+    """Spy on _emit: for ``req``, whether each token came from a
+    window's replay."""
+    order, state = [], {"replaying": False}
+    emit, replay = eng._emit, eng._replay_window
+
+    def spy_emit(slot_idx, token, logprob=None):
+        if eng.slots[slot_idx].request is req:
+            order.append(state["replaying"])
+        return emit(slot_idx, token, logprob=logprob)
+
+    def spy_replay(*a):
+        state["replaying"] = True
+        try:
+            return replay(*a)
+        finally:
+            state["replaying"] = False
+
+    eng._emit, eng._replay_window = spy_emit, spy_replay
+    return order
+
+
+def test_a_first_token_is_emitted_before_any_replayed_token_of_its_row():
+    eng, new, want, _ = _arrival_with_a_window_in_flight()
+    order = _emissions(eng, new)
+    _run(eng, [new])
+    assert new.output_tokens == want.output_tokens
+    assert order[0] is False and all(order[1:])
+
+
+def test_a_replay_that_meets_an_unresolved_first_token_resolves_it_first():
+    """The guard behind the order: a window that holds the row's later
+    tokens, retired with the first still on the device (the loop never
+    lets it come to that), emits the first before them."""
+    eng, new, want, _ = _arrival_with_a_window_in_flight()
+    order = _emissions(eng, new)
+    resolve = eng._resolve_first_tokens
+    eng._resolve_first_tokens = lambda ready_only=False: False
+    eng._drain_pipeline("idle")       # the window launched before the row
+    assert eng._first_pending and new.output_tokens == []
+    eng._decode_async(4)              # a window that holds the row
+    assert eng._first_pending
+    win, eng._inflight = eng._inflight, None
+    eng._resolve_first_tokens = \
+        lambda ready_only=False: False if ready_only else resolve()
+    eng._retire_window(win)
+    assert not eng._first_pending
+    # all five inside the replay, the guard's first
+    assert new.output_tokens == want.output_tokens[:5]
+    assert order == [True] * 5
+    eng._resolve_first_tokens = resolve
+    _run(eng, [new])
+    assert new.output_tokens == want.output_tokens
+
+
+def test_a_drain_with_a_first_token_pending_resolves_it_before_the_upload():
+    """Something only the host knows dirties the carry while a first
+    token is on the device: the drain reads it back, the mirrors hold
+    the row, and the upload from them rolls nothing back."""
+    ref = _mk(False)
+    wants = [ref.submit(p, _greedy(60)) for p in ([9, 8, 7], [2, 4, 6])]
+    _run(ref, wants)
+    eng, new, want, olds = _arrival_with_a_window_in_flight()
+    (staged, *_), = eng._first_pending
+    slot = staged[0][0]
+    eng._mark_state_dirty("active", "left", why="finish")
+    eng._drain_pipeline("finish")
+    assert not eng._first_pending and eng._inflight is None
+    assert new.output_tokens == want.output_tokens[:1]
+    assert eng.last_tokens[slot] == want.output_tokens[0]
+    assert eng.active[slot] and eng.positions[slot] == 4
+    uploads = eng.counters["h2d_uploads_total"]
+    eng.step()
+    assert eng.counters["h2d_uploads_total"] - uploads >= 2
+    _run(eng, [new] + olds)
+    assert new.output_tokens == want.output_tokens
+    assert [r.output_tokens for r in olds] == [w.output_tokens for w in wants]
+    assert eng.drain_counts["finish"] == 1
+
+
+def _grammar_of(eng):
+    from kaito_tpu.engine.grammar import GrammarSpec, canonical_schema
+
+    schema = {"type": "object", "properties": {"ok": {"type": "boolean"}},
+              "required": ["ok"]}
+    return eng.grammar_cache.get(
+        GrammarSpec("json_schema", canonical_schema(schema)), eng.tokenizer)
+
+
+@pytest.mark.parametrize("reason", ["grammar", "penalties", "stop_set",
+                                    "speculation"])
+def test_what_the_host_must_see_first_takes_the_blocking_path(reason):
+    """A request whose first token the host needs before the next
+    launch can be built is read back at once and counted by reason;
+    its neighbour's is deferred; both streams are the synchronous
+    loop's."""
+    cfg = dict(speculative_ngram=3) if reason == "speculation" else {}
+
+    def special(eng):
+        if reason == "grammar":
+            return SamplingParams(max_tokens=30, temperature=0.0,
+                                  grammar=_grammar_of(eng))
+        if reason == "penalties":
+            return _greedy(20, presence_penalty=0.7, frequency_penalty=0.3)
+        if reason == "stop_set":
+            return _greedy(20, stop_token_ids=tuple(range(300, 309)))
+        return _greedy(20)
+
+    out = []
+    for async_on in (False, True):
+        eng = _mk(async_on, **cfg)
+        plain = eng.submit([2, 4, 6], _greedy(24))
+        for _ in range(8):
+            eng.step()
+        marked = eng.submit([10, 20, 30], special(eng))
+        _run(eng, [plain, marked])
+        out.append((eng, plain, marked))
+    (_, ref_plain, ref_marked), (eng, plain, marked) = out
+    assert marked.output_tokens == ref_marked.output_tokens
+    assert plain.output_tokens == ref_plain.output_tokens
+    both = 2 if reason == "speculation" else 1
+    assert eng.first_token_blocking[reason] == both
+    assert sum(eng.first_token_blocking.values()) == both
+    assert eng.counters["first_tokens_deferred_total"] == 2 - both
+    assert not eng._first_pending
+
+
+def test_deferred_and_blocking_add_up_to_the_prompts_completed(sustained):
+    for name, deferred in (("eng", N_REQUESTS), ("blocking", 0)):
+        eng = sustained[name]
+        assert eng.counters["first_tokens_deferred_total"] == deferred
+        assert eng.counters["first_tokens_deferred_total"] \
+            + sum(eng.first_token_blocking.values()) \
+            == eng.counters["prefill_steps_total"] == N_REQUESTS
+        assert eng.first_token_resolve_hist._total == N_REQUESTS
+    # the fixture forced the path: the reason it gave is counted
+    assert sustained["blocking"].first_token_blocking["stop_set"] \
+        == N_REQUESTS
+    assert "first_tokens_deferred_total" not in _mk(False).counters
+
+
+def test_the_first_token_families_exist_where_the_loop_runs(sustained):
+    from kaito_tpu.engine.metrics import EngineMetrics
+
+    text = EngineMetrics(engine=sustained["blocking"]).registry.expose()
+    assert "kaito:engine_first_tokens_deferred_total 0" in text
+    assert f"kaito:engine_first_tokens_blocking_total {N_REQUESTS}" in text
+    assert ('kaito:engine_first_tokens_blocking_by_reason_total'
+            f'{{reason="stop_set"}} {N_REQUESTS}') in text
+    assert ('kaito:engine_first_tokens_blocking_by_reason_total'
+            '{reason="grammar"} 0') in text
+    assert ("kaito:engine_first_token_resolve_seconds_count "
+            f"{N_REQUESTS}") in text
+    off = EngineMetrics(engine=_mk(False)).registry.expose()
+    assert "engine_first_token" not in off
+
+
+def test_the_packed_path_defers_every_row_of_a_round():
+    """Without the prefill-pack 1 annotation several prompts complete
+    in one round: one program over the gathered rows, one readback,
+    every row deferred."""
+    prompts = [[3 + i, 5 + i, 7 + i, 9 + i] for i in range(4)]
+    out = []
+    for async_on in (False, True):
+        eng = _mk(async_on, prefill_pack=0)
+        reqs = [eng.submit(p, _greedy(12 + 3 * i, logprobs=True))
+                for i, p in enumerate(prompts)]
+        if async_on:
+            eng.step()
+            (staged, tok, _lp, _t0), = eng._first_pending
+            assert len(staged) == 4 and np.asarray(tok).shape == (4,)
+            assert all(eng.active[i] for i, _, _ in staged)
+        _run(eng, reqs)
+        out.append((eng, reqs))
+    (_, ref), (eng, reqs) = out
+    for a, b in zip(ref, reqs):
+        assert a.output_tokens == b.output_tokens
+        assert a.output_logprobs == b.output_logprobs
+    assert eng.counters["first_tokens_deferred_total"] == 4
+    assert not any(eng.first_token_blocking.values())
+    assert eng.first_token_resolve_hist._total == 1

@@ -247,3 +247,69 @@ def test_min_p_masks_tail():
         0, temperature=1.0, top_k=0, top_p=1.0, seed=s))[0][0])
         for s in range(1, 30)}
     assert len(toks) > 1
+
+
+def _rows_field_by_field(state, i, *, temperature, top_k, top_p, seed,
+                         presence=0.0, frequency=0.0, repetition=1.0,
+                         min_p=0.0):
+    """SamplingState.set_slot as it was written before its fields went
+    into one program: the reference the program is held to."""
+    import jax
+    import jax.numpy as jnp
+
+    from kaito_tpu.engine.sampler import SamplingState
+
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+    return SamplingState(
+        temperature=state.temperature.at[i].set(temperature),
+        top_k=state.top_k.at[i].set(top_k),
+        top_p=state.top_p.at[i].set(top_p),
+        key=state.key.at[i].set(jnp.asarray(key, jnp.uint32)),
+        presence=state.presence.at[i].set(presence),
+        frequency=state.frequency.at[i].set(frequency),
+        repetition=state.repetition.at[i].set(repetition),
+        min_p=state.min_p.at[i].set(min_p))
+
+
+_ROWS = [dict(temperature=0.0, top_k=0, top_p=1.0, seed=1),
+         dict(temperature=0.8, top_k=40, top_p=0.9, seed=107, presence=0.3,
+              frequency=0.1, repetition=1.2, min_p=0.05),
+         dict(temperature=1.0, top_k=0, top_p=1.0, seed=2 ** 31 - 1),
+         dict(temperature=0.7, top_k=3, top_p=0.5, seed=2 ** 40 + 5)]
+
+
+@pytest.mark.parametrize("row", range(len(_ROWS)))
+def test_a_slots_sampling_row_is_written_by_one_program(row):
+    """An admission writes its slot's row of the sampling state with
+    one program (sampler._set_row), bit for bit what the eight
+    field-by-field updates wrote, and a retirement resets it with the
+    same program, the key kept.  New values are arguments, not
+    constants: no admission compiles."""
+    from kaito_tpu.engine import sampler
+    from kaito_tpu.engine.sampler import SamplingState
+
+    fields = ("temperature", "top_k", "top_p", "key", "presence",
+              "frequency", "repetition", "min_p")
+
+    def same(a, b):
+        for f in fields:
+            x, y = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
+            assert x.dtype == y.dtype and x.shape == y.shape, f
+            assert (x == y).all(), (f, x, y)
+
+    got = want = SamplingState.create(4, seed=3)
+    got, want = (got.set_slot(1, **_ROWS[row]),
+                 _rows_field_by_field(want, 1, **_ROWS[row]))
+    same(got, want)
+    traces = sampler._set_row._cache_size()
+    again = got.set_slot(1, **_ROWS[(row + 1) % len(_ROWS)])
+    again = again.set_slot(3, **_ROWS[row])
+    assert sampler._set_row._cache_size() == traces
+    same(again.reset_slot(3), SamplingState(
+        temperature=again.temperature.at[3].set(0.0),
+        top_k=again.top_k.at[3].set(0),
+        top_p=again.top_p.at[3].set(1.0), key=again.key,
+        presence=again.presence.at[3].set(0.0),
+        frequency=again.frequency.at[3].set(0.0),
+        repetition=again.repetition.at[3].set(1.0),
+        min_p=again.min_p.at[3].set(0.0)))
