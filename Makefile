@@ -1,9 +1,10 @@
 # kaito-tpu build & test surface (counterpart of the reference Makefile
-# targets: unit-test, inference-api-e2e, rag-service-test, bench).
+# targets: unit-test, inference-api-e2e, rag-service-test; the
+# benchmark is `python kbench/run.py`, see BENCHMARK.json and PERF.md).
 
 PYTHON ?= python
 
-.PHONY: all native unit-test unit-test-fast unit-test-slow engine-test rag-test chaos kvq wquant kvpool kvtier lora structured obs devprof slo itl fleet autoscale spec qos asyncloop prefill overlap bench chip-smoke serve manager epp clean
+.PHONY: all native unit-test unit-test-fast unit-test-slow rag-test chaos kvq wquant kvpool kvtier lora structured obs devprof slo itl fleet autoscale spec qos asyncloop prefill overlap chip-smoke serve manager epp clean
 
 all: native
 
@@ -13,24 +14,24 @@ native:
 unit-test:
 	$(PYTHON) -m pytest tests/ -q
 
-# operator/controller/RAG/API surface only — skips the compile-heavy
-# engine/mesh tier (marked slow); finishes in well under a minute
+# tier 1, the selection the check runs: everything but the tests marked
+# `slow`, and a test is `slow` only by a mark written beside it with
+# its reason (its seconds, what the check's machine lacks, or the
+# failure it keeps the mark for).  The engine's own tests are in it.
+# About ten minutes on six workers (`-n 6 --dist loadfile`), several
+# times that on one.
 unit-test-fast:
 	$(PYTHON) -m pytest tests/ -q -m "not slow"
 
 unit-test-slow:
 	$(PYTHON) -m pytest tests/ -q -m "slow"
 
-engine-test:
-	$(PYTHON) -m pytest tests/test_engine_core.py tests/test_engine_model.py \
-	  tests/test_server.py tests/test_pallas_ops.py -q
-
 rag-test:
 	$(PYTHON) -m pytest tests/test_rag.py -q
 
 # fault-injection suite (docs/failure-domains.md): registry/router
-# chaos runs in the fast tier too; this target adds the compile-heavy
-# engine containment tests
+# chaos and the compile-heavy engine containment tests (all of it is in
+# tier 1 too), then the flight recorder's fatal-path legs
 chaos:
 	$(PYTHON) -m pytest tests/test_failpoints.py -q
 	$(PYTHON) -m pytest tests/test_itl_slo.py -q -m "not slow" \
@@ -106,10 +107,11 @@ devprof:
 	$(PYTHON) -m pytest tests/test_devprof.py -q
 
 # collective-compute overlap suite (docs/multichip.md): ring/reference
-# parity, prefetch bitwise pin, annotation plumbing (fast tier), then
-# the TP=2 greedy A-B smoke on a 4-device virtual CPU mesh (slow tier)
+# parity, prefetch bitwise pin, annotation plumbing, the engine gate and
+# the greedy A-B legs on the default 8-device virtual mesh, then the
+# TP=2 A-B smoke once more on a 4-device one
 overlap:
-	$(PYTHON) -m pytest tests/test_comm_overlap.py -q -m "not slow"
+	$(PYTHON) -m pytest tests/test_comm_overlap.py -q
 	XLA_FLAGS=--xla_force_host_platform_device_count=4 $(PYTHON) -m pytest \
 	  "tests/test_comm_overlap.py::test_tp_greedy_bit_equivalent_on_vs_off[2]" \
 	  tests/test_comm_overlap.py::test_gate_off_byte_identical_exposition -q
@@ -175,9 +177,6 @@ prefill:
 	  tests/test_flash_prefill.py -q
 	KAITO_PREFILL_PACK=8 $(PYTHON) -m pytest \
 	  tests/test_chunked_prefill.py -q
-
-bench:
-	$(PYTHON) bench.py
 
 # the real server at a real model's widths on the chip (one chip
 # process at a time; needs a TPU).  Rehearse on the CPU first:

@@ -1,17 +1,18 @@
-"""Parity and micro-benchmarks for the engine's Pallas kernels.
+"""The chip smoke's parity leg for the engine's Pallas kernels.
 
 Every kernel is built as a ``KernelCase`` at phi-4-mini shapes (H=24,
 Hkv=8, D=128, page 64, bf16) next to the pure-JAX function it must
 match.  The kernels are compiled for the chip (never interpreted), so
-everything here except ``--overlap-ring`` needs a TPU; the interpreter
-is covered by tests/test_pallas_ops.py.  All test data is generated ON
-DEVICE with jax.random.
+this needs a TPU; the interpreter is covered by
+tests/test_pallas_ops.py.  All test data is generated ON DEVICE with
+jax.random.  It times nothing: a kernel's time and roofline share come
+from the benchmark's trace (kbench/, PERF.md section 3).
 
 Usage:  python benchmarks/kernel_bench.py --parity
-        python benchmarks/kernel_bench.py [--decode] [--prefill] [--iters N]
 
-``--parity`` is the chip smoke's kernels leg (chip_smoke.py): one JSON
-line per case, exit 1 if any case fails to compile or to match.
+This is ``chip_smoke.py``'s ``kernels`` leg: one JSON line per case,
+then one naming the device; exit 1 if any case fails to compile or to
+match.
 """
 
 from __future__ import annotations
@@ -21,22 +22,11 @@ import dataclasses
 import json
 import os
 import sys
-import time
 from typing import Callable, Optional
 
 # make `python benchmarks/kernel_bench.py` work from anywhere (the
 # script dir, not the repo root, is what python puts on sys.path)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-if ("--overlap-ring" in sys.argv
-        and "xla_force_host_platform_device_count"
-        not in os.environ.get("XLA_FLAGS", "")):
-    # the ring needs >= 2 devices; give the CPU backend a virtual
-    # 4-chip mesh BEFORE jax initializes (the flag only affects the
-    # host platform, so it is a no-op on a real multi-chip slice)
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=4").strip()
 
 import jax
 import jax.numpy as jnp
@@ -67,8 +57,10 @@ class KernelCase:
     why: str                   # where the tolerance comes from
     relative: bool = False     # tol bounds err / max|reference|
     mask: Optional[jax.Array] = None   # entries with a defined result
-    unit: str = ""             # what `work` counts, for the bench rows
-    work: float = 0.0          # bytes or FLOPs one call must move/do
+    # bytes or FLOPs one call must move/do, and which: read by nothing
+    # here; kept for the move into kbench/rooflines.py (ROADMAP D5)
+    unit: str = ""
+    work: float = 0.0
 
 
 def _f32(x):
@@ -280,122 +272,15 @@ def run_parity() -> int:
     return 1 if failed else 0
 
 
-# ---------------------------------------------------------------------
-# timing rows
-# ---------------------------------------------------------------------
-
-def _timeit(fn, *args, iters: int = 50) -> float:
-    fn(*args).block_until_ready()          # compile + warm
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(*args)
-    out.block_until_ready()
-    return (time.perf_counter() - t0) / iters
-
-
-def bench_case(case: KernelCase, iters: int) -> None:
-    res = parity(case)
-    print(f"{case.name} parity: max err = {res['max_err']:.4g} "
-          f"(tol {case.tol:g}{', relative' if case.relative else ''})")
-    for impl, fn in (("pallas", case.kernel), ("jax", case.reference)):
-        dt = _timeit(jax.jit(fn), *case.args, iters=iters)
-        print(f"{case.name}[{impl}]: {dt * 1e6:9.1f} us/call, "
-              f"{case.work / dt / 1e9:8.1f} G{case.unit}/s")
-
-
-def bench_overlap_ring(iters: int) -> None:
-    """Pipelined ring collectives (ops/overlap_collectives.py): parity
-    vs the pure-lax psum reference and per-hop ring traffic.  Runs on
-    any >= 2-device mesh — CPU CI gets one via the --overlap-ring
-    XLA_FLAGS hook above, so the hop structure the TPU executes is
-    exactly what this row times."""
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from kaito_tpu.engine.ops.overlap_collectives import (
-        all_gather_matmul, overlap_linear)
-
-    devs = jax.devices()
-    if len(devs) < 2:
-        print("overlap-ring: skipped (needs >= 2 devices; run with "
-              "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-        return
-    n = 4 if len(devs) >= 4 else 2
-    mesh = Mesh(np.array(devs[:n]), ("tensor",))
-    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    rows, K, N = 8, 2048, 2048
-    kx, kw = jax.random.split(jax.random.PRNGKey(2))
-    x = jax.random.normal(kx, (rows, K), dtype)
-    w = jax.random.normal(kw, (K, N), dtype)
-
-    def traced(mode):
-        # KAITO_COMM_OVERLAP is read at TRACE time: pin it around the
-        # warm-up call so each jit bakes in exactly one body
-        prev = os.environ.get("KAITO_COMM_OVERLAP")
-        os.environ["KAITO_COMM_OVERLAP"] = mode
-        try:
-            f = jax.jit(lambda x, w: overlap_linear(x, w, mesh))
-            f(x, w).block_until_ready()
-        finally:
-            if prev is None:
-                os.environ.pop("KAITO_COMM_OVERLAP", None)
-            else:
-                os.environ["KAITO_COMM_OVERLAP"] = prev
-        return f
-
-    ring, ref = traced("ring"), traced("jax")
-    err = float(jnp.max(jnp.abs(_f32(ring(x, w)) - _f32(ref(x, w)))))
-    print(f"overlap-ring parity vs psum reference: max abs err = {err:.5f}")
-    # per-device ring traffic: (n-1) reduce-scatter hops + (n-1)
-    # all-gather hops, each moving one [rows, N/n] partial
-    hop_bytes = rows * (N // n) * jnp.dtype(dtype).itemsize
-    ring_bytes = 2 * (n - 1) * hop_bytes
-    for name, fn in (("ring", ring), ("psum-ref", ref)):
-        dt = _timeit(fn, x, w, iters=iters)
-        print(f"overlap[{name}]: {dt * 1e3:8.3f} ms/call, "
-              f"{ring_bytes / dt / 1e9:6.2f} GB/s ring traffic "
-              f"({n - 1} hops x {hop_bytes} B x 2 phases)")
-    # the column-parallel dual: x chunks rotate while each device
-    # matmuls the arrived chunk against its out-shard's row block
-    ag = jax.jit(lambda x, w: all_gather_matmul(x, w, mesh))
-    err = float(jnp.max(jnp.abs(_f32(ag(x, w)) - _f32(x @ w))))
-    dt = _timeit(ag, x, w, iters=iters)
-    print(f"overlap[ag+mm]: {dt * 1e3:8.3f} ms/call, "
-          f"max abs err = {err:.5f}")
-
-
 def main() -> None:
     from kaito_tpu.utils.platform import enable_compile_cache
 
     enable_compile_cache()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--parity", action="store_true",
+    ap.add_argument("--parity", action="store_true", required=True,
                     help="compile and check every kernel; JSON lines")
-    ap.add_argument("--decode", action="store_true")
-    ap.add_argument("--decode-int8", action="store_true")
-    ap.add_argument("--gemv-int8", action="store_true")
-    ap.add_argument("--gemv-int4", action="store_true")
-    ap.add_argument("--prefill", action="store_true")
-    ap.add_argument("--overlap-ring", action="store_true")
-    ap.add_argument("--iters", type=int, default=50)
-    args = ap.parse_args()
-    if args.parity:
-        sys.exit(run_parity())
-    picked = [name for flag, names in (
-        (args.decode, ("decode_bf16",)),
-        (args.decode_int8, ("decode_bf16", "decode_int8kv")),
-        (args.gemv_int8, ("gemv_int8", "gemv_int8_prefetch")),
-        (args.gemv_int4, ("gemv_int4", "gemv_int4_prefetch")),
-        (args.prefill, ("flash_prefill", "flash_prefill_packed")),
-    ) if flag for name in names]
-    run_all = not picked and not args.overlap_ring
-    dev = jax.devices()[0]
-    print(f"platform: {dev.platform}, device_kind: {dev.device_kind}, "
-          f"count: {len(jax.devices())}")
-    for name in (CASES if run_all else dict.fromkeys(picked)):
-        bench_case(CASES[name](), args.iters)
-    if args.overlap_ring or run_all:
-        bench_overlap_ring(args.iters)
+    ap.parse_args()
+    sys.exit(run_parity())
 
 
 if __name__ == "__main__":

@@ -189,9 +189,10 @@ def plan_workspace(store: Store, ws: Workspace):
     except ValueError as e:
         raise ValueError(
             f"invalid kaito-tpu.io/kv-pool-disk annotation: {e}")
-    # CP prefill auto-carve is evidence-gated (plan_parallelism
-    # docstring: BENCH_r05 cp_speedup 0.68 < 1.0) — serve plans
-    # only carve a sequence axis when the user opts in
+    # CP prefill auto-carve is off by default (plan_parallelism
+    # docstring: cp_speedup 0.68 in round 5's BENCH_r05, a file not in
+    # the tree, and no chip reading since) — serve plans only carve a
+    # sequence axis when the user opts in
     cp_opt_in = ws.metadata.annotations.get(
         "kaito-tpu.io/cp-autocarve", "") == "true"
     plan = plan_parallelism(md, chip, workload=workload,

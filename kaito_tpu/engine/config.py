@@ -40,10 +40,11 @@ class EngineConfig:
     # weight-only quantization: "" (off) | "int8" (per-out-channel
     # symmetric) | "int4" (packed two-per-byte, per-group g=128
     # per-out-channel scales; fused Pallas dequant matmul on TPU —
-    # docs/quantization.md).  Decode is param-bandwidth-bound, so
-    # halving/quartering weight bytes is a direct throughput lever;
-    # the reference's vLLM surface exposes the same knob as
-    # --quantization.
+    # docs/quantization.md).  Decode streams every weight a step, so
+    # halving/quartering weight bytes should raise throughput; not
+    # measured on a chip (PERF.md section 7: quantized weights have
+    # only CPU tests).  The reference's vLLM surface exposes the same
+    # knob as --quantization.
     quantization: str = ""
     seed: int = 0
     tensor_parallel: int = 1             # TP degree (mesh "tensor" axis)

@@ -521,9 +521,9 @@ def summarize_window(slices: List[Slice],
     phase_pct = {p: 100.0 * v / wall_us for p, v in phase_us.items()}
     attributed_pct = (100.0 * attributed_us / busy_us) if busy_us else 0.0
 
-    # Achieved-vs-peak rates beside bench.py's mfu_pct/hbm_roofline_pct:
-    # window token throughput against the chip peaks, attributed to the
-    # buckets that consume them (matmul ⇒ FLOPs, everything ⇒ HBM).
+    # Achieved-vs-peak rates: window token throughput against the chip
+    # peaks, attributed to the buckets that consume them (matmul ⇒
+    # FLOPs, everything ⇒ HBM).
     matmul_pct_of_peak = hbm_pct_of_peak = 0.0
     if roofline and capture_s > 0 and window_tokens > 0:
         tok_s = window_tokens / capture_s

@@ -385,10 +385,9 @@ class InferenceEngine:
         # Only a flat TP>=2 mesh qualifies: PP drives decode through
         # its own executor, CP only reshapes prefill, single-chip has
         # no collective to hide.
-        co = cfg.comm_overlap if getattr(cfg, "comm_overlap", None) \
-            is not None else (os.environ.get("KAITO_COMM_OVERLAP", "")
-                              .strip().lower()
-                              not in ("", "0", "false", "off"))
+        co = cfg.comm_overlap if cfg.comm_overlap is not None else (
+            os.environ.get("KAITO_COMM_OVERLAP", "").strip().lower()
+            not in ("", "0", "false", "off"))
         self.comm_overlap = False
         if co and self.mesh is not None and self.pp_exec is None:
             from kaito_tpu.parallel.sharding import SERVE_RULES, ring_axis
@@ -502,7 +501,7 @@ class InferenceEngine:
         # kaito:adapter_load_failures_total{reason} family; shared with
         # the cache's own counter dict when the cache is on)
         self.adapter_load_failures: dict[str, int] = {}
-        if (getattr(cfg, "adapter_slots", 0) > 0 and self.pp_exec is None
+        if (cfg.adapter_slots > 0 and self.pp_exec is None
                 and not self.model.is_mla):
             # dynamic multi-LoRA (docs/multi-lora.md): fixed-capacity
             # slot table sized NOW so /v1/adapters hot-loads are pure
@@ -513,9 +512,9 @@ class InferenceEngine:
 
             self.adapter_cache = AdapterCache(
                 self.model, slots=cfg.adapter_slots,
-                rmax=getattr(cfg, "adapter_rmax", 16),
+                rmax=cfg.adapter_rmax,
                 base_model=self.md.name,
-                host_bytes=getattr(cfg, "adapter_host_bytes", 0),
+                host_bytes=cfg.adapter_host_bytes,
                 allow_base_mismatch=getattr(
                     cfg, "adapter_allow_base_mismatch", False),
                 mesh=self.mesh)
@@ -531,8 +530,8 @@ class InferenceEngine:
                     pass        # counted + logged by the cache
             self.params = {**self.params,
                            "serve_lora": self.adapter_cache.serve_lora}
-        elif cfg.adapters_dir or getattr(cfg, "adapter_slots", 0) > 0:
-            if getattr(cfg, "adapter_slots", 0) > 0:
+        elif cfg.adapters_dir or cfg.adapter_slots > 0:
+            if cfg.adapter_slots > 0:
                 logger.warning(
                     "adapter cache requested but unsupported on this "
                     "engine (PP or MLA); falling back to boot-time "
@@ -612,7 +611,7 @@ class InferenceEngine:
         # None when the feature is off — every pool code path gates on
         # it, keeping scheduling and /metrics byte-identical to before.
         self.kv_pool = None
-        if getattr(cfg, "kv_pool_enabled", False):
+        if cfg.kv_pool_enabled:
             from kaito_tpu.engine.kv_pool import PrefixPageStore
 
             self.kv_pool = PrefixPageStore(cfg.kv_pool_bytes)
@@ -628,12 +627,12 @@ class InferenceEngine:
         self._spill_q: Optional[queue.Queue] = None
         self._spill_thread: Optional[threading.Thread] = None
         if (self.kv_pool is not None
-                and getattr(cfg, "kv_pool_disk_bytes", 0) > 0):
+                and cfg.kv_pool_disk_bytes > 0):
             import tempfile
 
             from kaito_tpu.engine.kv_pool import DiskPageStore
 
-            root = getattr(cfg, "kv_pool_disk_dir", "") or os.path.join(
+            root = cfg.kv_pool_disk_dir or os.path.join(
                 tempfile.gettempdir(), "kaito-kv-tier")
             self.kv_tier = DiskPageStore(root, cfg.kv_pool_disk_bytes)
             self._spill_q = queue.Queue(maxsize=256)
@@ -679,7 +678,7 @@ class InferenceEngine:
         # preemption evicts the lowest-priority newest sequence.
         from kaito_tpu.engine.qos import parse_qos_config
 
-        self.qos = parse_qos_config(getattr(cfg, "qos_config", ""))
+        self.qos = parse_qos_config(cfg.qos_config)
         self._tenant_queues: dict[str, "collections.deque[Request]"] = {}
         self._drr_order: dict[int, "collections.deque[str]"] = {}
         self._drr_deficit: dict[str, float] = {}
@@ -798,18 +797,15 @@ class InferenceEngine:
         # speculative-replay and async-dispatch-replay retire paths.
         # Off (default): itl_hist is None, _emit takes no extra work,
         # and the /metrics exposition is byte-identical.
-        itl = cfg.itl_enabled if getattr(cfg, "itl_enabled", None) \
-            is not None else False
-        if not itl:
-            itl = os.environ.get("KAITO_ITL", "") in ("1", "true")
-        self.itl_enabled = bool(itl)
+        self.itl_enabled = cfg.itl_enabled or (
+            os.environ.get("KAITO_ITL", "") in ("1", "true"))
         self.itl_hist = None
         # server wires this to SLOWatchdog.observe_itl(gap, tenant)
         self.itl_observer = None
         self._itl_time = time.monotonic
         if self.itl_enabled:
             self._itl_stall_s = max(
-                1e-6, float(getattr(cfg, "slo_itl_p99_ms", 250.0)) * 1e-3)
+                1e-6, float(cfg.slo_itl_p99_ms) * 1e-3)
             self.counters["itl_stalls_total"] = 0
             self.itl_hist = Histogram(
                 "kaito:inter_token_latency_seconds",
@@ -823,9 +819,11 @@ class InferenceEngine:
         ra = cfg.decode_run_ahead
         if ra is None:
             # fused steps amortize per-dispatch overhead (jit-cache
-            # walk, arg staging, runtime RPC on remote plugins); 16 is
-            # the measured knee on a v5e — beyond it, emission
-            # burstiness grows faster than the amortization gain
+            # walk, arg staging, runtime RPC on remote plugins); beyond
+            # some depth emission burstiness grows faster than the
+            # amortization gain.  16 is the depth every chip run of the
+            # benchmark has served at (PERF.md); no record holds a
+            # sweep of depths, so it is a default and not a knee
             ra = 16 if jax.default_backend() == "tpu" else 1
         self.run_ahead = max(1, int(ra))
         self._decode_multi_fns: dict[int, object] = {}
@@ -839,7 +837,7 @@ class InferenceEngine:
         # pin it.  PP drives decode through its own executor and
         # multi-process engines run lockstep off the step broadcast, so
         # both keep the synchronous loop whatever was asked.
-        ad = getattr(cfg, "async_dispatch", None)
+        ad = cfg.async_dispatch
         if ad is None:
             env = os.environ.get("KAITO_ASYNC_DISPATCH", "").strip().lower()
             ad = (True if env in ("1", "true")
@@ -911,8 +909,8 @@ class InferenceEngine:
         # constrained admission, so grammar-free engines keep the [1,1]
         # placeholder path compiled away and never retrace.
         self.grammar_cache = GrammarCache(
-            entries=getattr(cfg, "grammar_cache_entries", 64),
-            max_states=getattr(cfg, "grammar_max_states", 512))
+            entries=cfg.grammar_cache_entries,
+            max_states=cfg.grammar_max_states)
         self._gram_table: Optional[GrammarTable] = None
         self._gram_slots: list[Optional[GrammarSlot]] = [None] * S
         self._gram_state = np.zeros((S,), np.int32)
@@ -932,13 +930,13 @@ class InferenceEngine:
         # by default: no sampler thread, no kaito:device_* families,
         # /debug/device 403 — the exposition stays byte-identical.
         self.devprof = None
-        if getattr(cfg, "devprof_interval_s", 0.0) > 0:
+        if cfg.devprof_interval_s > 0:
             from kaito_tpu.engine.devprof import DeviceProfiler
 
             self.devprof = DeviceProfiler(
                 interval_s=cfg.devprof_interval_s,
-                window_s=getattr(cfg, "devprof_window_s", 0.25),
-                ring=getattr(cfg, "devprof_ring", 16),
+                window_s=cfg.devprof_window_s,
+                ring=cfg.devprof_ring,
                 roofline=self._devprof_roofline(),
                 tokens_fn=lambda: self.counters["generation_tokens_total"])
             logger.info("device profiler enabled: %.3gs window every "
@@ -951,9 +949,9 @@ class InferenceEngine:
 
     def _devprof_roofline(self) -> dict:
         """Chip peaks + model constants for devprof's achieved-vs-peak
-        window rates — the same math as bench._roofline_metrics, minus
-        the per-sequence KV term (batch composition changes mid-window,
-        so the weight stream is the stable lower bound)."""
+        window rates: the weight stream only, without the per-sequence
+        KV term (batch composition changes mid-window, so the weight
+        stream is the stable lower bound)."""
         from kaito_tpu.sku.catalog import CHIP_CATALOG
 
         chip = CHIP_CATALOG.get("v5e")
@@ -2951,7 +2949,7 @@ class InferenceEngine:
         round-robin single-slot scheduler byte-identically.  Pipeline
         parallelism keeps the serial path — its prefill runs through the
         stage executor, which has no packed route."""
-        pack = int(getattr(self.cfg, "prefill_pack", 0))
+        pack = int(self.cfg.prefill_pack)
         if pack <= 0:
             pack = int(os.environ.get("KAITO_PREFILL_PACK", "0") or "0")
         if pack <= 0:
@@ -2961,6 +2959,15 @@ class InferenceEngine:
         if pack <= 1:
             return self._advance_prefill_single()
         return self._advance_prefill_pack(pack)
+
+    def _fail_prefill(self, i: int, e: Exception) -> None:
+        """Fail the request staged in slot ``i`` over a prefill error
+        and free the slot without committing its pages."""
+        req = self.slots[i].request
+        self._evict_slot(i, commit=False)
+        self._fail_request(req, etype="prefill_failed",
+                           message=f"prefill failed: "
+                                   f"{type(e).__name__}: {e}")
 
     def _advance_prefill_single(self) -> bool:
         """Run ONE bounded prefill chunk for one staged slot
@@ -3016,10 +3023,7 @@ class InferenceEngine:
                         *args, jnp.asarray([pos], np.int32), aid)
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
-            self._evict_slot(i, commit=False)
-            self._fail_request(req, etype="prefill_failed",
-                               message=f"prefill failed: "
-                                       f"{type(e).__name__}: {e}")
+            self._fail_prefill(i, e)
             self._recover_cache_if_poisoned()
             return True
         self.counters["prefill_steps_total"] += 1
@@ -3096,6 +3100,24 @@ class InferenceEngine:
         if not picks:
             return False
 
+        # a fault scoped to one request fails that request and not its
+        # pack-mates: the failpoint fires per row BEFORE rows share a
+        # dispatch; a failure of a dispatch itself (below) is the
+        # group's, which is its real domain
+        sound = []
+        for p in picks:
+            req = self.slots[p[0]].request
+            try:
+                FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
+            except Exception as e:
+                logger.exception("prefill failed for %s", req.req_id)
+                self._fail_prefill(p[0], e)
+                continue
+            sound.append(p)
+        if not sound:
+            return True
+        picks = sound
+
         # group into dispatches, preserving priority order of first
         # members: fresh-complete prompts segment-pack per adapter
         # (batch-axis per bucket for MLA, which has no packed kernel),
@@ -3122,10 +3144,6 @@ class InferenceEngine:
             t0 = time.monotonic()
             try:
                 with self.phases.phase("engine.prefill.dispatch"):
-                    for (i, _, _, _) in rows:
-                        FAILPOINTS.fire(
-                            "engine.prefill",
-                            req_id=self.slots[i].request.req_id)
                     if gk[0] == "seg" and len(rows) > 1:
                         logits = self._dispatch_prefill_packed(rows)
                     elif gk[0] == "ctx":
@@ -3138,11 +3156,7 @@ class InferenceEngine:
                 logger.exception("prefill dispatch failed (%d slots)",
                                  len(rows))
                 for (i, _, _, _) in rows:
-                    req = self.slots[i].request
-                    self._evict_slot(i, commit=False)
-                    self._fail_request(req, etype="prefill_failed",
-                                       message=f"prefill failed: "
-                                               f"{type(e).__name__}: {e}")
+                    self._fail_prefill(i, e)
                 self._recover_cache_if_poisoned()
                 return True
             dur = time.monotonic() - t0
@@ -3205,10 +3219,7 @@ class InferenceEngine:
                     jnp.asarray(self.page_tables[i][None]), aid)
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
-            self._evict_slot(i, commit=False)
-            self._fail_request(req, etype="prefill_failed",
-                               message=f"prefill failed: "
-                                       f"{type(e).__name__}: {e}")
+            self._fail_prefill(i, e)
             self._recover_cache_if_poisoned()
             return True
         self.counters["prefill_steps_total"] += 1

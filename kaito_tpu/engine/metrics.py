@@ -407,7 +407,7 @@ class EngineMetrics:
                 if slots is not None:
                     return len(slots)
                 return engine.cfg.max_num_seqs * max(
-                    1, getattr(engine.cfg, "data_parallel", 1))
+                    1, engine.cfg.data_parallel)
 
             def _occupancy():
                 return engine.num_running / max(1, _slots_total())

@@ -27,7 +27,7 @@ Two ring primitives (both run INSIDE ``shard_map`` per-device bodies):
     against the matching row block of its out-sharded weight — the
     all-gather hides behind the partial dots.  (The wired decode path
     uses rs+ag; this pair is the building block for fusing the gather
-    into the NEXT projection and is exercised by tests/kernel_bench.)
+    into the NEXT projection and is exercised by tests.)
 
 ``overlap_linear`` is the model-facing entry: a ``shard_map`` over the
 mesh's tensor axis wrapping ring reduce-scatter + ring all-gather, with

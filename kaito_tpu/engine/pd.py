@@ -829,85 +829,3 @@ def should_import_from_disk(nbytes: int, n_tokens: int,
     read_s = nbytes / m["disk_bytes_s"]
     recompute_s = n_tokens / m["prefill_tok_s"]
     return read_s < recompute_s
-
-
-# ---------------------------------------------------------------------------
-# hand-off micro-benchmark (bench.py --phase pd)
-# ---------------------------------------------------------------------------
-
-def bench_kv_handoff(model_name: str, ctxs, on_tpu: bool) -> dict:
-    """Measure staged-export drain + chunked import scatter latency for
-    a request of each context length, KV only (no model weights — the
-    hand-off path never touches them).  Reports per-context latency and
-    effective bandwidth, plus the break-even estimate the serving layer
-    consults."""
-    import jax
-
-    from kaito_tpu.engine.kv_cache import create_kv_cache
-    from kaito_tpu.models import get_model_by_name
-
-    arch = get_model_by_name(model_name).arch
-    dtype = jnp.bfloat16 if on_tpu else jnp.float32
-    page_size = 64
-    out: dict = {"pd_model": model_name}
-    for ctx in ctxs:
-        n_pages = -(-ctx // page_size)
-        cache = create_kv_cache(arch, n_pages + 1, page_size, dtype)
-        pages = list(range(1, n_pages + 1))
-        # warm once (compile of gather/scatter programs), then measure
-        # a second, compile-free pass — only the last pass's timings are
-        # reported.  The import leg mirrors the engine: assemble chunks
-        # into host buffers (the overlappable work), one device scatter
-        # at the end.
-        staged = dest = None
-        for _ in range(2):
-            # free the warm-up pass's staged copy and dest pool BEFORE
-            # the timed pass so the measurement doesn't run against
-            # doubled HBM pressure (allocator churn skews the numbers)
-            del staged, dest
-            t0 = time.monotonic()
-            staged = stage_export(cache, pages, n_tokens=ctx,
-                                  model=model_name, prompt_tokens=[],
-                                  first_token=0)
-            staged.wait_all()
-            t_export = time.monotonic() - t0
-            dest = create_kv_cache(arch, n_pages + 1, page_size, dtype)
-            t1 = time.monotonic()
-            ci = ChunkedImport(staged.meta, staged.plans, 0)
-            for i in range(staged.n_chunks):
-                ci.feed(i, staged.get_chunk(i))
-            while not ci.complete:
-                ci.assemble(max_n=16)
-            dest = import_arrays(dest, pages, *ci.full_arrays())
-            jax.block_until_ready((dest.k, dest.v))
-            t_import = time.monotonic() - t1
-        total_mb = staged.meta and (
-            (int(np.prod(staged.meta["shape"]))
-             + int(np.prod(staged.meta["v_shape"])))
-            * np.dtype(staged.meta["dtype"]).itemsize / 2**20)
-        ms = (t_export + t_import) * 1e3
-        out[f"pd_handoff_ms@{ctx}"] = round(ms, 1)
-        out[f"pd_handoff_mb_s@{ctx}"] = round(total_mb / max(
-            t_export + t_import, 1e-9), 1)
-        # colocated device-to-device path (no host bounce): gather +
-        # one scatter, both on device — what a shared-slice/single-host
-        # MRI hand-off costs vs the host-staged wire above
-        dest2 = staged_d = None
-        for _ in range(2):
-            del dest2, staged_d     # free the warm pass before timing
-            dest2 = create_kv_cache(arch, n_pages + 1, page_size, dtype)
-            t2 = time.monotonic()
-            staged_d = stage_export(cache, pages, n_tokens=ctx,
-                                    model=model_name, prompt_tokens=[],
-                                    first_token=0, lazy_drain=True)
-            dest2 = import_arrays(dest2, pages, *staged_d.device_slabs())
-            jax.block_until_ready((dest2.k, dest2.v))
-            t_device = time.monotonic() - t2
-        out[f"pd_device_handoff_ms@{ctx}"] = round(t_device * 1e3, 1)
-        out[f"pd_device_mb_s@{ctx}"] = round(
-            total_mb / max(t_device, 1e-9), 1)
-        cost = transfer_cost(ctx, arch, np.dtype(dtype).itemsize)
-        out[f"pd_breakeven_transfer@{ctx}"] = bool(
-            cost["transfer_s"] < cost["recompute_s"])
-        del cache, dest, dest2, staged, staged_d
-    return out

@@ -124,13 +124,13 @@ class ServerState:
             targets=SLOTargets.from_env(SLOTargets(
                 ttft_p50_s=cfg.slo_ttft_p50_ms / 1000.0,
                 ttft_p99_s=cfg.slo_ttft_p99_ms / 1000.0,
-                itl_p99_s=getattr(cfg, "slo_itl_p99_ms", 250.0) / 1000.0,
+                itl_p99_s=cfg.slo_itl_p99_ms / 1000.0,
                 tokens_per_sec_per_chip=cfg.slo_tokens_per_sec_per_chip,
                 availability=cfg.slo_availability)),
             chips=engine_chip_count(engine),
             per_tenant=self.qos is not None,
             itl_enabled=itl_on,
-            role=getattr(cfg, "role", "")
+            role=cfg.role
             or os.environ.get("KAITO_INFERENCE_ROLE", ""))
         self.slo.register_metrics(self.metrics.registry)
         # per-token ITL: the engine's retire-path stamp feeds the
@@ -144,7 +144,7 @@ class ServerState:
         # no kaito:flight_bundles_total family, /debug/flight 403
         self.flight = None
         self.flight_watcher = None
-        if getattr(cfg, "flight_dir", ""):
+        if cfg.flight_dir:
             from kaito_tpu.engine.metrics import Gauge
             from kaito_tpu.utils.flightrec import (FlightRecorder,
                                                    FlightWatcher,
@@ -154,7 +154,7 @@ class ServerState:
                 cfg.flight_dir,
                 collect=lambda: engine_flight_snapshot(
                     self.engine, slo=self.slo, cfg=self.cfg),
-                max_bundles=getattr(cfg, "flight_max_bundles", 16))
+                max_bundles=cfg.flight_max_bundles)
 
             def _fatal_total() -> int:
                 return sum(int(e.counters.get("engine_fatal_total", 0))

@@ -66,8 +66,8 @@ def main(argv=None):
                          "bytes -> fewer chips; docs/quantization.md)")
     ap.add_argument("--cp-autocarve", action="store_true",
                     help="opt the plan preview into the >=32k serve CP "
-                         "carve (evidence-gated off by default: BENCH_r05 "
-                         "cp_speedup_vs_chunked=0.68)")
+                         "carve (off by default: plan_parallelism's "
+                         "docstring says on what evidence)")
     ap.add_argument("--speculative-draft", default="",
                     help="draft preset for speculative decoding: a "
                          "catalog name, or 'auto' for the curated "

@@ -150,11 +150,13 @@ def plan_parallelism(
 
     ``cp_autocarve`` opts the SERVE path into carving a sequence axis
     (ring-attention context-parallel prefill) at >= 32k context.  It
-    defaults OFF on measured evidence: BENCH_r05 shows
-    ``cp_speedup_seq4_vs_chunked = 0.68`` — CP prefill LOSES to chunked
-    prefill on the current kernel, so auto-carving would spend chips to
-    get slower.  Flip the default only once a benchmark round measures
-    ``cp_speedup_vs_chunked >= 1.0`` on real hardware (the train-path
+    defaults OFF on a reading no record in the tree holds any more:
+    round 5's BENCH_r05 file (not in the tree; CHANGES.md's PR 5 line
+    quotes it) had ``cp_speedup_seq4_vs_chunked = 0.68`` — CP prefill
+    LOST to chunked prefill, so auto-carving would spend chips to get
+    slower.  Nothing since has measured CP on a chip (PERF.md section
+    7).  Flip the default only once a cell of ``BENCHMARK.json``
+    measures CP prefill ahead of chunked on real hardware (the train-path
     carve is unaffected: ring attention there overlaps with grad
     compute and is not subject to this evidence gate).
     """
@@ -239,8 +241,8 @@ def plan_parallelism(
         # (single-slice only: the pipeline serving executor owns its
         # mesh and has no sequence axis — carving one there would
         # reserve chips the engine never uses)
-        # opt-in only (cp_autocarve): see the evidence gate in the
-        # docstring — BENCH_r05 measured CP prefill at 0.68x chunked
+        # opt-in only (cp_autocarve): see the docstring — the 0.68x
+        # reading is round 5's BENCH_r05, a file not in the tree
         if cp_autocarve and ctx >= 32768 and leftover >= 2 \
                 and num_slices == 1 \
                 and md.arch.attention_kind.value != "MLA":

@@ -45,8 +45,8 @@ import logging
 import signal
 import threading
 
-# Re-exported so existing imports (tests, helpers, bench harnesses)
-# keep working against the historical dp_router module surface.
+# Re-exported so existing imports (tests, helpers) keep working
+# against the historical dp_router module surface.
 from kaito_tpu.runtime.routing import (BREAKER_THRESHOLD,  # noqa: F401
                                        DOWN_COOLDOWN_MAX_S, DOWN_COOLDOWN_S,
                                        HOP_HEADERS, IDEMPOTENT_POST_PREFIXES,

@@ -3,8 +3,8 @@
 The engine compiles one program per prefill bucket, pack size and
 fused-decode depth, and a pod (or a chip run) that restarts pays all of
 them again unless the compiled executables persist.  Every standalone
-entry point (serving server, tuning CLI, RAG service, bench phases, the
-chip smoke's kernel child) calls :func:`enable_compile_cache` first
+entry point (serving server, tuning CLI, RAG service, the chip smoke's
+kernel child) calls :func:`enable_compile_cache` first
 thing in its ``main()`` — never at package import, so importing
 ``kaito_tpu`` configures nothing.
 

@@ -569,6 +569,8 @@ def test_manager_gates_autoscaler_off_by_default():
 # slow tier: the closed loop over real engine processes
 # ---------------------------------------------------------------------------
 
+# slow: 39 s, and it waits on wall-clock stabilization windows over real
+# engine processes: a loaded box can miss them
 @pytest.mark.slow
 def test_autoscaler_closed_loop_e2e():
     """idle -> pressure -> scale-up (warm NodePool before the

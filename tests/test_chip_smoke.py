@@ -1,4 +1,4 @@
-"""chip_smoke.py rehearsed on the CPU (slow tier): it passes at tiny
+"""chip_smoke.py rehearsed on the CPU: it passes at tiny
 size where it expects the CPU, and it cannot pass off the platform it
 expects or over a failure the server caught."""
 
@@ -6,6 +6,8 @@ import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -24,6 +26,9 @@ def _smoke(*args, devices=1, **env_extra):
 CPU = ("--model", "tiny-llama-test", "--expect-platform", "cpu")
 
 
+# slow: 164 s alone under the check's command: the whole smoke at tiny size,
+# four servers one after another
+@pytest.mark.slow
 def test_cpu_rehearsal_passes_and_names_the_cpu():
     # two virtual devices: the tp and dp legs run too
     res = _smoke(*CPU, devices=2)

@@ -251,11 +251,10 @@ def test_comm_overlap_annotation_renders_flag_only_when_true():
 
 
 # ---------------------------------------------------------------------------
-# engine gate + greedy bit-equivalence (slow: full engines on the mesh)
+# engine gate + greedy bit-equivalence (full engines on the mesh)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("tp", [2, 4])
 def test_tp_greedy_bit_equivalent_on_vs_off(cpu_devices, tp):
     """The acceptance bar: overlap on under TP>=2 produces the exact
@@ -274,7 +273,6 @@ def test_tp_greedy_bit_equivalent_on_vs_off(cpu_devices, tp):
     assert _run(on, prompt) == ref
 
 
-@pytest.mark.slow
 def test_compose_int4_int8kv_async_overlap(cpu_devices):
     """The full compose leg: int4 weights x int8 KV x async dispatch x
     overlap must still be token-identical to the same stack with the
@@ -290,7 +288,6 @@ def test_compose_int4_int8kv_async_overlap(cpu_devices):
     assert _run(on, prompt) == ref
 
 
-@pytest.mark.slow
 def test_no_retrace_steady_state(cpu_devices):
     """The ring path bakes into the one decode program: after warmup
     the jit cache never grows (no per-step retraces)."""
@@ -308,7 +305,6 @@ def test_no_retrace_steady_state(cpu_devices):
     assert eng._decode_fn._cache_size() == traced
 
 
-@pytest.mark.slow
 @pytest.mark.skipif(_ENV_FORCED, reason="KAITO_COMM_OVERLAP forces the "
                     "gate on; the gate-off exposition check needs a "
                     "true baseline engine")
@@ -322,7 +318,6 @@ def test_gate_off_byte_identical_exposition(cpu_devices):
     assert len(out) == 4
 
 
-@pytest.mark.slow
 @pytest.mark.skipif(_ENV_FORCED, reason="env forces the gate on")
 def test_gate_requires_tp_mesh(cpu_devices):
     """comm_overlap=True on a single-chip engine degrades to off with

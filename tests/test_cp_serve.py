@@ -142,9 +142,9 @@ def test_sequence_parallel_plumbs_to_pod_env():
 
 def test_serve_plan_carves_sequence_axis():
     """The planner gives long-context SERVE plans a sequence axis when
-    the user OPTS IN (cp_autocarve) — the carve is evidence-gated off
-    by default because BENCH_r05 measured CP prefill at 0.68x chunked
-    (plan_parallelism docstring)."""
+    the user OPTS IN (cp_autocarve) — the carve is off by default
+    (plan_parallelism's docstring says on what evidence: round 5's
+    BENCH_r05, a file not in the tree)."""
     from kaito_tpu.models import get_model_by_name
     from kaito_tpu.parallel.plan import plan_parallelism
     from kaito_tpu.sku.catalog import CHIP_CATALOG

@@ -7,7 +7,7 @@ across an admission into a free slot, give bit-identical tokens and
 logprobs to the synchronous loop, and still go back to depth 1 where
 only the host knows what happened (abort, deadline, preemption).
 Engines are stepped by hand, so what is in flight at each event is
-deterministic.  tests/test_async_dispatch.py (slow tier) holds the
+deterministic.  tests/test_async_dispatch.py holds the
 one-request-at-a-time parity checks.
 """
 

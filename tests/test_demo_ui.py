@@ -13,7 +13,6 @@ from kaito_tpu.engine.engine import InferenceEngine
 from kaito_tpu.engine.server import make_server as make_engine_server
 from kaito_tpu.ui import make_server as make_ui_server
 
-pytestmark = pytest.mark.slow
 
 
 @pytest.fixture(scope="module")

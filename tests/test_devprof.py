@@ -511,6 +511,8 @@ def test_devprof_annotation_renders_flag_only_when_present():
 # ---------------------------------------------------------------------------
 
 
+# slow: takes a jax.profiler trace on the CPU backend, where stop_trace has
+# killed the process in traced rehearsals (PERF.md section 7)
 @pytest.mark.slow
 def test_live_window_buckets_debug_device_and_metrics_agree():
     import threading

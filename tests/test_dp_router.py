@@ -9,6 +9,10 @@ import urllib.request
 
 import pytest
 
+# slow: 41 s for four tests: two engine servers behind the router (23 s of
+# set-up), HTTP on the wall clock; left out for the check's budget
+pytestmark = pytest.mark.slow
+
 
 def _post(url: str, body: dict, timeout: float = 240.0) -> dict:
     req = urllib.request.Request(

@@ -151,6 +151,9 @@ def test_sampler_greedy_and_topk():
     assert not np.array_equal(np.asarray(st.key[1]), np.asarray(st2.key[1]))
 
 
+# slow: 272 s alone under the check's command: it draws its samples one
+# dispatch at a time
+@pytest.mark.slow
 def test_sampler_distribution_sanity():
     logits = jnp.asarray(np.log([[0.7, 0.2, 0.1, 1e-9]]), jnp.float32)
     counts = np.zeros(4)

@@ -13,9 +13,9 @@ prove the isolation contracts of docs/failure-domains.md:
 - a failpoint-killed DP backend trips its circuit breaker and traffic
   fails over with a 100% success rate for idempotent requests.
 
-Registry/router/satellite tests run in the fast (``not slow``) tier;
-engine-driven chaos is compile-heavy and carries ``@pytest.mark.slow``
-(the ``make chaos`` target runs the whole module).
+Registry/router/satellite tests come first, the engine-driven chaos
+(compile-heavy) after them; the ``make chaos`` target runs the whole
+module.
 """
 
 import http.client
@@ -29,8 +29,6 @@ import pytest
 
 from kaito_tpu.utils.failpoints import (FAILPOINTS, FailpointError,
                                         FailpointRegistry, failpoint)
-
-slow = pytest.mark.slow
 
 
 @pytest.fixture(autouse=True)
@@ -411,7 +409,7 @@ def test_router_retryable_classification():
 
 
 # ---------------------------------------------------------------------------
-# engine chaos (compile-heavy -> slow tier; `make chaos` runs them)
+# engine chaos (compile-heavy; `make chaos` runs them)
 # ---------------------------------------------------------------------------
 
 BASE = dict(model="tiny-llama-test", max_model_len=256, page_size=16,
@@ -456,7 +454,6 @@ def _chunked_meta(eng, n_tokens):
     return meta, plans
 
 
-@slow
 def test_kv_import_fault_is_request_scoped(eng):
     """Acceptance: one request's KV import failpoint fires -> THAT
     request gets a structured error; a concurrent decode on the same
@@ -484,7 +481,6 @@ def test_kv_import_fault_is_request_scoped(eng):
     assert eng.counters["engine_fatal_total"] == fatal0
 
 
-@slow
 def test_transient_kv_fault_retries_as_local_recompute(eng):
     """A transient transfer failure consumes the retry budget and the
     request still SUCCEEDS via local prefill."""
@@ -504,7 +500,6 @@ def test_transient_kv_fault_retries_as_local_recompute(eng):
     assert eng.counters["kv_import_retries_total"] == retries0 + 1
 
 
-@slow
 def test_permanent_kv_fault_exhausts_no_budget_and_fails(eng):
     """A corrupt/mis-shaped transfer is NOT retried: the bytes would be
     wrong again."""
@@ -520,7 +515,6 @@ def test_permanent_kv_fault_exhausts_no_budget_and_fails(eng):
     assert b.kv_retries == 1              # budget untouched
 
 
-@slow
 def test_deadline_expires_in_queue_before_tpu_time(eng):
     expired0 = eng.counters["requests_expired_total"]
     prompts0 = eng.counters["prompt_tokens_total"]
@@ -535,7 +529,6 @@ def test_deadline_expires_in_queue_before_tpu_time(eng):
     assert eng.counters["prompt_tokens_total"] == prompts0
 
 
-@slow
 def test_deadline_aborts_active_decode_and_frees_pages(eng):
     free0 = eng.allocator.available
     r = eng.submit(list(range(1, 17)), _greedy(200), timeout_s=0.25)
@@ -548,7 +541,6 @@ def test_deadline_aborts_active_decode_and_frees_pages(eng):
     assert eng.allocator.available == free0   # pages all returned
 
 
-@slow
 def test_submit_with_kv_device_rejects_shape_mismatch(eng):
     """Satellite: incompatible slab layout fails in the REQUEST thread
     with ValueError (-> clean 4xx), never inside the scheduler."""
@@ -569,7 +561,6 @@ def test_submit_with_kv_device_rejects_shape_mismatch(eng):
                                   _greedy(2))
 
 
-@slow
 def test_engine_step_wires_export_registry_tick(eng):
     stale = _FakeExport(age_s=60.0)
     eng.kv_exports.put("tick-test", stale)
@@ -579,7 +570,6 @@ def test_engine_step_wires_export_registry_tick(eng):
     eng.kv_exports.pop("tick-test")
 
 
-@slow
 def test_step_failpoint_is_engine_fatal_then_recovers():
     """The engine-fatal domain: a fault at the top of step() fails
     EVERYTHING in flight (no stranded clients), and the engine serves
@@ -610,7 +600,6 @@ def test_step_failpoint_is_engine_fatal_then_recovers():
         e.stop()
 
 
-@slow
 def test_request_scoped_error_contained_by_loop():
     """RequestScopedError raised out of step() fails ONE request and
     the loop keeps serving (the scoped half of the classification)."""
@@ -648,7 +637,6 @@ def test_request_scoped_error_contained_by_loop():
         e.stop()
 
 
-@slow
 def test_prefill_failpoint_scoped_to_one_request(eng):
     failed0 = eng.counters["requests_failed_total"]
     a = eng.submit(list(range(1, 9)), _greedy(3))
@@ -661,19 +649,6 @@ def test_prefill_failpoint_scoped_to_one_request(eng):
     assert eng.counters["requests_failed_total"] == failed0 + 1
 
 
-@slow
-def test_bench_kv_handoff_runs_and_reports():
-    """Satellite regression: the warm/measure loops are well-formed (no
-    unused-flag confusion) and both hand-off paths report."""
-    from kaito_tpu.engine.pd import bench_kv_handoff
-
-    out = bench_kv_handoff("tiny-llama-test", [32], on_tpu=False)
-    assert out["pd_handoff_ms@32"] > 0
-    assert out["pd_device_handoff_ms@32"] > 0
-    assert "pd_breakeven_transfer@32" in out
-
-
-@slow
 def test_guaranteed_tenant_completes_under_flood_and_chaos():
     """Tenant-starvation chaos (docs/qos.md, `make chaos`): a
     best-effort flood oversubscribes a 2-slot engine while a prefill

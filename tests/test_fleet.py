@@ -618,6 +618,7 @@ def _direct(url, key):
         return parse_replica_metrics(r.read().decode()).get(key, 0.0)
 
 
+# slow: 32 s, two real replicas and scrape deadlines on the wall clock
 @pytest.mark.slow
 def test_fleet_e2e_two_real_replicas_plus_hung_third():
     from tests.helpers.dp_cluster import boot_backends

@@ -707,6 +707,9 @@ def test_live_off_surfaces_stay_byte_identical(served_off):
 # ---------------------------------------------------------------- e2e
 
 
+# slow: fails under the check's command: `assert (2 - 1) >= 3`, the three
+# injected 300 ms stalls must each land in the (0.25, 0.5] s bucket and
+# a loaded box stretches or merges them
 @pytest.mark.slow
 def test_e2e_decode_stall_pages_itl_and_records_one_bundle(tmp_path):
     """The acceptance loop: a scoped decode failpoint stalls a REAL

@@ -19,6 +19,10 @@ import urllib.request
 
 import pytest
 
+# slow: needs a `kind` cluster and kubectl, which the check's machine lacks
+# (the tests skip themselves there, and a skip is not a pass)
+pytestmark = pytest.mark.slow
+
 pytestmark = pytest.mark.skipif(
     shutil.which("kind") is None or shutil.which("kubectl") is None,
     reason="kind/kubectl not installed")

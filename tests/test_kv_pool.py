@@ -435,6 +435,8 @@ def test_fetch_failure_falls_back_to_local_recompute():
 # e2e: warm TTFT survives scale-out (slow tier)
 # ---------------------------------------------------------------------------
 
+# slow: compares two wall-clock TTFTs of one process (`warm_ttft <
+# cold_ttft`); its twin in test_kv_tier.py fails on a loaded box
 @pytest.mark.slow
 def test_warm_ttft_survives_scaleout():
     """The headline: replica A holds a warm prefix and is draining

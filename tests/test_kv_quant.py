@@ -5,7 +5,7 @@ dequant reads in kaito_tpu.engine.kv_cache, the in-kernel dequant of
 the Pallas decode kernel (interpreter mode), the P/D wire format with
 page scales, and the capacity / transfer-cost arithmetic the estimator
 and router build on.  End-to-end int8 serving is pinned separately by
-the golden tests in test_real_checkpoint.py (slow tier).
+the golden tests in test_real_checkpoint.py.
 """
 
 from datetime import datetime, timezone
@@ -271,7 +271,6 @@ def test_transfer_cost_counts_scale_bytes():
 # and greedy output must match the pinned int8 goldens exactly
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 def test_int8_kv_composes_with_draft_speculation():
     import json
     import os

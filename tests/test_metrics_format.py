@@ -3,8 +3,7 @@ toolkit (kaito_tpu/engine/metrics.py): bucket monotonicity, +Inf ==
 _count, percentile edge cases, labelled-series semantics, and label
 escaping — parsed with the promoted library parser
 (kaito_tpu/utils/promtext.py) and round-tripped against every registry
-in the codebase, plus a real sim engine's /metrics payload (slow
-tier)."""
+in the codebase, plus a real sim engine's /metrics payload."""
 
 import math
 import threading
@@ -173,7 +172,6 @@ def test_every_registry_round_trips():
             "kaito:tuning_completed"} <= names
 
 
-@pytest.mark.slow
 def test_sim_engine_metrics_payload_parses():
     """The real engine server's /metrics payload passes the parser and
     the histogram invariants end to end."""

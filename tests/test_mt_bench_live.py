@@ -29,7 +29,10 @@ from kaito_tpu.engine.config import EngineConfig
 from kaito_tpu.engine.engine import InferenceEngine
 from kaito_tpu.engine.server import make_server
 
+# slow: 38 s for two tests: the judge loop against a live engine; the
+# reference's quality job, not the served path
 pytestmark = pytest.mark.slow
+
 
 
 @pytest.fixture(scope="module")

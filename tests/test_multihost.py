@@ -61,6 +61,8 @@ def cluster_ep():
                               "--tensor-parallel-size", "2"])
 
 
+# slow: 35 s: a multi-process cluster of its own (25 s to boot) for one test
+@pytest.mark.slow
 def test_multihost_ep_serves_completions(cluster_ep):
     body = {"model": "tiny-moe-real", "prompt": "experts across processes",
             "max_tokens": 8, "temperature": 0}
@@ -93,6 +95,8 @@ def test_multihost_concurrent_requests(cluster):
     assert all(o["usage"]["completion_tokens"] == 6 for o in outs)
 
 
+# slow: 50 s with its twin below: a second multi-process cluster (29 s to boot)
+@pytest.mark.slow
 def test_multihost_pp_serves_completions(cluster_pp):
     """PP over 2 processes: stages live in different OS processes and
     activations cross the process boundary via the jitted ppermute
@@ -105,6 +109,8 @@ def test_multihost_pp_serves_completions(cluster_pp):
     assert out2["choices"][0]["text"] == out["choices"][0]["text"]
 
 
+# slow: shares cluster_pp with the test above; alone it would pay the boot
+@pytest.mark.slow
 def test_multihost_pp_concurrent_requests(cluster_pp):
     import concurrent.futures as cf
 
@@ -133,6 +139,8 @@ def cluster_pp_spill(tmp_path_factory):
         "--kaito-kv-cache-cpu-memory-utilization", "0.02"])
 
 
+# slow: 81 s: a third multi-process cluster (37 s to boot) and a spill cycle
+@pytest.mark.slow
 def test_multihost_pp_preempt_restores_from_host(cluster_pp_spill):
     """Two concurrent generations overflow the tiny page pool, so the
     newest preempts mid-decode; with the offload tier it must resume

@@ -131,6 +131,8 @@ def test_moe_pp2_ep2_tp2_parity(moe_md):
     assert full == ref
 
 
+# slow: 31 s alone under the check's command
+@pytest.mark.slow
 def test_mla_tp2_parity(mla_md):
     ref = _outputs(EngineConfig(model="tiny-mla-par", **BASE), mla_md, PROMPTS)
     tp = _outputs(EngineConfig(model="tiny-mla-par", **BASE,

@@ -48,6 +48,9 @@ def test_pipeline_loss_matches_reference(cpu_devices, stages, microbatches):
     np.testing.assert_allclose(float(got), float(ref), rtol=2e-5)
 
 
+# slow: 95 s alone under the check's command (the backward pass of every
+# schedule compiles)
+@pytest.mark.slow
 def test_pipeline_gradients_match_reference(cpu_devices):
     model = TransformerLM(TINY, dtype=jnp.float32)
     params = model.init_params(jax.random.PRNGKey(1))

@@ -105,6 +105,8 @@ def test_pp_guards():
                                         "expert_parallel": 2}))
 
 
+# slow: 26 s alone under the check's command
+@pytest.mark.slow
 def test_pd_handoff_across_layouts():
     """Round-4: the KV wire layout is canonical (layer-major), so a
     pipeline-staged prefill engine hands KV to a FLAT decode engine —

@@ -538,6 +538,8 @@ def _stream_ttft(url, tenant, prompt, max_tokens=8, timeout=120.0):
     return first, completed
 
 
+# slow: 35 s, and it holds a latency to 2x its unloaded baseline: a
+# wall-clock threshold on a shared box
 @pytest.mark.slow
 def test_two_tenant_overload_guaranteed_holds_best_effort_sheds():
     """The degradation ladder end to end over a real engine-server

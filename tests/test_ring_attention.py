@@ -43,6 +43,8 @@ def test_ring_non_causal(cpu_devices):
                                rtol=2e-5, atol=2e-5)
 
 
+# slow: 27 s alone under the check's command (training path)
+@pytest.mark.slow
 def test_ring_gradients_flow(cpu_devices):
     """Ring attention must be differentiable (training path)."""
     mesh = build_mesh(make_mesh_spec(sequence=4, data=2), cpu_devices)

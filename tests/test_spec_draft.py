@@ -298,6 +298,7 @@ def test_verify_sample_onehot_q_accept_prob_is_target_prob():
 REPEAT_PROMPT = [7, 11, 13, 7, 11, 13, 7, 11, 13, 7, 11]
 
 
+# slow: 33 s alone under the check's command
 @pytest.mark.slow
 def test_draft_greedy_equivalence_and_fewer_steps():
     ref = _mk()
@@ -313,7 +314,6 @@ def test_draft_greedy_equivalence_and_fewer_steps():
     assert eng.counters["spec_draft_accepted_tokens_total"] > 0
 
 
-@pytest.mark.slow
 def test_non_pow2_draft_k_clamps_to_verify_window():
     """speculative_draft_k=3: once the controller reaches full depth
     the pow2 program bucket (4) must clamp to W-1=3 — regression for a
@@ -328,7 +328,6 @@ def test_non_pow2_draft_k_clamps_to_verify_window():
     assert eng.counters["spec_draft_accepted_tokens_total"] > 0
 
 
-@pytest.mark.slow
 def test_full_accept_rounds_keep_draft_kv_exact():
     """Self-draft greedy full-accept steady state: identical weights
     mean nothing is ever rejected — IF the draft KV stays exact.
@@ -346,7 +345,6 @@ def test_full_accept_rounds_keep_draft_kv_exact():
     assert prop > 0 and acc == prop
 
 
-@pytest.mark.slow
 def test_probation_ticks_without_ngram_proposer():
     """A demoted slot must tick probation (and re-arm the draft) even
     with speculative_ngram=0, the default — regression for a permanent
@@ -368,7 +366,6 @@ def test_probation_ticks_without_ngram_proposer():
     assert len(req.output_tokens) == 24
 
 
-@pytest.mark.slow
 def test_draft_metrics_exposition():
     from kaito_tpu.engine.metrics import EngineMetrics
 
@@ -385,7 +382,6 @@ def test_draft_metrics_exposition():
             assert float(line.split()[-1]) > 0
 
 
-@pytest.mark.slow
 def test_draft_sampled_traffic_speculates_and_completes():
     eng = _mk(draft="tiny-llama-test")
     req = eng.submit(REPEAT_PROMPT, SamplingParams(
@@ -396,7 +392,6 @@ def test_draft_sampled_traffic_speculates_and_completes():
     assert eng.counters["spec_draft_proposed_tokens_total"] > 0
 
 
-@pytest.mark.slow
 def test_draft_batch_mixed_sampling_matches_plain_greedy_rows():
     """Greedy rows stay bit-exact even sharing a verify batch with
     sampled rows."""
@@ -411,7 +406,6 @@ def test_draft_batch_mixed_sampling_matches_plain_greedy_rows():
     assert len(outs[1]) == 20
 
 
-@pytest.mark.slow
 @pytest.mark.skipif(not HAS_REAL, reason="no committed real checkpoint")
 def test_real_checkpoint_draft_greedy_matches_goldens():
     """The acceptance bar: draft-spec greedy output is token-identical
@@ -444,7 +438,6 @@ def test_real_checkpoint_draft_greedy_matches_goldens():
         eng.stop()
 
 
-@pytest.mark.slow
 @pytest.mark.skipif(not HAS_REAL, reason="no committed real checkpoint")
 def test_adversarial_draft_falls_back_and_output_stays_exact():
     """Trained target + UNTRAINED (synthetic) draft: acceptance is

@@ -2,9 +2,9 @@
 step flight recorder, the router's own metrics, and the /debug export
 surface (docs/observability.md).
 
-Fast tier: pure tracing-unit tests plus router tests against cheap
-in-process stub backends (no engine, no XLA).  The ``@pytest.mark.slow``
-tests boot real engines and prove the acceptance path: a request
+Pure tracing-unit tests plus router tests against cheap in-process
+stub backends (no engine, no XLA); the tests at the end of the file
+boot real engines and prove the acceptance path: a request
 through dp_router -> engine comes back with an ``X-Request-Id`` whose
 span tree covers queue -> admission -> prefill -> decode, PD handoff
 spans share one id across both roles, and /debug/timeline is valid
@@ -591,7 +591,6 @@ def traced_stack():
     engine.stop()
 
 
-@pytest.mark.slow
 def test_request_id_spans_router_to_engine(traced_stack):
     """Acceptance: a completion through dp_router -> engine returns an
     X-Request-Id whose /debug/trace span tree covers queue ->
@@ -613,7 +612,6 @@ def test_request_id_spans_router_to_engine(traced_stack):
                for e in doc["traceEvents"] if e["ph"] == "X")
 
 
-@pytest.mark.slow
 def test_client_request_id_echoed_in_errors(traced_stack):
     router_url, _, _, _ = traced_stack
     import urllib.error
@@ -629,7 +627,6 @@ def test_client_request_id_echoed_in_errors(traced_stack):
     assert err["error"]["request_id"] == "err-trace-1"
 
 
-@pytest.mark.slow
 def test_debug_timeline_is_valid_chrome_trace(traced_stack):
     router_url, engine_url, engine, _ = traced_stack
     _post(router_url, "/v1/completions",
@@ -647,7 +644,6 @@ def test_debug_timeline_is_valid_chrome_trace(traced_stack):
     assert any(e["args"].get("decode_tokens", 0) > 0 for e in steps)
 
 
-@pytest.mark.slow
 def test_engine_metrics_gain_step_and_queue_series(traced_stack):
     router_url, engine_url, engine, _ = traced_stack
     _post(router_url, "/v1/completions",
@@ -661,7 +657,6 @@ def test_engine_metrics_gain_step_and_queue_series(traced_stack):
     assert engine.step_hist.percentile(0.5) > 0.0
 
 
-@pytest.mark.slow
 def test_slow_request_logs_span_tree(traced_stack, caplog):
     router_url, _, engine, _ = traced_stack
     with caplog.at_level(logging.WARNING, logger="kaito_tpu.engine.engine"):
@@ -682,7 +677,6 @@ def test_slow_request_logs_span_tree(traced_stack, caplog):
     assert "request" in slow[-1] and "decode" in slow[-1]
 
 
-@pytest.mark.slow
 def test_pd_handoff_shares_trace_id():
     """Acceptance: prefill and decode roles record spans under ONE
     trace id — carried by the staged-export meta — and the decode

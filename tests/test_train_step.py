@@ -78,6 +78,9 @@ def test_sharded_train_step_8dev(cpu_devices):
     np.testing.assert_allclose(float(m1["loss"]), float(m8["loss"]), rtol=1e-4)
 
 
+# slow: 296 s alone under the check's command (six workers on eight cores): it
+# boots the multi-chip dry run's child clusters one after another
+@pytest.mark.slow
 def test_graft_entry_dryrun(cpu_devices):
     spec = importlib.util.spec_from_file_location("graft", "__graft_entry__.py")
     m = importlib.util.module_from_spec(spec)
