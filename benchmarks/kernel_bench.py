@@ -57,6 +57,7 @@ class KernelCase:
     why: str                   # where the tolerance comes from
     relative: bool = False     # tol bounds err / max|reference|
     mask: Optional[jax.Array] = None   # entries with a defined result
+    zero_where_masked: bool = False    # ... and the rest must be zeros
     # bytes or FLOPs one call must move/do, and which: read by nothing
     # here; kept for the move into kbench/rooflines.py (ROADMAP D5)
     unit: str = ""
@@ -75,9 +76,14 @@ def parity(case: KernelCase) -> dict:
         raise AssertionError(
             f"{case.name}: no Mosaic custom call in the lowering "
             f"(interpret mode or a JAX path was taken)")
-    out = lowered.compile()(*case.args)
+    compiled = lowered.compile()
+    out = compiled(*case.args)
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(case.reference)(*case.args)
+    # twice: state a call leaves behind (a semaphore, a ring slot) would
+    # show in the next call of the same executable
+    again = compiled(*case.args)
+    repeats = bool(jnp.array_equal(out, again))
     diff = jnp.abs(_f32(out) - _f32(ref))
     if case.mask is not None:
         diff = diff * case.mask
@@ -85,7 +91,11 @@ def parity(case: KernelCase) -> dict:
     if case.relative:
         err /= float(jnp.max(jnp.abs(_f32(ref)))) or 1.0
     finite = bool(jnp.all(jnp.isfinite(_f32(out))))
-    return {"name": case.name, "ok": finite and err <= case.tol,
+    zeros = (not case.zero_where_masked
+             or not bool(jnp.any(_f32(out) * (1.0 - case.mask))))
+    return {"name": case.name,
+            "ok": finite and repeats and zeros and err <= case.tol,
+            "repeats": repeats,
             "max_err": round(err, 6), "tol": case.tol,
             "relative": case.relative, "finite": finite,
             "shape": list(out.shape), "why": case.why}
@@ -117,6 +127,16 @@ def decode_case(int8_kv: bool = False) -> KernelCase:
     cv = jax.random.normal(kv, (P, PS, HKV, D), jnp.bfloat16)
     pt = jax.random.randint(kt, (B, pmax), 0, P, jnp.int32)
     lens = jax.random.randint(kl, (B,), 1, pmax * PS, jnp.int32)
+    # ragged on purpose: the kernel carries its page ring from row to
+    # row, so a row that decodes nothing (first, between, last), one
+    # token, one page exactly and a page boundary sit among long rows.
+    # Only the chip shows a copy that was never waited for or a
+    # semaphore left signalled for the next row or call.
+    lens = lens.at[jnp.asarray([0, 1, 2, 3, 9, 10, B - 1])].set(
+        jnp.asarray([0, 1, PS, PS + 1, 0, 0, 0], jnp.int32))
+    # a row of length 0 is defined as zeros; the reference's mean over
+    # masked columns there is not compared
+    live = (lens > 0).astype(jnp.float32)[:, None, None]
     win = jnp.asarray(BIG_WINDOW, jnp.int32)
     live_rows = float(jnp.sum(lens)) * HKV * D
     if not int8_kv:
@@ -128,7 +148,8 @@ def decode_case(int8_kv: bool = False) -> KernelCase:
                 q, ck, cv, pt, lens, win, scale=scale),
             lambda q, ck, cv, pt, lens: paged_decode_attention(
                 q, ck, cv, pt, lens, scale=scale),
-            (q, ck, cv, pt, lens), ATTN_TOL, ATTN_WHY,
+            (q, ck, cv, pt, lens), ATTN_TOL, ATTN_WHY, mask=live,
+            zero_where_masked=True,
             unit="live-KV bytes", work=live_rows * 2 * 2)
     k8, ks = _quantize_pages(ck)
     v8, vs = _quantize_pages(cv)
@@ -141,7 +162,8 @@ def decode_case(int8_kv: bool = False) -> KernelCase:
             q, k8, v8, pt, lens, win, scale=scale, k_scale=ks, v_scale=vs),
         lambda q, k8, v8, ks, vs, pt, lens: paged_decode_attention(
             _f32(q), k8, v8, pt, lens, scale=scale, k_scale=ks, v_scale=vs),
-        (q, k8, v8, ks, vs, pt, lens), ATTN_TOL, ATTN_WHY,
+        (q, k8, v8, ks, vs, pt, lens), ATTN_TOL, ATTN_WHY, mask=live,
+        zero_where_masked=True,
         unit="live-KV bytes",
         work=live_rows * 2 + live_pages * 2 * HKV * 4)
 

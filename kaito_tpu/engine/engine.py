@@ -691,6 +691,11 @@ class InferenceEngine:
             "requests_finished_total": 0,
             "prefill_steps_total": 0,
             "decode_steps_total": 0,
+            # slot-steps the decode programs ran, and those of them
+            # whose slot was not decoding: such a row attends to nothing
+            # and copies no KV page (docs/kv-cache.md)
+            "decode_rows_total": 0,
+            "decode_rows_idle_total": 0,
             "prefix_cached_tokens_total": 0,
             # per-request prefix-cache outcome (routing layer scrapes
             # these to judge affinity quality, docs/routing.md)
@@ -3980,6 +3985,7 @@ class InferenceEngine:
         if self.token_counts is not None:
             self.token_counts = counts
         self.counters["decode_steps_total"] += 1
+        self._count_decode_rows(self.active)
         return [next_tokens, lps]
 
     def _decode_once(self):
@@ -4104,6 +4110,14 @@ class InferenceEngine:
         self.counters["decode_steps_total"] += K
         return [K, toks, acts, lps, self._slot_owners()]
 
+    def _count_decode_rows(self, active: np.ndarray) -> None:
+        """Count a dispatch's slot-steps from its ``active`` flags
+        ([S] for one step, a window's [K, S] trace): all of them, and
+        those that decoded nothing."""
+        self.counters["decode_rows_total"] += active.size
+        self.counters["decode_rows_idle_total"] += \
+            active.size - int(np.count_nonzero(active))
+
     def _slot_owners(self) -> list:
         """Each slot's admission number (0: free), recorded with a
         window at its launch."""
@@ -4120,6 +4134,7 @@ class InferenceEngine:
         when the window was launched (``owners``): a slot whose request
         the host has retired since, or that a later admission has taken
         over, gets none of the window's tokens (docs/decode-loop.md)."""
+        self._count_decode_rows(acts)
         toks = toks.tolist()          # [K, S]
         acts = acts.tolist()          # [K, S] — device active BEFORE step k
         lps = lps.tolist()            # [K, S]

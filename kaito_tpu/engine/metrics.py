@@ -435,6 +435,15 @@ class EngineMetrics:
                   fn=lambda: engine.cfg.page_size)
             Gauge("kaito:num_preemptions_total", "Sequences preempted", r,
                   fn=lambda: engine.counters["preemptions_total"])
+            Gauge("kaito:engine_decode_rows_total",
+                  "Slot-steps the decode programs ran (slots x steps of "
+                  "every step and window replayed)", r,
+                  fn=lambda: engine.counters.get("decode_rows_total", 0))
+            Gauge("kaito:engine_decode_rows_idle_total",
+                  "Slot-steps of them whose slot was not decoding: the "
+                  "attention kernel copies no KV page for such a row", r,
+                  fn=lambda: engine.counters.get(
+                      "decode_rows_idle_total", 0))
             Gauge("kaito:prefix_cached_tokens_total",
                   "Prompt tokens served from the prefix cache", r,
                   fn=lambda: engine.counters["prefix_cached_tokens_total"])

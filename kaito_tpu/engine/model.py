@@ -1029,14 +1029,20 @@ class TransformerLM:
         """One decode step for a batch of slots.
 
         tokens: [B] last sampled token; positions: [B] their positions;
-        lengths after write are positions+1.  Returns (cache, logits).
+        lengths after write are positions+1, and 0 for a slot that
+        ``active`` leaves out: it writes no KV and attends to nothing
+        (the Pallas kernel copies no page for it), and no caller reads
+        its logits (docs/kv-cache.md).  Returns (cache, logits).
         """
         B = tokens.shape[0]
         pos2 = positions[:, None].astype(jnp.int32)
+        lengths = positions + 1
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
         x = self._embed(params, tokens[:, None])
         x, cache = self._run_layers(
             params, cache, x, "decode", positions=pos2,
-            page_tables=page_tables, lengths=positions + 1, true_lens=None,
+            page_tables=page_tables, lengths=lengths, true_lens=None,
             active=active, adapter_ids=adapter_ids)
         x = self._norm(x, params, "final_norm")
         return cache, self._logits(params, x[:, 0])

@@ -1,10 +1,25 @@
 """Pallas TPU kernel: paged decode attention.
 
-One grid program per sequence.  Each loop iteration DMAs one page of K
-and V for *all* KV heads (the token-major cache layout makes a page one
-contiguous ``[page_size * Hkv, D]`` panel) into a 4-deep VMEM ring
-while the previous page's flash-attention block computes.  HBM traffic
-is exactly one read of the live KV — the decode roofline.
+One call is one pass over the batch's live KV pages.  The grid walks
+the rows (sequences) in order; the pages of all rows form one stream,
+row after row, and a ring of ``N_BUF`` VMEM slots runs ``N_BUF`` pages
+ahead of the page being computed.  A page is K and V for *all* KV heads
+(the token-major cache layout makes it one contiguous
+``[page_size * Hkv, D]`` panel each), and HBM traffic is one read of the
+pages that hold live KV — the decode roofline.
+
+The ring is carried from one grid step to the next (the grid dimension
+is sequential, so VMEM/SMEM scratch and DMA semaphores outlive a step):
+while a row's last pages compute, the first pages of the next row that
+has any are already in flight, and that row starts by waiting for
+copies issued a row ago.  Only the first live row of a call starts
+cold, and the last issues nothing it does not wait for.  What a row
+needs that is not the row's own is made once a call: the pages each row
+holds and the next row that holds any (SMEM, first grid step), and the
+head-match mask with the page-row index, which depend on shapes alone
+and come in as one constant operand whose block never changes
+(``_score_columns``).  A row of length 0 — a slot that is not decoding —
+copies nothing, takes no slot of the ring and writes zeros.
 
 Compute is the *flat cross-head* formulation: scores for ALL query
 heads against ALL of the page's rows in one MXU matmul
@@ -43,11 +58,30 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# score-panel columns of another kv head than the query row's: no page
+# position ever compares below a row's length
+_NO_COLUMN = np.iinfo(np.int32).max
+# slots of the K/V page ring, a power of two: 4 pages of K and V in
+# flight hide a copy's latency; 8 and 16 were no faster at 128 KB pages
+# nor at 32 KB ones (PERF.md, PR 32)
 N_BUF = 4
+
+
+def _score_columns(num_heads: int, num_kv: int, page_size: int) -> np.ndarray:
+    """[H, ps*Hkv] int32: column t*Hkv + h' of a page's score panel is
+    page row t of kv head h'; query row h*G + g matches kv head h.  The
+    entry is t where the heads match and ``_NO_COLUMN`` where they do
+    not, so one compare against the row's length masks both."""
+    group = num_heads // num_kv
+    col = np.arange(page_size * num_kv)
+    row_kv = np.arange(num_heads)[:, None] // group
+    return np.where(row_kv == (col % num_kv)[None, :],
+                    (col // num_kv)[None, :], _NO_COLUMN).astype(np.int32)
 
 
 def _decode_kernel(
@@ -58,83 +92,91 @@ def _decode_kernel(
     layer_ref,         # [1] SMEM layer index into the stacked cache
     # inputs
     q_ref,             # [1, H, D] VMEM (pre-scaled)
+    cols_ref,          # [H, ps*Hkv] VMEM (_score_columns), fetched once
     k_hbm,             # [Lg, P, ps*Hkv, D] ANY/HBM (full group stack)
     v_hbm,
     # quantized mode only: [Lg, P, 1, ps*Hkv] fp32 dequant rows, then
     # outputs + scratch (+[N_BUF, 1, ps*Hkv] scale ring / extra sems)
     *rest,
     page_size: int,
-    num_kv: int,
     softcap: Optional[float],
     quantized: bool,
 ):
     if quantized:
-        (ks_hbm, vs_hbm, o_ref,
-         k_buf, v_buf, sems, ks_buf, vs_buf, ssems) = rest
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, sems, ring, row_pages,
+         row_next, ks_buf, vs_buf, ssems) = rest
     else:
-        o_ref, k_buf, v_buf, sems = rest
+        o_ref, k_buf, v_buf, sems, ring, row_pages, row_next = rest
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssems = None
 
     b = pl.program_id(0)
+    B = pl.num_programs(0)
     length = lengths_ref[b]
     window = window_ref[0]
     li = layer_ref[0]
-    n_pages = pl.cdiv(length, page_size)
-    H = q_ref.shape[1]
-    G = H // num_kv
-    cols = page_size * num_kv
 
-    def k_dma(slot, p):
-        return pltpu.make_async_copy(
-            k_hbm.at[li, page_tables_ref[b, p]], k_buf.at[slot],
-            sems.at[slot, 0])
-
-    def v_dma(slot, p):
-        return pltpu.make_async_copy(
-            v_hbm.at[li, page_tables_ref[b, p]], v_buf.at[slot],
-            sems.at[slot, 1])
-
-    def ks_dma(slot, p):
-        return pltpu.make_async_copy(
-            ks_hbm.at[li, page_tables_ref[b, p]], ks_buf.at[slot],
-            ssems.at[slot, 0])
-
-    def vs_dma(slot, p):
-        return pltpu.make_async_copy(
-            vs_hbm.at[li, page_tables_ref[b, p]], vs_buf.at[slot],
-            ssems.at[slot, 1])
-
-    def start_page(slot, p):
-        k_dma(slot, p).start()
-        v_dma(slot, p).start()
+    def page_copies(slot, page):
+        copies = [
+            pltpu.make_async_copy(k_hbm.at[li, page], k_buf.at[slot],
+                                  sems.at[slot, 0]),
+            pltpu.make_async_copy(v_hbm.at[li, page], v_buf.at[slot],
+                                  sems.at[slot, 1])]
         if quantized:
-            ks_dma(slot, p).start()
-            vs_dma(slot, p).start()
+            copies += [
+                pltpu.make_async_copy(ks_hbm.at[li, page], ks_buf.at[slot],
+                                      ssems.at[slot, 0]),
+                pltpu.make_async_copy(vs_hbm.at[li, page], vs_buf.at[slot],
+                                      ssems.at[slot, 1])]
+        return copies
 
-    for i in range(N_BUF):
-        @pl.when(i < n_pages)
-        def _(i=i):
-            start_page(i, i)
+    def issue(slot, row, page):
+        # start the copies of the cursor's page, if rows are left, and
+        # move the cursor on: to the row's next page, or to page 0 of
+        # the next row that has any (B when none has)
+        @pl.when(row < B)
+        def _():
+            for c in page_copies(slot, page_tables_ref[row, page]):
+                c.start()
+        r = jnp.minimum(row, B - 1)
+        done = page + 1 >= row_pages[r]
+        return (jnp.where(done, row_next[r], row),
+                jnp.where(done, 0, page + 1))
 
+    @pl.when(b == 0)
+    def _cold_start():
+        # pages a row holds and the next row that holds any, once a
+        # call: the page loop then never divides or searches
+        def fill(i, live):
+            r = B - 1 - i
+            n = pl.cdiv(lengths_ref[r], page_size)
+            row_pages[r] = n
+            row_next[r] = live
+            return jnp.where(n > 0, r, live)
+        row = jax.lax.fori_loop(0, B, fill, B)
+        page = jnp.int32(0)
+        for slot in range(N_BUF):
+            row, page = issue(slot, row, page)
+        ring[0] = 0
+        ring[1] = row
+        ring[2] = page
+
+    # the ring as the rows before left it: the slot of this row's first
+    # page, whose copy (and the next N_BUF - 1) is already in flight,
+    # and the cursor of the next page to ask for
+    head = ring[0]
+    n_pages = row_pages[b]
     q2 = q_ref[0]                                  # [H, D]
-    # score-panel coordinates: column t*Hkv + h' is page row t, kv head
-    # h'; query row h*G+g matches kv head h
-    row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 0) // G
-    col_kv = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 1) % num_kv
-    col_t = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 1) // num_kv
-    head_ok = row_kv == col_kv
+    H, D = q2.shape
 
     def body(p, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(p, N_BUF)
+        m, l, acc, row, page = carry
+        slot = (head + p) & (N_BUF - 1)
 
-        k_dma(slot, p).wait()
-        v_dma(slot, p).wait()
+        for c in page_copies(slot, 0):
+            c.wait()
         k2 = k_buf[slot]                           # [ps*Hkv, D]
         v2 = v_buf[slot]
         if quantized:
-            ks_dma(slot, p).wait()
-            vs_dma(slot, p).wait()
             # Per-column scales factor out of the D-contraction exactly:
             # fold sigma_k into the scores and sigma_v into the probs, so
             # the int8 dots match the dequantize-then-dot fallback.
@@ -147,8 +189,11 @@ def _decode_kernel(
             s = s * ks_buf[slot]                   # [1, ps*Hkv] broadcast
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        pos = p * page_size + col_t
-        valid = head_ok & (pos < length) & (pos >= length - window)
+        # a column's position is p*ps + t: compare t against the row's
+        # bounds moved by the page's start (two scalar subtractions)
+        t = cols_ref[...]
+        end = length - p * page_size
+        valid = (t < end) & (t >= end - window)
         s = jnp.where(valid, s, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -162,17 +207,20 @@ def _decode_kernel(
             p_ij.astype(v2.dtype), v2, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)    # [H, D]
 
-        # refill the slot we just consumed
-        @pl.when(p + N_BUF < n_pages)
-        def _():
-            start_page(slot, p + N_BUF)
-        return m_new, l_new, acc * alpha + pv
+        # refill the slot just consumed with the page N_BUF ahead, which
+        # may be a later row's
+        row, page = issue(slot, row, page)
+        return m_new, l_new, acc * alpha + pv, row, page
 
-    D = q_ref.shape[2]
     m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((H, 1), jnp.float32)
     acc0 = jnp.zeros((H, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
+    m, l, acc, row, page = jax.lax.fori_loop(
+        0, n_pages, body, (m0, l0, acc0, ring[1], ring[2]))
+    ring[0] = (head + n_pages) & (N_BUF - 1)
+    ring[1] = row
+    ring[2] = page
+    # a row of length 0 ran no page: acc 0 over the floor is 0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -222,7 +270,9 @@ def paged_decode_attention_pallas(
     cv_flat = cache_v.reshape(Lg, P, ps * Hkv, D)
     q_scaled = q * scale
 
-    operands = [q_scaled, ck_flat, cv_flat]
+    # the head-match mask and page-row index depend on shapes alone: a
+    # constant of the program, one block for every grid step
+    operands = [q_scaled, _score_columns(H, Hkv, ps), ck_flat, cv_flat]
     cache_specs = [
         pl.BlockSpec(memory_space=pltpu.ANY),
         pl.BlockSpec(memory_space=pltpu.ANY),
@@ -231,6 +281,12 @@ def paged_decode_attention_pallas(
         pltpu.VMEM((N_BUF, ps * Hkv, D), cache_k.dtype),
         pltpu.VMEM((N_BUF, ps * Hkv, D), cache_v.dtype),
         pltpu.SemaphoreType.DMA((N_BUF, 2)),
+        # carried from one grid step (row) to the next: the ring's head
+        # slot and the (row, page) cursor of the next page to copy; the
+        # pages each row holds; the next row that holds any
+        pltpu.SMEM((3,), jnp.int32),
+        pltpu.SMEM((B,), jnp.int32),
+        pltpu.SMEM((B,), jnp.int32),
     ]
     if quantized:
         # Pre-expand the per-page scales to per-COLUMN dequant rows
@@ -256,13 +312,14 @@ def paged_decode_attention_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0))]
+        in_specs=[pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec((H, ps * Hkv), lambda b, *_: (0, 0))]
         + cache_specs,
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=scratch,
     )
 
-    kernel = functools.partial(_decode_kernel, page_size=ps, num_kv=Hkv,
+    kernel = functools.partial(_decode_kernel, page_size=ps,
                                softcap=softcap, quantized=quantized)
     out = pl.pallas_call(
         kernel,

@@ -19,6 +19,9 @@ from kaito_tpu.engine.kv_cache import (
     KVCache, create_kv_cache, dequantize_pages, kv_cache_is_quantized,
     scale_bytes_per_page, write_decode_tokens_q, write_prefill_tokens_q)
 from kaito_tpu.models.registry import get_model_by_name
+from tests.helpers.decode_kernel_cases import CASES as DECODE_CASES
+from tests.helpers.decode_kernel_cases import (check_decode_case,
+                                               quantize_pages)
 
 PS = 16  # page size used throughout
 
@@ -128,14 +131,8 @@ def test_pallas_int8_decode_matches_jax():
     lens = jax.random.randint(kl, (B,), PS, pmax * PS, jnp.int32)
     scale = D ** -0.5
 
-    def quantize(pages):
-        s = jnp.max(jnp.abs(pages), axis=(1, 3)) / 127.0
-        codes = jnp.clip(jnp.round(
-            pages / jnp.maximum(s, 1e-30)[:, None, :, None]), -127, 127)
-        return codes.astype(jnp.int8), s
-
-    k8, ks = quantize(ck)
-    v8, vs = quantize(cv)
+    k8, ks = quantize_pages(ck)
+    v8, vs = quantize_pages(cv)
     o_jax = paged_decode_attention(q, k8, v8, pt, lens, scale=scale,
                                    k_scale=ks, v_scale=vs)
     o_pl = paged_decode_attention_pallas(
@@ -146,6 +143,13 @@ def test_pallas_int8_decode_matches_jax():
     # and the whole quantized path stays close to full precision
     o_ref = paged_decode_attention(q, ck, cv, pt, lens, scale=scale)
     assert float(jnp.max(jnp.abs(o_pl - o_ref))) < 0.05
+
+
+@pytest.mark.parametrize("name", list(DECODE_CASES))
+def test_pallas_int8_decode_rows(name):
+    """The decode kernel's row cases over int8 pages: the two scale
+    rings ride the carried page ring slot for slot."""
+    check_decode_case(DECODE_CASES[name], int8_kv=True)
 
 
 # ---------------------------------------------------------------------------

@@ -83,3 +83,54 @@ def test_pallas_path_under_tp_mesh_matches_jax_path(cpu_devices, kv_dtype):
     with pltpu.force_tpu_interpret_mode():
         got = _greedy_tp2(True, kv_dtype, prompts)
     assert got == want
+
+
+def _greedy_ragged(use_pallas, budgets):
+    """Greedy tokens and the decode-row counters of a one-chip engine
+    whose batch never fills (one slot more than requests) and whose
+    rows end at different steps, under the two-deep loop a chip runs."""
+    from kaito_tpu.engine.config import EngineConfig
+    from kaito_tpu.engine.engine import InferenceEngine, SamplingParams
+
+    eng = InferenceEngine(EngineConfig(
+        model="tiny-llama-test", max_model_len=256, page_size=PS,
+        max_num_seqs=len(budgets) + 1, dtype="float32", kv_dtype="float32",
+        prefill_buckets=(32, 64), max_prefill_tokens=64,
+        use_pallas=use_pallas, decode_run_ahead=8, async_dispatch=True,
+        enable_prefix_caching=False, seed=0))
+    assert eng.model.attn_impl == ("pallas" if use_pallas else "jax")
+    rng = np.random.RandomState(1)
+    reqs = [eng.submit(rng.randint(3, 2000, size=n).tolist(),
+                       SamplingParams(max_tokens=m, temperature=0.0,
+                                      ignore_eos=True))
+            for n, m in budgets]
+    for _ in range(400):
+        if all(r.finish_reason for r in reqs):
+            break
+        eng.step()
+    # the window launched behind the last one is retired by the idle
+    # step, as the serving loop's next iteration would
+    while eng.step():
+        pass
+    eng.stop()
+    assert all(r.finish_reason == "length" for r in reqs)
+    return [list(r.output_tokens) for r in reqs], dict(eng.counters)
+
+
+def test_rows_that_decode_nothing_leave_the_others_exact():
+    """A free slot all along and a row that finishes in the middle of a
+    fused window pass the kernel a length of 0: the rows still decoding
+    give the pure-JAX path's greedy ids, and the counters hold every
+    slot-step the programs ran and those that decoded nothing."""
+    budgets = [(20, 3), (37, 14), (9, 11)]     # (prompt, max_tokens)
+    want, _ = _greedy_ragged(False, budgets)
+    with pltpu.force_tpu_interpret_mode():
+        got, counters = _greedy_ragged(True, budgets)
+    assert got == want
+    rows = counters["decode_rows_total"]
+    # every step ran every slot; a request's first token is its
+    # prefill's, each later one a row that decoded
+    assert rows == (len(budgets) + 1) * counters["decode_steps_total"]
+    decoded = sum(m - 1 for _, m in budgets)
+    assert counters["decode_rows_idle_total"] == rows - decoded
+    assert 0 < decoded < rows

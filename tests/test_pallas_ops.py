@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 
 from kaito_tpu.engine.attention import paged_decode_attention
-from kaito_tpu.engine.ops.decode_attention import paged_decode_attention_pallas
+from kaito_tpu.engine.ops.decode_attention import (
+    _score_columns, paged_decode_attention_pallas)
+from tests.helpers.decode_kernel_cases import CASES, check_decode_case
 
 BIG = 1 << 30
 
@@ -60,3 +62,27 @@ def test_pallas_decode_mqa():
         q, ck, cv, pt, lengths, jnp.asarray(BIG, jnp.int32), scale=0.25,
         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_pallas_decode_rows(name):
+    """Ragged rows, rows that decode nothing (zeros out, neighbours
+    exact), head layouts, masks and the stacked pool: the ring carried
+    from row to row must hand every row its own pages."""
+    check_decode_case(CASES[name])
+
+
+@pytest.mark.parametrize("heads,num_kv,page", [(24, 8, 64), (4, 1, 16),
+                                                (6, 2, 16)])
+def test_score_columns_are_the_head_mask_and_page_row(heads, num_kv, page):
+    """The kernel's constant operand, column by column: page row t where
+    the query row's kv head is the column's, out of reach elsewhere."""
+    t = _score_columns(heads, num_kv, page)
+    row = np.arange(heads)[:, None] // (heads // num_kv)
+    col = np.arange(page * num_kv)[None, :]
+    match = row == col % num_kv
+    assert t.shape == (heads, page * num_kv) and t.dtype == np.int32
+    np.testing.assert_array_equal(
+        t[match], np.broadcast_to(col // num_kv, t.shape)[match])
+    # a mismatched column sits at or past every length a row can have
+    assert (t[~match] == np.iinfo(np.int32).max).all()
