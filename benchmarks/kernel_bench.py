@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import os
 import sys
 from typing import Callable, Optional
@@ -262,7 +263,56 @@ def gemv_case(scheme: str, prefetch: bool = False) -> KernelCase:
         unit="weight bytes", work=w_bytes)
 
 
+def ssm_case() -> KernelCase:
+    """The selective state update at Falcon-H1-34B's mixer widths, on
+    layer 1 of a two-layer bfloat16 pool (the type it is served in),
+    from a non-zero state.  Ragged on
+    purpose: rows that decode nothing first, between and last.  The
+    result is the layer's new state less what an idle row held, and y:
+    an idle row then reads exact zeros if and only if the kernel left
+    its state bit for bit and wrote it no y."""
+    from kaito_tpu.engine.ops import ssm
+
+    L, S, Hm, Pm, Gm, Nm = 2, 32, 32, 128, 2, 256
+    kp, kx, kd, kb, kc, ka = jax.random.split(jax.random.PRNGKey(4), 6)
+    pool = jax.random.normal(kp, (L, S, Hm, Pm, Nm), jnp.bfloat16)
+    x = jax.random.normal(kx, (S, Hm, Pm), jnp.float32)
+    dt = jnp.exp(jax.random.uniform(kd, (S, Hm), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    B = jax.random.normal(kb, (S, Gm, Nm), jnp.float32)
+    C = jax.random.normal(kc, (S, Gm, Nm), jnp.float32)
+    A = -jax.random.uniform(ka, (Hm,), jnp.float32, 1.0, 16.0)
+    active = jnp.ones((S,), bool).at[
+        jnp.asarray([0, 1, 9, 10, 17, S - 1])].set(False)
+    live = active.astype(jnp.float32)[:, None]
+
+    def pack(pool_in, new_pool, y):
+        idle = jnp.where(active[:, None, None, None], 0.0, _f32(pool_in[1]))
+        y = jnp.where(active[:, None, None], y, 0.0)
+        return jnp.concatenate([(_f32(new_pool[1]) - idle).reshape(S, -1),
+                                y.reshape(S, -1)], axis=1)
+
+    def kernel(pool, x, dt, B, C):
+        rows, n_live = ssm.live_rows(active)
+        new_pool, y = ssm.ssm_state_update(pool, jnp.int32(1), rows, n_live,
+                                           x, dt, A, B, C)
+        return pack(pool, new_pool, y)
+
+    def reference(pool, x, dt, B, C):
+        new_pool, y = ssm.ssm_state_update_jax(pool, 1, x, dt, A, B, C,
+                                               active)
+        return pack(pool, new_pool, y)
+
+    return KernelCase(
+        "ssm_state_update", kernel, reference, (pool, x, dt, B, C), 0.04,
+        "both sides round one float32 state to bfloat16: a last place can "
+        "move one step, 0.031 at |h| in 4..8; y float32, a 256-term sum",
+        mask=live, zero_where_masked=True, unit="live-state bytes",
+        work=float(jnp.sum(active)) * 2 * Hm * Pm * Nm * 2)
+
+
 CASES: dict[str, Callable[[], KernelCase]] = {
+    "ssm_state_update": ssm_case,
     "decode_bf16": decode_case,
     "decode_int8kv": lambda: decode_case(int8_kv=True),
     "flash_prefill": prefill_case,

@@ -64,6 +64,10 @@ EXPECT = {
 # the kernels on the default bf16 serving path; every other kernel must
 # match too, but these three are what the serve leg just ran
 MAIN_PATH_KERNELS = ("decode_bf16", "flash_prefill", "flash_prefill_packed")
+# and the decode kernel of a model with a state-space mixer beside
+# attention (its state pool's rows, empty ones included), which the
+# serve leg's model does not run
+REQUIRED_KERNELS = MAIN_PATH_KERNELS + ("ssm_state_update",)
 
 
 class SmokeError(Exception):
@@ -444,7 +448,7 @@ def kernels_leg() -> tuple:
     bad = [f"{n}: " + (r.get("error")
                        or f"max_err {r['max_err']} > tol {r['tol']}")
            for n, r in rows.items() if not r["ok"]]
-    missing = [n for n in MAIN_PATH_KERNELS if n not in rows]
+    missing = [n for n in REQUIRED_KERNELS if n not in rows]
     check(not bad and not missing and device and proc.returncode == 0,
           f"kernels leg failed (exit {proc.returncode}); missing "
           f"{missing}; failed:\n" + "\n".join(bad) + "\n" + tail(log_path))

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import logging
 import os
 import queue
@@ -42,6 +43,7 @@ from kaito_tpu.engine.config import EngineConfig
 from kaito_tpu.engine.devprof import phase_scope
 from kaito_tpu.engine.grammar import GrammarCache, GrammarSlot, GrammarTable
 from kaito_tpu.engine.kv_cache import (KVCache, NULL_PAGE, create_kv_cache,
+                                       create_state_pool,
                                        kv_cache_is_quantized,
                                        scale_bytes_per_page)
 from kaito_tpu.engine.model import TransformerLM
@@ -57,6 +59,36 @@ from kaito_tpu.utils.tracing import (PhaseClock, RingTracer, StepTimeline,
                                      format_span_tree)
 
 logger = logging.getLogger(__name__)
+
+# The collector's full passes (docs/observability.md, "The heap").  A
+# full collection walks every tracked Python object under the
+# interpreter lock, and tracing the step programs leaves about 10^5 of
+# them a program for the process's life: 0.4 s for 600,000 objects on an
+# idle core.  One landed inside 2 of 28 windows of the benchmark's widest
+# cell and stopped every stream for 1.7 s and 3.5 s (PERF.md section 6).
+# So the loop freezes the heap when it goes idle after new programs
+# were compiled (``InferenceEngine._settle_heap``), and from then on a
+# pass that still holds the lock for long is named in the log.
+_COMPILES = [0]
+_GC_STARTED = [0.0]
+
+
+def _count_compile(name: str, _secs: float, **_kw) -> None:
+    if name == "/jax/core/compile/backend_compile_duration":
+        _COMPILES[0] += 1
+
+
+def _watch_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC_STARTED[0] = time.monotonic()
+        return
+    held = time.monotonic() - _GC_STARTED[0]
+    if held > 0.1:
+        logger.warning("gc: a generation-%d pass held the interpreter lock "
+                       "%.2fs", info["generation"], held)
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 class RequestScopedError(RuntimeError):
@@ -324,6 +356,8 @@ class InferenceEngine:
             self.model.moe_impl = ("dense" if cfg.expert_parallel > 1
                                    else "ragged")
         self.tokenizer = load_tokenizer(self.md.hf_id, arch.vocab_size)
+        if self.model.has_ssm:
+            self._refuse_for_state_pool(mesh)
         if jnp.dtype(cfg.kv_dtype) == jnp.int8 and (
                 cfg.pipeline_parallel > 1 or cfg.sequence_parallel > 1):
             # the staged 6-dim PP pools and the CP ring prefill don't
@@ -482,6 +516,11 @@ class InferenceEngine:
             self.spec_ctl = DepthController(cfg.max_num_seqs,
                                             cfg.speculative_draft_k)
 
+        # the per-slot recurrent-state pool of a model with a
+        # state-space mixer (docs/kv-cache.md) is allocated BEFORE HBM
+        # is measured: the pages get what it leaves
+        self._state_pool = create_state_pool(arch, cfg.max_num_seqs,
+                                             self.dtype)
         self.sizing_report: dict = {}
         num_pages = cfg.max_pages or self._derive_max_pages()
         num_pages = max(num_pages, cfg.max_num_seqs * self.pages_per_seq // 4 + 2)
@@ -494,6 +533,12 @@ class InferenceEngine:
         logger.info("KV cache: %d pages x %d tokens (%.2f GiB)",
                     num_pages, cfg.page_size,
                     2 * self.cache.k.nbytes / 2**30)
+        if self.model.has_ssm:
+            self.sizing_report["state_pool_bytes"] = \
+                self.cache.state_pool_bytes
+            logger.info("state pool: %d slots x %d layers, %s "
+                        "(%.2f GiB)", cfg.max_num_seqs, arch.num_layers,
+                        self.dtype.name, self.cache.state_pool_bytes / 2**30)
         self.adapter_index: dict[str, int] = {}
         self.adapters_merged = False
         self.adapter_cache = None
@@ -570,7 +615,13 @@ class InferenceEngine:
         if self.pp_exec is not None:
             self.params = self.pp_exec.stage_params(self.params)
         self.prefix_cache = None
-        if cfg.enable_prefix_caching and not self.model.is_mla:
+        if cfg.enable_prefix_caching and self.model.has_ssm:
+            # a page of a shared prefix carries keys and values but no
+            # recurrent state: reuse waits for state snapshots
+            logger.warning("prefix caching requested but this model keeps "
+                           "a recurrent state no page carries; serving "
+                           "WITHOUT prefix reuse")
+        elif cfg.enable_prefix_caching and not self.model.is_mla:
             # the radix tree tracks host-side PAGE IDS only — the same
             # ids index the sharded (TP) or stage-split (PP) pools, so
             # prefix reuse is layout-independent and works under any
@@ -702,6 +753,12 @@ class InferenceEngine:
             "prefix_cache_hits_total": 0,
             "prefix_cache_misses_total": 0,
             "preemptions_total": 0,
+            # the per-slot recurrent-state pool (docs/kv-cache.md): rows
+            # reset at an admission (its first prefill chunk starts from
+            # zeros), and resumes that had to rebuild a state by
+            # recompute; both stay 0 for a model with no mixer
+            "state_resets_total": 0,
+            "state_recomputes_total": 0,
             "host_kv_spilled_pages_total": 0,
             "host_kv_restored_pages_total": 0,
             "spec_steps_total": 0,
@@ -821,6 +878,7 @@ class InferenceEngine:
 
         self._decode_fn = self._build_decode_fn()
         self._prefill_fns: dict[int, object] = {}
+        self._heap_settled_at = -1      # _COMPILES at the last gc.freeze
         ra = cfg.decode_run_ahead
         if ra is None:
             # fused steps amortize per-dispatch overhead (jit-cache
@@ -1037,6 +1095,56 @@ class InferenceEngine:
                         self.cfg.pp_microbatches, M, self.cfg.max_num_seqs)
         return PipelineServeExecutor(self.model, mesh, num_microbatches=M)
 
+    # what cannot serve a model whose cache holds a recurrent state
+    # beside its pages (docs/kv-cache.md), and what each waits for
+    _STATE_POOL_REFUSALS = (
+        ("tensor_parallel", 1, "tensor parallelism (the mixer's heads "
+         "are not sharded)"),
+        ("pipeline_parallel", 1, "pipeline parallelism (the stage "
+         "executor threads no state pool)"),
+        ("sequence_parallel", 1, "context-parallel prefill (the ring has "
+         "no scan of the state)"),
+        ("expert_parallel", 1, "expert parallelism"),
+        ("host_kv_offload_bytes", 0, "host KV offload (a spilled "
+         "sequence's state is not spilled; resume recomputes it)"),
+        ("pd_enabled", False, "prefill/decode disaggregation (the wire "
+         "carries pages, not the recurrent state)"),
+        ("kv_pool_enabled", False, "the cluster KV pool (a published "
+         "prefix carries no recurrent state)"),
+        ("speculative_ngram", 0, "n-gram speculation (a rejected token's "
+         "state update cannot be rolled back)"),
+        ("speculative_draft", "", "draft-model speculation (a rejected "
+         "token's state update cannot be rolled back)"),
+    )
+
+    def _refuse_for_state_pool(self, mesh) -> None:
+        """Refuse by name, at start, every setting a model with a
+        state-space mixer cannot be served under yet."""
+        if mesh is not None:
+            raise ValueError(
+                f"{self.md.name} keeps a per-slot recurrent state beside "
+                f"its KV pages and is served on one device: no mesh")
+        for field_name, off, what in self._STATE_POOL_REFUSALS:
+            if getattr(self.cfg, field_name) != off:
+                raise ValueError(
+                    f"{self.md.name} keeps a per-slot recurrent state "
+                    f"beside its KV pages and cannot be served with "
+                    f"{what}: unset {field_name}")
+
+    def _refuse_kv_import(self) -> None:
+        if self.model.has_ssm:
+            raise ValueError(
+                f"{self.md.name} keeps a per-slot recurrent state beside "
+                f"its KV pages: imported KV pages carry none of it, so a "
+                f"request with KV cannot be admitted")
+
+    def _state_rows(self, idxs) -> Optional[jax.Array]:
+        """The slots' rows of the state pool, for a prefill program (None
+        for a model with no mixer: the argument compiles away)."""
+        if not self.model.has_ssm:
+            return None
+        return jnp.asarray(np.asarray(idxs, np.int32))
+
     def _fresh_cache(self) -> KVCache:
         """Zeroed page pool, laid out for the active parallelism mode.
         Under a mesh the pool is CREATED under its sharding: no device —
@@ -1045,6 +1153,16 @@ class InferenceEngine:
         than its own shard."""
         make = partial(create_kv_cache, self.md.arch, self._num_pages,
                        self.cfg.page_size, jnp.dtype(self.cfg.kv_dtype))
+        if self.model.has_ssm:
+            # one device (_refuse_for_state_pool); the state pool that
+            # was there when HBM was measured, or a new one after a
+            # failed step took it
+            pool, self._state_pool = self._state_pool, (None, None)
+            if pool[0] is None:
+                pool = create_state_pool(self.md.arch, self.cfg.max_num_seqs,
+                                         self.dtype)
+            return dataclasses.replace(make(), ssm_state=pool[0],
+                                       ssm_conv=pool[1])
         if self.pp_exec is not None:
             return self.pp_exec.stage_cache(make())
         if self.mesh is None:
@@ -1467,13 +1585,14 @@ class InferenceEngine:
             @partial(jax.jit, donate_argnums=(1,))
             @phase_scope("prefill")
             def prefill_step(params, cache, tokens, true_lens, page_tables,
-                             adapter_ids):
+                             adapter_ids, state_rows=None):
                 if pp_prefill is not None:
                     return pp_prefill(params, cache, tokens, true_lens,
                                       page_tables, adapter_ids=adapter_ids)
                 cache, logits, _ = model.prefill(params, cache, tokens,
                                                  true_lens, page_tables,
-                                                 adapter_ids=adapter_ids)
+                                                 adapter_ids=adapter_ids,
+                                                 state_rows=state_rows)
                 return cache, logits
 
             fn = prefill_step
@@ -1537,7 +1656,7 @@ class InferenceEngine:
             @partial(jax.jit, donate_argnums=(1,))
             @phase_scope("prefill")
             def prefill_ctx(params, cache, tokens, true_lens, page_tables,
-                            start_pos, adapter_ids):
+                            start_pos, adapter_ids, state_rows=None):
                 if pp_prefill is not None:
                     return pp_prefill(params, cache, tokens, true_lens,
                                       page_tables, start_pos,
@@ -1545,7 +1664,8 @@ class InferenceEngine:
                 cache, logits, _ = model.prefill(params, cache, tokens,
                                                  true_lens, page_tables,
                                                  start_pos=start_pos,
-                                                 adapter_ids=adapter_ids)
+                                                 adapter_ids=adapter_ids,
+                                                 state_rows=state_rows)
                 return cache, logits
 
             fn = prefill_ctx
@@ -1632,6 +1752,14 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     @property
+    def state_rows_in_use(self) -> int:
+        """Rows of the recurrent-state pool that hold a sequence's state
+        (a slot with a request; 0 for a model with no mixer)."""
+        if not self.model.has_ssm:
+            return 0
+        return sum(1 for s in self.slots if s.request is not None)
+
+    @property
     def num_waiting(self) -> int:
         return self._waiting_count
 
@@ -1662,6 +1790,7 @@ class InferenceEngine:
         count, page_size and head layout must match this engine's pool
         too.  Chunked imports stay lenient: their assemble step
         re-checks per-chunk shapes against the host buffers anyway."""
+        self._refuse_kv_import()
         if meta.get("model") not in ("", None, self.md.name):
             raise ValueError(f"KV transfer model mismatch: {meta.get('model')} "
                              f"!= {self.md.name}")
@@ -1857,6 +1986,7 @@ class InferenceEngine:
         optimization, never a correctness dependency."""
         from kaito_tpu.engine.pd import ChunkedImport
 
+        self._refuse_kv_import()
         self._validate_submit(prompt_tokens, params)
         self._resolve_adapter(adapter)
         if meta.get("model") not in ("", None, self.md.name):
@@ -2014,6 +2144,13 @@ class InferenceEngine:
         self._wake.set()
         if self._thread:
             self._thread.join(timeout=30)
+        if self._heap_settled_at >= 0:
+            # what _settle_heap froze is the collector's again: an
+            # engine that stops may be garbage the process outlives
+            self._heap_settled_at = -1
+            gc.unfreeze()
+            if _watch_gc in gc.callbacks:
+                gc.callbacks.remove(_watch_gc)
         if self.async_dispatch:
             # the labelled family is easy to lose in a scrape's
             # reduction: the log keeps the reasons beside the windows
@@ -2061,9 +2198,28 @@ class InferenceEngine:
                 self._fail_all()
                 continue
             if not did_work:
+                self._settle_heap()
                 with self.phases.annotate("engine.idle"):
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
+
+    def _settle_heap(self) -> None:
+        """The loop is idle.  If programs were compiled since it last
+        was, move every live object to the permanent generation: what
+        tracing left behind lives as long as the process, and the
+        collector's full passes then walk only what came after.  No
+        collection here, so a request that arrives now waits for
+        nothing; an object frozen while still in use is freed by its
+        reference count as before."""
+        if _COMPILES[0] == self._heap_settled_at:
+            return
+        self._heap_settled_at = _COMPILES[0]
+        gc.freeze()
+        if _watch_gc not in gc.callbacks:
+            gc.callbacks.append(_watch_gc)
+        logger.info("heap settled after %d compiles: %d objects in the "
+                    "permanent generation", _COMPILES[0],
+                    gc.get_freeze_count())
 
     def _pop_waiting(self) -> Optional[Request]:
         with self._lock:
@@ -2461,7 +2617,9 @@ class InferenceEngine:
                 expired=c["requests_expired_total"] - before[5],
                 shed=c["requests_shed_total"] - before[6],
                 kv_pages_used=(self.allocator.num_pages - 1
-                               - self.allocator.available))
+                               - self.allocator.available),
+                **({"state_rows": self.state_rows_in_use}
+                   if self.model.has_ssm else {}))
         return did
 
     def _step_inner(self) -> bool:
@@ -2672,6 +2830,10 @@ class InferenceEngine:
         slot.prefilling = True
         slot.prefill_pos = cached
         slot.prefill_tokens = tokens
+        if self.model.has_ssm:
+            self.counters["state_resets_total"] += 1
+            if req.preemptions:
+                self.counters["state_recomputes_total"] += 1
         now = time.monotonic()
         slot.staged_t0 = now
         # queue wait only on FIRST admission — a resume after preemption
@@ -3017,7 +3179,8 @@ class InferenceEngine:
                     self.cache, logits = fn(*args, aid)
                 elif pos == 0 and m == n:
                     fn = self._prefill_fn(bucket)
-                    self.cache, logits = fn(*args, aid)
+                    self.cache, logits = fn(*args, aid,
+                                            self._state_rows([i]))
                 else:
                     # chunk attends over the paged history (cached
                     # prefix + earlier chunks) — bounds per-step latency
@@ -3025,7 +3188,8 @@ class InferenceEngine:
                     # reference)
                     fn = self._prefill_ctx_fn(bucket)
                     self.cache, logits = fn(
-                        *args, jnp.asarray([pos], np.int32), aid)
+                        *args, jnp.asarray([pos], np.int32), aid,
+                        self._state_rows([i]))
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
             self._fail_prefill(i, e)
@@ -3127,13 +3291,14 @@ class InferenceEngine:
         # members: fresh-complete prompts segment-pack per adapter
         # (batch-axis per bucket for MLA, which has no packed kernel),
         # context chunks batch per bucket
-        mla = self.model.is_mla
+        # (a state-space mixer has no segment-packed scan either)
+        no_pack = self.model.is_mla or self.model.has_ssm
         groups: list[tuple[tuple, list]] = []
         index: dict[tuple, int] = {}
         for p in picks:
             i, pos, take, n = p
             if pos == 0 and take == n:
-                gk = (("fresh", self._bucket(take)) if mla
+                gk = (("fresh", self._bucket(take)) if no_pack
                       else ("seg", int(self.slot_adapters[i])))
             else:
                 gk = ("ctx", self._bucket(take))
@@ -3264,7 +3429,8 @@ class InferenceEngine:
         fn = self._prefill_fn(bucket)
         self.cache, logits = fn(self.params, self.cache,
                                 jnp.asarray(ctoks), jnp.asarray(tls),
-                                jnp.asarray(pts), jnp.asarray(aids))
+                                jnp.asarray(pts), jnp.asarray(aids),
+                                self._state_rows([r[0] for r in rows]))
         return logits
 
     def _dispatch_prefill_ctx(self, rows):
@@ -3288,7 +3454,8 @@ class InferenceEngine:
         self.cache, logits = fn(self.params, self.cache,
                                 jnp.asarray(ctoks), jnp.asarray(tls),
                                 jnp.asarray(pts), jnp.asarray(sps),
-                                jnp.asarray(aids))
+                                jnp.asarray(aids),
+                                self._state_rows([r[0] for r in rows]))
         return logits
 
     def _dispatch_prefill_packed(self, rows):

@@ -60,6 +60,16 @@ class KVCache:
     # the bf16 mode's scan carries and donation are untouched).
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    # The second kind of state (docs/kv-cache.md): a model with a
+    # state-space mixer beside attention keeps, per decode slot and not
+    # per page, the mixer's recurrent state
+    # [L, slots, ssm_heads, ssm_head_dim, ssm_state] and the last
+    # ssm_conv-1 inputs of its convolution
+    # [L, slots, ssm_conv-1, conv channels], both in the type the model
+    # is served in.  A slot's row costs the same at token 1 and token
+    # 1,000; no page carries any of it.  None for every other model.
+    ssm_state: Optional[jax.Array] = None
+    ssm_conv: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -73,6 +83,13 @@ class KVCache:
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    @property
+    def state_pool_bytes(self) -> int:
+        """Bytes of the per-slot recurrent-state pool (0: no mixer)."""
+        if self.ssm_state is None:
+            return 0
+        return int(self.ssm_state.nbytes + self.ssm_conv.nbytes)
+
 
 def kv_cache_is_quantized(dtype) -> bool:
     return jnp.dtype(dtype) == jnp.int8
@@ -81,6 +98,20 @@ def kv_cache_is_quantized(dtype) -> bool:
 def scale_bytes_per_page(arch: ModelArch) -> int:
     """HBM overhead of the two fp32 scale rows one page carries."""
     return 2 * arch.num_layers * arch.kv_cache_heads * 4
+
+
+def create_state_pool(arch: ModelArch, slots: int, dtype: jnp.dtype):
+    """Zeroed (state, convolution tail) pools for ``slots`` decode
+    slots, or (None, None) for a model with no state-space mixer.
+    ``dtype`` is the model's: the step programs compute the recurrence
+    in float32 and round once, where a state is written back."""
+    if not arch.ssm_state:
+        return None, None
+    L = arch.num_layers
+    return (jnp.zeros((L, slots, arch.ssm_heads, arch.ssm_head_dim,
+                       arch.ssm_state), dtype),
+            jnp.zeros((L, slots, arch.ssm_conv - 1, arch.ssm_conv_dim),
+                      dtype))
 
 
 def create_kv_cache(

@@ -444,6 +444,24 @@ class EngineMetrics:
                   "attention kernel copies no KV page for such a row", r,
                   fn=lambda: engine.counters.get(
                       "decode_rows_idle_total", 0))
+            if getattr(getattr(engine, "model", None), "has_ssm", False):
+                # the second kind of state in the cache (docs/kv-cache.md)
+                Gauge("kaito:engine_state_pool_bytes",
+                      "Bytes of the per-slot recurrent-state pool (mixer "
+                      "state and convolution tail of every slot and layer)",
+                      r, fn=lambda: engine.cache.state_pool_bytes)
+                Gauge("kaito:engine_state_rows_in_use",
+                      "Rows of the recurrent-state pool that hold a "
+                      "sequence's state", r,
+                      fn=lambda: engine.state_rows_in_use)
+                Gauge("kaito:engine_state_resets_total",
+                      "Rows of the state pool reset at an admission (the "
+                      "first prefill chunk starts from zeros)", r,
+                      fn=lambda: engine.counters["state_resets_total"])
+                Gauge("kaito:engine_state_recomputes_total",
+                      "Resumes after preemption that rebuilt a recurrent "
+                      "state by recompute", r,
+                      fn=lambda: engine.counters["state_recomputes_total"])
             Gauge("kaito:prefix_cached_tokens_total",
                   "Prompt tokens served from the prefix cache", r,
                   fn=lambda: engine.counters["prefix_cached_tokens_total"])

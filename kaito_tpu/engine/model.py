@@ -6,7 +6,11 @@ phi-3 / phi-4 / gemma-3 / falcon / phi-2) and token-choice MoE
 parameters so an 80-layer model compiles as one layer, and per-layer
 heterogeneity (sliding vs global attention, local vs global RoPE) rides
 along as scanned flag arrays.  Dense-prefix MoE models (DeepSeek-style
-``first_k_dense_replace``) split into two scans.
+``first_k_dense_replace``) split into two scans.  A state-space mixer
+beside attention in every block (falcon-h1: ``ModelArch.ssm_state``)
+reads the block's normed input as attention does, adds its output to
+the same residual, and keeps a per-slot recurrent state that rides the
+layer scan with the page pools (``_ssm_mixer``, engine/ops/ssm.py).
 
 This replaces the model zoo the reference gets for free from vLLM
 (SURVEY.md §2.2, §7 step 3); parameters are plain pytrees whose logical
@@ -23,6 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from kaito_tpu.engine import attention as attn
@@ -114,6 +119,11 @@ class TransformerLM:
         # axis (heads do not divide) runs every head on every device.
         self.head_shard = None
         self.moe_impl = "dense"     # "dense" | "ragged" (grouped matmul)
+        # a state-space mixer beside attention in every block: the
+        # cache then holds a per-slot state pool beside the pages
+        # (docs/kv-cache.md), and prefix reuse, PD and speculation
+        # are refused by the engine
+        self.has_ssm = arch.ssm_state > 0
         self.groups = _layer_groups(arch)
         self.vocab_padded = -(-arch.vocab_size // VOCAB_ALIGN) * VOCAB_ALIGN
         # rope tables are concrete constants; computing them lazily inside
@@ -202,6 +212,18 @@ class TransformerLM:
         if a.pre_post_norm:
             specs["post_attn_norm"] = ((E,), ("embed",))
             specs["post_mlp_norm"] = ((E,), ("embed",))
+        if a.ssm_state:
+            Hm, Cd = a.ssm_heads, a.ssm_conv_dim
+            specs.update({
+                "ssm_in": ((E, a.ssm_proj_dim), ("embed", None)),
+                "ssm_conv": ((a.ssm_conv, Cd), (None, None)),
+                "ssm_conv_bias": ((Cd,), (None,)),
+                "ssm_dt_bias": ((Hm,), (None,)),
+                "ssm_a_log": ((Hm,), (None,)),
+                "ssm_d": ((Hm,), (None,)),
+                "ssm_norm": ((a.ssm_inner,), (None,)),
+                "ssm_out": ((a.ssm_inner, E), (None, "embed")),
+            })
         if moe:
             X = a.num_experts
             Im = a.moe_intermediate_size or I
@@ -245,28 +267,92 @@ class TransformerLM:
         """Random (synthetic) weights with sane init scales."""
         params: dict = {}
         keys = jax.random.split(key, len(self.groups) + 1)
+        follow = self._draw_multipliers()
         for spec_key, (shape, _) in self._top_specs().items():
             if "norm" in spec_key:
                 params[spec_key] = jnp.zeros(shape, self.dtype) if "bias" in spec_key or self.arch.norm_offset else jnp.ones(shape, self.dtype)
             else:
-                params[spec_key] = 0.02 * jax.random.normal(
+                params[spec_key] = (0.02 / follow.get(spec_key, 1.0)) * jax.random.normal(
                     jax.random.fold_in(keys[0], _name_salt(spec_key)), shape, self.dtype)
         for gi, g in enumerate(self.groups):
             layer: dict = {}
             for name, (shape, _) in self._layer_specs(g.moe).items():
                 full = (g.count,) + shape
-                if "norm" in name and "bias" not in name:
+                if name in ("ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_conv",
+                            "ssm_conv_bias"):
+                    init = self._ssm_draw(
+                        name, jax.random.fold_in(keys[1 + gi],
+                                                 _name_salt(name)), full)
+                elif "norm" in name and "bias" not in name:
                     init = jnp.zeros(full, self.dtype) if self.arch.norm_offset else jnp.ones(full, self.dtype)
                 elif name.endswith("_bias") or "bias" in name:
                     init = jnp.zeros(full, self.dtype)
                 else:
                     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
                     std = 1.0 / math.sqrt(fan_in)
-                    init = std * jax.random.normal(
-                        jax.random.fold_in(keys[1 + gi], _name_salt(name)), full, self.dtype)
+                    if name in follow:
+                        init = ((std / follow[name]) * jax.random.normal(
+                            jax.random.fold_in(keys[1 + gi], _name_salt(name)),
+                            full, jnp.float32)).astype(self.dtype)
+                    else:
+                        init = std * jax.random.normal(
+                            jax.random.fold_in(keys[1 + gi], _name_salt(name)), full, self.dtype)
                 layer[name] = init
             params[g.name] = layer
         return params
+
+    @cached_property
+    def _ssm_mup(self) -> np.ndarray:
+        """falcon-h1's ``mup_vector``: ``ssm_multipliers`` over the five
+        segments [z | x | B | C | dt] of the mixer's input projection."""
+        a = self.arch
+        gn = a.ssm_groups * a.ssm_state
+        widths = (a.ssm_inner, a.ssm_inner, gn, gn, a.ssm_heads)
+        return np.concatenate([np.full((w,), m, np.float32) for w, m in
+                               zip(widths, a.ssm_multipliers or (1.0,) * 5)])
+
+    def _draw_multipliers(self) -> dict:
+        """Synthetic weights of an architecture that carries its muP
+        multipliers in the forward pass (falcon-h1): each matrix that a
+        multiplier follows is drawn at the multiplier's inverse, so that
+        every branch moves the logits as a trained model's does.  Drawn
+        at the plain scales, the published multipliers shrink attention,
+        mixer and MLP to a hundredth of the residual, and a check
+        against the reference passes a model with a branch deleted.
+        The forward pass keeps every multiplier as published."""
+        a = self.arch
+        gate_m, down_m = a.mlp_multipliers or (None, None)
+        follow = {"lm_head": a.lm_head_multiplier, "k": a.key_multiplier,
+                  "o": a.attention_out_multiplier, "gate": gate_m,
+                  "down": down_m, "ssm_out": a.ssm_out_multiplier}
+        follow = {k: float(v) for k, v in follow.items() if v}
+        if a.ssm_in_multiplier or a.ssm_multipliers:
+            follow["ssm_in"] = float(a.ssm_in_multiplier or 1.0) \
+                * self._ssm_mup
+        # the embedding's scale alone is no muP multiplier (gemma's
+        # sqrt(hidden)): it is compensated only beside the others
+        if follow and a.embedding_multiplier:
+            follow["embed"] = float(a.embedding_multiplier)
+        return follow
+
+    def _ssm_draw(self, name: str, key: jax.Array, shape: tuple):
+        """The mixer's small parameters by Mamba-2's conventions: A in
+        1..16, the step dt (softplus of its bias) log-uniform between
+        1e-3 and 1e-1, D one, and a convolution with a bias."""
+        f32 = jnp.float32
+        if name == "ssm_a_log":
+            v = jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0))
+        elif name == "ssm_dt_bias":
+            dt = jnp.exp(jax.random.uniform(key, shape, f32, math.log(1e-3),
+                                            math.log(1e-1)))
+            v = dt + jnp.log(-jnp.expm1(-dt))       # softplus's inverse
+        elif name == "ssm_d":
+            v = jnp.ones(shape, f32)
+        elif name == "ssm_conv":
+            v = jax.random.normal(key, shape, f32) / math.sqrt(shape[-2])
+        else:                                       # ssm_conv_bias
+            v = 0.1 * jax.random.normal(key, shape, f32)
+        return v.astype(self.dtype)
 
     def param_logical_axes(self) -> dict:
         """Tree matching init_params with logical axis names per dim."""
@@ -453,6 +539,8 @@ class TransformerLM:
         a = self.arch
         B, T, _ = x.shape
         ls = self.lora_scaling
+        if a.attention_in_multiplier is not None:
+            x = x * jnp.asarray(a.attention_in_multiplier, x.dtype)
         if overlap is not None:
             from kaito_tpu.engine.ops.overlap_collectives import (
                 ag_matmul_eligible, all_gather_matmul)
@@ -474,6 +562,8 @@ class TransformerLM:
             + nn.multi_lora_delta(x, lora, "v", lora_ids)
         if "q_bias" in p:
             q, k, v = q + p["q_bias"], k + p["k_bias"], v + p["v_bias"]
+        if a.key_multiplier is not None:
+            k = k * jnp.asarray(a.key_multiplier, k.dtype)
         q = q.reshape(B, T, a.num_heads, a.head_dim)
         k = k.reshape(B, T, a.num_kv_heads, a.head_dim)
         v = v.reshape(B, T, a.num_kv_heads, a.head_dim)
@@ -515,8 +605,13 @@ class TransformerLM:
     def _layer(self, x, p, ck, cv, li, window, moe, mode, *,
                positions, page_tables, lengths, true_lens, active,
                start_pos=None, lora=None, lora_ids=None,
-               ks=None, vs=None, packed=None, pf=None):
-        """One transformer block. Returns (x, ck, cv, ks, vs).
+               ks=None, vs=None, packed=None, pf=None, ssm=None,
+               ssm_rows=None):
+        """One transformer block. Returns (x, ck, cv, ks, vs, ssm).
+
+        ``ssm`` is the mixer's per-slot pools (state [Lg, S, H, P, N],
+        convolution tail [Lg, S, K-1, C]) of a model with a state-space
+        mixer beside attention, riding the same carry; None otherwise.
 
         ``ck``/``cv`` are the FULL layer-group page pools
         [Lg, P, ps, Hkv, D] riding the layer scan as a carry; ``li`` is
@@ -536,10 +631,11 @@ class TransformerLM:
                 page_tables=page_tables, lengths=lengths,
                 true_lens=true_lens, active=active, start_pos=start_pos)
             if a.parallel_residual:
-                return x + attn_out + self._mlp(h, p, moe), ck, cv, ks, vs
+                return (x + attn_out + self._mlp(h, p, moe), ck, cv, ks, vs,
+                        ssm)
             x = x + attn_out
             h2 = self._norm(x, p, "mlp_norm")
-            return x + self._mlp(h2, p, moe), ck, cv, ks, vs
+            return x + self._mlp(h2, p, moe), ck, cv, ks, vs, ssm
         # collective-compute overlap (docs/multichip.md): DECODE-only,
         # resolved once here — q (column-parallel, below), o and down
         # (row-parallel, further down) all key off the same handle
@@ -709,11 +805,24 @@ class TransformerLM:
             + nn.multi_lora_delta(o_in, lora, "o", lora_ids)
         if "o_bias" in p:
             attn_out = attn_out + p["o_bias"]
+        if a.attention_out_multiplier is not None:
+            attn_out = attn_out * jnp.asarray(a.attention_out_multiplier,
+                                              attn_out.dtype)
+        if self.has_ssm:
+            # the mixer reads the same normed input as attention; the
+            # two outputs share one residual add
+            if mode not in ("prefill", "decode"):
+                raise NotImplementedError(
+                    f"the state-space mixer has no {mode!r} path")
+            ssm_out, ssm = self._ssm_mixer(
+                h, p, ssm, li, mode, true_lens=true_lens, active=active,
+                start_pos=start_pos, rows=ssm_rows)
+            attn_out = attn_out + ssm_out
 
         if a.parallel_residual:
             mlp_out = self._mlp(h, p, moe, lora=lora, lora_ids=lora_ids,
                                 overlap=ov, pf_down=(pf or {}).get("down"))
-            return x + attn_out + mlp_out, ck, cv, ks, vs
+            return x + attn_out + mlp_out, ck, cv, ks, vs, ssm
 
         if a.pre_post_norm:
             attn_out = self._norm(attn_out, p, "post_attn_norm")
@@ -723,7 +832,100 @@ class TransformerLM:
                             overlap=ov, pf_down=(pf or {}).get("down"))
         if a.pre_post_norm:
             mlp_out = self._norm(mlp_out, p, "post_mlp_norm")
-        return x + mlp_out, ck, cv, ks, vs
+        return x + mlp_out, ck, cv, ks, vs, ssm
+
+    def _ssm_mixer(self, h, p, pools, li, mode, *, true_lens, active,
+                   start_pos, rows):
+        """The state-space mixer (Mamba-2's, as falcon-h1 publishes it)
+        on the block's normed input ``h`` [B, T, E].  Returns (its
+        output [B, T, E], pools).
+
+        ``pools`` is (state [Lg, S, H, P, N], convolution tail
+        [Lg, S, K-1, C]), both in the model's type (the recurrence is
+        computed in float32 and rounded once, where a state is written
+        back), or None for a forward pass with no cache
+        (``train``: every sequence from a zero state).  Prefill reads
+        and writes the rows ``rows`` ([B] slot indices): a chunk that
+        starts at position 0 starts from zeros, which is what resets a
+        reused slot's row; a later chunk starts from what the chunk
+        before left.  Decode updates every row that ``active`` names,
+        in place, and leaves the others bit for bit: ``rows`` is then
+        ``ssm.live_rows(active)``."""
+        from kaito_tpu.engine.ops import ssm as S
+
+        a = self.arch
+        Bn, T, _ = h.shape
+        Hm, Pm, G, N = a.ssm_heads, a.ssm_head_dim, a.ssm_groups, a.ssm_state
+        inner, Cd = a.ssm_inner, a.ssm_conv_dim
+        f32 = jnp.float32
+        if a.ssm_in_multiplier is not None:
+            h = h * jnp.asarray(a.ssm_in_multiplier, h.dtype)
+        proj = nn.linear(h, p["ssm_in"])
+        if a.ssm_multipliers is not None:
+            proj = proj * jnp.asarray(self._ssm_mup, proj.dtype)
+        z = proj[..., :inner].astype(f32)
+        xbc = proj[..., inner:inner + Cd].astype(f32)
+        dt = jax.nn.softplus(proj[..., inner + Cd:].astype(f32)
+                             + p["ssm_dt_bias"].astype(f32))
+        A = -jnp.exp(p["ssm_a_log"].astype(f32))
+        D = p["ssm_d"].astype(f32)
+        w, wb = p["ssm_conv"].astype(f32), p["ssm_conv_bias"].astype(f32)
+
+        def split(c):
+            return (c[..., :inner].reshape(c.shape[:-1] + (Hm, Pm)),
+                    c[..., inner:inner + G * N].reshape(c.shape[:-1] + (G, N)),
+                    c[..., inner + G * N:].reshape(c.shape[:-1] + (G, N)))
+
+        if mode == "decode":
+            st, cv = pools
+            tail = cv[li]
+            conv, new_tail = S.conv_step(xbc[:, 0], tail.astype(f32), w, wb)
+            new_tail = new_tail.astype(cv.dtype)
+            if active is not None:
+                new_tail = jnp.where(active[:, None, None], new_tail, tail)
+            cv = cv.at[li].set(new_tail)
+            xs, Bm, Cm = split(jax.nn.silu(conv))
+            if self.attn_impl == "pallas":
+                st, y = S.ssm_state_update(st, li, rows[0], rows[1], xs,
+                                           dt[:, 0], A, Bm, Cm)
+                if active is not None:
+                    y = jnp.where(active[:, None, None], y, 0.0)
+            else:
+                st, y = S.ssm_state_update_jax(st, li, xs, dt[:, 0], A, Bm,
+                                               Cm, active)
+            y = (y + D[None, :, None] * xs)[:, None]         # [S, 1, H, P]
+        else:
+            if pools is None or start_pos is None:
+                tail0 = jnp.zeros((Bn, a.ssm_conv - 1, Cd), f32)
+                h0 = jnp.zeros((Bn, Hm, Pm, N), f32)
+            else:
+                keep = start_pos > 0
+                tail0 = jnp.where(keep[:, None, None],
+                                  pools[1][li, rows].astype(f32), 0.0)
+                h0 = jnp.where(keep[:, None, None, None],
+                               pools[0][li, rows].astype(f32), 0.0)
+            xs, Bm, Cm = split(jax.nn.silu(S.causal_conv(xbc, tail0, w, wb)))
+            valid = jnp.arange(T)[None, :] < true_lens[:, None]
+            y, h_last = S.ssm_chunked_scan(
+                xs, jnp.where(valid[..., None], dt, 0.0), A, Bm, Cm, h0,
+                a.ssm_chunk)
+            y = y + D[None, None, :, None] * xs
+            if pools is not None:
+                st, cv = pools
+                st = st.at[li, rows].set(h_last.astype(st.dtype))
+                cv = cv.at[li, rows].set(
+                    S.conv_tail(xbc, tail0, true_lens).astype(cv.dtype))
+        # gated norm, the gate first (mamba_norm_before_gate false):
+        # RMSNorm over each group's channels, then one weight a channel
+        y = y.reshape(Bn, T, inner) * jax.nn.silu(z)
+        yg = y.reshape(Bn, T, G, inner // G)
+        yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1,
+                                         keepdims=True) + a.rms_norm_eps)
+        y = yg.reshape(Bn, T, inner) * p["ssm_norm"].astype(f32)
+        out = nn.linear(y.astype(self.dtype), p["ssm_out"])
+        if a.ssm_out_multiplier is not None:
+            out = out * jnp.asarray(a.ssm_out_multiplier, out.dtype)
+        return out, (None if pools is None else (st, cv))
 
     # ------------------------------------------------------------------
     # Forward passes
@@ -732,9 +934,9 @@ class TransformerLM:
     def _run_layers(self, params, cache: Optional[KVCache], x, mode, *,
                     positions, page_tables, lengths, true_lens, active,
                     remat: bool = False, start_pos=None, adapter_ids=None,
-                    packed=None):
+                    packed=None, ssm_rows=None):
         serve_lora = params.get("serve_lora") if mode != "train" else None
-        new_k, new_v, new_ks, new_vs = [], [], [], []
+        new_k, new_v, new_ks, new_vs, new_ssm = [], [], [], [], []
         for g in self.groups:
             stack = params[g.name]
             flags = self._window_flags(g.start, g.count)
@@ -765,6 +967,14 @@ class TransformerLM:
                     if cache.k_scale is not None else None)
             vs_g = (cache.v_scale[g.start:g.start + g.count]
                     if cache.v_scale is not None else None)
+            # the mixer's per-slot pools ride it too (None: no mixer)
+            if self.has_ssm and cache.ssm_state is None:
+                raise ValueError("a model with a state-space mixer serves "
+                                 "from a cache with a state pool "
+                                 "(kv_cache.create_state_pool)")
+            ssm_g = ((cache.ssm_state[g.start:g.start + g.count],
+                      cache.ssm_conv[g.start:g.start + g.count])
+                     if cache.ssm_state is not None else None)
             # per-request adapters ride the scan as an extra [L, n, ...]
             # stack (None for groups without one, e.g. MoE)
             lora_g = serve_lora.get(g.name) if serve_lora else None
@@ -781,7 +991,7 @@ class TransformerLM:
 
             def body(carry, xs, moe=g.moe, has_lora=has_lora,
                      has_pf=has_pf):
-                h, ck_g, cv_g, ks_g, vs_g = carry
+                h, ck_g, cv_g, ks_g, vs_g, ssm_g = carry
                 items = list(xs)
                 li, p = items[0], items[1]
                 k = 2
@@ -789,13 +999,14 @@ class TransformerLM:
                 k += int(has_lora)
                 pf_l = items[k] if has_pf else None
                 window = items[-1] if flags is not None else None
-                h, ck_g, cv_g, ks_g, vs_g = self._layer(
+                h, ck_g, cv_g, ks_g, vs_g, ssm_g = self._layer(
                     h, p, ck_g, cv_g, li, window, moe, mode,
                     positions=positions, page_tables=page_tables,
                     lengths=lengths, true_lens=true_lens, active=active,
                     start_pos=start_pos, lora=lora_l, lora_ids=adapter_ids,
-                    ks=ks_g, vs=vs_g, packed=packed, pf=pf_l)
-                return (h, ck_g, cv_g, ks_g, vs_g), None
+                    ks=ks_g, vs=vs_g, packed=packed, pf=pf_l, ssm=ssm_g,
+                    ssm_rows=ssm_rows)
+                return (h, ck_g, cv_g, ks_g, vs_g, ssm_g), None
 
             # scan length follows the actual stack: pipeline stages pass
             # stage-local views whose leading axis is a fraction of the
@@ -816,12 +1027,13 @@ class TransformerLM:
                         f"sliding-window pattern ({pat}); per-stage window "
                         f"flags are not implemented")
                 xs = xs + (flags[:Lg],)
-            (x, ck_new, cv_new, ks_new, vs_new), _ = jax.lax.scan(
-                body, (x, ck_g, cv_g, ks_g, vs_g), xs)
+            (x, ck_new, cv_new, ks_new, vs_new, ssm_new), _ = jax.lax.scan(
+                body, (x, ck_g, cv_g, ks_g, vs_g, ssm_g), xs)
             new_k.append(ck_new)
             new_v.append(cv_new)
             new_ks.append(ks_new)
             new_vs.append(vs_new)
+            new_ssm.append(ssm_new)
         if mode == "train":
             return x, None
 
@@ -830,8 +1042,11 @@ class TransformerLM:
                 return None
             return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
+        st, cv = (_cat([s[i] for s in new_ssm]) if new_ssm[0] is not None
+                  else None for i in (0, 1))
         cache = KVCache(k=_cat(new_k), v=_cat(new_v),
-                        k_scale=_cat(new_ks), v_scale=_cat(new_vs))
+                        k_scale=_cat(new_ks), v_scale=_cat(new_vs),
+                        ssm_state=st, ssm_conv=cv)
         return x, cache
 
     def _layer_train(self, x, p, window, moe, *, positions, true_lens):
@@ -866,6 +1081,14 @@ class TransformerLM:
         attn_out = nn.linear(o_in, p["o"]) + nn.lora_delta(o_in, p, "o", self.lora_scaling)
         if "o_bias" in p:
             attn_out = attn_out + p["o_bias"]
+        if a.attention_out_multiplier is not None:
+            attn_out = attn_out * jnp.asarray(a.attention_out_multiplier,
+                                              attn_out.dtype)
+        if self.has_ssm:
+            ssm_out, _ = self._ssm_mixer(
+                h, p, None, None, "train", true_lens=true_lens, active=None,
+                start_pos=None, rows=None)
+            attn_out = attn_out + ssm_out
         if a.parallel_residual:
             return x + attn_out + self._mlp(h, p, moe)
         if a.pre_post_norm:
@@ -890,26 +1113,35 @@ class TransformerLM:
         logits = jax.lax.dot_general(
             x, head, (((x.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if self.arch.lm_head_multiplier is not None:
+            logits = logits * self.arch.lm_head_multiplier
         logits = nn.softcap(logits, self.arch.final_logit_softcap)
         return logits[..., : self.arch.vocab_size]
 
     def prefill(self, params, cache: KVCache, tokens, true_lens, page_tables,
-                start_pos=None, adapter_ids=None):
+                start_pos=None, adapter_ids=None, state_rows=None):
         """Process prompts (or prompt suffixes when ``start_pos`` marks a
         cached/chunked prefix already present in the pages).
 
         tokens: [B, T] padded chunks; true_lens: [B] valid NEW tokens;
-        page_tables: [B, pages_per_seq] pre-allocated.  Returns (cache,
-        last_logits [B, vocab], last_hidden [B, E]).
+        page_tables: [B, pages_per_seq] pre-allocated; state_rows: [B]
+        each row's slot in the mixer's state pool (a model with a
+        state-space mixer only).  Returns (cache, last_logits [B, vocab],
+        last_hidden [B, E]).
         """
         B, T = tokens.shape
         rel = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         positions = rel if start_pos is None else rel + start_pos[:, None]
+        if self.has_ssm and state_rows is None:
+            raise ValueError("a model with a state-space mixer prefills "
+                             "into its slots' rows of the state pool: "
+                             "state_rows is required")
         x = self._embed(params, tokens)
         x, cache = self._run_layers(
             params, cache, x, "prefill", positions=positions,
             page_tables=page_tables, lengths=true_lens, true_lens=true_lens,
-            active=None, start_pos=start_pos, adapter_ids=adapter_ids)
+            active=None, start_pos=start_pos, adapter_ids=adapter_ids,
+            ssm_rows=state_rows)
         x = self._norm(x, params, "final_norm")
         last = jnp.take_along_axis(
             x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
@@ -929,11 +1161,11 @@ class TransformerLM:
         the usual [B] row vector with B=1).  Returns (cache, last_logits
         [S, vocab], last_hidden [S, E]).
         """
-        if self.is_mla:
+        if self.is_mla or self.has_ssm:
             raise NotImplementedError(
                 "segment-packed prefill is not implemented for MLA "
-                "attention; the engine batches fresh MLA prompts on the "
-                "batch axis instead")
+                "attention nor for a state-space mixer; the engine "
+                "batches such fresh prompts on the batch axis instead")
         x = self._embed(params, tokens)
         x, cache = self._run_layers(
             params, cache, x, "prefill_packed", positions=positions,
@@ -1039,11 +1271,18 @@ class TransformerLM:
         lengths = positions + 1
         if active is not None:
             lengths = jnp.where(active, lengths, 0)
+        ssm_rows = None
+        if self.has_ssm and self.attn_impl == "pallas":
+            # once a step, for every layer's call of the kernel
+            from kaito_tpu.engine.ops.ssm import live_rows
+
+            ssm_rows = live_rows(active if active is not None
+                                 else jnp.ones((B,), bool))
         x = self._embed(params, tokens[:, None])
         x, cache = self._run_layers(
             params, cache, x, "decode", positions=pos2,
             page_tables=page_tables, lengths=lengths, true_lens=None,
-            active=active, adapter_ids=adapter_ids)
+            active=active, adapter_ids=adapter_ids, ssm_rows=ssm_rows)
         x = self._norm(x, params, "final_norm")
         return cache, self._logits(params, x[:, 0])
 

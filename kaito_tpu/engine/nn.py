@@ -302,10 +302,14 @@ def mlp(x: jax.Array, p: dict, arch: ModelArch, lora_scaling: float = 0.0,
                 return all_gather_matmul(x, w, mesh, axis_name=axis)
         return linear(x, w)
 
+    # falcon-h1: (gate pre-activation, down) multipliers
+    gate_m, down_m = arch.mlp_multipliers or (None, None)
     if arch.gated_mlp:
-        gate = activation(_col(p["gate"]) + lora_delta(x, p, "gate", lora_scaling)
-                          + multi_lora_delta(x, serve_lora, "gate", lora_ids),
-                          arch.hidden_act)
+        gate = _col(p["gate"]) + lora_delta(x, p, "gate", lora_scaling) \
+            + multi_lora_delta(x, serve_lora, "gate", lora_ids)
+        if gate_m is not None:
+            gate = gate * jnp.asarray(gate_m, gate.dtype)
+        gate = activation(gate, arch.hidden_act)
         up = _col(p["up"]) + lora_delta(x, p, "up", lora_scaling) \
             + multi_lora_delta(x, serve_lora, "up", lora_ids)
         h = gate * up
@@ -327,6 +331,8 @@ def mlp(x: jax.Array, p: dict, arch: ModelArch, lora_scaling: float = 0.0,
         + multi_lora_delta(h, serve_lora, "down", lora_ids)
     if "down_bias" in p:
         out = out + p["down_bias"]
+    if down_m is not None:
+        out = out * jnp.asarray(down_m, out.dtype)
     return out
 
 

@@ -1978,6 +1978,10 @@ class _PDServer(ThreadingHTTPServer):
     cache and divert future colocated lookups to a dead engine."""
 
     _pd_urls: tuple[str, ...] = ()
+    # the listen backlog: socketserver's 5 drops the SYNs of a burst of
+    # clients (192 opened at once lost 16 to ETIMEDOUT on the chip)
+    # while the accept loop waits for the interpreter lock
+    request_queue_size = 1024
 
     def _pd_unregister(self):
         with _LOCAL_PD_LOCK:

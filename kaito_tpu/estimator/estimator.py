@@ -92,7 +92,10 @@ def estimate_chip_count(
         raise ValueError(f"chip {chip.generation} has no usable HBM budget")
     w = weight_bytes(md, quantization)
     ctx = max_model_len or md.max_model_len
-    kv_one_seq = ctx * md.kv_bytes_per_token(kv_dtype_bytes)
+    # a sequence's bytes: its KV at full context and, for a model with a
+    # state-space mixer, its row of the recurrent-state pool
+    kv_one_seq = (ctx * md.kv_bytes_per_token(kv_dtype_bytes)
+                  + md.arch.state_bytes_per_seq())
     chips = math.ceil((w + kv_one_seq) / budget)
     return max(chips, 1)
 

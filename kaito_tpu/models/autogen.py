@@ -33,6 +33,7 @@ SUPPORTED_ARCHITECTURES = {
     "DeepseekV2ForCausalLM",
     "DeepseekV3ForCausalLM",
     "FalconForCausalLM",
+    "FalconH1ForCausalLM",
     "GptOssForCausalLM",
 }
 
@@ -143,6 +144,54 @@ def arch_from_hf_config(cfg: Mapping) -> ModelArch:
             kw["num_kv_heads"] = 1
         kw.update(gated_mlp=False, parallel_residual=bool(cfg.get("parallel_attn", True)),
                   norm_type="layernorm")
+
+    if model_type == "falcon_h1":
+        # a Mamba-2 mixer beside attention in every block, and the
+        # published multipliers in the forward pass
+        if cfg.get("attn_layer_indices") is not None:
+            raise ValueError("falcon_h1 with attn_layer_indices (attention "
+                             "in some blocks only) is not implemented")
+        if not bool(cfg.get("mamba_rms_norm", True)) or bool(
+                cfg.get("mamba_norm_before_gate", False)):
+            raise ValueError("falcon_h1 is implemented with the gated "
+                             "RMSNorm after the gate only")
+        if any(bool(cfg.get(k, False)) for k in (
+                "mamba_proj_bias", "mlp_bias", "projectors_bias")):
+            raise ValueError("falcon_h1 projection biases are not "
+                             "implemented")
+        d_ssm = int(_first(cfg, "mamba_d_ssm", default=0)
+                    or int(cfg.get("mamba_expand", 2)) * hidden)
+        m_heads = int(cfg["mamba_n_heads"])
+        m_head_dim = int(_first(cfg, "mamba_d_head", default=0)
+                         or d_ssm // m_heads)
+        if m_heads * m_head_dim != d_ssm:
+            raise ValueError(f"falcon_h1: mamba_n_heads {m_heads} x "
+                             f"mamba_d_head {m_head_dim} != mamba_d_ssm "
+                             f"{d_ssm}")
+        if not bool(cfg.get("mamba_conv_bias", True)):
+            raise ValueError("falcon_h1 without mamba_conv_bias is not "
+                             "implemented")
+        kw.update(
+            ssm_state=int(cfg["mamba_d_state"]),
+            ssm_heads=m_heads,
+            ssm_head_dim=m_head_dim,
+            ssm_groups=int(cfg.get("mamba_n_groups", 1)),
+            ssm_conv=int(cfg.get("mamba_d_conv", 4)),
+            ssm_chunk=int(cfg.get("mamba_chunk_size", 128)),
+            embedding_multiplier=float(cfg.get("embedding_multiplier", 1.0)),
+            lm_head_multiplier=float(cfg.get("lm_head_multiplier", 1.0)),
+            attention_in_multiplier=float(
+                cfg.get("attention_in_multiplier", 1.0)),
+            attention_out_multiplier=float(
+                cfg.get("attention_out_multiplier", 1.0)),
+            key_multiplier=float(cfg.get("key_multiplier", 1.0)),
+            ssm_in_multiplier=float(cfg.get("ssm_in_multiplier", 1.0)),
+            ssm_out_multiplier=float(cfg.get("ssm_out_multiplier", 1.0)),
+            ssm_multipliers=tuple(
+                float(x) for x in cfg.get("ssm_multipliers", (1.0,) * 5)),
+            mlp_multipliers=tuple(
+                float(x) for x in cfg.get("mlp_multipliers", (1.0, 1.0))),
+        )
 
     if model_type == "phi":
         kw.update(gated_mlp=False, parallel_residual=True, norm_type="layernorm",

@@ -88,6 +88,55 @@ class ModelArch:
     qk_nope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
 
+    # state-space mixer beside attention in every block (falcon-h1: a
+    # Mamba-2 mixer fed the same normed input as attention, the two
+    # outputs scaled and summed into one residual); no mixer if
+    # ssm_state == 0.  The mixer keeps a recurrent state per sequence
+    # ([ssm_heads, ssm_head_dim, ssm_state]) and the last ssm_conv-1
+    # inputs of its causal convolution: docs/kv-cache.md
+    ssm_state: int = 0                # d_state
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1               # B and C are shared by heads/groups heads
+    ssm_conv: int = 4                 # depthwise causal conv kernel
+    ssm_chunk: int = 128              # prefill scan chunk
+    # falcon-h1's published multipliers (muP folded into the forward
+    # pass); None = not applied
+    attention_in_multiplier: Optional[float] = None
+    attention_out_multiplier: Optional[float] = None
+    key_multiplier: Optional[float] = None
+    ssm_in_multiplier: Optional[float] = None
+    ssm_out_multiplier: Optional[float] = None
+    ssm_multipliers: Optional[tuple] = None   # over [z | x | B | C | dt]
+    mlp_multipliers: Optional[tuple] = None   # (gate pre-activation, down)
+    lm_head_multiplier: Optional[float] = None
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of the mixer's x and z streams (d_ssm)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the mixer's convolution: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_proj_dim(self) -> int:
+        """Width of the mixer's input projection: [z | x | B | C | dt]."""
+        return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
+
+    def state_bytes_per_seq(self, dtype_bytes: int = 2) -> int:
+        """Bytes of recurrent state one sequence holds across all
+        layers, whatever its length: the mixer's state and the
+        convolution's tail, in the type the model is served in (0 for a
+        model with no mixer)."""
+        if not self.ssm_state:
+            return 0
+        per_layer = (self.ssm_inner * self.ssm_state
+                     + (self.ssm_conv - 1) * self.ssm_conv_dim)
+        return self.num_layers * per_layer * dtype_bytes
+
     @property
     def kv_cache_heads(self) -> int:
         """Head count of the KV cache: MLA caches ONE shared latent."""
@@ -137,7 +186,15 @@ class ModelArch:
         else:
             mlp_total = self.num_layers * 3 * h * self.intermediate_size
         norms = self.num_layers * 2 * h + h
-        return embed + self.num_layers * attn + mlp_total + norms
+        mixer = 0
+        if self.ssm_state:
+            # in/out projections, conv weight and bias, dt_bias, A_log,
+            # D, and the gated norm's weight
+            mixer = (h * self.ssm_proj_dim + self.ssm_inner * h
+                     + (self.ssm_conv + 1) * self.ssm_conv_dim
+                     + 3 * self.ssm_heads + self.ssm_inner)
+        return (embed + self.num_layers * (attn + mixer) + mlp_total
+                + norms)
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         """KV-cache bytes per token across all layers.
