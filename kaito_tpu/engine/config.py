@@ -28,8 +28,9 @@ class EngineConfig:
     # a PACK of staged slots (segment packing for fresh prompts,
     # batch-axis packing for same-bucket context chunks), so concurrent
     # arrivals stop serializing at batch 1.  0 = auto (pack up to
-    # max_num_seqs); 1 reproduces the serial round-robin scheduler
-    # byte-identically.
+    # max_num_seqs); 1 is the serial round-robin scheduler: one-row
+    # programs only, as many whole staged prompts a turn as its chunk
+    # budget holds.
     prefill_pack: int = 0
     prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048, 4096)
     dtype: str = "bfloat16"

@@ -741,6 +741,10 @@ class InferenceEngine:
             "requests_total": 0,
             "requests_finished_total": 0,
             "prefill_steps_total": 0,
+            # prefill turns of the serial scheduler that ran two or
+            # more prompts / exactly one (docs/prefill.md)
+            "prefill_turns_multi_total": 0,
+            "prefill_turns_single_total": 0,
             "decode_steps_total": 0,
             # slot-steps the decode programs ran, and those of them
             # whose slot was not decoding: such a row attends to nothing
@@ -838,13 +842,15 @@ class InferenceEngine:
                 ("loop_stall", "loop_stall",
                  "Per step: wall less thread CPU time over the phases "
                  "that never block on the device"))}
-        # packed prefill (docs/prefill.md): sequences per prefill
-        # dispatch and staged-to-first-dispatch wait — the two numbers
-        # that say whether concurrent arrivals are actually sharing
-        # bucket work or still serializing
+        # prefill scheduling (docs/prefill.md): sequences per packed
+        # dispatch, or per turn of the serial scheduler, and
+        # staged-to-first-dispatch wait — the two numbers that say
+        # whether concurrent arrivals are admitted together or still
+        # one an iteration
         self.prefill_pack_hist = Histogram(
             "kaito:engine_prefill_pack_size",
-            "Sequences packed per prefill dispatch", None,
+            "Sequences per packed prefill dispatch, or per prefill turn "
+            "of the serial scheduler", None,
             buckets=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 24.0, 32.0))
         self.prefill_wait_hist = Histogram(
             "kaito:prefill_queue_wait_seconds",
@@ -3112,10 +3118,11 @@ class InferenceEngine:
 
         ``prefill_pack > 1`` (the default resolves to ``max_num_seqs``)
         spreads the per-step token budget over a PACK of staged slots
-        (docs/prefill.md); ``prefill_pack == 1`` reproduces the serial
-        round-robin single-slot scheduler byte-identically.  Pipeline
-        parallelism keeps the serial path — its prefill runs through the
-        stage executor, which has no packed route."""
+        (docs/prefill.md); ``prefill_pack == 1`` is the serial
+        round-robin scheduler, one-row programs only
+        (_advance_prefill_single).  Pipeline parallelism keeps the
+        serial path — its prefill runs through the stage executor,
+        which has no packed route."""
         pack = int(self.cfg.prefill_pack)
         if pack <= 0:
             pack = int(os.environ.get("KAITO_PREFILL_PACK", "0") or "0")
@@ -3136,29 +3143,87 @@ class InferenceEngine:
                            message=f"prefill failed: "
                                    f"{type(e).__name__}: {e}")
 
+    def _takes_cp(self, pos: int, n: int) -> bool:
+        """A long fresh prompt takes the context-parallel single-shot
+        path: the ring shards the memory the chunk budget was bounding,
+        so the whole prompt runs in ONE dispatch at ~1/seq the
+        latency."""
+        return (self.model.cp is not None and pos == 0
+                and n >= self.cfg.cp_min_tokens
+                and self._bucket(n) % dict(
+                    self.model.cp[0].shape)["sequence"] == 0)
+
+    def _prefill_turn_budget(self) -> int:
+        """Tokens a prefill turn of the serial scheduler may spend:
+        one chunk of ``max_prefill_tokens`` for every
+        ``prefill_interleave`` decode steps run since the last turn,
+        and one when nothing decodes.  Steps that ran while nothing was
+        staged earned nothing anyone waited for, so no more count than
+        one window under load holds (``fused_under_load``)."""
+        cfg = self.cfg
+        every = max(1, cfg.prefill_interleave)
+        steps = min(self._decode_since_prefill,
+                    max(cfg.fused_under_load, every))
+        return max(cfg.max_prefill_tokens, cfg.page_size) \
+            * max(1, steps // every)
+
     def _advance_prefill_single(self) -> bool:
-        """Run ONE bounded prefill chunk for one staged slot
-        (round-robin), completing admission when the prompt is done."""
+        """One prefill turn of the serial scheduler (docs/prefill.md).
+
+        Staged slots are served round-robin.  The first pick is always
+        taken and runs ONE bounded chunk, as it always did.  When it
+        was a whole fresh prompt (position 0, the whole prompt within
+        one chunk), the turn goes on to the next whole fresh prompts
+        while they fit what is left of its budget
+        (_prefill_turn_budget), each through the same one-row programs:
+        admission then follows the free slots instead of taking one
+        request an iteration whatever the batch's width.  A fresh
+        prompt is never split to fit (the remainder would go down the
+        context-prefill program); a context chunk or a context-parallel
+        prompt takes a turn alone."""
         idxs = [i for i, s in enumerate(self.slots)
                 if s.request is not None and s.prefilling
                 and not s.importing]
         if not idxs:
             return False
-        i = idxs[self._prefill_rr % len(idxs)]
-        self._prefill_rr += 1
+        chunk = max(self.cfg.max_prefill_tokens, self.cfg.page_size)
+        left = self._prefill_turn_budget()
+        start = self._prefill_rr % len(idxs)
+        picks = []
+        for i in idxs[start:] + idxs[:start]:
+            slot = self.slots[i]
+            n = len(slot.prefill_tokens)
+            whole = (slot.prefill_pos == 0 and n <= chunk
+                     and not self._takes_cp(0, n))
+            if picks and not (whole and n <= left):
+                break
+            picks.append(i)
+            left -= n
+            if not whole:
+                break
+        for ran, i in enumerate(picks, 1):
+            self._prefill_rr += 1
+            if not self._prefill_serial_chunk(i, len(picks)):
+                break
+        self.counters["prefill_turns_multi_total" if ran > 1
+                      else "prefill_turns_single_total"] += 1
+        self.prefill_pack_hist.observe(float(ran))
+        if ran > 1:
+            self._prefill_pack_note = max(self._prefill_pack_note, ran)
+        return True
+
+    def _prefill_serial_chunk(self, i: int, turn: int) -> bool:
+        """Run ONE bounded prefill chunk for the staged slot ``i``,
+        completing admission when the prompt is done; ``turn`` is the
+        number of prompts its turn takes.  False when the chunk failed
+        (the request is failed and the slot freed)."""
         slot = self.slots[i]
         req = slot.request
         tokens = slot.prefill_tokens
         n = len(tokens)
         budget = max(self.cfg.max_prefill_tokens, self.cfg.page_size)
         pos = slot.prefill_pos
-        # long fresh prompts take the context-parallel single-shot path:
-        # the ring shards the memory the chunk budget was bounding, so
-        # the whole prompt runs in ONE dispatch at ~1/seq the latency
-        use_cp = (self.model.cp is not None and pos == 0
-                  and n >= self.cfg.cp_min_tokens
-                  and self._bucket(n) % dict(
-                      self.model.cp[0].shape)["sequence"] == 0)
+        use_cp = self._takes_cp(pos, n)
         if use_cp:
             budget = n
         chunk = tokens[pos: pos + budget]
@@ -3194,10 +3259,9 @@ class InferenceEngine:
             logger.exception("prefill failed for %s", req.req_id)
             self._fail_prefill(i, e)
             self._recover_cache_if_poisoned()
-            return True
+            return False
         self.counters["prefill_steps_total"] += 1
         self.counters["prefill_tokens_total"] += m
-        self.prefill_pack_hist.observe(1.0)
         wait = 0.0
         if not slot.prefill_t0:
             slot.prefill_t0 = t_first_chunk
@@ -3208,7 +3272,7 @@ class InferenceEngine:
         self.tracer.record("prefill.chunk", req.trace_id, t_first_chunk,
                            time.monotonic() - t_first_chunk, pos=pos,
                            tokens=m, bucket=bucket, slot=i, cp=bool(use_cp),
-                           queue_wait=round(wait, 6))
+                           pack=turn, queue_wait=round(wait, 6))
         slot.prefill_pos = pos + m
         if slot.prefill_pos >= n:
             self._complete_prefills([(i, n)], logits)

@@ -444,6 +444,16 @@ class EngineMetrics:
                   "attention kernel copies no KV page for such a row", r,
                   fn=lambda: engine.counters.get(
                       "decode_rows_idle_total", 0))
+            Gauge("kaito:engine_prefill_turns_multi_total",
+                  "Prefill turns of the serial scheduler that ran two or "
+                  "more whole staged prompts", r,
+                  fn=lambda: engine.counters.get(
+                      "prefill_turns_multi_total", 0))
+            Gauge("kaito:engine_prefill_turns_single_total",
+                  "Prefill turns of the serial scheduler that ran one "
+                  "prompt or one chunk", r,
+                  fn=lambda: engine.counters.get(
+                      "prefill_turns_single_total", 0))
             if getattr(getattr(engine, "model", None), "has_ssm", False):
                 # the second kind of state in the cache (docs/kv-cache.md)
                 Gauge("kaito:engine_state_pool_bytes",

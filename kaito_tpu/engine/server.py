@@ -2286,7 +2286,8 @@ def main(argv=None):
                          "round under the shared token budget "
                          "(docs/prefill.md); 0 = auto (up to "
                          "max-num-seqs), 1 = serial round-robin "
-                         "(byte-identical legacy scheduler)")
+                         "(one-row programs; a turn takes the whole "
+                         "staged prompts its chunk budget holds)")
     ap.add_argument("--qos-config",
                     default=os.environ.get("KAITO_QOS_CONFIG", ""),
                     help="multi-tenant QoS classes as inline JSON or "

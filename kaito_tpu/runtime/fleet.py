@@ -1197,11 +1197,13 @@ class FleetTelemetry:
               "Fleet prompt-token prefill rate", r,
               labels=("kind", "name"), fn=family("prefill_tokens_rate"))
         Gauge("kaito:fleet_prefill_dispatches_per_s",
-              "Fleet prefill dispatch rate (packed rounds count once)", r,
+              "Fleet prefill dispatch rate (a packed round, or a turn of "
+              "the serial scheduler, counts once)", r,
               labels=("kind", "name"), fn=family("prefill_dispatch_rate"))
         Gauge("kaito:fleet_prefill_pack_mean",
-              "Mean sequences per prefill dispatch across the fleet "
-              "(1.0 = serial; higher = packing engaged)", r,
+              "Mean sequences per packed prefill dispatch, or per turn "
+              "of the serial scheduler, across the fleet (1.0 = "
+              "arrivals never taken together)", r,
               labels=("kind", "name"), fn=family("prefill_pack_mean"))
         Gauge("kaito:fleet_prefill_queue_wait_mean",
               "Mean staged-to-first-prefill-dispatch wait across the "

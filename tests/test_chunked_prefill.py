@@ -54,3 +54,42 @@ def test_chunked_prefill_with_prefix_cache():
         eng.stop()
     assert first == ref and second == ref
     assert eng.counters["prefix_cached_tokens_total"] > 0
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_chunked_prompt_takes_one_chunk_a_turn_beside_short_ones(async_on):
+    """The serial scheduler (prefill_pack=1) takes several whole short
+    prompts in one turn (docs/prefill.md); a prompt longer than a chunk
+    still goes one chunk a turn, alone, through the context program,
+    and decodes what it decodes when served alone."""
+    long_p = [(7 * i) % 1800 + 2 for i in range(200)]
+    shorts = [[(m * i) % 1800 + 2 for i in range(n)]
+              for m, n in ((3, 11), (5, 19), (11, 14))]
+    p = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+
+    def serve(prompts):
+        eng = InferenceEngine(EngineConfig(
+            **{**BASE, "max_num_seqs": 4}, max_prefill_tokens=48,
+            prefill_pack=1, async_dispatch=async_on))
+        reqs = [eng.submit(list(q), p) for q in prompts]
+        for _ in range(400):
+            if all(r.finish_reason for r in reqs):
+                break
+            eng.step()
+        return eng, [list(r.output_tokens) for r in reqs]
+
+    alone = [serve([q])[1][0] for q in [long_p] + shorts]
+    eng, together = serve([long_p] + shorts)
+    assert together == alone
+    chunks = [(s.attrs["slot"], s.attrs["pos"], s.attrs["tokens"],
+               s.attrs["pack"]) for s in eng.tracer.spans()
+              if s.name == "prefill.chunk"]
+    assert [c for c in chunks if c[0] == 0] == [
+        (0, 0, 48, 1), (0, 48, 48, 1), (0, 96, 48, 1), (0, 144, 48, 1),
+        (0, 192, 8, 1)]
+    # the three short prompts (44 tokens) shared the turn after the
+    # long prompt's first chunk
+    assert [c for c in chunks if c[0] != 0] == [
+        (1, 0, 11, 3), (2, 0, 19, 3), (3, 0, 14, 3)]
+    assert eng.counters["prefill_turns_multi_total"] == 1
+    assert eng.counters["prefill_turns_single_total"] == 5

@@ -436,14 +436,26 @@ def _arrival_with_a_window_in_flight():
     return eng, new, want, olds
 
 
+def _prefill_turns(eng):
+    return (eng.counters["prefill_turns_multi_total"]
+            + eng.counters["prefill_turns_single_total"])
+
+
 def test_the_host_does_not_wait_for_a_first_token_at_the_prefill(sustained):
     """No step of the deferred run spent time in prefill.wait, the
     blocking run's steps did, and resolution has its own span."""
     deferred = sustained["eng"].timeline.records()
     assert not any("prefill.wait" in r for r in deferred)
-    assert sum(1 for r in deferred if "prefill.resolve" in r) >= N_REQUESTS - 4
+    # a prefill turn takes every staged prompt its budget holds, and
+    # the turn's first tokens are resolved where the loop next waits:
+    # a step with a resolution for each turn, not for each request
+    turns = _prefill_turns(sustained["eng"])
+    assert 1 < turns < N_REQUESTS
+    assert turns - 1 <= sum(1 for r in deferred
+                            if "prefill.resolve" in r) <= turns
     blocking = sustained["blocking"].timeline.records()
-    assert sum(1 for r in blocking if "prefill.wait" in r) == N_REQUESTS
+    assert sum(1 for r in blocking if "prefill.wait" in r) \
+        == _prefill_turns(sustained["blocking"])
     assert not any("prefill.resolve" in r for r in blocking)
 
 
@@ -604,6 +616,9 @@ def test_deferred_and_blocking_add_up_to_the_prompts_completed(sustained):
             + sum(eng.first_token_blocking.values()) \
             == eng.counters["prefill_steps_total"] == N_REQUESTS
         assert eng.first_token_resolve_hist._total == N_REQUESTS
+        # one first-token program a prompt, however many its turn took
+        assert eng.prefill_pack_hist._sum == N_REQUESTS
+        assert eng.prefill_pack_hist._total == _prefill_turns(eng)
     # the fixture forced the path: the reason it gave is counted
     assert sustained["blocking"].first_token_blocking["stop_set"] \
         == N_REQUESTS

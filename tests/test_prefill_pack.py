@@ -75,16 +75,26 @@ def test_pack_matches_serial_mixed_lengths():
     assert packed.prefill_pack_hist._sum > packed.prefill_pack_hist._total
 
 
-def test_pack_one_reproduces_serial_counters():
-    """prefill_pack=1 is the serial scheduler: same outputs AND the
-    same dispatch count as the legacy round-robin."""
-    a = _mk(1)
+@pytest.mark.parametrize("chunk,turns", [(512, (1, 0)), (16, (0, 3))])
+def test_pack_one_is_the_serial_scheduler(chunk, turns):
+    """prefill_pack=1 is the serial scheduler: one one-row dispatch a
+    prompt, the default packing's outputs, and as many prompts a turn
+    as its chunk budget holds whole (docs/prefill.md) — both at 512,
+    one at 16, where the second prompt (21 tokens) is chunked."""
+    a = _mk(1, max_prefill_tokens=chunk)
     ra = _run_concurrent(a, PROMPTS[:2])
-    b = _mk(1)
+    b = _mk(0, max_prefill_tokens=512)
     rb = _run_concurrent(b, PROMPTS[:2])
     assert ra == rb
-    assert (a.counters["prefill_steps_total"]
-            == b.counters["prefill_steps_total"])
+    assert b.counters["prefill_steps_total"] == 1
+    assert a.counters["prefill_steps_total"] == (2 if chunk == 512 else 3)
+    assert (a.counters["prefill_turns_multi_total"],
+            a.counters["prefill_turns_single_total"]) == turns
+    assert a.prefill_pack_hist._sum == a.counters["prefill_steps_total"]
+    # the pack path counts no turns: the two counters are the serial
+    # scheduler's
+    assert b.counters["prefill_turns_multi_total"] \
+        + b.counters["prefill_turns_single_total"] == 0
 
 
 def test_long_prompt_straddles_pack_rounds():

@@ -7,6 +7,7 @@ the step level rather than by wall-clock.
 """
 
 import numpy as np
+import pytest
 
 from kaito_tpu.engine.config import EngineConfig
 from kaito_tpu.engine.engine import InferenceEngine, SamplingParams
@@ -117,3 +118,185 @@ def test_preemption_with_prefix_cache_reuses_committed_pages():
     # committed prefixes of preempted sequences may legitimately have
     # been evicted to feed the survivor's growth)
     assert eng.allocator.available == eng.allocator.num_pages - 1
+
+
+# ---------------------------------------------------------------------------
+# the serial scheduler's prefill turn (prefill_pack=1, docs/prefill.md):
+# whole staged prompts while they fit the turn's budget, each through
+# the one-row programs
+# ---------------------------------------------------------------------------
+
+def _serial(async_on=False, **kw):
+    cfg = dict(BASE, prefill_pack=1, async_dispatch=async_on,
+               decode_run_ahead=4, fused_under_load=4)
+    cfg.update(kw)
+    return InferenceEngine(EngineConfig(**cfg))
+
+
+def _tokens(n, mul=7):
+    return [(mul * i) % 1800 + 2 for i in range(n)]
+
+
+def _chunks(eng):
+    """(slot, pos, tokens, turn size) of every prefill chunk so far."""
+    return [(s.attrs["slot"], s.attrs["pos"], s.attrs["tokens"],
+             s.attrs["pack"]) for s in eng.tracer.spans()
+            if s.name == "prefill.chunk"]
+
+
+def _turns(eng):
+    c = eng.counters
+    return (c["prefill_turns_multi_total"], c["prefill_turns_single_total"])
+
+
+def _finish(eng, reqs, limit=2000):
+    for _ in range(limit):
+        if all(r.finish_reason for r in reqs):
+            return [list(r.output_tokens) for r in reqs]
+        eng.step()
+    raise AssertionError("requests did not finish")
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_turn_takes_every_staged_prompt_that_fits(async_on):
+    """Four staged fresh prompts of 72 tokens together, a budget of one
+    chunk of 128 (nothing decodes): one step() prefills all four, each
+    a one-row call, and the counters and the histogram say so."""
+    eng = _serial(async_on, max_prefill_tokens=128)
+    lens = (9, 21, 30, 12)
+    for n in lens:
+        eng.submit(_tokens(n), _greedy(4))
+    eng.step()
+    assert eng.counters["prefill_steps_total"] == 4
+    assert _chunks(eng) == [(i, 0, n, 4) for i, n in enumerate(lens)]
+    assert not any(s.prefilling for s in eng.slots)
+    assert _turns(eng) == (1, 0)
+    h = eng.prefill_pack_hist
+    assert (h._total, h._sum) == (1, 4.0)
+    assert eng.timeline.records()[-1]["prefill_pack"] == 4
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_prompt_that_does_not_fit_whole_waits_and_is_never_split(
+        async_on):
+    """Three prompts of 30 against a budget of 64: two go, the third
+    waits with nothing of it written, and goes whole in the next turn.
+    The context-prefill program is never asked for."""
+    eng = _serial(async_on, max_prefill_tokens=64)
+    eng._prefill_ctx_fn = lambda bucket: pytest.fail(
+        "a fresh prompt went down the context-prefill program")
+    reqs = [eng.submit(_tokens(30, m), _greedy(6)) for m in (3, 5, 7)]
+    eng.step()
+    assert _chunks(eng) == [(0, 0, 30, 2), (1, 0, 30, 2)]
+    assert eng.slots[2].prefilling and eng.slots[2].prefill_pos == 0
+    _finish(eng, reqs)
+    assert _chunks(eng)[2] == (2, 0, 30, 1)
+    assert _turns(eng) == (1, 1)
+    assert eng.prefill_pack_hist._sum == 3.0
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_chunked_prompt_takes_its_turns_alone_in_round_robin(async_on):
+    """The first pick is taken whatever its size: a prompt of 100
+    tokens at a chunk of 32 runs one chunk a turn, alone, as it always
+    did, and the two short prompts staged behind it share the turn
+    between its first chunk and its second."""
+    eng = _serial(async_on, max_prefill_tokens=32)
+    reqs = [eng.submit(_tokens(n, m), _greedy(6))
+            for n, m in ((100, 3), (12, 5), (14, 7))]
+    _finish(eng, reqs)
+    assert _chunks(eng) == [
+        (0, 0, 32, 1), (1, 0, 12, 2), (2, 0, 14, 2),
+        (0, 32, 32, 1), (0, 64, 32, 1), (0, 96, 4, 1)]
+    assert _turns(eng) == (1, 4)
+
+
+def test_a_turn_starts_where_the_pointer_stands_and_wraps():
+    """Round-robin as before: the pointer counts prompts served and
+    indexes the slots still staged; a turn starts there and goes on
+    cyclically.  Slot 0 alone (a chunk of 32 holds one prompt of 20);
+    then the pointer, 1, names the second of the staged slots 1, 2, 3,
+    and the window of 4 steps has earned two chunks, which hold three
+    such prompts: slots 2, 3 and, wrapping, 1."""
+    eng = _serial(max_prefill_tokens=32)
+    for m in (3, 5, 7, 11):
+        eng.submit(_tokens(20, m), _greedy(30))
+    eng.step()
+    assert _chunks(eng) == [(0, 0, 20, 1)]
+    eng.step()
+    assert _chunks(eng)[1:] == [(2, 0, 20, 3), (3, 0, 20, 3), (1, 0, 20, 3)]
+
+
+@pytest.mark.parametrize("steps,every,under_load,chunks", [
+    (0, 2, 4, 1),       # nothing decodes: one chunk, as before
+    (1, 2, 4, 1),       # a single step: never less than one chunk
+    (2, 2, 4, 1),
+    (3, 2, 4, 1),
+    (4, 2, 4, 2),       # a window of 4 steps has earned two
+    (1000, 2, 4, 2),    # steps run with nothing staged earn nothing
+    (4, 1, 4, 4),
+    (4, 0, 4, 4),
+    (8, 4, 8, 2),
+    (8, 4, 0, 1),       # no fusing under load: a chunk an interleave
+])
+def test_the_turns_budget_is_a_chunk_for_every_interleave_of_steps(
+        steps, every, under_load, chunks):
+    eng = _serial(max_prefill_tokens=48, prefill_interleave=every,
+                  fused_under_load=under_load)
+    eng._decode_since_prefill = steps
+    assert eng._prefill_turn_budget() == 48 * chunks
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_window_of_four_steps_earns_two_chunks(async_on):
+    """Behind a decoding batch the turn spends what the window earned:
+    of three prompts of 30 staged during a window of 4 steps (chunk 32,
+    interleave 2) two go in the turn after it."""
+    eng = _serial(async_on, max_prefill_tokens=32)
+    first = eng.submit(_tokens(8), _greedy(200))
+    for _ in range(6):
+        eng.step()
+    assert eng.num_running == 1 and _turns(eng) == (0, 1)
+    late = [eng.submit(_tokens(30, m), _greedy(6)) for m in (3, 5, 7)]
+    d0 = eng.counters["decode_steps_total"]
+    eng.step()
+    assert eng.counters["decode_steps_total"] - d0 == 4
+    assert [c[3] for c in _chunks(eng)] == [1, 2, 2]
+    assert _turns(eng) == (1, 1)
+    _finish(eng, late)
+    assert not first.finish_reason
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_greedy_outputs_are_those_of_one_prompt_turns(async_on):
+    """Token for token what the engine gives when every turn takes one
+    prompt (a budget of nothing: the first pick is always taken), under
+    both loops."""
+    prompts = [_tokens(n, m) for n, m in
+               ((9, 3), (21, 5), (30, 7), (12, 11), (17, 13), (25, 17),
+                (11, 19))]
+    one = _serial(async_on, max_prefill_tokens=128)
+    one._prefill_turn_budget = lambda: 0     # the first pick, no more
+    ref = _finish(one, [one.submit(p, _greedy(10 + 2 * i))
+                        for i, p in enumerate(prompts)])
+    assert _turns(one) == (0, len(prompts))
+    many = _serial(async_on, max_prefill_tokens=128)
+    out = _finish(many, [many.submit(p, _greedy(10 + 2 * i))
+                         for i, p in enumerate(prompts)])
+    assert out == ref
+    assert _turns(many)[0] >= 1
+    assert (many.counters["prefill_steps_total"]
+            == one.counters["prefill_steps_total"] == len(prompts))
+
+
+def test_the_turn_counters_are_exposed():
+    from kaito_tpu.engine.metrics import EngineMetrics
+
+    eng = _serial(max_prefill_tokens=128)
+    for n in (9, 21):
+        eng.submit(_tokens(n), _greedy(2))
+    eng.step()
+    text = EngineMetrics(engine=eng).registry.expose()
+    assert "kaito:engine_prefill_turns_multi_total 1" in text
+    assert "kaito:engine_prefill_turns_single_total 0" in text
+    assert "kaito:engine_prefill_pack_size_sum 2" in text

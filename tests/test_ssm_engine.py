@@ -201,6 +201,62 @@ def test_batched_fresh_prompts_under_the_default_packing():
         assert r.output_tokens == _run(serial, [p], 8)[0].output_tokens
 
 
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_serial_turn_prefills_every_staged_prompt_that_fits(async_on):
+    """The serial scheduler's turn (prefill_pack=1, docs/prefill.md)
+    with a mixer: three staged fresh prompts within one chunk of 128
+    are prefilled by one step(), each a one-row call on its own row of
+    the state pool; every emitted logprob is the full forward's, and
+    the tokens are those of turns held to one prompt."""
+    prompts = [_prompt(20, 31), _prompt(27, 32), _prompt(33, 33)]
+    eng = _mk(async_on, prefill_pack=1, max_prefill_tokens=128)
+    reqs = [eng.submit(list(p), SamplingParams(
+        max_tokens=10, temperature=0.0, ignore_eos=True, logprobs=1))
+        for p in prompts]
+    eng.step()
+    assert eng.counters["prefill_steps_total"] == 3
+    assert eng.counters["state_resets_total"] == 3
+    assert (eng.counters["prefill_turns_multi_total"],
+            eng.counters["prefill_turns_single_total"]) == (1, 0)
+    assert (eng.prefill_pack_hist._total, eng.prefill_pack_hist._sum) \
+        == (1, 3.0)
+    for _ in range(200):
+        if all(r.finish_reason for r in reqs):
+            break
+        eng.step()
+    one = _mk(async_on, prefill_pack=1, max_prefill_tokens=128)
+    one._prefill_turn_budget = lambda: 0     # the first pick, no more
+    ref = _run(one, prompts, 10)
+    assert one.counters["prefill_turns_multi_total"] == 0
+    for p, r, want in zip(prompts, reqs, ref):
+        assert r.output_tokens == want.output_tokens
+        lp = _teacher(eng, p + r.output_tokens)
+        for j, tok in enumerate(r.output_tokens):
+            assert abs(r.output_logprobs[j] - lp[len(p) - 1 + j][tok]) < 2e-4
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_a_serial_turn_never_splits_a_prompt_and_chunks_go_alone(async_on):
+    """At a chunk of 32: a prompt of 90 tokens goes in three chunks, a
+    turn each, its state carried between them through the pool, while
+    prompts of 12 and 14 wait whole and share the turn between its
+    first chunk and its second."""
+    prompts = [_prompt(90, 41), _prompt(12, 42), _prompt(14, 43)]
+    eng = _mk(async_on, prefill_pack=1)
+    reqs = _run(eng, prompts, 8)
+    chunks = [(s.attrs["slot"], s.attrs["pos"], s.attrs["tokens"],
+               s.attrs["pack"]) for s in eng.tracer.spans()
+              if s.name == "prefill.chunk"]
+    assert chunks == [(0, 0, 32, 1), (1, 0, 12, 2), (2, 0, 14, 2),
+                      (0, 32, 32, 1), (0, 64, 26, 1)]
+    assert eng.counters["state_recomputes_total"] == 0
+    for p, r in zip(prompts, reqs):
+        lp = _teacher(eng, p + r.output_tokens)
+        for j, tok in enumerate(r.output_tokens):
+            assert tok == int(np.argmax(lp[len(p) - 1 + j]))
+            assert abs(r.output_logprobs[j] - lp[len(p) - 1 + j][tok]) < 2e-4
+
+
 def test_health_surface_and_metrics():
     from kaito_tpu.engine.metrics import EngineMetrics
 
