@@ -178,7 +178,7 @@ class AdapterCache:
         self._specs: dict[str, dict[str, tuple[int, int]]] = {}
         serve_lora: dict = {}
         for g in model.groups:
-            specs = model._layer_specs(g.moe)
+            specs = model._layer_specs(g.moe, g.kind)
             targets = (("q", "k", "v", "o") if g.moe
                        else ("q", "k", "v", "o", "gate", "up", "down"))
             group_buf: dict = {}

@@ -101,7 +101,7 @@ def load_adapter_stacks(model, adapters_dir: str, base_model: str = "",
     n = len(loaded)
     serve_lora: dict = {}
     for g in model.groups:
-        specs = model._layer_specs(g.moe)
+        specs = model._layer_specs(g.moe, g.kind)
         # MoE groups still have dense ATTENTION projections — their
         # q/k/v/o adapters apply; only the expert MLP targets are
         # per-request-unsupported (the moe path has no LoRA sites)

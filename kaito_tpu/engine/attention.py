@@ -36,6 +36,24 @@ def _gqa_expand(x: jax.Array, groups: int) -> jax.Array:
     return jnp.repeat(x, groups, axis=-2)
 
 
+def _softmax_with_sink(scores: jax.Array, sink: Optional[jax.Array],
+                       head_axes: tuple) -> jax.Array:
+    """Softmax over the last axis with, given ``sink`` (one float a
+    query head), one more column a head that takes probability and
+    carries no value: ``exp(a - m) / (exp(sink - m) + sum exp(a - m))``.
+    ``head_axes`` are the axes of ``scores`` the heads lie on, in the
+    order that flattens to the head index."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    shape = [1] * scores.ndim
+    for ax in head_axes:
+        shape[ax] = scores.shape[ax]
+    col = jnp.broadcast_to(sink.astype(jnp.float32).reshape(shape),
+                           scores.shape[:-1] + (1,))
+    probs = jax.nn.softmax(jnp.concatenate([scores, col], axis=-1), axis=-1)
+    return probs[..., :-1]
+
+
 def _layer_view(cache: jax.Array, layer):
     """Resolve the optional stacked-group form of a paged cache.
 
@@ -73,8 +91,10 @@ def prefill_attention(
     sliding_window: Optional[int] = None,
     logit_softcap: Optional[float] = None,
     true_len: Optional[jax.Array] = None,   # [B]
+    sink: Optional[jax.Array] = None,       # [H] fp32 sink bias a head
 ) -> jax.Array:
-    """Causal self-attention over a freshly prefillled chunk.
+    """Causal self-attention over a freshly prefillled chunk.  ``v``'s
+    heads may be narrower than ``k``'s: the output has ``v``'s size.
 
     Positions are 0..T-1 within the chunk (round-1 engine prefills a
     request in one padded chunk; the chunked long-prompt path arrives
@@ -99,7 +119,7 @@ def prefill_attention(
         mask = mask[None, :, :] & (s_pos[None] < true_len[:, None, None])
         mask = mask[:, None]  # [B, 1, T, S]
     scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    probs = _softmax_with_sink(scores, sink, (1,))
     return jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v)
 
 
@@ -161,13 +181,21 @@ def paged_context_attention(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,   # [P, Hkv] / [Lg, P, Hkv] int8 pools
     v_scale: Optional[jax.Array] = None,
+    sink: Optional[jax.Array] = None,      # [H] fp32 sink bias a head
+    kv_heads: Optional[int] = None,        # pools are token-flat:
+                                           # [(Lg,) P, ps*kv_heads, D]
 ) -> jax.Array:
     """Chunked prefill WITH prior context: queries attend over the whole
     paged history (cached prefix + the freshly-written chunk) with
     absolute-position causal masking.  Backs prefix-cache reuse and
-    long-prompt chunked prefill."""
+    long-prompt chunked prefill.  A table entry behind the window may
+    be the null page (a window kind's freed page): the mask hides it."""
     B, T, H, D = q.shape
-    ps, Hkv, _ = cache_k.shape[-3:]
+    if kv_heads is None:
+        ps, Hkv, _ = cache_k.shape[-3:]
+    else:
+        ps, Hkv = cache_k.shape[-2] // kv_heads, kv_heads
+    Dv = cache_v.shape[-1]
     pmax = page_tables.shape[1]
     S = pmax * ps
     groups = H // Hkv
@@ -180,7 +208,7 @@ def paged_context_attention(
         k = _dequant_gathered(k, k_scale, page_tables, base, layer, q.dtype)
         v = _dequant_gathered(v, v_scale, page_tables, base, layer, q.dtype)
     k = k.reshape(B, S, Hkv, D)
-    v = v.reshape(B, S, Hkv, D)
+    v = v.reshape(B, S, Hkv, Dv)
     k = _gqa_expand(k, groups)
     v = _gqa_expand(v, groups)
 
@@ -195,7 +223,7 @@ def paged_context_attention(
     if sliding_window is not None:
         mask &= k_pos[:, None, :] > q_pos[:, :, None] - sliding_window
     scores = jnp.where(mask[:, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    probs = _softmax_with_sink(scores, sink, (1,))
     return jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v)
 
 
@@ -361,12 +389,19 @@ def paged_decode_attention(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,   # [P, Hkv] / [Lg, P, Hkv] int8 pools
     v_scale: Optional[jax.Array] = None,
+    sink: Optional[jax.Array] = None,      # [H] fp32 sink bias a head
+    kv_heads: Optional[int] = None,        # pools are token-flat:
+                                           # [(Lg,) P, ps*kv_heads, D]
 ) -> jax.Array:
     """Attend one query token per sequence over its paged KV history
     (pure-JAX reference; the Pallas kernel in engine.ops implements the
     same contract)."""
     B, H, D = q.shape
-    ps, Hkv, _ = cache_k.shape[-3:]
+    if kv_heads is None:
+        ps, Hkv, _ = cache_k.shape[-3:]
+    else:
+        ps, Hkv = cache_k.shape[-2] // kv_heads, kv_heads
+    Dv = cache_v.shape[-1]
     pmax = page_tables.shape[1]
     S = pmax * ps
     groups = H // Hkv
@@ -379,7 +414,7 @@ def paged_decode_attention(
         k = _dequant_gathered(k, k_scale, page_tables, base, layer, q.dtype)
         v = _dequant_gathered(v, v_scale, page_tables, base, layer, q.dtype)
     k = k.reshape(B, S, Hkv, D)
-    v = v.reshape(B, S, Hkv, D)
+    v = v.reshape(B, S, Hkv, Dv)
 
     qg = q.reshape(B, Hkv, groups, D)
     scores = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32)
@@ -391,6 +426,6 @@ def paged_decode_attention(
     if sliding_window is not None:
         mask &= s_pos >= lengths[:, None] - sliding_window
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    probs = _softmax_with_sink(scores, sink, (1, 2))
     out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v.dtype), v)
-    return out.reshape(B, H, D)
+    return out.reshape(B, H, Dv)

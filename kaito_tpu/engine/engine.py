@@ -246,6 +246,10 @@ class PageAllocator:
 class _Slot:
     request: Optional[Request] = None
     pages: list[int] = field(default_factory=list)
+    # the window kind's table (docs/kv-cache.md, "Two kinds of page"):
+    # page index -> page of the window pool; entries behind the window
+    # have gone back to the pool.  Empty for a one-kind cache.
+    wpages: dict = field(default_factory=dict)
     position: int = 0          # next token position (== current length)
     remaining: int = 0
     prefilling: bool = False
@@ -262,6 +266,21 @@ class _Slot:
     def written(self) -> int:
         """Tokens whose KV has actually landed in the cache."""
         return self.prefill_pos if self.prefilling else self.position
+
+
+def _zero_moe_stats(cache: KVCache) -> KVCache:
+    """A decode program counts its own steps: the counters start at
+    zero (a no-op for a cache that has none)."""
+    if cache.moe_stats is None:
+        return cache
+    return dataclasses.replace(cache,
+                               moe_stats=jnp.zeros_like(cache.moe_stats))
+
+
+def _moe_stats_out(cache: KVCache):
+    """The counters as an output of their own: the cache is donated to
+    the next program before the host reads this one's tokens."""
+    return None if cache.moe_stats is None else cache.moe_stats + 0
 
 
 @jax.jit
@@ -358,6 +377,13 @@ class InferenceEngine:
         self.tokenizer = load_tokenizer(self.md.hf_id, arch.vocab_size)
         if self.model.has_ssm:
             self._refuse_for_state_pool(mesh)
+        # an expert layer's grouped matmuls: the Pallas kernel where the
+        # attention kernels are, on one chip (engine/nn.py); on a mesh
+        # the partitioner splits XLA's ragged dot, as it did
+        self.model.moe_kernel = use_pallas and mesh is None
+        self.two_kinds = arch.two_kind_cache
+        if self.two_kinds:
+            self._refuse_for_two_kinds(mesh)
         if jnp.dtype(cfg.kv_dtype) == jnp.int8 and (
                 cfg.pipeline_parallel > 1 or cfg.sequence_parallel > 1):
             # the staged 6-dim PP pools and the CP ring prefill don't
@@ -522,9 +548,14 @@ class InferenceEngine:
         self._state_pool = create_state_pool(arch, cfg.max_num_seqs,
                                              self.dtype)
         self.sizing_report: dict = {}
+        self._num_window_pages = 0
         num_pages = cfg.max_pages or self._derive_max_pages()
         num_pages = max(num_pages, cfg.max_num_seqs * self.pages_per_seq // 4 + 2)
         self._num_pages = num_pages
+        if self.two_kinds and not self._num_window_pages:
+            # max_pages sizes the full kind's pool by hand; the window
+            # kind's follows the slots
+            self._num_window_pages = self._window_pool_cap()
         if cfg.max_pages:
             self.sizing_report = {"source": "configured"}
         # report the FINAL pool size (post-floor), not the derived value
@@ -532,7 +563,19 @@ class InferenceEngine:
         self.cache = self._fresh_cache()
         logger.info("KV cache: %d pages x %d tokens (%.2f GiB)",
                     num_pages, cfg.page_size,
-                    2 * self.cache.k.nbytes / 2**30)
+                    (self.cache.k.nbytes + self.cache.v.nbytes) / 2**30)
+        if self.two_kinds:
+            self.sizing_report["window_pages"] = self._num_window_pages
+            self.sizing_report["full_pool_bytes"] = \
+                int(self.cache.k.nbytes + self.cache.v.nbytes)
+            self.sizing_report["window_pool_bytes"] = \
+                self.cache.window_pool_bytes
+            logger.info("window pool: %d pages x %d tokens, %d layers "
+                        "(%.2f GiB); a sequence holds at most %d of them "
+                        "while it decodes", self._num_window_pages,
+                        cfg.page_size, arch.attention_layers(1),
+                        self.cache.window_pool_bytes / 2**30,
+                        self._window_pages_per_seq)
         if self.model.has_ssm:
             self.sizing_report["state_pool_bytes"] = \
                 self.cache.state_pool_bytes
@@ -621,6 +664,13 @@ class InferenceEngine:
             logger.warning("prefix caching requested but this model keeps "
                            "a recurrent state no page carries; serving "
                            "WITHOUT prefix reuse")
+        elif cfg.enable_prefix_caching and self.two_kinds:
+            # a window page goes back to its pool once the sequence is a
+            # window past it: a shared prefix would need pages that are
+            # no longer there
+            logger.warning("prefix caching requested but this model's "
+                           "window layers free their pages behind the "
+                           "window; serving WITHOUT prefix reuse")
         elif cfg.enable_prefix_caching and not self.model.is_mla:
             # the radix tree tracks host-side PAGE IDS only — the same
             # ids index the sharded (TP) or stage-split (PP) pools, so
@@ -642,6 +692,9 @@ class InferenceEngine:
         # the prefix cache subsumes the free-list (same available/num_pages
         # surface for metrics)
         self.allocator = self.prefix_cache or PageAllocator(num_pages)
+        # the window kind's pool has a free list of its own
+        self.window_allocator = (PageAllocator(self._num_window_pages)
+                                 if self.two_kinds else None)
         # a single sequence can never outgrow the whole pool (generation
         # is length-capped so the preempt-self path always terminates)
         self._capacity_tokens = (num_pages - 1) * cfg.page_size
@@ -696,7 +749,11 @@ class InferenceEngine:
                         cfg.kv_pool_disk_bytes / 2**30, root)
         S = cfg.max_num_seqs
         self.slots = [_Slot() for _ in range(S)]
-        self.page_tables = np.zeros((S, self.pages_per_seq), np.int32)
+        # a table a sequence, and for two kinds of page a table a kind:
+        # [S, 2, pages], the full kind's first (model._run_layers_kinds)
+        self.page_tables = np.zeros(
+            (S, 2, self.pages_per_seq) if self.two_kinds
+            else (S, self.pages_per_seq), np.int32)
         self.positions = np.zeros((S,), np.int32)
         self.active = np.zeros((S,), bool)
         self.sampling = SamplingState.create(S, cfg.seed)
@@ -763,6 +820,14 @@ class InferenceEngine:
             # recompute; both stay 0 for a model with no mixer
             "state_resets_total": 0,
             "state_recomputes_total": 0,
+            # two kinds of page: window pages returned behind the window
+            "window_pages_freed_total": 0,
+            # an expert layer's counters over the decode steps
+            # (kv_cache.KVCache.moe_stats, read back with each window)
+            "moe_expert_calls_total": 0,
+            "moe_experts_touched_total": 0,
+            "moe_pairs_held_total": 0,
+            "moe_pairs_routed_total": 0,
             "host_kv_spilled_pages_total": 0,
             "host_kv_restored_pages_total": 0,
             "spec_steps_total": 0,
@@ -1137,7 +1202,56 @@ class InferenceEngine:
                     f"beside its KV pages and cannot be served with "
                     f"{what}: unset {field_name}")
 
+    # what cannot serve a model whose window layers keep their own page
+    # pool and table and free pages behind the window (docs/kv-cache.md),
+    # and what each waits for
+    _TWO_KIND_REFUSALS = (
+        ("tensor_parallel", 1, "tensor parallelism (the two kinds' KV "
+         "heads, 4 and 8, would need a sharding each)"),
+        ("pipeline_parallel", 1, "pipeline parallelism (the stage "
+         "executor threads one pool and one table)"),
+        ("sequence_parallel", 1, "context-parallel prefill (the ring "
+         "writes one pool)"),
+        ("expert_parallel", 1, "expert parallelism inside the engine "
+         "(the chip's share of the experts is the model's "
+         "configuration: expert_shards)"),
+        ("host_kv_offload_bytes", 0, "host KV offload (a spilled "
+         "sequence's window pages are not spilled)"),
+        ("pd_enabled", False, "prefill/decode disaggregation (the wire "
+         "carries one pool's pages)"),
+        ("kv_pool_enabled", False, "the cluster KV pool (a published "
+         "prefix would need window pages that were freed)"),
+        ("speculative_ngram", 0, "n-gram speculation (the verify window "
+         "has no two-table path)"),
+        ("speculative_draft", "", "draft-model speculation (the verify "
+         "window has no two-table path)"),
+        ("prefill_pack", 1, "packed prefill (segment packing writes one "
+         "pool; prefill_pack 1 serves one-row programs)"),
+    )
+
+    def _refuse_for_two_kinds(self, mesh) -> None:
+        """Refuse by name, at start, every setting a model with two
+        kinds of page cannot be served under yet."""
+        what = (f"{self.md.name} keeps a page pool and a page table for "
+                f"its window layers beside the full layers'")
+        if mesh is not None:
+            raise ValueError(f"{what} and is served on one device: no mesh")
+        if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
+            raise ValueError(f"{what} and cannot be served with an int8 KV "
+                             f"cache (the window pool has no scale "
+                             f"tensors): unset kv_dtype")
+        for field_name, off, why in self._TWO_KIND_REFUSALS:
+            if getattr(self.cfg, field_name) != off:
+                raise ValueError(
+                    f"{what} and cannot be served with {why}: "
+                    f"set {field_name} to {off!r}")
+
     def _refuse_kv_import(self) -> None:
+        if self.two_kinds:
+            raise ValueError(
+                f"{self.md.name} keeps a second page pool for its window "
+                f"layers: imported KV pages carry none of it, so a "
+                f"request with KV cannot be admitted")
         if self.model.has_ssm:
             raise ValueError(
                 f"{self.md.name} keeps a per-slot recurrent state beside "
@@ -1158,7 +1272,8 @@ class InferenceEngine:
         holds its own group's weights and pool — ever allocates more
         than its own shard."""
         make = partial(create_kv_cache, self.md.arch, self._num_pages,
-                       self.cfg.page_size, jnp.dtype(self.cfg.kv_dtype))
+                       self.cfg.page_size, jnp.dtype(self.cfg.kv_dtype),
+                       window_pages=self._num_window_pages)
         if self.model.has_ssm:
             # one device (_refuse_for_state_pool); the state pool that
             # was there when HBM was measured, or a new one after a
@@ -1374,8 +1489,20 @@ class InferenceEngine:
                        jax.local_devices()[0])
         else:
             dev = jax.local_devices()[0]
-        bpt = self.md.kv_bytes_per_token(jnp.dtype(self.cfg.kv_dtype).itemsize)
-        page_bytes = bpt * self.cfg.page_size
+        itemsize = jnp.dtype(self.cfg.kv_dtype).itemsize
+        two_kinds = self.md.arch.two_kind_cache
+        if two_kinds:
+            # what one slot's worth of pages costs: a whole context of
+            # the full kind's and the few the window kind ever holds
+            arch = self.md.arch
+            full_pb, win_pb = (arch.kv_bytes_per_token_kind(
+                k, itemsize, stored=True) * self.cfg.page_size
+                for k in (0, 1))
+            page_bytes = full_pb + win_pb * self._window_pages_per_seq \
+                / self.pages_per_seq
+        else:
+            bpt = self.md.kv_bytes_per_token(itemsize)
+            page_bytes = bpt * self.cfg.page_size
         if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
             # each page also carries two fp32 scale rows (k + v), one
             # entry per (layer, kv head) — ~0.4% of the int8 page bytes
@@ -1390,6 +1517,8 @@ class InferenceEngine:
             # host RAM: enough for max_num_seqs full contexts
             pages = self.cfg.max_num_seqs * self.pages_per_seq + 1
             self.sizing_report = {"source": "seq-cap", "pages": pages}
+            if two_kinds:
+                self._num_window_pages = self._window_pool_cap()
             return pages
         # an accelerator that cannot report its memory is an error, not
         # a reason to budget against an assumed HBM size
@@ -1428,9 +1557,41 @@ class InferenceEngine:
                 "overhead %.2f GiB); sizing from measurement",
                 in_use / 2**30, weights / 2**30, drift / 2**30,
                 est_overhead / 2**30)
+        if two_kinds:
+            # both pools from what HBM leaves, in the ratio a slot needs
+            # them (docs/kv-cache.md): the window kind's transient pages
+            # first, then whole slots' worth of both
+            free -= (self._window_transient_pages + 1) * win_pb
+            seqs = int(max(free, 0) // (page_bytes * self.pages_per_seq))
+            seqs = max(1, min(seqs, self.cfg.max_num_seqs))
+            self._num_window_pages = self._window_pool_cap(seqs)
+            return seqs * self.pages_per_seq + 1
         pages = int(max(free, 0) // page_bytes)
         cap = self.cfg.max_num_seqs * self.pages_per_seq
         return max(2, min(pages, cap) + 1)
+
+    @property
+    def _window_pages_per_seq(self) -> int:
+        """Most window pages a decoding sequence holds: the window's
+        positions touch ``window/page_size + 1`` pages, and a decode
+        window writes up to a page ahead."""
+        return -(-self.md.arch.sliding_window // self.cfg.page_size) + 2
+
+    @property
+    def _window_transient_pages(self) -> int:
+        """Window pages one context-prefill chunk holds for the one step
+        that reads them: the chunk's own and the window before it."""
+        chunk = min(max(self.cfg.max_prefill_tokens, self.cfg.page_size),
+                    self.cfg.max_model_len)
+        return -(-(chunk + self.md.arch.sliding_window)
+                 // self.cfg.page_size) + 1
+
+    def _window_pool_cap(self, seqs: Optional[int] = None) -> int:
+        """Pages of the window pool that ``seqs`` slots can ever hold
+        (null page included): each its few, and one chunk's transient."""
+        seqs = self.cfg.max_num_seqs if seqs is None else seqs
+        return seqs * self._window_pages_per_seq \
+            + self._window_transient_pages + 1
 
     def _measure_sampler_temps(self, dev) -> int:
         """Compile + run the [max_num_seqs, vocab] sampler with the
@@ -1477,6 +1638,7 @@ class InferenceEngine:
         def decode_step(params, cache, sampling, counts, prompt_seen,
                         tokens, positions, page_tables, active, adapter_ids,
                         gmask, gtrans, gstate):
+            cache = _zero_moe_stats(cache)
             if pp_decode is not None:
                 cache, logits = pp_decode(params, cache, tokens, positions,
                                           page_tables, active,
@@ -1510,7 +1672,7 @@ class InferenceEngine:
                     active.astype(jnp.int32))
             # logprobs report the MODEL distribution (pre-penalty)
             return cache, sampling, counts, next_tokens, \
-                chosen_logprob(logits, next_tokens)
+                chosen_logprob(logits, next_tokens), _moe_stats_out(cache)
 
         return decode_step
 
@@ -1534,6 +1696,8 @@ class InferenceEngine:
         def decode_multi(params, cache, sampling, counts, prompt_seen,
                          tokens, positions, page_tables, active, adapter_ids,
                          stop_ids, steps_left, gmask, gtrans, gstate):
+            cache = _zero_moe_stats(cache)
+
             def body(carry, _):
                 cache, sampling, counts, toks, pos, act, left, gst = carry
                 cache, logits = model.decode(params, cache, toks, pos,
@@ -1574,10 +1738,13 @@ class InferenceEngine:
                      steps_left, gstate)
             (cache, sampling, counts, nxt, pos, act, left, gst), \
                 (toks, acts, lps) = jax.lax.scan(body, carry, None, length=K)
+            # an expert layer's counters over the window's steps ride
+            # back with its tokens (None: no such layer)
+            stats = _moe_stats_out(cache)
             if with_state:
-                return (cache, sampling, counts, toks, acts, lps,
+                return (cache, sampling, counts, toks, acts, lps, stats,
                         (nxt, pos, act, left, gst))
-            return cache, sampling, counts, toks, acts, lps
+            return cache, sampling, counts, toks, acts, lps, stats
 
         return decode_multi
 
@@ -1764,6 +1931,15 @@ class InferenceEngine:
         if not self.model.has_ssm:
             return 0
         return sum(1 for s in self.slots if s.request is not None)
+
+    @property
+    def window_pages_in_use(self) -> int:
+        """Pages of the window kind's pool that sequences hold (0 for a
+        one-kind cache)."""
+        if self.window_allocator is None:
+            return 0
+        return self.window_allocator.num_pages - 1 \
+            - self.window_allocator.available
 
     @property
     def num_waiting(self) -> int:
@@ -2351,6 +2527,11 @@ class InferenceEngine:
                 self.prefix_cache.release_uncommitted(tokens, slot.pages)
         else:
             self.allocator.release(slot.pages)
+        if slot.wpages:
+            # both tables' pages go back together
+            self.window_allocator.release(list(slot.wpages.values()))
+            slot.wpages = {}
+            self.page_tables[slot_idx, 1] = 0
         # reset the sampling row to greedy/no-mask: the sampler's
         # sort-skip and draw-skip gates read EVERY row, so one retired
         # top-p request would otherwise defeat them forever.  Greedy
@@ -2563,6 +2744,8 @@ class InferenceEngine:
                 self.allocator = self.prefix_cache
             else:
                 self.allocator = PageAllocator(self._num_pages)
+            if self.two_kinds:
+                self.window_allocator = PageAllocator(self._num_window_pages)
             self.cache = self._fresh_cache()
 
     def step(self) -> bool:
@@ -2580,6 +2763,7 @@ class InferenceEngine:
                   c["generation_tokens_total"], c["prefill_tokens_total"],
                   c["preemptions_total"], c["requests_expired_total"],
                   c["requests_shed_total"])
+        freed0 = c["window_pages_freed_total"]
         t0 = time.monotonic()
         with self.phases.phase("engine.step", n=self._tick,
                                rows=self.num_running):
@@ -2625,7 +2809,13 @@ class InferenceEngine:
                 kv_pages_used=(self.allocator.num_pages - 1
                                - self.allocator.available),
                 **({"state_rows": self.state_rows_in_use}
-                   if self.model.has_ssm else {}))
+                   if self.model.has_ssm else {}),
+                # two kinds of page: what the schedule freed behind the
+                # window in this step, and what is held
+                **({"window_pages_freed":
+                    c["window_pages_freed_total"] - freed0,
+                    "window_pages_used": self.window_pages_in_use}
+                   if self.two_kinds else {}))
         return did
 
     def _step_inner(self) -> bool:
@@ -2821,8 +3011,11 @@ class InferenceEngine:
             pages = self.allocator.alloc(pages_needed)
 
         slot = self.slots[free_slot]
-        table = np.zeros((self.pages_per_seq,), np.int32)
-        table[:len(pages)] = pages
+        table = np.zeros(self.page_tables.shape[1:], np.int32)
+        # the full kind's table (the only one of a one-kind cache); the
+        # window kind's fills as the prefill and the decode reach its
+        # pages (_window_sync)
+        (table[0] if self.two_kinds else table)[:len(pages)] = pages
         self.page_tables[free_slot] = table
         slot.request = req
         slot.pages = list(pages)
@@ -3231,18 +3424,30 @@ class InferenceEngine:
         bucket = self._bucket(m)
         t_first_chunk = time.monotonic()
         try:
+            if self.two_kinds:
+                # a fresh chunk attends over itself and leaves what the
+                # next step's window reads; a later chunk reads the
+                # window before it from the pages too
+                self._window_sync(i, pos + m if pos == 0 else pos, pos + m)
             with self.phases.phase("engine.prefill.dispatch"):
                 ctoks = np.zeros((1, bucket), np.int32)
                 ctoks[0, :m] = chunk
                 aid = jnp.asarray(self.slot_adapters[i:i + 1])
+                # a copy: the CPU backend may alias a numpy buffer, and
+                # a window table's entries change right after the
+                # dispatch (_window_sync), before the program has run
                 args = (self.params, self.cache, jnp.asarray(ctoks),
                         jnp.asarray([m], np.int32),
-                        jnp.asarray(self.page_tables[i][None]))
+                        jnp.asarray(self.page_tables[i][None].copy()))
                 FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
                 if use_cp:
                     fn = self._prefill_cp_fn(bucket)
                     self.cache, logits = fn(*args, aid)
-                elif pos == 0 and m == n:
+                elif pos == 0 and (m == n or self.two_kinds):
+                    # a whole fresh prompt; with two kinds of page also
+                    # the first chunk of a longer one, which attends
+                    # over itself: its window table holds the chunk's
+                    # tail alone
                     fn = self._prefill_fn(bucket)
                     self.cache, logits = fn(*args, aid,
                                             self._state_rows([i]))
@@ -3274,6 +3479,11 @@ class InferenceEngine:
                            tokens=m, bucket=bucket, slot=i, cp=bool(use_cp),
                            pack=turn, queue_wait=round(wait, 6))
         slot.prefill_pos = pos + m
+        if self.two_kinds and pos:
+            # the chunk's own pages behind the window go back now: the
+            # programs that could read them are queued ahead of any that
+            # is given them
+            self._window_sync(i, pos + m, pos + m)
         if slot.prefill_pos >= n:
             self._complete_prefills([(i, n)], logits)
         return True
@@ -3681,6 +3891,8 @@ class InferenceEngine:
     def _start_readback(*arrays) -> None:
         """Start the copy to the host of arrays the loop reads later."""
         for arr in arrays:
+            if arr is None:         # a program with no such output
+                continue
             try:
                 arr.copy_to_host_async()
             except Exception:      # backend without async copies
@@ -4046,14 +4258,7 @@ class InferenceEngine:
         for i, slot in enumerate(self.slots):
             if not self.active[i] or slot.request is None:
                 continue
-            needed = self._pages_needed(slot, lookahead)
-            while len(slot.pages) < needed:
-                page = self._alloc_one_page()
-                if page is not None:
-                    self.page_tables[i, len(slot.pages)] = page
-                    slot.pages.append(page)
-                    self._mark_state_dirty("page_tables")
-                    continue
+            while not self._reserve_decode_pages(i, slot, lookahead):
                 victim = self._newest_slot()
                 if victim is None or victim == i:
                     # this slot is itself the newest (or the only one):
@@ -4061,6 +4266,57 @@ class InferenceEngine:
                     self._preempt_slot(i)
                     break
                 self._preempt_slot(victim)
+
+    def _reserve_decode_pages(self, i: int, slot: "_Slot",
+                              lookahead: int) -> bool:
+        """Make slot ``i`` own the pages its next ``lookahead`` KV
+        writes land in, in both tables where there are two; False when a
+        pool is dry (what was taken stays taken)."""
+        needed = self._pages_needed(slot, lookahead)
+        table = self.page_tables[i, 0] if self.two_kinds \
+            else self.page_tables[i]
+        while len(slot.pages) < needed:
+            page = self._alloc_one_page()
+            if page is None:
+                return False
+            table[len(slot.pages)] = page
+            slot.pages.append(page)
+            self._mark_state_dirty("page_tables")
+        if not self.two_kinds:
+            return True
+        steps = max(1, min(lookahead, slot.remaining))
+        return self._window_sync(i, slot.position, slot.position + steps)
+
+    def _window_sync(self, i: int, read_from: int, end: int) -> bool:
+        """Make slot ``i``'s window table hold the pages of positions
+        ``[read_from - window, end)`` and no other: the pages wholly
+        behind go back to the window pool (the null page in their
+        place), the missing ones ahead are taken from it.
+        ``read_from`` is the first position whose step still reads the
+        pages (a step at position p reads the ``window`` positions up
+        to p), ``end`` the position after the last one written.  False
+        when the pool cannot give the pages (nothing is taken then)."""
+        slot = self.slots[i]
+        ps = self.cfg.page_size
+        first = max(0, read_from + 1 - self.md.arch.sliding_window) // ps
+        last = (end - 1) // ps
+        missing = [j for j in range(first, last + 1) if j not in slot.wpages]
+        behind = [j for j in slot.wpages if j < first]
+        if len(missing) > self.window_allocator.available + len(behind):
+            return False
+        table = self.page_tables[i, 1]
+        if behind:
+            self.window_allocator.release([slot.wpages.pop(j)
+                                           for j in behind])
+            table[behind] = 0
+            self.counters["window_pages_freed_total"] += len(behind)
+        for j, page in zip(missing,
+                           self.window_allocator.alloc(len(missing))):
+            slot.wpages[j] = page
+            table[j] = page
+        if behind or missing:
+            self._mark_state_dirty("page_tables")
+        return True
 
     def _penalty_args(self):
         """(counts, prompt_seen) for the decode programs: the live
@@ -4203,7 +4459,7 @@ class InferenceEngine:
         [next_tokens, lps]."""
         counts_in, seen = self._penalty_args()
         gmask, gtrans, gstate = self._grammar_args()
-        cache, sampling, counts, next_tokens, lps = self._decode_fn(
+        cache, sampling, counts, next_tokens, lps, stats = self._decode_fn(
             self.params, self.cache, self.sampling, counts_in, seen,
             jnp.asarray(self.last_tokens),
             jnp.asarray(self.positions),
@@ -4217,6 +4473,7 @@ class InferenceEngine:
             self.token_counts = counts
         self.counters["decode_steps_total"] += 1
         self._count_decode_rows(self.active)
+        self._count_moe_stats(stats)
         return [next_tokens, lps]
 
     def _decode_once(self):
@@ -4305,6 +4562,8 @@ class InferenceEngine:
             if not self.active[i] or slot.request is None:
                 continue
             extra += max(0, self._pages_needed(slot, K) - len(slot.pages))
+        # the window kind's pool holds every slot's few pages by its
+        # sizing (_window_pool_cap): the full kind's decides
         return extra <= self.allocator.available
 
     def _decode_multi(self, K: int):
@@ -4324,7 +4583,7 @@ class InferenceEngine:
         stop_dev = self._stop_matrix()
         counts_in, seen = self._penalty_args()
         gmask, gtrans, gstate = self._grammar_args()
-        cache, sampling, counts, toks, acts, lps = fn(
+        cache, sampling, counts, toks, acts, lps, stats = fn(
             self.params, self.cache, self.sampling, counts_in, seen,
             jnp.asarray(self.last_tokens),
             jnp.asarray(self.positions),
@@ -4339,7 +4598,20 @@ class InferenceEngine:
         if self.token_counts is not None:
             self.token_counts = counts
         self.counters["decode_steps_total"] += K
-        return [K, toks, acts, lps, self._slot_owners()]
+        return [K, toks, acts, lps, self._slot_owners(), stats]
+
+    def _count_moe_stats(self, stats) -> None:
+        """Add a decode program's expert-layer counters ([held experts
+        (one call each), held experts that got a pair, pairs held, pairs
+        routed], already on the host or on its way) to the engine's."""
+        if stats is None:
+            return
+        calls, touched, held, routed = np.asarray(stats).tolist()
+        c = self.counters
+        c["moe_expert_calls_total"] += calls
+        c["moe_experts_touched_total"] += touched
+        c["moe_pairs_held_total"] += held
+        c["moe_pairs_routed_total"] += routed
 
     def _count_decode_rows(self, active: np.ndarray) -> None:
         """Count a dispatch's slot-steps from its ``active`` flags
@@ -4513,6 +4785,7 @@ class InferenceEngine:
             host = [np.asarray(a) for a in win[1:4]]
         self._last_ready_t = time.monotonic()
         with self.phases.phase("engine.decode.replay"):
+            self._count_moe_stats(win[5])
             self._replay_window(win[0], *host, win[4])
             win.clear()
         # the prefills completed since this window's launch are queued
@@ -4607,7 +4880,7 @@ class InferenceEngine:
             # here
             gap = (max(0.0, t_dispatch - self._last_ready_t)
                    if not primed and self._last_ready_t else 0.0)
-            cache, sampling, counts, toks, acts, lps, carry = fn(
+            cache, sampling, counts, toks, acts, lps, stats, carry = fn(
                 self.params, self.cache, self.sampling, counts_in, seen,
                 state["last_tokens"], state["positions"],
                 state["page_tables"], state["active"],
@@ -4620,13 +4893,13 @@ class InferenceEngine:
             nxt, pos, act, left, gst = carry
             self._dev_state.update(last_tokens=nxt, positions=pos,
                                    active=act, left=left, gstate=gst)
-            self._start_readback(toks, acts, lps)
+            self._start_readback(toks, acts, lps, stats)
             self.counters["decode_steps_total"] += K
         self._gap_last = gap
         if self.dispatch_gap_hist is not None:
             self.dispatch_gap_hist.observe(gap)
         prev, self._inflight = self._inflight, [K, toks, acts, lps,
-                                                self._slot_owners()]
+                                                self._slot_owners(), stats]
         if prev is not None:
             self._retire_window(prev)
 

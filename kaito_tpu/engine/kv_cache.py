@@ -43,7 +43,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from kaito_tpu.models.metadata import ModelArch
+from kaito_tpu.models.metadata import ModelArch, stored_key_dim
 
 NULL_PAGE = 0
 
@@ -70,6 +70,26 @@ class KVCache:
     # 1,000; no page carries any of it.  None for every other model.
     ssm_state: Optional[jax.Array] = None
     ssm_conv: Optional[jax.Array] = None
+    # Two kinds of page (docs/kv-cache.md): a model whose window layers
+    # have a geometry of their own (mimo_v2) keeps those layers' keys
+    # and values in a second pair of pools, addressed through a second
+    # page table a sequence; ``k`` and ``v`` then hold the full layers
+    # alone.  All four pools of such a model are TOKEN-FLAT, as the
+    # decode kernel reads a page: [layers, pages, page_size * kv heads,
+    # dim], token t of a page in rows t * kv heads and on (a key of
+    # two lane tiles under four KV heads made the five-dimensional
+    # form's merge of its two middle axes a copy of the pool a step).
+    # A window page goes back to its pool once every position in it is
+    # a window behind the sequence's next token.  None for every other
+    # model.
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
+    # An expert layer's counters over the decode steps of one program:
+    # int32 [held experts (one call each), held experts that got a
+    # pair, pairs held, pairs routed].  The decode programs zero it,
+    # the layers add to it and the program returns it with its tokens.
+    # None for a model with no expert layer that is shared.
+    moe_stats: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -82,6 +102,11 @@ class KVCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    @property
+    def window_pool_bytes(self) -> int:
+        """Bytes of the window kind's pools (0: one kind of page)."""
+        return 0 if self.wk is None else int(self.wk.nbytes + self.wv.nbytes)
 
     @property
     def state_pool_bytes(self) -> int:
@@ -119,7 +144,28 @@ def create_kv_cache(
     num_pages: int,
     page_size: int,
     dtype: jnp.dtype = jnp.bfloat16,
+    window_pages: int = 0,
 ) -> KVCache:
+    if arch.layer_attention is not None:
+        # a pair of pools an attention kind, each with its own geometry
+        if kv_cache_is_quantized(dtype):
+            raise ValueError("an int8 KV cache is not implemented for a "
+                             "model with two kinds of page")
+
+        def pools(kind, pages):
+            layers, heads, dk, dv = arch.kv_page_geometry(kind)
+            return (jnp.zeros((layers, pages, page_size * heads,
+                               stored_key_dim(dk)), dtype),
+                    jnp.zeros((layers, pages, page_size * heads, dv), dtype))
+
+        k, v = pools(0, num_pages)
+        wk, wv = pools(1, window_pages) if arch.two_kind_cache \
+            else (None, None)
+        return KVCache(
+            k=k, v=v, wk=wk, wv=wv,
+            moe_stats=(jnp.zeros((4,), jnp.int32)
+                       if arch.num_experts and arch.layer_experts
+                       and any(arch.layer_experts) else None))
     shape = (arch.num_layers, num_pages, page_size, arch.kv_cache_heads,
              arch.kv_cache_dim)
     k_scale = v_scale = None
@@ -149,9 +195,74 @@ def dequantize_pages(pages: jax.Array, scale: jax.Array) -> jax.Array:
     return pages.astype(jnp.float32) * scale[..., None, :, None]
 
 
+def _is_token_flat(cache_layer: jax.Array, layer) -> bool:
+    """A pool of token-flat pages, ([Lg,] P, ps*Hkv, D): one axis fewer
+    than ([Lg,] P, ps, Hkv, D)."""
+    return cache_layer.ndim == (3 if layer is None else 4)
+
+
+def _set_decode_rows(pool: jax.Array, layer, page_idx: jax.Array,
+                     offset: jax.Array, new: jax.Array) -> jax.Array:
+    """Token-flat pages ([Lg,] P, ps*Hkv, D): token ``offset[i]`` of
+    page ``page_idx[i]`` takes ``new[i]`` [Hkv, D] as rows ``offset *
+    Hkv`` and on.  Whole pages are read, changed and written back: a
+    scatter of [Hkv, D] windows into rows that are no whole tile runs
+    index by index (4,096 of them a prefill chunk halved the cell's
+    rate, PERF.md section 6, PR 38); a page is whole tiles."""
+    hkv = new.shape[-2]
+    lidx = (layer,) if layer is not None else ()
+    pages = pool[lidx + (page_idx,)]                  # [B, ps*Hkv, D]
+    row = jnp.arange(pages.shape[-2], dtype=jnp.int32)[None, :]
+    mine = row // hkv == offset.astype(jnp.int32)[:, None]  # [B, ps*Hkv]
+    # row r of a page is head r % Hkv of its token: the new token's
+    # heads, repeated down the page
+    rows = jnp.tile(new.astype(pool.dtype), (1, pages.shape[-2] // hkv, 1))
+    return pool.at[lidx + (page_idx,)].set(
+        jnp.where(mine[..., None], rows, pages))
+
+
+def _set_prefill_rows(pool: jax.Array, layer, new: jax.Array,
+                      page_tables: jax.Array, start_pos: jax.Array,
+                      true_lens: jax.Array, page_size: int) -> jax.Array:
+    """Token-flat pages: a batch of prefill chunks ``new`` [B, T, Hkv,
+    D] into the pages their tables name, page-wise, as the quantizing
+    writes do: the pages a chunk spans are gathered, the chunk's valid
+    tokens laid over their rows, and whole pages scattered back (what a
+    page held before the chunk's first token, and after its last valid
+    one, stays).  A slot past the table and a table's null entries go
+    to the null page."""
+    B, T, hkv, _ = new.shape
+    ps = page_size
+    n_pg = (T + ps - 1) // ps + 1
+    first_slot = (start_pos // ps).astype(jnp.int32)              # [B]
+    pmax = page_tables.shape[1]
+    slot_ids = first_slot[:, None] + jnp.arange(n_pg, dtype=jnp.int32)[None]
+    span_pages = jnp.where(
+        slot_ids < pmax,
+        jnp.take_along_axis(page_tables, jnp.clip(slot_ids, 0, pmax - 1),
+                            axis=1), NULL_PAGE)                    # [B, n_pg]
+    lidx = (layer,) if layer is not None else ()
+    pages = pool[lidx + (span_pages,)]             # [B, n_pg, ps*Hkv, D]
+    span = pages.reshape(B, n_pg * ps * hkv, pages.shape[-1])
+    # span row r holds token r // Hkv of the span, whose place in the
+    # chunk is that less the chunk's offset into its first page
+    off = (start_pos % ps).astype(jnp.int32)                      # [B]
+    tok = jnp.arange(n_pg * ps, dtype=jnp.int32)[None, :] - off[:, None]
+    mine = (tok >= 0) & (tok < true_lens[:, None])                # [B, n_pg*ps]
+    rows = new.astype(pool.dtype).reshape(B, T * hkv, new.shape[-1])
+    laid = jnp.stack([                 # each chunk moved to its offset
+        jax.lax.dynamic_update_slice(jnp.zeros_like(span[b]), rows[b],
+                                     (off[b] * hkv, 0))
+        for b in range(B)])
+    merged = jnp.where(jnp.repeat(mine, hkv, axis=1)[..., None], laid, span)
+    return pool.at[lidx + (span_pages.reshape(-1),)].set(
+        merged.reshape((B * n_pg,) + pages.shape[2:]))
+
+
 def write_prefill_tokens(
     cache_layer: jax.Array,       # [num_pages, ps, Hkv, D] or, with
-                                  # ``layer``, the stacked group [Lg, P, ps, Hkv, D]
+                                  # ``layer``, the stacked group [Lg, P, ps, Hkv, D];
+                                  # or token-flat, [(Lg,) P, ps*Hkv, D]
     new: jax.Array,               # [B, T, Hkv, D]
     page_tables: jax.Array,       # [B, pages_per_seq] int32
     start_pos: jax.Array,         # [B] sequence position of new[:, 0]
@@ -175,6 +286,9 @@ def write_prefill_tokens(
     page_idx = jnp.where(valid, page_idx, NULL_PAGE)
     offset = pos % page_size
     flat = new.reshape(B * T, *new.shape[2:])                      # [B*T, Hkv, D]
+    if _is_token_flat(cache_layer, layer):
+        return _set_prefill_rows(cache_layer, layer, new, page_tables,
+                                 start_pos, true_lens, page_size)
     if layer is None:
         return cache_layer.at[page_idx.reshape(-1), offset.reshape(-1)].set(flat)
     return cache_layer.at[layer, page_idx.reshape(-1), offset.reshape(-1)].set(flat)
@@ -245,7 +359,8 @@ def write_packed_prefill_tokens_q(
 
 def write_decode_tokens(
     cache_layer: jax.Array,       # [num_pages, ps, Hkv, D] or, with
-                                  # ``layer``, the stacked group [Lg, P, ps, Hkv, D]
+                                  # ``layer``, the stacked group [Lg, P, ps, Hkv, D];
+                                  # or token-flat, [(Lg,) P, ps*Hkv, D]
     new: jax.Array,               # [B, Hkv, D] one token per sequence
     page_tables: jax.Array,       # [B, pages_per_seq]
     positions: jax.Array,         # [B] current position of each new token
@@ -259,6 +374,8 @@ def write_decode_tokens(
         # inactive rows target the null page (harmless scratch writes)
         page_idx = jnp.where(active, page_idx, NULL_PAGE)
     offset = positions % page_size
+    if _is_token_flat(cache_layer, layer):
+        return _set_decode_rows(cache_layer, layer, page_idx, offset, new)
     if layer is None:
         return cache_layer.at[page_idx, offset].set(new)
     return cache_layer.at[layer, page_idx, offset].set(new)

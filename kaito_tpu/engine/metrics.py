@@ -472,6 +472,44 @@ class EngineMetrics:
                       "Resumes after preemption that rebuilt a recurrent "
                       "state by recompute", r,
                       fn=lambda: engine.counters["state_recomputes_total"])
+            if getattr(engine, "two_kinds", False):
+                # two kinds of page in the cache (docs/kv-cache.md)
+                Gauge("kaito:engine_window_pages_in_use",
+                      "Pages of the window layers' pool that sequences "
+                      "hold (a sequence holds window/page_size + 2 at "
+                      "most while it decodes)", r,
+                      fn=lambda: engine.window_pages_in_use)
+                Gauge("kaito:engine_window_pages_freed_total",
+                      "Window pages returned to their pool because every "
+                      "position in them had fallen a window behind", r,
+                      fn=lambda: engine.counters["window_pages_freed_total"])
+                Gauge("kaito:engine_window_pool_bytes",
+                      "Bytes of the window layers' page pool", r,
+                      fn=lambda: engine.cache.window_pool_bytes)
+                Gauge("kaito:engine_sequences_live",
+                      "Slots that hold a request (prefilling or "
+                      "decoding): what the window pages are held by", r,
+                      fn=lambda: sum(1 for s in engine.slots
+                                     if s.request is not None))
+            if getattr(getattr(engine, "cache", None), "moe_stats",
+                       None) is not None:
+                # a shared expert layer's counters over the decode
+                # steps, counted on the device (docs/observability.md)
+                for key, text in (
+                        ("moe_expert_calls_total",
+                         "Held experts x expert layers x decode steps: "
+                         "one call of the expert kernel's group each"),
+                        ("moe_experts_touched_total",
+                         "Of those calls, the ones whose expert got a "
+                         "routed pair (the others read nothing)"),
+                        ("moe_pairs_held_total",
+                         "(token, expert) pairs of decoding rows whose "
+                         "expert is held here"),
+                        ("moe_pairs_routed_total",
+                         "(token, expert) pairs the router chose for "
+                         "decoding rows, held here or not")):
+                    Gauge(f"kaito:engine_{key}", text, r,
+                          fn=lambda key=key: engine.counters[key])
             Gauge("kaito:prefix_cached_tokens_total",
                   "Prompt tokens served from the prefix cache", r,
                   fn=lambda: engine.counters["prefix_cached_tokens_total"])

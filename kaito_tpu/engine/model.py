@@ -11,6 +11,13 @@ beside attention in every block (falcon-h1: ``ModelArch.ssm_state``)
 reads the block's normed input as attention does, adds its output to
 the same residual, and keeps a per-slot recurrent state that rides the
 layer scan with the page pools (``_ssm_mixer``, engine/ops/ssm.py).
+Layers of more than one kind in one model (mimo_v2:
+``ModelArch.layer_attention`` / ``layer_experts``: window and full
+attention layers with their own head counts, dense and expert FFNs)
+differ in parameter SHAPES, so they are stacked by kind and run as a
+schedule of scans over those stacks (``_layer_schedule``,
+``_run_layers_kinds``); each attention kind has a page pool and a page
+table of its own (docs/kv-cache.md).
 
 This replaces the model zoo the reference gets for free from vLLM
 (SURVEY.md §2.2, §7 step 3); parameters are plain pytrees whose logical
@@ -19,6 +26,7 @@ axes map onto the planner's mesh via kaito_tpu.parallel.sharding.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import zlib
 from dataclasses import dataclass
@@ -38,7 +46,8 @@ from kaito_tpu.engine.kv_cache import (KVCache, write_decode_tokens,
                                        write_decode_tokens_q,
                                        write_prefill_tokens,
                                        write_prefill_tokens_q)
-from kaito_tpu.models.metadata import AttentionKind, ModelArch
+from kaito_tpu.models.metadata import (AttentionKind, ModelArch,
+                                       stored_key_dim)
 
 VOCAB_ALIGN = 128
 _BIG_WINDOW = 1 << 30
@@ -55,10 +64,76 @@ def _name_salt(name: str) -> int:
 
 @dataclass(frozen=True)
 class LayerGroup:
-    name: str          # "dense" | "moe"
+    name: str          # "dense" | "moe"; "<full|window>_<dense|moe>" by kind
     start: int
     count: int
     moe: bool
+    kind: int = 0      # attention kind of the stack (0 full, 1 window)
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """One attention kind's sizes (ModelArch.layer_attention).  ``k_dim``
+    is what a key's head is stored and multiplied at: ``head_dim``
+    zero-padded to whole 128-lane tiles (metadata.stored_key_dim)."""
+    index: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    v_head_dim: int
+    window: Optional[int]
+    sink: bool
+
+    @property
+    def k_dim(self) -> int:
+        return stored_key_dim(self.head_dim)
+
+
+@dataclass(frozen=True)
+class LayerRun:
+    """Consecutive layers of one stack: ``count`` layers from
+    ``stack_start`` of ``params[stack]``, whose attention kind's page
+    pool holds them from ``cache_start``."""
+    stack: str
+    stack_start: int
+    count: int
+    moe: bool
+    kind: int
+    cache_start: int
+
+
+def attention_kinds(arch: ModelArch) -> tuple:
+    """The attention kinds of a model whose layers name theirs."""
+    return (
+        AttnKind(0, arch.num_heads, arch.num_kv_heads, arch.head_dim,
+                 arch.v_head_dim or arch.head_dim, None, arch.full_sink),
+        AttnKind(1, arch.swa_num_heads, arch.swa_num_kv_heads,
+                 arch.swa_head_dim, arch.swa_v_head_dim or arch.swa_head_dim,
+                 arch.sliding_window, arch.swa_sink))
+
+
+def _layer_schedule(arch: ModelArch):
+    """(stacks, runs) of a model whose layers name their kinds: layers
+    of one attention kind and one FFN kind share a stack, in layer
+    order; a run is a stretch of consecutive layers of one stack."""
+    experts = arch.layer_experts or (0,) * arch.num_layers
+    members: dict = {}
+    runs: list = []
+    seen = [0, 0]
+    for kind, moe in zip(arch.layer_attention, experts):
+        name = ("window" if kind else "full") + ("_moe" if moe else "_dense")
+        at = len(members.setdefault(name, []))
+        members[name].append((kind, bool(moe)))
+        last = runs[-1] if runs else None
+        if last is not None and last.stack == name:
+            runs[-1] = LayerRun(name, last.stack_start, last.count + 1,
+                                last.moe, kind, last.cache_start)
+        else:
+            runs.append(LayerRun(name, at, 1, bool(moe), kind, seen[kind]))
+        seen[kind] += 1
+    stacks = tuple(LayerGroup(name, 0, len(ls), ls[0][1], ls[0][0])
+                   for name, ls in members.items())
+    return stacks, tuple(runs)
 
 
 def _layer_groups(arch: ModelArch) -> tuple[LayerGroup, ...]:
@@ -124,7 +199,20 @@ class TransformerLM:
         # (docs/kv-cache.md), and prefix reuse, PD and speculation
         # are refused by the engine
         self.has_ssm = arch.ssm_state > 0
-        self.groups = _layer_groups(arch)
+        self.moe_kernel = False     # Pallas grouped matmul (set by the engine)
+        # layers that name their kinds: stacks by kind, a schedule of
+        # runs, one page pool and table an attention kind
+        self.kinds = None
+        self.runs = ()
+        if arch.layer_attention is not None:
+            if self.is_mla or self.has_ssm:
+                raise NotImplementedError(
+                    "per-layer attention kinds with latent attention or "
+                    "a state-space mixer are not implemented")
+            self.kinds = attention_kinds(arch)
+            self.groups, self.runs = _layer_schedule(arch)
+        else:
+            self.groups = _layer_groups(arch)
         self.vocab_padded = -(-arch.vocab_size // VOCAB_ALIGN) * VOCAB_ALIGN
         # rope tables are concrete constants; computing them lazily inside
         # a traced scan body would cache tracers
@@ -142,6 +230,15 @@ class TransformerLM:
         # longrope (phi-3 family): per-position short/long table switch
         self._longrope = None if self.is_mla else nn.longrope_tables(arch)
         self._inv_freq_local = self._make_inv_freq_local()
+        if self.kinds is not None:
+            from dataclasses import replace
+
+            # one table a kind: the window kind has its own theta
+            self._kind_inv_freq = (
+                self._inv_freq_global,
+                nn.rope_frequencies(replace(
+                    arch, head_dim=arch.swa_head_dim,
+                    rope_theta=arch.swa_rope_theta, rope_scaling=None)))
 
     def _rope_select(self, positions):
         """(inv_freq, mscale) for the global table — per-position
@@ -159,10 +256,16 @@ class TransformerLM:
     # Parameter construction
     # ------------------------------------------------------------------
 
-    def _layer_specs(self, moe: bool) -> dict[str, tuple[tuple[int, ...], tuple]]:
+    def _layer_specs(self, moe: bool, kind: int = 0
+                     ) -> dict[str, tuple[tuple[int, ...], tuple]]:
         a = self.arch
         E, H, Hkv, D, I = (a.hidden_size, a.num_heads, a.num_kv_heads,
                            a.head_dim, a.intermediate_size)
+        Dv = D
+        if self.kinds is not None:
+            ak = self.kinds[kind]
+            H, Hkv, D, Dv = (ak.num_heads, ak.num_kv_heads, ak.head_dim,
+                             ak.v_head_dim)
         if self.is_mla:
             dn = a.qk_nope_head_dim or D
             dr = a.qk_rope_head_dim or 64
@@ -189,9 +292,11 @@ class TransformerLM:
                 "attn_norm": ((E,), ("embed",)),
                 "q": ((E, H * D), ("embed", "heads")),
                 "k": ((E, Hkv * D), ("embed", "kv_heads")),
-                "v": ((E, Hkv * D), ("embed", "kv_heads")),
-                "o": ((H * D, E), ("heads", "embed")),
+                "v": ((E, Hkv * Dv), ("embed", "kv_heads")),
+                "o": ((H * Dv, E), ("heads", "embed")),
             }
+            if self.kinds is not None and self.kinds[kind].sink:
+                specs["sink"] = ((H,), ("heads",))
         if a.qkv_bias or a.linear_bias:
             specs.update({
                 "q_bias": ((H * D,), ("heads",)),
@@ -225,14 +330,18 @@ class TransformerLM:
                 "ssm_out": ((a.ssm_inner, E), (None, "embed")),
             })
         if moe:
-            X = a.num_experts
+            # the router scores every expert; the stacks hold this
+            # chip's share of them (all, unless the layer is shared)
+            X, Xh = a.num_experts, a.experts_held
             Im = a.moe_intermediate_size or I
             specs.update({
                 "router": ((E, X), ("embed", "expert")),
-                "experts_gate": ((X, E, Im), ("expert", "embed", "intermediate")),
-                "experts_up": ((X, E, Im), ("expert", "embed", "intermediate")),
-                "experts_down": ((X, Im, E), ("expert", "intermediate", "embed")),
+                "experts_gate": ((Xh, E, Im), ("expert", "embed", "intermediate")),
+                "experts_up": ((Xh, E, Im), ("expert", "embed", "intermediate")),
+                "experts_down": ((Xh, Im, E), ("expert", "intermediate", "embed")),
             })
+            if a.router_bias:
+                specs["router_bias"] = ((X,), ("expert",))
             if a.num_shared_experts:
                 Is = Im * a.num_shared_experts
                 specs.update({
@@ -272,13 +381,17 @@ class TransformerLM:
             if "norm" in spec_key:
                 params[spec_key] = jnp.zeros(shape, self.dtype) if "bias" in spec_key or self.arch.norm_offset else jnp.ones(shape, self.dtype)
             else:
-                params[spec_key] = (0.02 / follow.get(spec_key, 1.0)) * jax.random.normal(
-                    jax.random.fold_in(keys[0], _name_salt(spec_key)), shape, self.dtype)
+                params[spec_key] = (0.02 / follow.get(spec_key, 1.0)) * self._normal(
+                    jax.random.fold_in(keys[0], _name_salt(spec_key)), shape)
         for gi, g in enumerate(self.groups):
             layer: dict = {}
-            for name, (shape, _) in self._layer_specs(g.moe).items():
+            for name, (shape, _) in self._layer_specs(g.moe, g.kind).items():
                 full = (g.count,) + shape
-                if name in ("ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_conv",
+                if name in ("sink", "router_bias"):
+                    init = self._kind_draw(
+                        name, jax.random.fold_in(keys[1 + gi],
+                                                 _name_salt(name)), full)
+                elif name in ("ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_conv",
                             "ssm_conv_bias"):
                     init = self._ssm_draw(
                         name, jax.random.fold_in(keys[1 + gi],
@@ -295,11 +408,32 @@ class TransformerLM:
                             jax.random.fold_in(keys[1 + gi], _name_salt(name)),
                             full, jnp.float32)).astype(self.dtype)
                     else:
-                        init = std * jax.random.normal(
-                            jax.random.fold_in(keys[1 + gi], _name_salt(name)), full, self.dtype)
+                        init = std * self._normal(
+                            jax.random.fold_in(keys[1 + gi], _name_salt(name)), full)
                 layer[name] = init
+            if "router_bias" in layer:
+                layer["router"], layer["router_bias"] = self._balanced_router(
+                    jax.random.fold_in(keys[1 + gi], _name_salt("skew")),
+                    layer["router"])
             params[g.name] = layer
         return params
+
+    def _normal(self, key: jax.Array, shape: tuple) -> jax.Array:
+        """A standard-normal draw in the model's type.  A model whose
+        layers name their kinds draws in float32 and rounds: JAX's
+        bfloat16 sampler gives 128 distinct values with a mean of
+        -0.012 (measured over 16.8M draws), every matrix then carries
+        a rank-one part that maps the all-ones direction onto itself
+        with a gain near one (sqrt(fan-in) x 0.012), and after seven
+        layers 58-70% of the residual's energy is one direction common
+        to every token: greedy decoding falls onto a handful of
+        attractor tokens whatever the prompt, every row routes to the
+        same experts, and whether a chip's share holds them is the
+        seed's luck (PERF.md section 6, PR 38).  The other models keep
+        the draw their tolerances were read with."""
+        if self.kinds is None or self.dtype == jnp.float32:
+            return jax.random.normal(key, shape, self.dtype)
+        return jax.random.normal(key, shape, jnp.float32).astype(self.dtype)
 
     @cached_property
     def _ssm_mup(self) -> np.ndarray:
@@ -335,6 +469,63 @@ class TransformerLM:
             follow["embed"] = float(a.embedding_multiplier)
         return follow
 
+    def _kind_draw(self, name: str, key: jax.Array, shape: tuple):
+        """Synthetic draws of the small parameters that a plain normal
+        draw would leave without effect.  ``sink``: the log of the
+        window plus a standard normal, so the sink's column takes tenths
+        of a window layer's probability (scores are of order one, and a
+        sink at that scale against 128 of them would take a hundredth).
+        ``router_bias``: zero here, fitted by ``_balanced_router``."""
+        if name != "sink":
+            return jnp.zeros(shape, self.dtype)
+        z = jax.random.normal(key, shape, jnp.float32)
+        return (math.log(max(self.arch.sliding_window or 2, 2))
+                + z).astype(self.dtype)
+
+    # the fit of a correction bias: samples, steps, first step
+    _BALANCE = (4096, 200, 0.02)
+
+    def _balanced_router(self, key: jax.Array, router: jax.Array):
+        """A synthetic router as a trained one is: experts of unequal
+        pull, and a correction bias that evens their load.  ``router``
+        [layers, E, X] drawn plain gets a log-normal (0.25) scale an
+        expert, so that by their scores alone a sixteenth of the experts
+        would take a fifth of the pairs and some none; the bias is then
+        fitted to the router as drawn by the rule that trains it
+        (``noaux_tc``: the bias of an expert over its even share goes
+        down, under it up), on seeded standard-normal inputs (a router
+        reads an RMS-normed stream), until every expert is chosen about
+        equally often.  So the bias decides two choices in five, as it
+        must for a check to see it, and every chip's share of the
+        experts gets its share of the pairs, whatever the seed.
+        Returns (router, bias [layers, X]) in the model's dtype."""
+        a = self.arch
+        L, E, X = router.shape
+        k = a.num_experts_per_tok
+        n, steps, step0 = self._BALANCE
+        k_scale, k_x = jax.random.split(key)
+        scale = jnp.exp(0.25 * jax.random.normal(k_scale, (L, 1, X),
+                                                 jnp.float32))
+        router = (router.astype(jnp.float32) * scale).astype(self.dtype)
+        x = jax.random.normal(k_x, (n, E), jnp.float32)
+        logits = jnp.einsum("ne,lex->lnx", x, router.astype(jnp.float32))
+        if a.router_scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+
+        def fit(t, bias):
+            _, idx = jax.lax.top_k(scores + bias[:, None, :], k)
+            chosen = jnp.zeros((L, X), jnp.float32).at[
+                jnp.arange(L)[:, None], idx.reshape(L, -1)].add(1.0)
+            load = chosen * (X / (n * k))          # 1 = the even share
+            bias = bias + step0 * 0.985 ** t * jnp.clip(1.0 - load, -1.0, 1.0)
+            return bias - jnp.mean(bias, axis=-1, keepdims=True)
+
+        bias = jax.lax.fori_loop(0, steps, fit,
+                                 jnp.zeros((L, X), jnp.float32))
+        return router, bias.astype(self.dtype)
+
     def _ssm_draw(self, name: str, key: jax.Array, shape: tuple):
         """The mixer's small parameters by Mamba-2's conventions: A in
         1..16, the step dt (softplus of its bias) log-uniform between
@@ -362,7 +553,7 @@ class TransformerLM:
         for g in self.groups:
             axes[g.name] = {
                 name: ("layers",) + ax
-                for name, (_, ax) in self._layer_specs(g.moe).items()
+                for name, (_, ax) in self._layer_specs(g.moe, g.kind).items()
             }
         return axes
 
@@ -523,10 +714,15 @@ class TransformerLM:
 
     def _attn_qkv(self, x: jax.Array, p: dict, positions: jax.Array,
                   window: Optional[jax.Array], lora: Optional[dict] = None,
-                  lora_ids: Optional[jax.Array] = None, overlap=None):
+                  lora_ids: Optional[jax.Array] = None, overlap=None,
+                  kind: Optional[AttnKind] = None):
         """Project to q/k/v heads with norms+rope applied.
 
-        x: [B, T, E]; positions: [B, T] absolute positions.
+        x: [B, T, E]; positions: [B, T] absolute positions.  ``kind``:
+        the layer's attention kind, of a model whose layers name
+        theirs: its head counts and sizes, its rope table, values
+        scaled by ``attention_value_scale``, and q and k zero-padded to
+        ``kind.k_dim`` (the scores do not change).
 
         ``overlap`` is the engine's (mesh, axis) comm-overlap handle
         (docs/multichip.md): when set, the COLUMN-parallel q projection
@@ -564,6 +760,27 @@ class TransformerLM:
             q, k, v = q + p["q_bias"], k + p["k_bias"], v + p["v_bias"]
         if a.key_multiplier is not None:
             k = k * jnp.asarray(a.key_multiplier, k.dtype)
+        if kind is not None:
+            if kind.k_dim != kind.head_dim:
+                # a head that is no whole number of 128-lane tiles: the
+                # projections stay plain matrix products on the weights
+                # as they lie, and the split into heads re-lays the
+                # activations out; without the barrier the compiler
+                # re-lays the weights out instead, every step
+                q, k, v = jax.lax.optimization_barrier((q, k, v))
+            q = q.reshape(B, T, kind.num_heads, kind.head_dim)
+            k = k.reshape(B, T, kind.num_kv_heads, kind.head_dim)
+            v = v.reshape(B, T, kind.num_kv_heads, kind.v_head_dim)
+            if a.attention_value_scale is not None:
+                v = v * jnp.asarray(a.attention_value_scale, v.dtype)
+            inv_freq = self._kind_inv_freq[kind.index]
+            q = nn.apply_rope(q, positions, inv_freq, kind.head_dim)
+            k = nn.apply_rope(k, positions, inv_freq, kind.head_dim)
+            pad = kind.k_dim - kind.head_dim
+            if pad:
+                widths = ((0, 0), (0, 0), (0, 0), (0, pad))
+                q, k = jnp.pad(q, widths), jnp.pad(k, widths)
+            return q, k, v
         q = q.reshape(B, T, a.num_heads, a.head_dim)
         k = k.reshape(B, T, a.num_kv_heads, a.head_dim)
         v = v.reshape(B, T, a.num_kv_heads, a.head_dim)
@@ -587,11 +804,24 @@ class TransformerLM:
     def _mlp(self, x: jax.Array, p: dict, moe: bool,
              lora: Optional[dict] = None,
              lora_ids: Optional[jax.Array] = None,
-             overlap=None, pf_down=None) -> jax.Array:
+             overlap=None, pf_down=None, valid=None, with_stats=False,
+             expert_layer=None):
+        """The block's FFN.  ``valid`` [B, T] bool: the tokens an expert
+        layer routes (None: all); ``with_stats``: an expert layer also
+        returns its counters; ``expert_layer``: ``p``'s expert stacks
+        are whole and this is the layer's index (nn.moe_mlp_ragged)."""
         if moe:
             B, T, E = x.shape
-            fn = nn.moe_mlp_ragged if self.moe_impl == "ragged" else nn.moe_mlp
-            y = fn(x.reshape(B * T, E), p, self.arch)
+            if self.moe_impl == "ragged":
+                out = nn.moe_mlp_ragged(
+                    x.reshape(B * T, E), p, self.arch,
+                    valid=None if valid is None else valid.reshape(B * T),
+                    kernel=self.moe_kernel, with_stats=with_stats,
+                    layer=expert_layer)
+                if with_stats:
+                    return out[0].reshape(B, T, E), out[1]
+                return out.reshape(B, T, E)
+            y = nn.moe_mlp(x.reshape(B * T, E), p, self.arch)
             return y.reshape(B, T, E)
         return nn.mlp(x, p, self.arch, self.lora_scaling,
                       serve_lora=lora, lora_ids=lora_ids,
@@ -606,8 +836,14 @@ class TransformerLM:
                positions, page_tables, lengths, true_lens, active,
                start_pos=None, lora=None, lora_ids=None,
                ks=None, vs=None, packed=None, pf=None, ssm=None,
-               ssm_rows=None):
+               ssm_rows=None, kind: Optional[AttnKind] = None, stats=None,
+               expert_layer=None):
         """One transformer block. Returns (x, ck, cv, ks, vs, ssm).
+
+        ``kind``: the layer's attention kind, of a model whose layers
+        name theirs; ``ck``/``cv``/``page_tables`` are then that kind's
+        pools and table, and the return has a seventh element: ``stats``
+        (int32 [4] or None) with an expert layer's counters added.
 
         ``ssm`` is the mixer's per-slot pools (state [Lg, S, H, P, N],
         convolution tail [Lg, S, K-1, C]) of a model with a state-space
@@ -642,8 +878,13 @@ class TransformerLM:
         ov = self.overlap if mode == "decode" else None
         q, k_new, v_new = self._attn_qkv(h, p, positions, window,
                                          lora=lora, lora_ids=lora_ids,
-                                         overlap=ov)
-        ps = ck.shape[-3]
+                                         overlap=ov, kind=kind)
+        # (a model whose layers name their kind keeps token-flat pools)
+        flat_heads = None if kind is None else kind.num_kv_heads
+        ps = ck.shape[-3] if kind is None else ck.shape[-2] // flat_heads
+        # a sink bias a head (window layers of mimo_v2): one more
+        # column of the softmax, probability and no value
+        sink = p["sink"].astype(jnp.float32) if "sink" in p else None
 
         if mode == "prefill_cp":
             # context-parallel single-shot prefill: q/k/v are sharded
@@ -730,23 +971,31 @@ class TransformerLM:
                     q, ck, cv, page_tables, start, true_lens,
                     scale=self._scale, sliding_window=window,
                     logit_softcap=a.attn_logit_softcap, layer=li,
-                    k_scale=ks, v_scale=vs)
+                    k_scale=ks, v_scale=vs, sink=sink, kv_heads=flat_heads)
             elif self.attn_impl == "pallas":
                 from kaito_tpu.engine.ops.flash_prefill import (
                     flash_prefill_attention)
 
                 win = window if window is not None else jnp.int32(_BIG_WINDOW)
-                out = self._pallas_attention(
-                    partial(flash_prefill_attention, scale=self._scale,
-                            softcap=a.attn_logit_softcap),
-                    (q, k_new, v_new, true_lens,
-                     jnp.asarray(win, jnp.int32)),
-                    (2, 2, 2, None, None), 2)
+                args = (q, k_new, v_new, true_lens,
+                        jnp.asarray(win, jnp.int32))
+                head_dims = (2, 2, 2, None, None)
+
+                def flash(q, k, v, tl, win, *sink):
+                    return flash_prefill_attention(
+                        q, k, v, tl, win, scale=self._scale,
+                        softcap=a.attn_logit_softcap,
+                        sink=sink[0] if sink else None)
+
+                if sink is not None:
+                    args += (sink,)
+                    head_dims += (0,)
+                out = self._pallas_attention(flash, args, head_dims, 2)
             else:
                 out = attn.prefill_attention(
                     q, k_new, v_new, scale=self._scale,
                     sliding_window=window, logit_softcap=a.attn_logit_softcap,
-                    true_len=true_lens)
+                    true_len=true_lens, sink=sink)
         else:
             if ks is not None:
                 ck, ks = write_decode_tokens_q(ck, ks, k_new[:, 0], page_tables,
@@ -766,17 +1015,23 @@ class TransformerLM:
 
                 win = window if window is not None else jnp.int32(_BIG_WINDOW)
 
-                def decode_kernel(q1, ck, cv, pt, ln, win, li, *scales):
-                    k_s, v_s = scales or (None, None)
+                def decode_kernel(q1, ck, cv, pt, ln, win, li, *rest):
+                    sk = rest[0] if sink is not None else None
+                    k_s, v_s = rest[sink is not None:] or (None, None)
                     return paged_decode_attention_pallas(
                         q1, ck, cv, pt, ln, win, scale=self._scale,
                         softcap=a.attn_logit_softcap, layer=li,
-                        k_scale=k_s, v_scale=v_s)
+                        k_scale=k_s, v_scale=v_s, sink=sk,
+                        kv_heads=flat_heads)
 
-                # q [B, H, D]; pools [Lg, P, ps, Hkv, D]; scales [Lg, P, Hkv]
+                # q [B, H, D]; pools [Lg, P, ps, Hkv, D]; sink [H];
+                # scales [Lg, P, Hkv]
                 args = (q[:, 0], ck, cv, page_tables, lengths,
                         jnp.asarray(win, jnp.int32), li)
                 head_dims = (1, 3, 3, None, None, None, None)
+                if sink is not None:
+                    args += (sink,)
+                    head_dims += (0,)
                 if ks is not None:
                     args += (ks, vs)
                     head_dims += (2, 2)
@@ -786,9 +1041,13 @@ class TransformerLM:
                 out = attn.paged_decode_attention(
                     q[:, 0], ck, cv, page_tables, lengths, scale=self._scale,
                     sliding_window=window, logit_softcap=a.attn_logit_softcap,
-                    layer=li, k_scale=ks, v_scale=vs)
+                    layer=li, k_scale=ks, v_scale=vs, sink=sink,
+                    kv_heads=flat_heads)
             out = out[:, None]
-        o_in = out.reshape(B, T, a.num_heads * a.head_dim)
+        if kind is not None:
+            o_in = out.reshape(B, T, kind.num_heads * kind.v_head_dim)
+        else:
+            o_in = out.reshape(B, T, a.num_heads * a.head_dim)
         # collective-compute overlap (docs/multichip.md): the DECODE
         # step's row-parallel attention-out projection routes through
         # the pipelined ring; every prefill mode and the gate-off path
@@ -828,6 +1087,20 @@ class TransformerLM:
             attn_out = self._norm(attn_out, p, "post_attn_norm")
         x = x + attn_out
         h2 = self._norm(x, p, "mlp_norm")
+        if kind is not None:
+            # an expert layer routes the tokens that are there: the rows
+            # that decode, a prompt's own positions
+            if mode == "decode":
+                valid = None if active is None else active[:, None]
+            else:
+                valid = jnp.arange(T)[None, :] < true_lens[:, None]
+            want = moe and stats is not None
+            mlp_out = self._mlp(h2, p, moe, valid=valid, with_stats=want,
+                                expert_layer=expert_layer)
+            if want:
+                mlp_out, layer_stats = mlp_out
+                stats = stats + layer_stats
+            return x + mlp_out, ck, cv, ks, vs, ssm, stats
         mlp_out = self._mlp(h2, p, moe, lora=lora, lora_ids=lora_ids,
                             overlap=ov, pf_down=(pf or {}).get("down"))
         if a.pre_post_norm:
@@ -935,6 +1208,12 @@ class TransformerLM:
                     positions, page_tables, lengths, true_lens, active,
                     remat: bool = False, start_pos=None, adapter_ids=None,
                     packed=None, ssm_rows=None):
+        if self.kinds is not None:
+            return self._run_layers_kinds(
+                params, cache, x, mode, positions=positions,
+                page_tables=page_tables, lengths=lengths,
+                true_lens=true_lens, active=active, remat=remat,
+                start_pos=start_pos)
         serve_lora = params.get("serve_lora") if mode != "train" else None
         new_k, new_v, new_ks, new_vs, new_ssm = [], [], [], [], []
         for g in self.groups:
@@ -1049,7 +1328,88 @@ class TransformerLM:
                         ssm_state=st, ssm_conv=cv)
         return x, cache
 
-    def _layer_train(self, x, p, window, moe, *, positions, true_lens):
+    def _run_layers_kinds(self, params, cache: Optional[KVCache], x, mode,
+                          *, positions, page_tables, lengths, true_lens,
+                          active, remat, start_pos):
+        """The layers of a model whose layers name their kinds: the
+        schedule's runs in order, each a scan over its stretch of its
+        stack (the stack rides as a loop invariant and the body takes
+        its layer by index: a slice of a stack would be a copy of it).
+        ``page_tables`` is [B, 2, pages]: a table an attention kind, the
+        full kind's first; a kind's pools ride the scans of its runs."""
+        if mode not in ("train", "prefill", "decode"):
+            raise NotImplementedError(
+                f"layers that name their attention kind have no "
+                f"{mode!r} path")
+        if mode != "train" and cache.wk is None:
+            raise ValueError("a model with window layers of their own "
+                             "geometry serves from a cache with a window "
+                             "pool (kv_cache.create_kv_cache)")
+        # ("train": the cache-free forward pass that scores a prompt)
+        pools = None if mode == "train" else \
+            [(cache.k, cache.v), (cache.wk, cache.wv)]
+        # an expert layer's counters are kept for the decode programs
+        # (what the per-layer metrics read)
+        stats = cache.moe_stats if mode == "decode" else None
+        for run in self.runs:
+            stack = params[run.stack]
+            kind = self.kinds[run.kind]
+            window = kind.window
+            # an expert layer's stacks stay whole and the layer goes by
+            # index: a slice handed to the grouped-matmul kernel would
+            # be a copy of the layer's matrices
+            whole = {k: v for k, v in stack.items()
+                     if run.moe and k.startswith("experts_")}
+            rest = {k: v for k, v in stack.items() if k not in whole}
+
+            def take(i, whole=whole, rest=rest, run=run):
+                at = run.stack_start + i
+                p = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
+                    w, at, 0, keepdims=False), rest)
+                return {**p, **whole}, (at if whole else None)
+
+            if mode == "train":
+                def one(h, p, at, kind=kind, window=window, moe=run.moe):
+                    return self._layer_train(
+                        h, p, window, moe, positions=positions,
+                        true_lens=true_lens, kind=kind, expert_layer=at)
+
+                if remat:
+                    one = jax.checkpoint(one, prevent_cse=False)
+                for i in range(run.count):
+                    x = one(x, *take(i))
+                continue
+            table = page_tables[:, run.kind]
+            ck, cv = pools[run.kind]
+
+            def step(carry, i, run=run, kind=kind, window=window,
+                     table=table, take=take):
+                h, ck, cv, st = carry
+                p, at = take(i)
+                h, ck, cv, _, _, _, st = self._layer(
+                    h, p, ck, cv, run.cache_start + i, window, run.moe, mode,
+                    positions=positions, page_tables=table, lengths=lengths,
+                    true_lens=true_lens, active=active, start_pos=start_pos,
+                    kind=kind, stats=st, expert_layer=at)
+                return (h, ck, cv, st), None
+
+            if run.count == 1:
+                (x, ck, cv, stats), _ = step((x, ck, cv, stats),
+                                             jnp.int32(0))
+            else:
+                (x, ck, cv, stats), _ = jax.lax.scan(
+                    step, (x, ck, cv, stats),
+                    jnp.arange(run.count, dtype=jnp.int32))
+            pools[run.kind] = (ck, cv)
+        if mode == "train":
+            return x, None
+        return x, dataclasses.replace(
+            cache, k=pools[0][0], v=pools[0][1], wk=pools[1][0],
+            wv=pools[1][1],
+            moe_stats=stats if mode == "decode" else cache.moe_stats)
+
+    def _layer_train(self, x, p, window, moe, *, positions, true_lens,
+                     kind: Optional[AttnKind] = None, expert_layer=None):
         """Transformer block without KV-cache plumbing (training)."""
         a = self.arch
         B, T, E = x.shape
@@ -1064,7 +1424,18 @@ class TransformerLM:
             x = x + attn_out
             h2 = self._norm(x, p, "mlp_norm")
             return x + self._mlp(h2, p, moe)
-        q, k_new, v_new = self._attn_qkv(h, p, positions, window)
+        q, k_new, v_new = self._attn_qkv(h, p, positions, window, kind=kind)
+        if kind is not None:
+            out = attn.prefill_attention(
+                q, k_new, v_new, scale=self._scale, sliding_window=window,
+                true_len=true_lens,
+                sink=p["sink"].astype(jnp.float32) if "sink" in p else None)
+            o_in = out.reshape(B, T, kind.num_heads * kind.v_head_dim)
+            x = x + nn.linear(o_in, p["o"])
+            h2 = self._norm(x, p, "mlp_norm")
+            valid = jnp.arange(T)[None, :] < true_lens[:, None]
+            return x + self._mlp(h2, p, moe, valid=valid,
+                                 expert_layer=expert_layer)
         if self.ring is not None and window is None:
             # sequence-parallel exact attention over the mesh ring;
             # training batches are packed dense (loss masks handle pads)
@@ -1161,11 +1532,12 @@ class TransformerLM:
         the usual [B] row vector with B=1).  Returns (cache, last_logits
         [S, vocab], last_hidden [S, E]).
         """
-        if self.is_mla or self.has_ssm:
+        if self.is_mla or self.has_ssm or self.kinds is not None:
             raise NotImplementedError(
                 "segment-packed prefill is not implemented for MLA "
-                "attention nor for a state-space mixer; the engine "
-                "batches such fresh prompts on the batch axis instead")
+                "attention, for a state-space mixer, nor for layers "
+                "that name their attention kind; the engine batches "
+                "such fresh prompts on the batch axis instead")
         x = self._embed(params, tokens)
         x, cache = self._run_layers(
             params, cache, x, "prefill_packed", positions=positions,

@@ -348,9 +348,12 @@ def moe_mlp(x: jax.Array, p: dict, arch: ModelArch) -> jax.Array:
     T, E = x.shape
     X = arch.num_experts
     k = arch.num_experts_per_tok
+    if arch.expert_shards != 1:
+        raise NotImplementedError("the dense expert path holds every "
+                                  "expert; a share of them is served by "
+                                  "moe_mlp_ragged")
     logits = (x.astype(jnp.float32) @ p["router"].astype(jnp.float32))  # [T, X]
-    weights, idx = jax.lax.top_k(logits, k)                             # [T, k]
-    weights = jax.nn.softmax(weights, axis=-1)
+    idx, weights = route_tokens(logits, arch, p.get("router_bias"))           # [T, k]
     # scatter top-k weights back to a dense [T, X] routing matrix
     route = jnp.zeros((T, X), jnp.float32)
     route = route.at[jnp.arange(T)[:, None], idx].set(weights)
@@ -383,64 +386,204 @@ def moe_mlp(x: jax.Array, p: dict, arch: ModelArch) -> jax.Array:
     return y
 
 
-def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch) -> jax.Array:
-    """Token-choice MoE via grouped (ragged) matmuls.
+def route_tokens(logits: jax.Array, arch: ModelArch,
+          bias: Optional[jax.Array] = None):
+    """The router's choice, for every scoring this repo serves:
+    ``logits`` [T, X] float32 -> (idx [T, k] int32, weights [T, k]
+    float32).  Scores are the softmax or the sigmoid of the logits over
+    ALL experts; the k experts with the largest score are chosen, with
+    the correction ``bias`` (one float an expert) added to choose and
+    never to weigh; the chosen scores are normalized to sum to one and
+    scaled by ``routed_scaling_factor``.  (Softmax scores normalized
+    over the chosen k are the softmax over the chosen logits: what
+    mixtral and deepseek-v2 publish.)"""
+    if arch.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choose = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(choose, arch.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if arch.routed_scaling_factor != 1.0:
+        weights = weights * arch.routed_scaling_factor
+    return idx, weights
 
-    Tokens sort by assigned expert and each expert runs one matmul over
-    its contiguous group (``lax.ragged_dot`` — XLA's grouped-GEMM,
-    megablox-style on TPU).  FLOPs scale with top_k instead of the
-    expert count, unlike the dense fallback in :func:`moe_mlp`.
-    Serving-path implementation; training keeps the dense form.
+
+# (m, k, n) tiles of the grouped-matmul kernel: a held expert's matrix
+# is read in 2 MiB tiles, once a call whatever the rows it gets
+_GMM_TILE_K = 2048
+_GMM_TILE_N = 512
+
+
+def _grouped_matmul(lhs: jax.Array, w, group_sizes: jax.Array,
+                    expert_of_row: jax.Array, kernel: bool,
+                    layer=None) -> jax.Array:
+    """``lhs[rows of group g] @ w[g]`` for each group, float32 out.
+    Rows past ``sum(group_sizes)`` belong to no group: what they hold
+    on return is undefined, and the caller masks them.  ``kernel``:
+    the Pallas grouped matmul (an expert with no row is not visited
+    and its matrix is not read); otherwise, and for quantized stacks,
+    XLA's ``ragged_dot``.  With ``layer``, ``w`` is the whole stack of
+    a layer kind, [layers, experts, in, out], and ``layer`` the index
+    into it: the kernel then reads its tiles straight out of the stack
+    (every other layer's experts are groups of no rows), where a slice
+    handed to a custom call would be a copy of the layer's matrices."""
+    from kaito_tpu.engine.quant import (dequant_weight, is_qtensor,
+                                        qtensor_kind)
+
+    if layer is not None:
+        if kernel and not is_qtensor(w):
+            from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+            n_layers, held = w.shape[:2]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n_layers * held,), jnp.int32),
+                group_sizes.astype(jnp.int32), (layer * held,))
+            return gmm(lhs, w.reshape((n_layers * held,) + w.shape[2:]),
+                       sizes, preferred_element_type=jnp.float32,
+                       tiling=_gmm_tile(lhs, w))
+        w = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, layer, 0, keepdims=False), w)
+    if is_qtensor(w):
+        # int8's convert fuses into the grouped GEMM's RHS load and each
+        # row's output scales by its expert's per-out-channel scale;
+        # int4 dequants the stack first (per-group scales don't fold
+        # post-dot across groups — same trade as expert_dot in moe_mlp)
+        if qtensor_kind(w) == "int4":
+            return jax.lax.ragged_dot(
+                lhs, dequant_weight(w, lhs.dtype), group_sizes,
+                preferred_element_type=jnp.float32)
+        out = jax.lax.ragged_dot(lhs, w["q8"].astype(lhs.dtype),
+                                 group_sizes,
+                                 preferred_element_type=jnp.float32)
+        return out * w["scale"][expert_of_row].astype(out.dtype)
+    if kernel:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        return gmm(lhs, w, group_sizes.astype(jnp.int32),
+                   preferred_element_type=jnp.float32,
+                   tiling=_gmm_tile(lhs, w))
+    return jax.lax.ragged_dot(lhs, w, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+_GMM_TILE_M = 128
+
+
+def _gmm_rows(rows: int) -> int:
+    """The rows the grouped-matmul kernel is handed for ``rows`` sorted
+    pairs: a whole number of its row tiles (the kernel refuses any
+    other count), 128 rows a tile, 16 for a handful of pairs."""
+    tile = _GMM_TILE_M if rows >= _GMM_TILE_M else 16
+    return -(-rows // tile) * tile
+
+
+def _gmm_tile(lhs: jax.Array, w: jax.Array) -> tuple:
+    return (min(lhs.shape[0], _GMM_TILE_M), min(w.shape[-2], _GMM_TILE_K),
+            min(w.shape[-1], _GMM_TILE_N))
+
+
+def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
+                   valid: Optional[jax.Array] = None, kernel: bool = False,
+                   with_stats: bool = False, layer=None):
+    """Token-choice MoE via grouped matmuls over the experts HELD here.
+
+    x: [T, E].  The router scores all ``arch.num_experts``; the
+    parameters hold the ``arch.experts_held`` experts of this chip's
+    share (all of them when ``expert_shards`` is 1).  The (token,
+    expert) pairs whose expert is held sort by expert to the front and
+    each held expert runs one matmul over its contiguous group
+    (``_grouped_matmul``); a pair whose expert lives on another chip
+    adds nothing here, and a token none of whose experts is held gets a
+    zero.  The sorted pairs are computed ``cap`` rows a pass, as many
+    passes as the held pairs need: every pair when the layer is whole,
+    twice the even share of a layer that ``expert_shards`` chips share
+    (one pass unless routing sends this share more than twice its
+    due), so no pair is dropped whatever the routing.  ``valid`` [T]
+    bool leaves a row's pairs out (a slot that decodes nothing, a
+    prompt's padding).  FLOPs scale with the pairs held, not the expert
+    count.  Decode and prefill use this function.
+
+    ``layer``: the expert stacks of ``p`` are a layer kind's whole
+    stacks and this the layer's index into them (``_grouped_matmul``).
+
+    ``with_stats``: also returns int32 [4]: held experts (one call
+    each), held experts that got a pair, pairs held, pairs routed.
     """
     T, E = x.shape
-    X = arch.num_experts
     k = arch.num_experts_per_tok
+    held = arch.experts_held
+    lo = arch.expert_shard * held
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    weights, idx = jax.lax.top_k(logits, k)            # [T, k]
-    weights = jax.nn.softmax(weights, axis=-1)
+    idx, weights = route_tokens(logits, arch, p.get("router_bias"))
 
-    flat_expert = idx.reshape(-1)                      # [T*k]
-    order = jnp.argsort(flat_expert)                   # stable
-    token_of = order // k                              # originating token
-    x_sorted = x[token_of]                             # [T*k, E]
-    group_sizes = jnp.bincount(flat_expert, length=X)
-    expert_of_row = flat_expert[order]                 # [T*k]
+    local = idx - lo                                   # [T, k]
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here &= valid[:, None]
+    key = jnp.where(here, local, held).reshape(-1)     # held = not here
+    order = jnp.argsort(key)                           # stable
+    group_sizes = jnp.bincount(key, length=held + 1)[:held]
+    n_here = jnp.sum(group_sizes)
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    flat_w = weights.reshape(-1)
+    pairs = T * k
+    # each pair's place in the sorted order
+    place = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    cap = pairs if arch.expert_shards == 1 else min(
+        pairs, max(256, 2 * pairs // arch.expert_shards))
+    n_rows = _gmm_rows(cap) if kernel else cap
 
-    def ragged(lhs, w):
-        """ragged_dot accepting a plain stack or a QTensor: int8's
-        convert fuses into the grouped GEMM's RHS load and each row's
-        output scales by its expert's per-out-channel scale; int4
-        dequants the stack first (per-group scales don't fold post-dot
-        across groups — same trade as expert_dot in moe_mlp)."""
-        from kaito_tpu.engine.quant import (dequant_weight, is_qtensor,
-                                            qtensor_kind)
+    def one_pass(i, y):
+        """Sorted pairs [i*cap, (i+1)*cap) through the held experts,
+        added to ``y`` [T, E] float32 at their tokens."""
+        at = i * cap
+        slot = jnp.arange(n_rows, dtype=jnp.int32)
+        live = (slot < cap) & (at + slot < n_here)
+        rows = order[jnp.minimum(at + slot, pairs - 1)]
+        sizes = jnp.clip(ends, at, at + cap) - jnp.clip(starts, at, at + cap)
+        x_sorted = x[rows // k]                        # [n_rows, E]
+        expert_of_row = jnp.minimum(key[rows], held - 1)
 
-        if is_qtensor(w):
-            if qtensor_kind(w) == "int4":
-                return jax.lax.ragged_dot(
-                    lhs, dequant_weight(w, lhs.dtype), group_sizes,
-                    preferred_element_type=jnp.float32)
-            out = jax.lax.ragged_dot(lhs, w["q8"].astype(lhs.dtype),
-                                     group_sizes,
-                                     preferred_element_type=jnp.float32)
-            return out * w["scale"][expert_of_row].astype(out.dtype)
-        return jax.lax.ragged_dot(lhs, w, group_sizes,
-                                  preferred_element_type=jnp.float32)
+        def grouped(lhs, w):
+            return _grouped_matmul(lhs, w, sizes, expert_of_row, kernel,
+                                   layer)
 
-    gate = ragged(x_sorted, p["experts_gate"])
-    up = ragged(x_sorted, p["experts_up"])
-    h = (activation(gate, arch.hidden_act) * up).astype(x.dtype)
-    out_sorted = ragged(h, p["experts_down"])
+        with jax.named_scope("moe_experts"):
+            gate = grouped(x_sorted, p["experts_gate"])
+            up = grouped(x_sorted, p["experts_up"])
+            h = jnp.where(live[:, None], activation(gate, arch.hidden_act)
+                          * up, 0.0).astype(x.dtype)
+            out = grouped(h, p["experts_down"])
+        out = jnp.where(live[:, None], out * flat_w[rows][:, None], 0.0)
+        # back to the tokens by each pair's place, summed in float32:
+        # a token's k pairs one after the other ([T, E] a time)
+        rel = (place - at).reshape(T, k)
+        for j in range(k):
+            r = rel[:, j]
+            y = y + jnp.where(((r >= 0) & (r < cap))[:, None],
+                              out[jnp.clip(r, 0, n_rows - 1)], 0.0)
+        return y
 
-    w_sorted = weights.reshape(-1)[order]
-    y = jnp.zeros((T, E), jnp.float32).at[token_of].add(
-        out_sorted * w_sorted[:, None])
+    y = jnp.zeros((T, E), jnp.float32)
+    if cap == pairs:
+        y = one_pass(0, y)
+    else:
+        y = jax.lax.fori_loop(0, -(-n_here // cap), one_pass, y)
     y = y.astype(x.dtype)
     if "shared_gate" in p:
         shared = {"gate": p["shared_gate"], "up": p["shared_up"],
                   "down": p["shared_down"]}
         y = y + mlp(x, shared, arch)
-    return y
+    if not with_stats:
+        return y
+    routed = (T if valid is None else jnp.sum(valid)) * k
+    stats = jnp.stack([jnp.int32(held), jnp.sum(group_sizes > 0),
+                       n_here, routed]).astype(jnp.int32)
+    return y, stats
 
 
 def softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:

@@ -46,7 +46,14 @@ per-layer slice is ever materialized (feeding per-layer slices through
 the scan cost more than the kernel itself).
 
 Supports GQA (grouped queries), sliding windows (traced per-layer
-window sizes from the model's scan flags), and gemma-2 logit softcap.
+window sizes from the model's scan flags), gemma-2 logit softcap,
+values narrower than keys (the output has the values' size) and a sink
+bias a query head (``sink``: one more column of the softmax that takes
+probability and carries no value).  With a window a row starts at the
+page that holds the first position inside it: pages wholly behind the
+window are neither copied nor computed, and their table entries are
+never read, so a window kind's freed pages may be the null page
+(docs/kv-cache.md).
 The pure-JAX fallback in kaito_tpu.engine.attention implements the same
 contract; tests compare the two in interpreter mode and on-chip.
 """
@@ -93,20 +100,27 @@ def _decode_kernel(
     # inputs
     q_ref,             # [1, H, D] VMEM (pre-scaled)
     cols_ref,          # [H, ps*Hkv] VMEM (_score_columns), fetched once
-    k_hbm,             # [Lg, P, ps*Hkv, D] ANY/HBM (full group stack)
-    v_hbm,
-    # quantized mode only: [Lg, P, 1, ps*Hkv] fp32 dequant rows, then
-    # outputs + scratch (+[N_BUF, 1, ps*Hkv] scale ring / extra sems)
+    # with a sink only: [H, 1] fp32 VMEM, fetched once; then
+    # k_hbm [Lg, P, ps*Hkv, D] and v_hbm [Lg, P, ps*Hkv, Dv] ANY/HBM
+    # (full group stack); quantized mode only: [Lg, P, 1, ps*Hkv] fp32
+    # dequant rows; then outputs + scratch (+[N_BUF, 1, ps*Hkv] scale
+    # ring / extra sems)
     *rest,
     page_size: int,
     softcap: Optional[float],
     quantized: bool,
+    has_sink: bool,
 ):
+    sink_ref = None
+    if has_sink:
+        sink_ref, *rest = rest
+    k_hbm, v_hbm, *rest = rest
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, sems, ring, row_pages,
-         row_next, ks_buf, vs_buf, ssems) = rest
+         row_next, row_first, ks_buf, vs_buf, ssems) = rest
     else:
-        o_ref, k_buf, v_buf, sems, ring, row_pages, row_next = rest
+        (o_ref, k_buf, v_buf, sems, ring, row_pages, row_next,
+         row_first) = rest
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssems = None
 
     b = pl.program_id(0)
@@ -133,22 +147,27 @@ def _decode_kernel(
         # start the copies of the cursor's page, if rows are left, and
         # move the cursor on: to the row's next page, or to page 0 of
         # the next row that has any (B when none has)
+        r = jnp.minimum(row, B - 1)
+
         @pl.when(row < B)
         def _():
-            for c in page_copies(slot, page_tables_ref[row, page]):
+            for c in page_copies(slot,
+                                 page_tables_ref[row, row_first[r] + page]):
                 c.start()
-        r = jnp.minimum(row, B - 1)
         done = page + 1 >= row_pages[r]
         return (jnp.where(done, row_next[r], row),
                 jnp.where(done, 0, page + 1))
 
     @pl.when(b == 0)
     def _cold_start():
-        # pages a row holds and the next row that holds any, once a
-        # call: the page loop then never divides or searches
+        # the pages a row reads (from the one that holds the first
+        # position inside the window) and the next row that reads any,
+        # once a call: the page loop then never divides or searches
         def fill(i, live):
             r = B - 1 - i
-            n = pl.cdiv(lengths_ref[r], page_size)
+            first = jnp.maximum(lengths_ref[r] - window, 0) // page_size
+            n = pl.cdiv(lengths_ref[r], page_size) - first
+            row_first[r] = first
             row_pages[r] = n
             row_next[r] = live
             return jnp.where(n > 0, r, live)
@@ -165,8 +184,10 @@ def _decode_kernel(
     # and the cursor of the next page to ask for
     head = ring[0]
     n_pages = row_pages[b]
+    first = row_first[b]
     q2 = q_ref[0]                                  # [H, D]
     H, D = q2.shape
+    Dv = v_buf.shape[-1]
 
     def body(p, carry):
         m, l, acc, row, page = carry
@@ -189,10 +210,11 @@ def _decode_kernel(
             s = s * ks_buf[slot]                   # [1, ps*Hkv] broadcast
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        # a column's position is p*ps + t: compare t against the row's
-        # bounds moved by the page's start (two scalar subtractions)
+        # a column's position is (first + p)*ps + t: compare t against
+        # the row's bounds moved by the page's start (two scalar
+        # subtractions)
         t = cols_ref[...]
-        end = length - p * page_size
+        end = length - (first + p) * page_size
         valid = (t < end) & (t >= end - window)
         s = jnp.where(valid, s, NEG_INF)
 
@@ -214,12 +236,19 @@ def _decode_kernel(
 
     m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((H, 1), jnp.float32)
-    acc0 = jnp.zeros((H, D), jnp.float32)
+    acc0 = jnp.zeros((H, Dv), jnp.float32)
     m, l, acc, row, page = jax.lax.fori_loop(
         0, n_pages, body, (m0, l0, acc0, ring[1], ring[2]))
     ring[0] = (head + n_pages) & (N_BUF - 1)
     ring[1] = row
     ring[2] = page
+    if has_sink:
+        # the sink's column: probability and no value
+        sink = sink_ref[...]                       # [H, 1]
+        m_new = jnp.maximum(m, sink)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.exp(sink - m_new)
+        acc = acc * alpha
     # a row of length 0 ran no page: acc 0 over the floor is 0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -237,7 +266,7 @@ def _scoped(fn):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "softcap", "interpret"))
+    static_argnames=("scale", "softcap", "interpret", "kv_heads"))
 @_scoped
 def paged_decode_attention_pallas(
     q: jax.Array,            # [B, H, D]
@@ -253,9 +282,13 @@ def paged_decode_attention_pallas(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,   # [P, Hkv] / [Lg, P, Hkv] fp32
     v_scale: Optional[jax.Array] = None,
+    sink: Optional[jax.Array] = None,      # [H] fp32 sink bias a head
+    kv_heads: Optional[int] = None,        # the pools are token-flat,
+                                           # [(Lg,) P, ps*kv_heads, D]
 ) -> jax.Array:
     B, H, D = q.shape
     quantized = k_scale is not None
+    has_sink = sink is not None
     if layer is None:
         cache_k = cache_k[None]
         cache_v = cache_v[None]
@@ -263,28 +296,42 @@ def paged_decode_attention_pallas(
             k_scale = k_scale[None]
             v_scale = v_scale[None]
         layer = jnp.zeros((), jnp.int32)
-    Lg, P, ps, Hkv, _ = cache_k.shape
-    # token-flat page view [Lg, P, ps*Hkv, D]: free reshape, and the
-    # page DMA plus both kernel dots run on it without any relayout
-    ck_flat = cache_k.reshape(Lg, P, ps * Hkv, D)
-    cv_flat = cache_v.reshape(Lg, P, ps * Hkv, D)
+    Dv = cache_v.shape[-1]
+    if kv_heads is not None:
+        # stored as the kernel reads them: no view to take
+        Lg, P, rows, _ = cache_k.shape
+        ps, Hkv = rows // kv_heads, kv_heads
+        ck_flat, cv_flat = cache_k, cache_v
+    else:
+        Lg, P, ps, Hkv, _ = cache_k.shape
+        # token-flat page view [Lg, P, ps*Hkv, D]: free reshape, and the
+        # page DMA plus both kernel dots run on it without any relayout
+        ck_flat = cache_k.reshape(Lg, P, ps * Hkv, D)
+        cv_flat = cache_v.reshape(Lg, P, ps * Hkv, Dv)
     q_scaled = q * scale
 
     # the head-match mask and page-row index depend on shapes alone: a
     # constant of the program, one block for every grid step
-    operands = [q_scaled, _score_columns(H, Hkv, ps), ck_flat, cv_flat]
+    operands = [q_scaled, _score_columns(H, Hkv, ps)]
+    const_specs = [pl.BlockSpec((H, ps * Hkv), lambda b, *_: (0, 0))]
+    if has_sink:
+        operands.append(sink.astype(jnp.float32).reshape(H, 1))
+        const_specs.append(pl.BlockSpec((H, 1), lambda b, *_: (0, 0)))
+    operands += [ck_flat, cv_flat]
     cache_specs = [
         pl.BlockSpec(memory_space=pltpu.ANY),
         pl.BlockSpec(memory_space=pltpu.ANY),
     ]
     scratch = [
         pltpu.VMEM((N_BUF, ps * Hkv, D), cache_k.dtype),
-        pltpu.VMEM((N_BUF, ps * Hkv, D), cache_v.dtype),
+        pltpu.VMEM((N_BUF, ps * Hkv, Dv), cache_v.dtype),
         pltpu.SemaphoreType.DMA((N_BUF, 2)),
         # carried from one grid step (row) to the next: the ring's head
         # slot and the (row, page) cursor of the next page to copy; the
-        # pages each row holds; the next row that holds any
+        # pages each row reads; the next row that reads any; the first
+        # page each row reads
         pltpu.SMEM((3,), jnp.int32),
+        pltpu.SMEM((B,), jnp.int32),
         pltpu.SMEM((B,), jnp.int32),
         pltpu.SMEM((B,), jnp.int32),
     ]
@@ -312,19 +359,19 @@ def paged_decode_attention_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
-                  pl.BlockSpec((H, ps * Hkv), lambda b, *_: (0, 0))]
-        + cache_specs,
-        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+        in_specs=[pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0))]
+        + const_specs + cache_specs,
+        out_specs=pl.BlockSpec((1, H, Dv), lambda b, *_: (b, 0, 0)),
         scratch_shapes=scratch,
     )
 
     kernel = functools.partial(_decode_kernel, page_size=ps,
-                               softcap=softcap, quantized=quantized)
+                               softcap=softcap, quantized=quantized,
+                               has_sink=has_sink)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

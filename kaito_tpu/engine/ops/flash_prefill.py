@@ -8,7 +8,10 @@ kernel walks K blocks with online softmax, skipping blocks entirely
 above the causal diagonal.
 
 Same contract as engine.attention.prefill_attention (GQA, true_len,
-sliding window, softcap); tests compare the two in interpreter mode.
+sliding window, softcap, values narrower than keys, a sink bias a
+head); tests compare the two in interpreter mode.  With a window the
+walk starts at the K block that holds the first position any query of
+the block can see: blocks wholly behind the window are not computed.
 """
 
 from __future__ import annotations
@@ -35,8 +38,8 @@ DEFAULT_BLOCK_K = 128
 _KV_VMEM_BUDGET = 12 << 20
 
 
-def _check_kv_fits_vmem(T: int, D: int, dtype) -> None:
-    need = 4 * T * D * jnp.dtype(dtype).itemsize
+def _check_kv_fits_vmem(T: int, D: int, dtype, Dv: Optional[int] = None) -> None:
+    need = 2 * T * (D + (D if Dv is None else Dv)) * jnp.dtype(dtype).itemsize
     if need > _KV_VMEM_BUDGET:
         raise ValueError(
             f"flash prefill keeps a head's K and V in VMEM: a {T}-token "
@@ -47,14 +50,18 @@ def _check_kv_fits_vmem(T: int, D: int, dtype) -> None:
 def _flash_kernel(
     true_len_ref,      # [B] SMEM (scalar prefetch)
     window_ref,        # [1] SMEM
-    q_ref,             # [1, 1, Bq, D] VMEM (pre-scaled)
-    k_ref,             # [1, 1, T, D] VMEM
-    v_ref,             # [1, 1, T, D] VMEM
-    o_ref,             # [1, 1, Bq, D] VMEM
-    *,
+    # with a sink only: [H] fp32 SMEM (scalar prefetch); then
+    # q_ref [1, 1, Bq, D] VMEM (pre-scaled), k_ref [1, 1, T, D],
+    # v_ref [1, 1, T, Dv], o_ref [1, 1, Bq, Dv]
+    *rest,
     block_k: int,
     softcap: Optional[float],
+    has_sink: bool,
 ):
+    sink_ref = None
+    if has_sink:
+        sink_ref, *rest = rest
+    q_ref, k_ref, v_ref, o_ref = rest
     b = pl.program_id(0)
     qi = pl.program_id(2)
     true_len = true_len_ref[b]
@@ -62,9 +69,11 @@ def _flash_kernel(
 
     q = q_ref[0, 0]                          # [Bq, D]
     Bq, D = q.shape
-    T = k_ref.shape[2]
+    Dv = v_ref.shape[3]
     q_start = qi * Bq
     num_k_blocks = pl.cdiv(jnp.minimum(q_start + Bq, true_len), block_k)
+    # the block's first query sees no position before q_start - window + 1
+    first_k_block = jnp.maximum(q_start - window + 1, 0) // block_k
 
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (Bq, 1), 0)
 
@@ -93,8 +102,16 @@ def _flash_kernel(
 
     m0 = jnp.full((Bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((Bq, 1), jnp.float32)
-    acc0 = jnp.zeros((Bq, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_k_blocks, body, (m0, l0, acc0))
+    acc0 = jnp.zeros((Bq, Dv), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(first_k_block, num_k_blocks, body,
+                                  (m0, l0, acc0))
+    if has_sink:
+        # the sink's column: probability and no value
+        sink = sink_ref[pl.program_id(1)]
+        m_new = jnp.maximum(m, sink)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.exp(sink - m_new)
+        acc = acc * alpha
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -248,16 +265,19 @@ def flash_prefill_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    sink: Optional[jax.Array] = None,      # [H] fp32 sink bias a head
 ) -> jax.Array:
     B, T, H, D = q.shape
     Hkv = k.shape[2]
+    Dv = v.shape[3]
     G = H // Hkv
+    has_sink = sink is not None
     bq = min(block_q, T)
     bk = min(block_k, T)
     if T % bq or T % bk:
         raise ValueError(f"chunk length {T} must be a multiple of the "
                          f"block sizes ({bq}, {bk})")
-    _check_kv_fits_vmem(T, D, k.dtype)
+    _check_kv_fits_vmem(T, D, k.dtype, Dv)
     grid = (B, H, T // bq)
 
     # Head-major [B, H, T, D] layout so every block's trailing two dims
@@ -267,23 +287,27 @@ def flash_prefill_attention(
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
 
+    prefetch = [true_len, jnp.reshape(window, (1,))]
+    if has_sink:
+        prefetch.append(sink.astype(jnp.float32).reshape(H))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, t, *_: (b, h, t, 0)),
             pl.BlockSpec((1, 1, T, D), lambda b, h, t, *_: (b, h // G, 0, 0)),
-            pl.BlockSpec((1, 1, T, D), lambda b, h, t, *_: (b, h // G, 0, 0)),
+            pl.BlockSpec((1, 1, T, Dv), lambda b, h, t, *_: (b, h // G, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, t, *_: (b, h, t, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, Dv), lambda b, h, t, *_: (b, h, t, 0)),
     )
-    kernel = functools.partial(_flash_kernel, block_k=bk, softcap=softcap)
+    kernel = functools.partial(_flash_kernel, block_k=bk, softcap=softcap,
+                               has_sink=has_sink)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, T, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(true_len, jnp.reshape(window, (1,)), qt, kt, vt)
+    )(*prefetch, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

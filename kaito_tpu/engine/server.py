@@ -2126,6 +2126,9 @@ def load_config_file(cfg: EngineConfig, path: str) -> EngineConfig:
         "sequence-parallel-size": "sequence_parallel",
         "sequence_parallel_size": "sequence_parallel",
         "page-size": "page_size", "page_size": "page_size",
+        # vLLM's name for the prefill chunk budget a step
+        "max-num-batched-tokens": "max_prefill_tokens",
+        "max_num_batched_tokens": "max_prefill_tokens",
         "dtype": "dtype", "kv-cache-dtype": "kv_dtype",
         "quantization": "quantization",
         "seed": "seed", "port": "port",

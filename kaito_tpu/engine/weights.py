@@ -138,7 +138,7 @@ def assemble_params(model: TransformerLM,
         layer_map.update(_GEMMA_OVERRIDES)
 
     for g in model.groups:
-        specs = model._layer_specs(g.moe)
+        specs = model._layer_specs(g.moe, g.kind)
         stack: dict[str, list] = {}
         for li in range(g.start, g.start + g.count):
             fused_qkv = None

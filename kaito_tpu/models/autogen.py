@@ -35,6 +35,7 @@ SUPPORTED_ARCHITECTURES = {
     "FalconForCausalLM",
     "FalconH1ForCausalLM",
     "GptOssForCausalLM",
+    "MiMoV2ForCausalLM",
 }
 
 
@@ -193,11 +194,93 @@ def arch_from_hf_config(cfg: Mapping) -> ModelArch:
                 float(x) for x in cfg.get("mlp_multipliers", (1.0, 1.0))),
         )
 
+    if model_type == "mimo_v2":
+        kw.update(_mimo_v2_fields(cfg, layers))
+
     if model_type == "phi":
         kw.update(gated_mlp=False, parallel_residual=True, norm_type="layernorm",
                   linear_bias=True)
 
     return ModelArch(**kw)
+
+
+def _mimo_v2_fields(cfg: Mapping, layers: int) -> dict:
+    """MiMo-V2.5's language model: window and full attention layers
+    with their own head counts (``hybrid_layer_pattern``), a sink bias
+    on the window layers, values narrower than keys, and a
+    sigmoid-routed expert layer (``moe_layer_freq``) of which this
+    chip may hold a share (``expert_shards`` / ``expert_shard``, this
+    repo's keys: ``n_routed_experts`` then counts the experts held).
+    What is not implemented is refused by name."""
+    def refuse(what):
+        raise ValueError(f"mimo_v2: {what} is not implemented")
+
+    pattern = tuple(int(x) for x in cfg.get("hybrid_layer_pattern") or ())
+    freq = tuple(int(x) for x in cfg.get("moe_layer_freq") or ())
+    if len(pattern) != layers or len(freq) != layers:
+        raise ValueError(
+            f"mimo_v2: hybrid_layer_pattern ({len(pattern)}) and "
+            f"moe_layer_freq ({len(freq)}) must name each of the "
+            f"{layers} layers")
+    if set(pattern) - {0, 1} or set(freq) - {0, 1}:
+        refuse("a layer kind other than 0 and 1")
+    if cfg.get("hybrid_block_size") is not None:
+        refuse("hybrid_block_size")
+    if bool(cfg.get("attention_bias", False)):
+        refuse("attention_bias")
+    if int(cfg.get("n_group") or 1) != 1 or int(cfg.get("topk_group") or 1) != 1:
+        refuse("group-limited routing (n_group, topk_group above 1)")
+    if cfg.get("n_shared_experts"):
+        refuse("shared experts")
+    if str(cfg.get("scoring_func", "sigmoid")) != "sigmoid":
+        refuse(f"scoring_func {cfg.get('scoring_func')!r}")
+    if str(cfg.get("topk_method", "noaux_tc")) != "noaux_tc":
+        refuse(f"topk_method {cfg.get('topk_method')!r}")
+    if not bool(cfg.get("norm_topk_prob", True)):
+        refuse("norm_topk_prob false")
+    scaling = cfg.get("rope_scaling") or {}
+    if str(scaling.get("rope_type", scaling.get("type", "default"))) \
+            != "default":
+        refuse(f"rope_scaling {scaling!r}")
+    window = int(_first(cfg, "sliding_window", "sliding_window_size",
+                        default=0))
+    if any(pattern) and window <= 0:
+        refuse("window layers without sliding_window")
+    shards = int(cfg.get("expert_shards", 1))
+    shard = int(cfg.get("expert_shard", 0))
+    held = int(cfg.get("n_routed_experts", 0))
+    if not 0 <= shard < shards:
+        raise ValueError(f"mimo_v2: expert_shard {shard} of {shards}")
+    return dict(
+        rms_norm_eps=float(_first(cfg, "layernorm_epsilon", "rms_norm_eps",
+                                  default=1e-5)),
+        rope_scaling=None,
+        qkv_bias=False,
+        sliding_window=window or None,
+        v_head_dim=int(_first(cfg, "v_head_dim", default=0)) or None,
+        layer_attention=pattern,
+        layer_experts=freq,
+        swa_num_heads=int(_first(cfg, "swa_num_attention_heads",
+                                 "num_attention_heads")),
+        swa_num_kv_heads=int(_first(cfg, "swa_num_key_value_heads",
+                                    "num_key_value_heads")),
+        swa_head_dim=int(_first(cfg, "swa_head_dim", "head_dim")),
+        swa_v_head_dim=int(_first(cfg, "swa_v_head_dim", "v_head_dim",
+                                  "head_dim")),
+        swa_rope_theta=float(_first(cfg, "swa_rope_theta", default=10000.0)),
+        swa_sink=bool(cfg.get("add_swa_attention_sink_bias", False)),
+        full_sink=bool(cfg.get("add_full_attention_sink_bias", False)),
+        attention_value_scale=(float(cfg["attention_value_scale"])
+                               if cfg.get("attention_value_scale") else None),
+        num_experts=held * shards,
+        num_experts_per_tok=int(cfg.get("num_experts_per_tok", 0)),
+        moe_intermediate_size=_first(cfg, "moe_intermediate_size"),
+        router_scoring="sigmoid",
+        router_bias=True,
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
+        expert_shards=shards,
+        expert_shard=shard,
+    )
 
 
 # Parser-mode derivation for generated presets (the reference's

@@ -18,6 +18,17 @@ from dataclasses import dataclass, field, replace
 from typing import Optional
 
 
+def stored_key_dim(head_dim: int) -> int:
+    """Lanes a key's head is stored and multiplied at.  A head wider
+    than one 128-lane tile and not a whole number of them lies in whole
+    tiles of HBM whatever its logical shape (the TPU's (8, 128) tiling),
+    and the Pallas kernels copy whole tiles, so the pools say what they
+    hold: 192 is stored as 256, the pad zero (docs/kv-cache.md)."""
+    if head_dim <= 128 or head_dim % 128 == 0:
+        return head_dim
+    return -(-head_dim // 128) * 128
+
+
 class AttentionKind(str, enum.Enum):
     """Attention family — drives the KV bytes/token formula (reference:
     ``presets/workspace/generator/generator.go:620`` calculateKVCacheTokenSize)."""
@@ -111,6 +122,70 @@ class ModelArch:
     mlp_multipliers: Optional[tuple] = None   # (gate pre-activation, down)
     lm_head_multiplier: Optional[float] = None
 
+    # layers of more than one kind in one model (mimo_v2: docs/kv-cache.md,
+    # "Two kinds of page").  ``layer_attention[l]`` is 0 for a full layer
+    # (num_heads / num_kv_heads / head_dim / v_head_dim / rope_theta
+    # above) and 1 for a window layer, which has its own head counts and
+    # sizes, its own rope theta, a causal window of ``sliding_window``
+    # positions and, with ``swa_sink``, a learnable sink bias a head;
+    # ``layer_experts[l]`` is 0 for a dense FFN and 1 for an expert
+    # layer.  None: every layer is of the one kind the fields above give.
+    layer_attention: Optional[tuple] = None
+    layer_experts: Optional[tuple] = None
+    swa_num_heads: int = 0
+    swa_num_kv_heads: int = 0
+    swa_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    swa_sink: bool = False
+    full_sink: bool = False
+    attention_value_scale: Optional[float] = None   # v scaled before attention
+    # the router: "softmax" (mixtral, deepseek-v2) or "sigmoid" scores;
+    # a correction bias a expert that is added to choose the experts and
+    # never to weigh them (deepseek-v3's noaux_tc)
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    # the chip's share of an expert layer: ``expert_shards`` chips share
+    # each layer by experts and this is share ``expert_shard`` of them.
+    # The router keeps ``num_experts`` outputs; the parameters hold the
+    # ``experts_held`` experts [expert_shard * held, (expert_shard+1) * held)
+    expert_shards: int = 1
+    expert_shard: int = 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts // max(self.expert_shards, 1)
+
+    @property
+    def two_kind_cache(self) -> bool:
+        """Window layers with a page pool and a page table of their own."""
+        return bool(self.layer_attention) and any(self.layer_attention)
+
+    def attention_layers(self, kind: int) -> int:
+        """How many layers are of attention kind ``kind`` (0 full, 1 window)."""
+        if self.layer_attention is None:
+            return self.num_layers if kind == 0 else 0
+        return sum(1 for k in self.layer_attention if k == kind)
+
+    def kv_page_geometry(self, kind: int) -> tuple:
+        """(layers, kv heads, k head dim, v head dim) of kind ``kind``'s
+        page pool."""
+        if kind == 1:
+            return (self.attention_layers(1), self.swa_num_kv_heads,
+                    self.swa_head_dim, self.swa_v_head_dim or self.swa_head_dim)
+        return (self.attention_layers(0), self.num_kv_heads, self.head_dim,
+                self.v_head_dim or self.head_dim)
+
+    def kv_bytes_per_token_kind(self, kind: int, dtype_bytes: int = 2,
+                                stored: bool = False) -> int:
+        """Bytes one cached token holds across the layers of one kind;
+        ``stored``: as the pool lays it out (``stored_key_dim``)."""
+        layers, heads, dk, dv = self.kv_page_geometry(kind)
+        if stored:
+            dk = stored_key_dim(dk)
+        return layers * heads * (dk + dv) * dtype_bytes
+
     @property
     def ssm_inner(self) -> int:
         """Width of the mixer's x and z streams (d_ssm)."""
@@ -174,6 +249,8 @@ class ModelArch:
                 + (self.kv_lora_rank or 0) * self.num_heads * ((self.qk_nope_head_dim or 0) + (self.v_head_dim or 0))
                 + self.num_heads * (self.v_head_dim or 0) * h
             )
+        elif self.layer_attention is not None:
+            return self._param_count_by_kind(embed)
         else:
             attn = h * self.num_heads * self.head_dim + 2 * h * self.num_kv_heads * self.head_dim + self.num_heads * self.head_dim * h
         if self.num_experts > 0:
@@ -196,6 +273,33 @@ class ModelArch:
         return (embed + self.num_layers * (attn + mixer) + mlp_total
                 + norms)
 
+    def _param_count_by_kind(self, embed: int) -> int:
+        """Parameters HELD here by a model whose layers are of more than
+        one kind: each layer's attention by its kind, a dense FFN or the
+        held experts and the router's full width, two norms a layer."""
+        h = self.hidden_size
+        total = embed + h
+        experts = self.layer_experts or (0,) * self.num_layers
+        for kind, moe in zip(self.layer_attention, experts):
+            if kind:
+                H, Hkv = self.swa_num_heads, self.swa_num_kv_heads
+                dk, dv = self.swa_head_dim, self.swa_v_head_dim or self.swa_head_dim
+                sink = H if self.swa_sink else 0
+            else:
+                H, Hkv = self.num_heads, self.num_kv_heads
+                dk, dv = self.head_dim, self.v_head_dim or self.head_dim
+                sink = H if self.full_sink else 0
+            total += h * H * dk + h * Hkv * (dk + dv) + H * dv * h + sink
+            if moe:
+                inter = self.moe_intermediate_size or self.intermediate_size
+                total += 3 * h * inter * self.experts_held \
+                    + h * self.num_experts \
+                    + (self.num_experts if self.router_bias else 0)
+            else:
+                total += 3 * h * self.intermediate_size
+            total += 2 * h
+        return total
+
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         """KV-cache bytes per token across all layers.
 
@@ -206,6 +310,12 @@ class ModelArch:
         if self.attention_kind == AttentionKind.MLA:
             per_layer = (self.kv_lora_rank or 0) + (self.qk_rope_head_dim or 0)
             return self.num_layers * per_layer * dtype_bytes
+        if self.layer_attention is not None:
+            # what a token holds while every layer still holds it: a
+            # window layer's share goes back to its pool once the token
+            # is a window behind (docs/kv-cache.md)
+            return (self.kv_bytes_per_token_kind(0, dtype_bytes)
+                    + self.kv_bytes_per_token_kind(1, dtype_bytes))
         return 2 * self.num_layers * self.num_kv_heads * self.head_dim * dtype_bytes
 
 

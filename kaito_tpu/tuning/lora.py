@@ -45,7 +45,7 @@ def add_lora_params(model: TransformerLM, params: dict, cfg: LoraConfig,
     out = dict(params)
     for g in model.groups:
         stack = dict(params[g.name])
-        specs = model._layer_specs(g.moe)
+        specs = model._layer_specs(g.moe, g.kind)
         for t in cfg.targets:
             if t not in specs:
                 continue
