@@ -5,8 +5,11 @@ Hkv=8, D=128, page 64, bf16) next to the pure-JAX function it must
 match.  The kernels are compiled for the chip (never interpreted), so
 this needs a TPU; the interpreter is covered by
 tests/test_pallas_ops.py.  All test data is generated ON DEVICE with
-jax.random.  It times nothing: a kernel's time and roofline share come
-from the benchmark's trace (kbench/, PERF.md section 3).
+jax.random.  A kernel's time in a served step and its roofline share
+come from the benchmark's trace (kbench/, PERF.md section 3); each row
+here carries ``call_ms``, the host's clock round the case called alone
+(the wrapper's transposes included), which is what a kernel change is
+measured by before a cell is run and is no benchmark metric.
 
 Usage:  python benchmarks/kernel_bench.py --parity
 
@@ -23,6 +26,7 @@ import json
 import math
 import os
 import sys
+import time
 from typing import Callable, Optional
 
 # make `python benchmarks/kernel_bench.py` work from anywhere (the
@@ -85,6 +89,16 @@ def parity(case: KernelCase) -> dict:
     # show in the next call of the same executable
     again = compiled(*case.args)
     repeats = bool(jnp.array_equal(out, again))
+    # the host's clock round five more calls, whatever the wrapper does
+    # around the kernel (a transpose, a gather) included: what a change
+    # to a kernel is first measured by, alone, before any cell is run;
+    # no benchmark metric
+    jax.block_until_ready(again)
+    t0 = time.perf_counter()
+    for _ in range(5):
+        again = compiled(*case.args)
+    jax.block_until_ready(again)
+    call_ms = (time.perf_counter() - t0) / 5 * 1e3
     diff = jnp.abs(_f32(out) - _f32(ref))
     if case.mask is not None:
         diff = diff * case.mask
@@ -96,7 +110,7 @@ def parity(case: KernelCase) -> dict:
              or not bool(jnp.any(_f32(out) * (1.0 - case.mask))))
     return {"name": case.name,
             "ok": finite and repeats and zeros and err <= case.tol,
-            "repeats": repeats,
+            "repeats": repeats, "call_ms": round(call_ms, 4),
             "max_err": round(err, 6), "tol": case.tol,
             "relative": case.relative, "finite": finite,
             "shape": list(out.shape), "why": case.why}
@@ -169,29 +183,69 @@ def decode_case(int8_kv: bool = False) -> KernelCase:
         work=live_rows * 2 + live_pages * 2 * HKV * 4)
 
 
-def prefill_case() -> KernelCase:
+# flash prefill's geometries: (name, B, T, H, Hkv, D, Dv, window, sink,
+# true_len).  phi-4-mini's, ragged; then MiMo-V2.5's two kinds at its
+# longest bucket (64 query heads on 4 and on 8 KV heads, keys stored at
+# 256 lanes, values of 128; the window kind with its window of 128 and
+# a sink bias a head): 16 and 8 query heads stacked on each key block,
+# and 1,536 rows of padding whose query blocks the kernel walks past
+# and writes as zeros.
+PREFILL_GEOMETRIES = {
+    "flash_prefill": (4, 1024, H, HKV, D, D, None, False,
+                      (1024, 768, 127, 1)),
+    "flash_prefill_gqa16": (1, 4096, 64, 4, 256, 128, None, False, (2560,)),
+    "flash_prefill_window_sink": (1, 4096, 64, 8, 256, 128, 128, True,
+                                  (2560,)),
+}
+
+
+def prefill_case(name: str = "flash_prefill") -> KernelCase:
     from kaito_tpu.engine.attention import prefill_attention
     from kaito_tpu.engine.ops.flash_prefill import flash_prefill_attention
 
-    B, T = 4, 1024
-    scale = D ** -0.5
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(kq, (B, T, H, D), jnp.bfloat16)
-    k = jax.random.normal(kk, (B, T, HKV, D), jnp.bfloat16)
-    v = jax.random.normal(kv, (B, T, HKV, D), jnp.bfloat16)
-    tl = jnp.asarray([T, T * 3 // 4, 127, 1], jnp.int32)
-    win = jnp.asarray(BIG_WINDOW, jnp.int32)
-    # rows past true_len are padding the engine never reads
+    B, T, Hq, Hkv, Dk, Dv, window, sink_on, lens = PREFILL_GEOMETRIES[name]
+    G = Hq // Hkv
+    scale = Dk ** -0.5
+    kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q = jax.random.normal(kq, (B, T, Hq, Dk), jnp.bfloat16)
+    k = jax.random.normal(kk, (B, T, Hkv, Dk), jnp.bfloat16)
+    v = jax.random.normal(kv, (B, T, Hkv, Dv), jnp.bfloat16)
+    tl = jnp.asarray(lens, jnp.int32)
+    win = jnp.asarray(window or BIG_WINDOW, jnp.int32)
+    # near log(window), where a sink takes a real share of a row
+    sink = (math.log(128.0) + jax.random.normal(ks, (Hq,), jnp.float32)
+            if sink_on else None)
+    # rows past true_len are padding the engine never reads; where they
+    # fill whole query blocks (true_len a multiple of every tile) the
+    # kernel owes zeros
     mask = (jnp.arange(T)[None, :, None, None]
             < tl[:, None, None, None]).astype(jnp.float32)
+
+    def reference(q, k, v, tl):
+        # a KV head at a time: the [G, T, T] float32 scores of all 64
+        # heads at once are 4 GiB
+        def one(h):
+            qh = jax.lax.dynamic_slice_in_dim(q, h * G, G, axis=2)
+            kh = jax.lax.dynamic_slice_in_dim(k, h, 1, axis=2)
+            vh = jax.lax.dynamic_slice_in_dim(v, h, 1, axis=2)
+            sh = (jax.lax.dynamic_slice_in_dim(sink, h * G, G)
+                  if sink_on else None)
+            return prefill_attention(qh, kh, vh, scale=scale, true_len=tl,
+                                     sliding_window=window, sink=sh)
+        out = jax.lax.map(one, jnp.arange(Hkv))      # [Hkv, B, T, G, Dv]
+        return out.transpose(1, 2, 0, 3, 4).reshape(B, T, Hq, Dv)
+
+    live = float(sum(n * (n + 1) // 2 if window is None else
+                     sum(min(p + 1, window) for p in range(n))
+                     for n in lens))
     return KernelCase(
-        "flash_prefill",
+        name,
         lambda q, k, v, tl: flash_prefill_attention(
-            q, k, v, tl, win, scale=scale),
-        lambda q, k, v, tl: prefill_attention(
-            q, k, v, scale=scale, true_len=tl),
+            q, k, v, tl, win, scale=scale, sink=sink),
+        reference,
         (q, k, v, tl), ATTN_TOL, ATTN_WHY, mask=mask,
-        unit="causal FLOPs", work=4.0 * B * H * D * T * T / 2)
+        zero_where_masked=all(n % 512 == 0 for n in lens),
+        unit="live causal FLOPs", work=2.0 * Hq * (Dk + Dv) * live)
 
 
 def packed_case() -> KernelCase:
@@ -316,6 +370,9 @@ CASES: dict[str, Callable[[], KernelCase]] = {
     "decode_bf16": decode_case,
     "decode_int8kv": lambda: decode_case(int8_kv=True),
     "flash_prefill": prefill_case,
+    "flash_prefill_gqa16": lambda: prefill_case("flash_prefill_gqa16"),
+    "flash_prefill_window_sink":
+        lambda: prefill_case("flash_prefill_window_sink"),
     "flash_prefill_packed": packed_case,
     "gemv_int8": lambda: gemv_case("int8"),
     "gemv_int8_prefetch": lambda: gemv_case("int8", prefetch=True),

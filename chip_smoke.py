@@ -64,10 +64,14 @@ EXPECT = {
 # the kernels on the default bf16 serving path; every other kernel must
 # match too, but these three are what the serve leg just ran
 MAIN_PATH_KERNELS = ("decode_bf16", "flash_prefill", "flash_prefill_packed")
-# and the decode kernel of a model with a state-space mixer beside
-# attention (its state pool's rows, empty ones included), which the
-# serve leg's model does not run
-REQUIRED_KERNELS = MAIN_PATH_KERNELS + ("ssm_state_update",)
+# and what the serve leg's model does not run: the decode kernel of a
+# model with a state-space mixer beside attention (its state pool's
+# rows, empty ones included), and flash prefill at MiMo-V2.5's two
+# geometries (16 and 8 query heads stacked on a KV head's key blocks,
+# keys wider than values, a window with a sink, whole query blocks of
+# padding written as zeros)
+REQUIRED_KERNELS = MAIN_PATH_KERNELS + (
+    "ssm_state_update", "flash_prefill_gqa16", "flash_prefill_window_sink")
 
 
 class SmokeError(Exception):
