@@ -106,6 +106,7 @@ class DataParallelEngine:
         self.phase_hists = first.phase_hists
         # handler threads use only its annotate (no shared totals)
         self.phases = first.phases
+        self.compile_totals = first.compile_totals      # process-wide
         self._rr = 0
         self._lock = threading.Lock()
         logger.info("data-parallel serving: %d groups x %d device(s)",

@@ -69,13 +69,18 @@ logger = logging.getLogger(__name__)
 # So the loop freezes the heap when it goes idle after new programs
 # were compiled (``InferenceEngine._settle_heap``), and from then on a
 # pass that still holds the lock for long is named in the log.
+# Compiles are counted where they happen, with their seconds
+# (kaito:engine_compiles_total, kaito:engine_compile_seconds_total; a
+# step that compiled carries both on its timeline record).
 _COMPILES = [0]
+_COMPILE_SECONDS = [0.0]
 _GC_STARTED = [0.0]
 
 
-def _count_compile(name: str, _secs: float, **_kw) -> None:
+def _count_compile(name: str, secs: float, **_kw) -> None:
     if name == "/jax/core/compile/backend_compile_duration":
         _COMPILES[0] += 1
+        _COMPILE_SECONDS[0] += secs
 
 
 def _watch_gc(phase: str, info: dict) -> None:
@@ -905,8 +910,25 @@ class InferenceEngine:
                  "Per step: prefill dispatch, first-token sampling and "
                  "admission to decode"),
                 ("loop_stall", "loop_stall",
-                 "Per step: wall less thread CPU time over the phases "
-                 "that never block on the device"))}
+                 "Per step: wall less thread CPU time over schedule, "
+                 "both dispatches and the replay"),
+                # parts of a phase (PhaseClock.part), decode and
+                # prefill summed
+                ("host.args", "dispatch_args",
+                 "Per step: inside the dispatches, everything before "
+                 "the jitted call: host arrays, uploads"),
+                ("host.launch", "launch",
+                 "Per step: the jitted calls themselves, call to return"),
+                ("launch_stall", "launch_stall",
+                 "Per step: wall less thread CPU time inside the jitted "
+                 "calls: held by the runtime or waiting for the "
+                 "interpreter lock"),
+                ("host.plan", "decode_plan",
+                 "Per step: the planning loops over the slots before a "
+                 "decode launch"),
+                ("replay_stall", "replay_stall",
+                 "Per step: wall less thread CPU time inside the replay "
+                 "alone: the interpreter lock or the OS"))}
         # prefill scheduling (docs/prefill.md): sequences per packed
         # dispatch, or per turn of the serial scheduler, and
         # staged-to-first-dispatch wait — the two numbers that say
@@ -979,7 +1001,6 @@ class InferenceEngine:
                   else jax.default_backend() != "cpu")
         self.async_dispatch = (bool(ad) and self.pp_exec is None
                                and jax.process_count() == 1)
-        self.dispatch_gap_hist = None
         # drains of a window in flight, by what forced them; the
         # step's own go on its timeline record
         self.drain_counts: dict[str, int] = {}
@@ -1007,12 +1028,6 @@ class InferenceEngine:
             # launch: the host's work for that one overlaps this one
             self.counters["decode_windows_primed_total"] = 0
             self.counters["decode_windows_unprimed_total"] = 0
-            self.dispatch_gap_hist = Histogram(
-                "kaito:engine_dispatch_gap_seconds",
-                "Host-side gap between decode dispatches (device idle "
-                "between windows; ~0 when the pipeline is primed)", None,
-                buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
-                         0.01, 0.025, 0.05, 0.1, 0.25))
             logger.info("async decode dispatch enabled (two-deep "
                         "pipeline, device-resident loop state)")
         # device-resident state mirrors: host numpy stays authoritative
@@ -1025,8 +1040,6 @@ class InferenceEngine:
         # and each slot's owner (slot.seq) when the window was launched
         self._inflight: Optional[list] = None
         self._dirty_reason = ""
-        self._last_ready_t = 0.0
-        self._gap_last = 0.0
         # fused-dispatch argument caches (built for both loops): the
         # stop matrix is epoch-keyed (stop sets are per-request
         # immutable, so batch membership is the only invalidation) and
@@ -2385,6 +2398,12 @@ class InferenceEngine:
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
 
+    @staticmethod
+    def compile_totals() -> tuple[int, float]:
+        """Programs this process has compiled (or fetched from the
+        compile cache) and the seconds that took, since its start."""
+        return _COMPILES[0], _COMPILE_SECONDS[0]
+
     def _settle_heap(self) -> None:
         """The loop is idle.  If programs were compiled since it last
         was, move every live object to the permanent generation: what
@@ -2764,32 +2783,43 @@ class InferenceEngine:
                   c["preemptions_total"], c["requests_expired_total"],
                   c["requests_shed_total"])
         freed0 = c["window_pages_freed_total"]
+        compiles0, compile_s0 = _COMPILES[0], _COMPILE_SECONDS[0]
+        tick = self._tick
         t0 = time.monotonic()
-        with self.phases.phase("engine.step", n=self._tick,
+        with self.phases.phase("engine.step", n=tick,
                                rows=self.num_running):
             did = self._step_inner()
-        seconds, stall = self.phases.flush()
+        seconds, stall, stalled = self.phases.flush()
         if did:
             wall = time.monotonic() - t0
             self.step_hist.observe(wall)
             seconds["loop_stall"] = stall
+            seconds["launch_stall"] = stalled.get("host.launch", 0.0)
+            seconds["replay_stall"] = stalled.get("engine.decode.replay", 0.0)
             for phase, hist in self.phase_hists.items():
                 hist.observe(seconds.get(phase, 0.0))
             # the same seconds on the record, engine.step left out
-            # (it is the record's own dur)
-            extra = {name.removeprefix("engine."): round(sec, 6)
-                     for name, sec in seconds.items()
-                     if name != "engine.step"}
-            if self.async_dispatch:
-                # per-dispatch gap span (docs/decode-loop.md): host-side
-                # idle between the previous window's readback and this
-                # step's dispatch; ~0 whenever the pipeline was primed
-                extra["dispatch_gap"] = round(self._gap_last, 6)
-                self._gap_last = 0.0
-                if self._step_drains:
-                    # what took the pipeline to depth 1 in this step
-                    extra["drain"] = ",".join(self._step_drains)
-                    self._step_drains.clear()
+            # (it is the record's own dur); a part or a stall of its
+            # own only where there was one
+            extra = {name.removeprefix("engine.").removeprefix("host."):
+                     round(sec, 6) for name, sec in seconds.items()
+                     if name != "engine.step" and (
+                         sec or name not in ("launch_stall", "replay_stall"))}
+            compiled = _COMPILES[0] - compiles0
+            if compiled:
+                extra["compiles"] = compiled
+                extra["compile_s"] = round(
+                    _COMPILE_SECONDS[0] - compile_s0, 6)
+                if self._heap_settled_at >= 0:
+                    # the warm-up is over: a program first met now holds
+                    # every stream for as long as it compiles
+                    logger.warning("compiled %d program(s) in %.2f s inside "
+                                   "step %d", compiled, extra["compile_s"],
+                                   tick)
+            if self.async_dispatch and self._step_drains:
+                # what took the pipeline to depth 1 in this step
+                extra["drain"] = ",".join(self._step_drains)
+                self._step_drains.clear()
             if self._prefill_pack_note:
                 # largest prefill pack dispatched this step — the
                 # /debug/timeline annotation for packed rounds
@@ -2852,15 +2882,21 @@ class InferenceEngine:
             if self._advance_imports():
                 did = True
             decoding = bool(self.active.any())
-            spec = decoding and self._spec_ok()
-            if decoding and not spec:
-                la2 = self._plan_decode(la, did)
+            # this loop plans before engine.decode opens: the span
+            # carries the depth the plan chose
+            spec = False
+            if decoding:
+                with self.phases.part("host.plan"):
+                    spec = self._spec_ok()
+                    if not spec:
+                        la2 = self._plan_decode(la, did)
         steps_run = 0
         if spec:
             with phase("engine.decode", rows=self.num_running):
                 steps_run = self._decode_speculative()
             if not steps_run:
-                with phase("engine.schedule"):
+                with phase("engine.schedule"), \
+                        self.phases.part("host.plan"):
                     la2 = self._plan_decode(la, did)
         if decoding:
             if not steps_run:
@@ -3429,37 +3465,40 @@ class InferenceEngine:
                 # next step's window reads; a later chunk reads the
                 # window before it from the pages too
                 self._window_sync(i, pos + m if pos == 0 else pos, pos + m)
+            part = self.phases.part
             with self.phases.phase("engine.prefill.dispatch"):
-                ctoks = np.zeros((1, bucket), np.int32)
-                ctoks[0, :m] = chunk
-                aid = jnp.asarray(self.slot_adapters[i:i + 1])
-                # a copy: the CPU backend may alias a numpy buffer, and
-                # a window table's entries change right after the
-                # dispatch (_window_sync), before the program has run
-                args = (self.params, self.cache, jnp.asarray(ctoks),
-                        jnp.asarray([m], np.int32),
-                        jnp.asarray(self.page_tables[i][None].copy()))
-                FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
-                if use_cp:
-                    fn = self._prefill_cp_fn(bucket)
-                    self.cache, logits = fn(*args, aid)
-                elif pos == 0 and (m == n or self.two_kinds):
-                    # a whole fresh prompt; with two kinds of page also
-                    # the first chunk of a longer one, which attends
-                    # over itself: its window table holds the chunk's
-                    # tail alone
-                    fn = self._prefill_fn(bucket)
-                    self.cache, logits = fn(*args, aid,
-                                            self._state_rows([i]))
-                else:
-                    # chunk attends over the paged history (cached
-                    # prefix + earlier chunks) — bounds per-step latency
-                    # for long prompts (the feature vLLM gives the
-                    # reference)
-                    fn = self._prefill_ctx_fn(bucket)
-                    self.cache, logits = fn(
-                        *args, jnp.asarray([pos], np.int32), aid,
-                        self._state_rows([i]))
+                with part("host.args"):
+                    ctoks = np.zeros((1, bucket), np.int32)
+                    ctoks[0, :m] = chunk
+                    aid = jnp.asarray(self.slot_adapters[i:i + 1])
+                    # a copy: the CPU backend may alias a numpy buffer,
+                    # and a window table's entries change right after
+                    # the dispatch (_window_sync), before the program
+                    # has run
+                    args = (jnp.asarray(ctoks), jnp.asarray([m], np.int32),
+                            jnp.asarray(self.page_tables[i][None].copy()))
+                    FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
+                    if use_cp:
+                        fn = self._prefill_cp_fn(bucket)
+                        args += (aid,)
+                    elif pos == 0 and (m == n or self.two_kinds):
+                        # a whole fresh prompt; with two kinds of page
+                        # also the first chunk of a longer one, which
+                        # attends over itself: its window table holds
+                        # the chunk's tail alone
+                        fn = self._prefill_fn(bucket)
+                        args += (aid, self._state_rows([i]))
+                    else:
+                        # chunk attends over the paged history (cached
+                        # prefix + earlier chunks) — bounds per-step
+                        # latency for long prompts (the feature vLLM
+                        # gives the reference)
+                        fn = self._prefill_ctx_fn(bucket)
+                        args += (jnp.asarray([pos], np.int32), aid,
+                                 self._state_rows([i]))
+                with part("host.launch"):
+                    self.cache, logits = fn(self.params, self.cache, *args)
+                del args
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
             self._fail_prefill(i, e)
@@ -3586,16 +3625,23 @@ class InferenceEngine:
         completed = []   # (slot_idx, n, logits, row)
         for gk, rows in groups:
             t0 = time.monotonic()
+            part = self.phases.part
             try:
                 with self.phases.phase("engine.prefill.dispatch"):
-                    if gk[0] == "seg" and len(rows) > 1:
-                        logits = self._dispatch_prefill_packed(rows)
-                    elif gk[0] == "ctx":
-                        logits = self._dispatch_prefill_ctx(rows)
-                    else:
-                        # single fresh prompt or MLA fresh bucket: the
-                        # serial scheduler's own jitted family, batched
-                        logits = self._dispatch_prefill_fresh(rows)
+                    with part("host.args"):
+                        if gk[0] == "seg" and len(rows) > 1:
+                            fn, args = self._prefill_packed_args(rows)
+                        elif gk[0] == "ctx":
+                            fn, args = self._prefill_ctx_args(rows)
+                        else:
+                            # single fresh prompt or MLA fresh bucket:
+                            # the serial scheduler's own jitted family,
+                            # batched
+                            fn, args = self._prefill_fresh_args(rows)
+                    with part("host.launch"):
+                        self.cache, logits = fn(self.params, self.cache,
+                                                *args)
+                    del args
             except Exception as e:
                 logger.exception("prefill dispatch failed (%d slots)",
                                  len(rows))
@@ -3651,16 +3697,19 @@ class InferenceEngine:
         bucket = self._bucket(n)
         t0 = time.monotonic()
         try:
+            part = self.phases.part
             with self.phases.phase("engine.prefill.dispatch"):
-                ctoks = np.zeros((1, bucket), np.int32)
-                ctoks[0, :n] = slot.prefill_tokens
-                aid = jnp.asarray(self.slot_adapters[i:i + 1])
-                FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
-                fn = self._prefill_cp_fn(bucket)
-                self.cache, logits = fn(
-                    self.params, self.cache, jnp.asarray(ctoks),
-                    jnp.asarray([n], np.int32),
-                    jnp.asarray(self.page_tables[i][None]), aid)
+                with part("host.args"):
+                    ctoks = np.zeros((1, bucket), np.int32)
+                    ctoks[0, :n] = slot.prefill_tokens
+                    aid = jnp.asarray(self.slot_adapters[i:i + 1])
+                    FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
+                    fn = self._prefill_cp_fn(bucket)
+                    args = (jnp.asarray(ctoks), jnp.asarray([n], np.int32),
+                            jnp.asarray(self.page_tables[i][None]), aid)
+                with part("host.launch"):
+                    self.cache, logits = fn(self.params, self.cache, *args)
+                del args
         except Exception as e:
             logger.exception("prefill failed for %s", req.req_id)
             self._fail_prefill(i, e)
@@ -3684,11 +3733,12 @@ class InferenceEngine:
         self._complete_prefills([(i, n)], logits)
         return True
 
-    def _dispatch_prefill_fresh(self, rows):
+    def _prefill_fresh_args(self, rows):
         """Batch-axis dispatch of fresh-complete prompts sharing one
         bucket: tokens [B, bucket] with per-row true_lens/page tables —
         `model.prefill` was already row-wise, the serial scheduler just
-        never passed B > 1."""
+        never passed B > 1.  Returns the program and its arguments
+        behind (params, cache); the caller launches it."""
         bucket = self._bucket(max(n for (_, _, _, n) in rows))
         B = len(rows)
         ctoks = np.zeros((B, bucket), np.int32)
@@ -3700,17 +3750,15 @@ class InferenceEngine:
             tls[j] = n
             pts[j] = self.page_tables[i]
             aids[j] = self.slot_adapters[i]
-        fn = self._prefill_fn(bucket)
-        self.cache, logits = fn(self.params, self.cache,
-                                jnp.asarray(ctoks), jnp.asarray(tls),
-                                jnp.asarray(pts), jnp.asarray(aids),
-                                self._state_rows([r[0] for r in rows]))
-        return logits
+        return self._prefill_fn(bucket), (
+            jnp.asarray(ctoks), jnp.asarray(tls), jnp.asarray(pts),
+            jnp.asarray(aids), self._state_rows([r[0] for r in rows]))
 
-    def _dispatch_prefill_ctx(self, rows):
+    def _prefill_ctx_args(self, rows):
         """Batch-axis dispatch of context chunks sharing one bucket:
         per-row start_pos, each chunk attending over its own paged
-        history (cached prefix + earlier chunks)."""
+        history (cached prefix + earlier chunks).  Returns the program
+        and its arguments, as _prefill_fresh_args does."""
         bucket = self._bucket(max(take for (_, _, take, _) in rows))
         B = len(rows)
         ctoks = np.zeros((B, bucket), np.int32)
@@ -3724,20 +3772,18 @@ class InferenceEngine:
             sps[j] = pos
             pts[j] = self.page_tables[i]
             aids[j] = self.slot_adapters[i]
-        fn = self._prefill_ctx_fn(bucket)
-        self.cache, logits = fn(self.params, self.cache,
-                                jnp.asarray(ctoks), jnp.asarray(tls),
-                                jnp.asarray(pts), jnp.asarray(sps),
-                                jnp.asarray(aids),
-                                self._state_rows([r[0] for r in rows]))
-        return logits
+        return self._prefill_ctx_fn(bucket), (
+            jnp.asarray(ctoks), jnp.asarray(tls), jnp.asarray(pts),
+            jnp.asarray(sps), jnp.asarray(aids),
+            self._state_rows([r[0] for r in rows]))
 
-    def _dispatch_prefill_packed(self, rows):
+    def _prefill_packed_args(self, rows):
         """Sequence-axis segment packing: concatenate S fresh prompts
         (same adapter) into ONE padded row with per-token segment ids,
         positions and page targets, so short prompts share one bucket's
         MXU work instead of each padding a batch-1 row (docs/prefill.md).
-        Returns last-token logits [S, V] in pack order."""
+        Returns the program, whose last-token logits [S, V] are in pack
+        order, and its arguments, as _prefill_fresh_args does."""
         ps = self.cfg.page_size
         total = sum(take for (_, _, take, _) in rows)
         T = self._bucket(total)
@@ -3772,15 +3818,12 @@ class InferenceEngine:
                 pg += npg
             last_idx[si] = off + take - 1
             off += take
-        fn = self._prefill_packed_fn()
         aid = jnp.asarray(self.slot_adapters[rows[0][0]:rows[0][0] + 1])
-        self.cache, logits = fn(
-            self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(segs), jnp.asarray(poss),
+        return self._prefill_packed_fn(), (
+            jnp.asarray(toks), jnp.asarray(segs), jnp.asarray(poss),
             jnp.asarray(tok_pages), jnp.asarray(last_idx),
             jnp.asarray(pack_pages) if int8 else None,
             jnp.asarray(tok_pgslot) if int8 else None, aid)
-        return logits
 
     # what makes the host read a completed prefill's first token back
     # at once (docs/decode-loop.md): it must see the token before the
@@ -3858,12 +3901,17 @@ class InferenceEngine:
             carry = tuple(st[f] for f in self._CARRY_FIELDS)
         # the blocking path's span is the wait it ends in, as it was
         # when the sample was read back inside it
+        part = self.phases.part
         with self.phases.phase("engine.prefill.dispatch" if why is None
                                else "engine.prefill.wait"):
             t0 = time.monotonic()
-            carry, key, tok, lp = _first_token_step(
-                jnp.asarray(logits), self.sampling, carry, jnp.asarray(rows),
-                counts, seen, None if grows is None else jnp.asarray(grows))
+            with part("host.args"):
+                args = (jnp.asarray(logits), self.sampling, carry,
+                        jnp.asarray(rows), counts, seen,
+                        None if grows is None else jnp.asarray(grows))
+            with part("host.launch"):
+                carry, key, tok, lp = _first_token_step(*args)
+            del args
             self.sampling = dataclasses.replace(self.sampling, key=key)
             if why is not None:
                 # blocks on the logits, behind any window in flight
@@ -3912,6 +3960,9 @@ class InferenceEngine:
             with self.phases.phase("engine.prefill.resolve"):
                 toks, lps = (np.asarray(a).tolist() for a in (tok, lp))
                 self._land_first_tokens(staged, toks, lps, t0, join=False)
+                # the two device arrays die here, inside the span that
+                # read them, never between phases (see _decode_once)
+                del tok, lp
             did = True
         return did
 
@@ -4457,16 +4508,21 @@ class InferenceEngine:
     def _launch_decode_once(self) -> list:
         """Dispatch one decode step; returns its device outputs
         [next_tokens, lps]."""
-        counts_in, seen = self._penalty_args()
-        gmask, gtrans, gstate = self._grammar_args()
-        cache, sampling, counts, next_tokens, lps, stats = self._decode_fn(
-            self.params, self.cache, self.sampling, counts_in, seen,
-            jnp.asarray(self.last_tokens),
-            jnp.asarray(self.positions),
-            jnp.asarray(self.page_tables),
-            jnp.asarray(self.active),
-            jnp.asarray(self.slot_adapters),
-            gmask, gtrans, gstate)
+        part = self.phases.part
+        with part("host.args"):
+            counts_in, seen = self._penalty_args()
+            gmask, gtrans, gstate = self._grammar_args()
+            args = (counts_in, seen,
+                    jnp.asarray(self.last_tokens),
+                    jnp.asarray(self.positions),
+                    jnp.asarray(self.page_tables),
+                    jnp.asarray(self.active),
+                    jnp.asarray(self.slot_adapters),
+                    gmask, gtrans, gstate)
+        with part("host.launch"):
+            cache, sampling, counts, next_tokens, lps, stats = \
+                self._decode_fn(self.params, self.cache, self.sampling, *args)
+        del args
         self.cache = cache
         self.sampling = sampling
         if self.token_counts is not None:
@@ -4580,25 +4636,31 @@ class InferenceEngine:
         fn = self._decode_multi_fns.get(K)
         if fn is None:
             fn = self._decode_multi_fns[K] = self._build_decode_multi_fn(K)
-        stop_dev = self._stop_matrix()
-        counts_in, seen = self._penalty_args()
-        gmask, gtrans, gstate = self._grammar_args()
-        cache, sampling, counts, toks, acts, lps, stats = fn(
-            self.params, self.cache, self.sampling, counts_in, seen,
-            jnp.asarray(self.last_tokens),
-            jnp.asarray(self.positions),
-            jnp.asarray(self.page_tables),
-            jnp.asarray(self.active),
-            jnp.asarray(self.slot_adapters),
-            stop_dev,
-            jnp.asarray(self._remaining),
-            gmask, gtrans, gstate)
+        part = self.phases.part
+        with part("host.args"):
+            stop_dev = self._stop_matrix()
+            counts_in, seen = self._penalty_args()
+            gmask, gtrans, gstate = self._grammar_args()
+            args = (counts_in, seen,
+                    jnp.asarray(self.last_tokens),
+                    jnp.asarray(self.positions),
+                    jnp.asarray(self.page_tables),
+                    jnp.asarray(self.active),
+                    jnp.asarray(self.slot_adapters),
+                    stop_dev,
+                    jnp.asarray(self._remaining),
+                    gmask, gtrans, gstate)
+            owners = self._slot_owners()
+        with part("host.launch"):
+            cache, sampling, counts, toks, acts, lps, stats = fn(
+                self.params, self.cache, self.sampling, *args)
+        del args
         self.cache = cache
         self.sampling = sampling
         if self.token_counts is not None:
             self.token_counts = counts
         self.counters["decode_steps_total"] += K
-        return [K, toks, acts, lps, self._slot_owners(), stats]
+        return [K, toks, acts, lps, owners, stats]
 
     def _count_moe_stats(self, stats) -> None:
         """Add a decode program's expert-layer counters ([held experts
@@ -4717,8 +4779,9 @@ class InferenceEngine:
         row = np.asarray(
             [slot_idx, self.last_tokens[slot_idx], self.positions[slot_idx],
              self._remaining[slot_idx], self._gram_state[slot_idx]], np.int32)
-        st.update(zip(self._CARRY_FIELDS, _patch_carry_row(
-            *(st[f] for f in self._CARRY_FIELDS), row)))
+        with self.phases.part("host.launch"):
+            st.update(zip(self._CARRY_FIELDS, _patch_carry_row(
+                *(st[f] for f in self._CARRY_FIELDS), row)))
         self.counters["h2d_uploads_total"] += 1
 
     def _stop_matrix(self):
@@ -4783,7 +4846,6 @@ class InferenceEngine:
         with self.phases.phase("engine.decode.wait", **attrs):
             # blocks until the readback lands
             host = [np.asarray(a) for a in win[1:4]]
-        self._last_ready_t = time.monotonic()
         with self.phases.phase("engine.decode.replay"):
             self._count_moe_stats(win[5])
             self._replay_window(win[0], *host, win[4])
@@ -4869,23 +4931,27 @@ class InferenceEngine:
         primed = self._inflight is not None
         self.counters["decode_windows_primed_total" if primed
                       else "decode_windows_unprimed_total"] += 1
+        part = self.phases.part
         with self.phases.phase("engine.decode.dispatch"):
-            stop_dev = self._stop_matrix()
-            state = self._device_state()
-            counts_in, seen = self._penalty_args()
-            gmask, gtrans, _ = self._grammar_args()
-            t_dispatch = time.monotonic()
-            # device-idle gap: only the unprimed case exposes latency —
-            # a primed pipeline has window N still running while we are
-            # here
-            gap = (max(0.0, t_dispatch - self._last_ready_t)
-                   if not primed and self._last_ready_t else 0.0)
-            cache, sampling, counts, toks, acts, lps, stats, carry = fn(
-                self.params, self.cache, self.sampling, counts_in, seen,
-                state["last_tokens"], state["positions"],
-                state["page_tables"], state["active"],
-                state["slot_adapters"], stop_dev, state["left"],
-                gmask, gtrans, state["gstate"])
+            with part("host.args"):
+                stop_dev = self._stop_matrix()
+                state = self._device_state()
+                counts_in, seen = self._penalty_args()
+                gmask, gtrans, _ = self._grammar_args()
+                owners = self._slot_owners()
+            with part("host.launch"):
+                cache, sampling, counts, toks, acts, lps, stats, carry = fn(
+                    self.params, self.cache, self.sampling, counts_in, seen,
+                    state["last_tokens"], state["positions"],
+                    state["page_tables"], state["active"],
+                    state["slot_adapters"], stop_dev, state["left"],
+                    gmask, gtrans, state["gstate"])
+            # the launch's temporaries die here, not when this function
+            # returns behind the previous window's replay (see
+            # _decode_once): released there, each gave the lock to the
+            # handler threads the replay had just woken, 20 ms an
+            # iteration of engine.decode outside every child at 96 slots
+            del counts_in, seen, gmask, gtrans
             self.cache = cache
             self.sampling = sampling
             if self.token_counts is not None:
@@ -4895,11 +4961,8 @@ class InferenceEngine:
                                    active=act, left=left, gstate=gst)
             self._start_readback(toks, acts, lps, stats)
             self.counters["decode_steps_total"] += K
-        self._gap_last = gap
-        if self.dispatch_gap_hist is not None:
-            self.dispatch_gap_hist.observe(gap)
-        prev, self._inflight = self._inflight, [K, toks, acts, lps,
-                                                self._slot_owners(), stats]
+        prev, self._inflight = self._inflight, [K, toks, acts, lps, owners,
+                                                stats]
         if prev is not None:
             self._retire_window(prev)
 
@@ -4956,13 +5019,21 @@ class InferenceEngine:
             decoding = bool(self.active.any())
         steps_run = 0
         if decoding:
+            part = self.phases.part
             with phase("engine.decode", rows=self.num_running):
-                if self._needs_sync_decode():
+                # the planning before a launch: Python loops over the
+                # slots.  A drain inside a part nests its own
+                # decode.wait and decode.replay spans there, as one
+                # inside engine.schedule does
+                with part("host.plan"):
+                    sync = self._needs_sync_decode()
+                    spec = not sync and self._spec_ok()
+                if sync:
                     self._drain_pipeline("sync_decode")
                     self._decode_once()
                     self._mark_state_dirty()
                     steps_run = 1
-                elif self._spec_ok():
+                elif spec:
                     # speculation windows depend on each window's
                     # accepted length — inherently depth-1, but it still
                     # reads the reconciled host mirrors
@@ -4974,16 +5045,18 @@ class InferenceEngine:
                     self.counters["decode_windows_unprimed_total"] += 1
                     did = True
                 elif bool(self.active.any()):
-                    la2 = self._decode_lookahead()
-                    pend = self._inflight[0] \
-                        if self._inflight is not None else 0
-                    while la2 > 1 and not self._lookahead_fits(la2 + pend):
-                        la2 //= 2
-                    if pend and not self._lookahead_fits(la2 + pend):
-                        self._drain_pipeline("page_pressure")
-                        pend = 0
-                    if did or la2 + pend > la:
-                        self._ensure_decode_pages(la2 + pend)
+                    with part("host.plan"):
+                        la2 = self._decode_lookahead()
+                        pend = self._inflight[0] \
+                            if self._inflight is not None else 0
+                        while la2 > 1 \
+                                and not self._lookahead_fits(la2 + pend):
+                            la2 //= 2
+                        if pend and not self._lookahead_fits(la2 + pend):
+                            self._drain_pipeline("page_pressure")
+                            pend = 0
+                        if did or la2 + pend > la:
+                            self._ensure_decode_pages(la2 + pend)
                     self._decode_async(la2)
                     steps_run = la2
                     did = True

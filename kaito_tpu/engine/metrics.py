@@ -369,6 +369,14 @@ class EngineMetrics:
             "serialise, write", r,
             buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
                      0.005, 0.01, 0.025, 0.05, 0.1, 0.25))
+        # what those tokens cost the interpreter: thread CPU seconds of
+        # the streaming handlers, added once a stream at the moment its
+        # chunk seconds are (server._chunk_times); over
+        # http_stream_chunk_seconds_count, the CPU a token costs
+        self.stream_cpu = Counter(
+            "kaito:http_stream_cpu_seconds_total",
+            "Thread CPU seconds of streaming handlers between the "
+            "start of a stream's token loop and its end", r)
         # process-level gauges: fleet rollups use uptime to tell a
         # restarted replica (counters reset, uptime tiny) from a quiet
         # one, and RSS to spot a leaking replica before the OOM-killer
@@ -383,8 +391,7 @@ class EngineMetrics:
             # the engine owns its step/queue-wait histograms (observed
             # from the scheduler thread); expose them through this
             # registry rather than duplicating series
-            for attr in ("step_hist", "queue_wait_hist",
-                         "dispatch_gap_hist", "prefill_pack_hist",
+            for attr in ("step_hist", "queue_wait_hist", "prefill_pack_hist",
                          "prefill_wait_hist", "first_token_resolve_hist"):
                 h = getattr(engine, attr, None)
                 if h is not None:
@@ -435,6 +442,17 @@ class EngineMetrics:
                   fn=lambda: engine.cfg.page_size)
             Gauge("kaito:num_preemptions_total", "Sequences preempted", r,
                   fn=lambda: engine.counters["preemptions_total"])
+            if hasattr(engine, "compile_totals"):
+                # process-wide and monotone: a step that compiled
+                # carries its share on its timeline record, and the log
+                # names it once the warm-up is over
+                Gauge("kaito:engine_compiles_total",
+                      "Programs compiled (or fetched from the compile "
+                      "cache) since the process started", r,
+                      fn=lambda: engine.compile_totals()[0])
+                Gauge("kaito:engine_compile_seconds_total",
+                      "Seconds those compiles took", r,
+                      fn=lambda: engine.compile_totals()[1])
             Gauge("kaito:engine_decode_rows_total",
                   "Slot-steps the decode programs ran (slots x steps of "
                   "every step and window replayed)", r,

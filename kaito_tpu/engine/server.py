@@ -1351,12 +1351,20 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         detokenize, serialise, write; the wait for the token outside —
         handed to ``kaito:http_stream_chunk_seconds`` in one piece when
         the stream ends: per token the loop pays two clock reads, an
-        append and its ``http.stream.chunk`` span, and no lock."""
+        append and its ``http.stream.chunk`` span, and no lock.  With
+        them goes what the stream cost the interpreter, not the clock:
+        this thread's CPU seconds over the token loop, two reads a
+        stream (the first where the loop is entered: until its first
+        token arrives the thread sleeps on the request's queue)."""
         seconds: list[float] = []
+        cpu0 = time.thread_time()
         try:
             yield seconds
         finally:
-            self.state.metrics.stream_chunk.observe_many(seconds)
+            metrics = self.state.metrics
+            metrics.stream_chunk.observe_many(seconds)
+            if seconds:
+                metrics.stream_cpu.inc(time.thread_time() - cpu0)
 
     def _stream_tool_calls(self, st, req, base, body, forced: bool):
         """SSE tail for chat requests with tools (the role delta is

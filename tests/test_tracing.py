@@ -335,20 +335,58 @@ def test_phase_clock_sums_the_owner_thread_and_only_it():
         pass
     with clock.annotate("http.stream.chunk", rid="r1"):
         time.sleep(0.005)
-    seconds, stall = clock.flush()
+    seconds, stall, stalled = clock.flush()
     assert set(seconds) == {"engine.schedule", "engine.decode",
                             "engine.decode.wait"}   # annotate adds nothing
+    assert stalled == {"engine.schedule": stall}
     assert seconds["engine.schedule"] >= 0.02
     assert seconds["engine.decode"] >= seconds["engine.decode.wait"] >= 0.01
     # schedule never blocks on the device, so its sleep is a stall; the
     # wait phase blocks by design and is left out
     assert 0.015 <= stall <= seconds["engine.schedule"]
-    assert clock.flush() == ({}, 0.0)
+    assert clock.flush() == ({}, 0, {})
     assert [r["name"] for r in rec.log] == [
         "engine.schedule", "engine.decode", "engine.decode.wait",
         "engine.schedule", "http.stream.chunk"]
     assert rec.of("engine.decode")[0]["attrs"] == {"k": 4, "rows": 2}
     assert rec.of("engine.decode.wait")[0]["depth"] == 1
+
+
+def test_a_part_has_seconds_and_a_stall_of_its_own():
+    """A part of a phase: a span, wall seconds and stalled seconds
+    under its own name, and nothing added twice to the loop's stall."""
+    rec = SpanRecorder()
+    clock = PhaseClock(rec)
+    with clock.phase("engine.decode", rows=1):
+        with clock.part("host.plan"):
+            time.sleep(0.01)
+        with clock.phase("engine.decode.dispatch"):
+            with clock.part("host.args"):
+                sum(range(20000))                   # computing: no stall
+            with clock.part("host.launch"):
+                time.sleep(0.02)                    # held: a stall
+    with clock.phase("engine.decode.replay"):
+        time.sleep(0.005)
+    seconds, stall, stalled = clock.flush()
+    assert seconds["host.launch"] >= 0.02 and seconds["host.plan"] >= 0.01
+    assert seconds["host.args"] + seconds["host.launch"] \
+        <= seconds["engine.decode.dispatch"]
+    assert 0.015 <= stalled["host.launch"] <= seconds["host.launch"]
+    assert stalled["host.args"] < 0.005 <= 0.008 <= stalled["host.plan"]
+    # the loop's stall is its phases': dispatch (which holds the
+    # launch's) and replay, the parts' not added again
+    assert stall == pytest.approx(stalled["engine.decode.dispatch"]
+                                  + stalled["engine.decode.replay"])
+    assert stalled["engine.decode.replay"] >= 0.004
+    assert set(stalled) == {"host.plan", "host.args", "host.launch",
+                            "engine.decode.dispatch",
+                            "engine.decode.replay"}
+    assert [(r["name"], r["depth"]) for r in rec.log] == [
+        ("engine.decode", 0), ("host.plan", 1),
+        ("engine.decode.dispatch", 1), ("host.args", 2), ("host.launch", 2),
+        ("engine.decode.replay", 0)]
+    assert all(r["attrs"] == {} for r in rec.log[1:])
+    assert clock.flush() == ({}, 0.0, {})
 
 
 PHASE_FAMILIES = {
@@ -359,7 +397,21 @@ PHASE_FAMILIES = {
     "engine.decode.replay": "kaito:engine_decode_replay_seconds",
     "engine.prefill": "kaito:engine_prefill_step_seconds",
     "loop_stall": "kaito:engine_loop_stall_seconds",
+    # parts of a phase and the two stalls told apart
+    "host.args": "kaito:engine_dispatch_args_seconds",
+    "host.launch": "kaito:engine_launch_seconds",
+    "launch_stall": "kaito:engine_launch_stall_seconds",
+    "host.plan": "kaito:engine_decode_plan_seconds",
+    "replay_stall": "kaito:engine_replay_stall_seconds",
 }
+# every engine.* span there is (docs/observability.md's table): the
+# benchmark's reduction maps these names to kinds through a fixed table
+# (kbench/trace_spans.KIND) and calls any other name unattributed
+ENGINE_SPANS = {"engine.step", "engine.schedule", "engine.decode",
+                "engine.decode.dispatch", "engine.decode.wait",
+                "engine.decode.replay", "engine.prefill",
+                "engine.prefill.dispatch", "engine.prefill.wait",
+                "engine.prefill.resolve", "engine.idle"}
 
 
 @pytest.fixture(scope="module")
@@ -395,7 +447,7 @@ def test_one_step_emits_each_phase_once_and_they_add_up(phased):
     rec.log.clear()
     steps0 = engine.step_hist._total
     assert engine.step()      # admits the second, decodes the first, prefills
-    names = [r["name"] for r in rec.log]
+    names = [r["name"] for r in rec.log if r["name"].startswith("engine.")]
     want = ["engine.step", "engine.schedule", "engine.decode",
             "engine.decode.dispatch", "engine.decode.wait",
             "engine.decode.replay", "engine.prefill",
@@ -431,6 +483,93 @@ def test_one_step_emits_each_phase_once_and_they_add_up(phased):
         abs=1e-4)
 
 
+def test_each_dispatch_opens_args_then_launch(phased):
+    """One step of the synchronous loop: inside the decode dispatch,
+    inside the prefill dispatch and inside the blocking first-token
+    program's span, ``host.args`` then ``host.launch``, each nested in
+    it; the planning is a part of its own; their seconds reach the
+    record."""
+    from kaito_tpu.engine.engine import SamplingParams
+
+    engine, rec = phased
+    while engine.step():
+        pass
+    params = SamplingParams(max_tokens=8, temperature=0.0, ignore_eos=True)
+    engine.submit(list(range(5, 25)), params)
+    assert engine.step()
+    engine.submit(list(range(7, 60)), params)
+    rec.log.clear()
+    assert engine.step()        # decodes the first, prefills the second
+    log = rec.log
+    assert {r["name"] for r in log if r["name"].startswith("engine.")} \
+        <= ENGINE_SPANS
+    assert {r["name"] for r in log if not r["name"].startswith("engine.")} \
+        == {"host.plan", "host.args", "host.launch"}
+    held = ("engine.decode.dispatch", "engine.prefill.dispatch",
+            "engine.prefill.wait")
+    for outer in (r for r in log if r["name"] in held):
+        inside = [r for r in log if _contains(outer, r)]
+        assert [r["name"] for r in inside] == ["host.args", "host.launch"], \
+            outer["name"]
+        assert inside[0]["t1"] <= inside[1]["t0"]
+    assert len([r for r in log if r["name"] in held]) == 3
+    assert len(rec.of("host.launch")) == len(rec.of("host.args")) == 3
+    plan, = rec.of("host.plan")
+    assert _contains(rec.of("engine.schedule")[0], plan)   # this loop's
+    rec_ = engine.timeline.records()[-1]
+    assert 0 < rec_["launch"] and 0 < rec_["args"] and 0 < rec_["plan"]
+    assert rec_["args"] + rec_["launch"] <= (
+        rec_["decode.dispatch"] + rec_["prefill.dispatch"]
+        + rec_["prefill.wait"])
+    # the decode dispatch's own two parts against its own seconds
+    d = rec.of("engine.decode.dispatch")[0]
+    mine = [r for r in log if _contains(d, r)]
+    assert sum(r["t1"] - r["t0"] for r in mine) <= d["t1"] - d["t0"]
+    assert rec_.get("launch_stall", 0.0) <= rec_["launch"]
+    assert rec_.get("replay_stall", 0.0) <= rec_["decode.replay"]
+    assert "dispatch_gap" not in rec_
+    for key in ("host.launch", "launch_stall", "host.args", "host.plan",
+                "replay_stall"):
+        assert engine.phase_hists[key]._total == engine.step_hist._total
+
+
+def test_a_step_that_compiles_says_so(phased, caplog):
+    """A program first met after the warm-up: the process-wide count
+    moves, the step's record carries it, and once the heap has settled
+    the log names the step."""
+    from kaito_tpu.engine.engine import SamplingParams
+    from kaito_tpu.engine.metrics import EngineMetrics
+
+    engine, _ = phased
+    while engine.step():
+        pass
+    assert all("compiles" not in r for r in engine.timeline.records()[-2:])
+    engine._settle_heap()                   # as the loop does when idle
+    n0, s0 = engine.compile_totals()
+    tick = engine._tick
+    # a prompt of a bucket no earlier test of this engine has used
+    engine.submit(list(range(3, 103)),
+                  SamplingParams(max_tokens=2, temperature=0.0,
+                                 ignore_eos=True))
+    with caplog.at_level(logging.WARNING, logger="kaito_tpu.engine.engine"):
+        assert engine.step()
+    n1, s1 = engine.compile_totals()
+    rec_ = engine.timeline.records()[-1]
+    assert n1 > n0 and s1 > s0
+    assert rec_["compiles"] == n1 - n0
+    assert rec_["compile_s"] == pytest.approx(s1 - s0, abs=1e-5)
+    assert rec_["compile_s"] <= rec_["launch"] + rec_["args"] + 1e-3
+    said = [r.getMessage() for r in caplog.records
+            if "program(s)" in r.getMessage()]
+    assert said == [f"compiled {n1 - n0} program(s) in "
+                    f"{rec_['compile_s']:.2f} s inside step {tick}"]
+    text = EngineMetrics(engine=engine).registry.expose()
+    assert f"kaito:engine_compiles_total {n1}" in text
+    while engine.step():
+        pass
+    assert "compiles" not in engine.timeline.records()[-1]
+
+
 def test_an_idle_poll_observes_nothing(phased):
     engine, rec = phased
     while engine.step():
@@ -446,6 +585,111 @@ def test_an_idle_poll_observes_nothing(phased):
 
 
 @pytest.fixture(scope="module")
+def phased_async():
+    """(engine, recorder): the two-deep loop (what a chip runs), stepped
+    by the test."""
+    from kaito_tpu.engine.config import EngineConfig
+    from kaito_tpu.engine.engine import InferenceEngine
+
+    engine = InferenceEngine(EngineConfig(
+        **E2E_CFG, prefill_interleave=1, decode_run_ahead=1,
+        async_dispatch=True))
+    rec = engine.phases.annotate = SpanRecorder()
+    yield engine, rec
+    engine.stop()
+
+
+def test_the_two_deep_loop_plans_and_launches_in_parts(phased_async):
+    """The loop a chip runs: ``host.plan`` inside ``engine.decode``
+    before the dispatch, ``host.args`` then ``host.launch`` inside the
+    decode dispatch and inside both prefill dispatches (the chunk's and
+    the first-token program's), and no ``engine.*`` span that the
+    table does not list."""
+    from kaito_tpu.engine.engine import SamplingParams
+
+    engine, rec = phased_async
+    params = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+    first = engine.submit(list(range(5, 25)), params)
+    for _ in range(50):
+        engine.step()
+        if engine._inflight is not None:
+            break
+    assert engine._inflight is not None
+    engine.submit(list(range(7, 60)), params)
+    rec.log.clear()
+    assert engine.step()    # a primed window, the second prompt's prefill
+    log = rec.log
+    assert {r["name"] for r in log if r["name"].startswith("engine.")} \
+        <= ENGINE_SPANS
+    decode, = rec.of("engine.decode")
+    plans = rec.of("host.plan")
+    assert len(plans) == 2 and all(_contains(decode, p) for p in plans)
+    dispatch, = rec.of("engine.decode.dispatch")
+    assert plans[1]["t1"] <= dispatch["t0"]
+    spans = [dispatch] + rec.of("engine.prefill.dispatch")
+    assert len(spans) == 3
+    for outer in spans:
+        inside = [r for r in log if _contains(outer, r)]
+        assert [r["name"] for r in inside] == ["host.args", "host.launch"], \
+            outer["name"]
+    rec_ = engine.timeline.records()[-1]
+    assert "drain" not in rec_ and "dispatch_gap" not in rec_
+    decode_parts = [r for r in log if _contains(dispatch, r)]
+    assert sum(r["t1"] - r["t0"] for r in decode_parts) \
+        <= rec_["decode.dispatch"] + 1e-6
+    assert rec_["args"] + rec_["launch"] \
+        <= rec_["decode.dispatch"] + rec_["prefill.dispatch"]
+    assert rec_["plan"] == pytest.approx(
+        sum(p["t1"] - p["t0"] for p in plans), abs=1e-4)
+    while not first.finish_reason:
+        engine.step()
+
+
+def test_a_drain_inside_the_plan_keeps_its_own_spans(phased_async,
+                                                     monkeypatch):
+    """Page pressure found while planning retires the window in flight
+    there: its wait and replay are spans of their own inside
+    ``host.plan``, innermost, as a drain inside ``engine.schedule`` is."""
+    from kaito_tpu.engine.engine import SamplingParams
+
+    engine, rec = phased_async
+    while engine.step():
+        pass
+    req = engine.submit(list(range(9, 40)), SamplingParams(
+        max_tokens=12, temperature=0.0, ignore_eos=True))
+    for _ in range(50):
+        engine.step()
+        if engine._inflight is not None:
+            break
+    assert engine._inflight is not None
+    # the schedule's question is answered yes, the plan's no
+    asked = []
+    monkeypatch.setattr(engine, "_lookahead_fits",
+                        lambda k: (asked.append(k), len(asked) != 2)[1])
+    rec.log.clear()
+    assert engine.step()
+    monkeypatch.undo()
+    assert len(asked) >= 2
+    plan = rec.of("host.plan")[-1]
+    wait = [r for r in rec.of("engine.decode.wait")
+            if r["attrs"] == {"drain": "page_pressure"}]
+    assert len(wait) == 1 and _contains(plan, wait[0])
+    replay = [r for r in rec.of("engine.decode.replay")
+              if _contains(plan, r)]
+    assert len(replay) == 1 and wait[0]["t1"] <= replay[0]["t0"]
+    rec_ = engine.timeline.records()[-1]
+    assert rec_["drain"] == "page_pressure"
+    assert rec_["plan"] >= rec_["decode.wait"]
+    # the window launched after it went into an idle device
+    assert _contains(rec.of("engine.decode")[0],
+                     rec.of("engine.decode.dispatch")[0])
+    while not req.finish_reason:
+        engine.step()
+    assert {r["name"] for r in rec.log if r["name"].startswith("engine.")} \
+        <= ENGINE_SPANS
+
+
+@pytest.fixture(scope="module")
 def phased_server(phased):
     from kaito_tpu.engine.server import make_server
 
@@ -458,10 +702,16 @@ def phased_server(phased):
     server.server_close()
 
 
-def test_streaming_emits_request_and_chunk_spans(phased_server):
+def test_streaming_emits_request_and_chunk_spans(phased_server, monkeypatch):
     server, url, rec = phased_server
     rec.log.clear()
-    chunks0 = server.state.metrics.stream_chunk._total
+    metrics = server.state.metrics
+    chunks0 = metrics.stream_chunk._total
+    cpu0, added = metrics.stream_cpu.value(), []
+    real_inc = metrics.stream_cpu.inc
+    monkeypatch.setattr(metrics.stream_cpu, "inc",
+                        lambda s: (added.append(s), real_inc(s)))
+    t0 = time.perf_counter()
     with _post(url, "/v1/completions",
                {"prompt": "stream me", "max_tokens": 6, "temperature": 0.0,
                 "ignore_eos": True, "stream": True},
@@ -477,7 +727,12 @@ def test_streaming_emits_request_and_chunk_spans(phased_server):
     # than the request
     assert all(c["t0"] >= request[0]["t1"] for c in chunks)
     assert all(c["thread"] == request[0]["thread"] for c in chunks)
-    assert server.state.metrics.stream_chunk._total == chunks0 + 6
+    wall = time.perf_counter() - t0
+    assert metrics.stream_chunk._total == chunks0 + 6
+    # what the six tokens cost this handler's thread in CPU: handed
+    # over once, with the chunk seconds, and no more than the clock saw
+    assert len(added) == 1 and 0 < added[0] <= wall
+    assert metrics.stream_cpu.value() == pytest.approx(cpu0 + added[0])
     # the engine's own thread idles in a span of its own
     assert rec.of("engine.idle") or rec.of("engine.step")
 
@@ -493,6 +748,13 @@ def test_new_families_are_unlabelled_on_metrics(phased_server):
         for suffix in ("_sum", "_count"):
             mine = [ln for ln in lines if ln.startswith(family + suffix)]
             assert len(mine) == 1 and "{" not in mine[0], (family, mine)
+    for name in ("kaito:http_stream_cpu_seconds_total",
+                 "kaito:engine_compiles_total",
+                 "kaito:engine_compile_seconds_total"):
+        mine = [ln for ln in lines if ln.startswith(name)]
+        assert len(mine) == 1 and "{" not in mine[0], (name, mine)
+        assert float(mine[0].split()[1]) > 0
+    assert not [ln for ln in lines if "dispatch_gap" in ln]
     count = {ln.split()[0]: float(ln.split()[1]) for ln in lines
              if ln.startswith("kaito:engine_") and "_count" in ln}
     assert count["kaito:engine_schedule_seconds_count"] \
