@@ -67,10 +67,26 @@ class KernelCase:
     # here; kept for the move into kbench/rooflines.py (ROADMAP D5)
     unit: str = ""
     work: float = 0.0
+    # other forms of the same result, timed as the kernel is:
+    # {name: *args -> array}
+    others: dict = dataclasses.field(default_factory=dict)
 
 
 def _f32(x):
     return x.astype(jnp.float32)
+
+
+def _call_ms(compiled, args) -> float:
+    """The host's clock round five calls, whatever the wrapper does
+    around the kernel (a transpose, a gather) included: what a change
+    to a kernel is first measured by, alone, before any cell is run;
+    no benchmark metric."""
+    jax.block_until_ready(compiled(*args))
+    t0 = time.perf_counter()
+    for _ in range(5):
+        out = compiled(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / 5 * 1e3
 
 
 def parity(case: KernelCase) -> dict:
@@ -89,16 +105,11 @@ def parity(case: KernelCase) -> dict:
     # show in the next call of the same executable
     again = compiled(*case.args)
     repeats = bool(jnp.array_equal(out, again))
-    # the host's clock round five more calls, whatever the wrapper does
-    # around the kernel (a transpose, a gather) included: what a change
-    # to a kernel is first measured by, alone, before any cell is run;
-    # no benchmark metric
-    jax.block_until_ready(again)
-    t0 = time.perf_counter()
-    for _ in range(5):
-        again = compiled(*case.args)
-    jax.block_until_ready(again)
-    call_ms = (time.perf_counter() - t0) / 5 * 1e3
+    call_ms = _call_ms(compiled, case.args)
+    others = {
+        f"call_ms.{name}": round(_call_ms(
+            jax.jit(fn).lower(*case.args).compile(), case.args), 4)
+        for name, fn in case.others.items()}
     diff = jnp.abs(_f32(out) - _f32(ref))
     if case.mask is not None:
         diff = diff * case.mask
@@ -113,7 +124,7 @@ def parity(case: KernelCase) -> dict:
             "repeats": repeats, "call_ms": round(call_ms, 4),
             "max_err": round(err, 6), "tol": case.tol,
             "relative": case.relative, "finite": finite,
-            "shape": list(out.shape), "why": case.why}
+            "shape": list(out.shape), "why": case.why, **others}
 
 
 # ---------------------------------------------------------------------
@@ -365,6 +376,44 @@ def ssm_case() -> KernelCase:
         work=float(jnp.sum(active)) * 2 * Hm * Pm * Nm * 2)
 
 
+def combine_case() -> KernelCase:
+    """An expert layer's rows back to their tokens at MiMo-V2.5's
+    longest prefill bucket on one of sixteen chips: 4,096 rows of which
+    2,489 are a prompt, 8 of 256 experts a token, 16 held, so one pass
+    of 4,096 sorted rows of which about 1,250 hold a pair.  The kernel
+    with its sort against the gathers of all eight slots with the
+    scatter that builds their places, bit for bit; ``call_ms.loop`` is
+    the gathers' time."""
+    from kaito_tpu.engine import nn
+
+    T, E, k, X, held, n_valid = 4096, 4096, 8, 256, 16, 2489
+    cap = T                                  # nn.moe_mlp_ragged's, 16 shards
+    kr, ko = jax.random.split(jax.random.PRNGKey(5))
+    _, idx = jax.lax.top_k(jax.random.uniform(kr, (T, X)), k)
+    here = (idx < held) & (jnp.arange(T) < n_valid)[:, None]
+    order = jnp.argsort(jnp.where(here, idx, held).reshape(-1))
+    live = jnp.arange(cap) < jnp.sum(here)
+    out = jnp.where(live[:, None],
+                    jax.random.normal(ko, (cap, E), jnp.float32), 0.0)
+
+    def loop(out, order, live):
+        place = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        return nn._combine_slots(jnp.zeros((T, E), jnp.float32), out,
+                                 place.reshape(T, k), cap)
+
+    def kernel(out, order, live):
+        return nn._combine_held(jnp.zeros((T, E), jnp.float32), out,
+                                order[:cap].astype(jnp.int32), live, k,
+                                fresh=jnp.bool_(True))
+
+    return KernelCase(
+        "moe_combine_ep16", kernel, loop, (out, order, live), 0.0,
+        "the same float32 sums in the same order, less exact zeros",
+        unit="live-row bytes", work=float(jnp.sum(live)) * E * 4 + T * E * 4,
+        others={"loop": loop})
+
+
 CASES: dict[str, Callable[[], KernelCase]] = {
     "ssm_state_update": ssm_case,
     "decode_bf16": decode_case,
@@ -378,6 +427,7 @@ CASES: dict[str, Callable[[], KernelCase]] = {
     "gemv_int8_prefetch": lambda: gemv_case("int8", prefetch=True),
     "gemv_int4": lambda: gemv_case("int4"),
     "gemv_int4_prefetch": lambda: gemv_case("int4", prefetch=True),
+    "moe_combine_ep16": combine_case,
 }
 
 

@@ -69,9 +69,11 @@ MAIN_PATH_KERNELS = ("decode_bf16", "flash_prefill", "flash_prefill_packed")
 # rows, empty ones included), and flash prefill at MiMo-V2.5's two
 # geometries (16 and 8 query heads stacked on a KV head's key blocks,
 # keys wider than values, a window with a sink, whole query blocks of
-# padding written as zeros)
+# padding written as zeros), and the un-sort of its shared expert layer
+# at the longest prefill bucket
 REQUIRED_KERNELS = MAIN_PATH_KERNELS + (
-    "ssm_state_update", "flash_prefill_gqa16", "flash_prefill_window_sink")
+    "ssm_state_update", "flash_prefill_gqa16", "flash_prefill_window_sink",
+    "moe_combine_ep16")
 
 
 class SmokeError(Exception):

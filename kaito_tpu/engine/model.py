@@ -199,7 +199,9 @@ class TransformerLM:
         # (docs/kv-cache.md), and prefix reuse, PD and speculation
         # are refused by the engine
         self.has_ssm = arch.ssm_state > 0
-        self.moe_kernel = False     # Pallas grouped matmul (set by the engine)
+        # Pallas grouped matmul, and the Pallas un-sort of a shared
+        # expert layer's prefill (set by the engine)
+        self.moe_kernel = False
         # layers that name their kinds: stacks by kind, a schedule of
         # runs, one page pool and table an attention kind
         self.kinds = None
@@ -585,6 +587,19 @@ class TransformerLM:
         else:
             is_global = jnp.zeros_like(idx, dtype=bool)
         return jnp.where(is_global, _BIG_WINDOW, a.sliding_window).astype(jnp.int32)
+
+    @property
+    def moe_combine(self) -> Optional[str]:
+        """What takes a grouped expert layer's rows back to their
+        tokens, as ``/health`` names it: ``pallas`` where the compact
+        un-sort's kernel runs (a layer shared between chips, at the
+        widths where a pass holds fewer pairs than were routed:
+        ``nn.moe_mlp_ragged``), ``xla`` otherwise; None with no such
+        layer."""
+        if self.arch.num_experts == 0 or self.moe_impl != "ragged":
+            return None
+        shared = self.arch.expert_shards > 1
+        return "pallas" if self.moe_kernel and shared else "xla"
 
     @property
     def _scale(self) -> float:

@@ -484,6 +484,39 @@ def _gmm_tile(lhs: jax.Array, w: jax.Array) -> tuple:
             min(w.shape[-1], _GMM_TILE_N))
 
 
+def _combine_slots(y: jax.Array, out: jax.Array, rel: jax.Array,
+                   cap: int) -> jax.Array:
+    """``y`` [T, E] float32 with every token's pairs added: slot ``j``
+    of token ``t`` is row ``rel[t, j]`` of ``out`` [rows, E] where that
+    lies in [0, cap) and nothing otherwise; summed in float32, a token's
+    k pairs one after the other ([T, E] a time)."""
+    for j in range(rel.shape[1]):
+        r = rel[:, j]
+        y = y + jnp.where(((r >= 0) & (r < cap))[:, None],
+                          out[jnp.clip(r, 0, out.shape[0] - 1)], 0.0)
+    return y
+
+
+def _combine_held(y: jax.Array, out: jax.Array, rows: jax.Array,
+                  live: jax.Array, k: int, fresh) -> jax.Array:
+    """What ``_combine_slots`` gives, moving only the rows that hold a
+    pair: row ``n`` of ``out`` [rows, E] float32 holds pair ``rows[n]``
+    (token ``rows[n] // k``) where ``live[n]`` and nothing otherwise.
+    The live pairs sort into token order, a token's in the order of its
+    slots, and the Pallas kernel adds each to its token's row of ``y``
+    [T, E] in that order (``ops/moe_combine.py``, a whole number of its
+    token tiles): the same float32 sums as the loop over all ``k``
+    slots, less its exact zeros.  ``fresh`` (bool scalar): ``y`` is all
+    zeros."""
+    from kaito_tpu.engine.ops.moe_combine import moe_combine_pallas
+
+    T = y.shape[0]
+    pair, pos = jax.lax.sort(
+        (jnp.where(live, rows, T * k),
+         jnp.arange(out.shape[0], dtype=jnp.int32)), num_keys=1)
+    return moe_combine_pallas(y, out, pair // k, pos, fresh)
+
+
 def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
                    valid: Optional[jax.Array] = None, kernel: bool = False,
                    with_stats: bool = False, layer=None):
@@ -504,6 +537,18 @@ def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
     bool leaves a row's pairs out (a slot that decodes nothing, a
     prompt's padding).  FLOPs scale with the pairs held, not the expert
     count.  Decode and prefill use this function.
+
+    A pass's rows go back to their tokens summed in float32, a token's
+    pairs in the order of its ``k`` slots: ``k`` gathers of [T, E]
+    (``_combine_slots``).  Where a pass holds fewer pairs than were
+    routed (``cap < pairs``: a share at prefill widths) and ``kernel``
+    is set, only the rows that hold a pair move (``_combine_held``, the
+    Pallas kernel ``ops/moe_combine.py``: ``moe_combine`` in a trace,
+    and ``pallas`` under ``moe_combine`` on ``/health``, ``xla``
+    otherwise).  The sums are the same sums in the same order: the
+    terms left out are exact zeros.  A whole layer and a share's decode
+    widths (``cap == pairs``) hold every routed pair, and the gathers
+    stand.
 
     ``layer``: the expert stacks of ``p`` are a layer kind's whole
     stacks and this the layer's index into them (``_grouped_matmul``).
@@ -530,12 +575,18 @@ def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
     starts = ends - group_sizes
     flat_w = weights.reshape(-1)
     pairs = T * k
-    # each pair's place in the sorted order
-    place = jnp.zeros((pairs,), jnp.int32).at[order].set(
-        jnp.arange(pairs, dtype=jnp.int32))
     cap = pairs if arch.expert_shards == 1 else min(
         pairs, max(256, 2 * pairs // arch.expert_shards))
     n_rows = _gmm_rows(cap) if kernel else cap
+    compact = False
+    if kernel and cap < pairs:
+        from kaito_tpu.engine.ops.moe_combine import token_tile
+
+        compact = T % token_tile(T) == 0
+    if not compact:
+        # each pair's place in the sorted order
+        place = jnp.zeros((pairs,), jnp.int32).at[order].set(
+            jnp.arange(pairs, dtype=jnp.int32))
 
     def one_pass(i, y):
         """Sorted pairs [i*cap, (i+1)*cap) through the held experts,
@@ -559,14 +610,9 @@ def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
                           * up, 0.0).astype(x.dtype)
             out = grouped(h, p["experts_down"])
         out = jnp.where(live[:, None], out * flat_w[rows][:, None], 0.0)
-        # back to the tokens by each pair's place, summed in float32:
-        # a token's k pairs one after the other ([T, E] a time)
-        rel = (place - at).reshape(T, k)
-        for j in range(k):
-            r = rel[:, j]
-            y = y + jnp.where(((r >= 0) & (r < cap))[:, None],
-                              out[jnp.clip(r, 0, n_rows - 1)], 0.0)
-        return y
+        if compact:
+            return _combine_held(y, out, rows, live, k, fresh=i == 0)
+        return _combine_slots(y, out, (place - at).reshape(T, k), cap)
 
     y = jnp.zeros((T, E), jnp.float32)
     if cap == pairs:

@@ -370,6 +370,9 @@ class OpenAIHandler(BaseHTTPRequestHandler):
                 e.prefix_cache is not None for e in engines) else "off"),
             "devices": [],
         }
+        combine = engines[0].model.moe_combine
+        if combine:
+            body["moe_combine"] = combine
         for d in jax.local_devices():
             stats = d.memory_stats() or {}
             body["devices"].append({

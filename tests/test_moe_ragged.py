@@ -73,3 +73,96 @@ def test_model_prefill_decode_with_ragged_moe():
                                     jnp.asarray([9], jnp.int32), pt)
     np.testing.assert_allclose(np.asarray(full), np.asarray(ref),
                                rtol=3e-4, atol=3e-4)
+
+
+def _crafted_routing(tokens, top_k, experts, held, seed):
+    """Distinct experts a token, drawn evenly; token 5 chooses held
+    experts only and tokens 128..255 (a whole tile of the kernel's)
+    none, where the layer is shared."""
+    rng = np.random.default_rng(seed)
+    idx = np.stack([rng.permutation(experts)[:top_k] for _ in range(tokens)])
+    if held < experts:
+        idx[5] = np.arange(top_k)
+        away = held + np.stack([rng.permutation(experts - held)[:top_k]
+                                for _ in range(128)])
+        idx[128:256] = away[:max(0, min(tokens, 256) - 128)]
+    weights = rng.random((tokens, top_k)) + 0.1
+    return (jnp.asarray(idx, jnp.int32),
+            jnp.asarray(weights / weights.sum(1, keepdims=True), jnp.float32))
+
+
+def _loop_of_every_slot(y, out, rows, live, k, fresh):
+    """The un-sort where no kernel runs: every one of a token's ``k``
+    slots gathers a row of ``out``."""
+    pairs = y.shape[0] * k
+    n_rows = out.shape[0]
+    rel = jnp.full((pairs,), n_rows, jnp.int32).at[
+        jnp.where(live, rows, pairs)].set(
+            jnp.arange(n_rows, dtype=jnp.int32), mode="drop")
+    return nn._combine_slots(y, out, rel.reshape(-1, k), n_rows)
+
+
+@pytest.mark.parametrize("tokens,top_k,shards,n_valid,compact", [
+    (4096, 8, 16, 2489, True),   # the longest prefill bucket, padded
+    (2048, 8, 16, None, True),
+    (404, 8, 16, 300, False),    # no whole number of the kernel's tiles
+    (32, 8, 16, None, False),    # a share's decode width: every pair a pass
+    (64, 2, 1, 50, False),       # a whole layer
+])
+def test_compact_unsort_equals_the_loop_bit_for_bit(monkeypatch, tokens,
+                                                    top_k, shards, n_valid,
+                                                    compact):
+    """Where a pass holds fewer pairs than were routed only the rows
+    that hold a pair go back to their tokens (the kernel, interpreted),
+    and the float32 sums are the loop's over all k slots bit for bit: a
+    mask that ends mid-tile, a token all of whose pairs are held, a tile
+    of tokens with none.  Where a pass holds every pair, or the tokens
+    are no whole number of tiles, the loop itself still runs."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from kaito_tpu.models.metadata import ModelArch
+
+    held = 16
+    arch = ModelArch(
+        vocab_size=64, hidden_size=128, num_layers=1, num_heads=2,
+        num_kv_heads=2, head_dim=16, intermediate_size=64,
+        num_experts=held * shards, num_experts_per_tok=top_k,
+        moe_intermediate_size=128, expert_shards=shards)
+    rng = np.random.default_rng(tokens + shards)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32) / 8
+
+    p = {"router": draw(128, held * shards),
+         "experts_gate": draw(held, 128, 128),
+         "experts_up": draw(held, 128, 128),
+         "experts_down": draw(held, 128, 128)}
+    x = draw(tokens, 128) * 8
+    valid = None if n_valid is None else jnp.arange(tokens) < n_valid
+    routing = _crafted_routing(tokens, top_k, held * shards, held, tokens)
+    monkeypatch.setattr(nn, "route_tokens", lambda *a, **kw: routing)
+    calls = []
+    compacted = nn._combine_held
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return compacted(*a, **kw)
+
+    def layer():
+        # one program: an interpreted kernel's callbacks deadlock
+        # against operations dispatched one by one behind it
+        with pltpu.force_tpu_interpret_mode():
+            return np.asarray(jax.jit(lambda x, p: nn.moe_mlp_ragged(
+                x, p, arch, valid=valid, kernel=True))(x, p))
+
+    monkeypatch.setattr(nn, "_combine_held", counted)
+    got = layer()
+    assert bool(calls) == compact
+    monkeypatch.setattr(nn, "_combine_held", _loop_of_every_slot)
+    want = layer()
+    assert np.array_equal(got, want)
+    assert np.abs(want[5]).max() > 1e-3
+    if shards > 1 and tokens >= 256:
+        assert not want[128:256].any()
+    if n_valid is not None:
+        assert not want[n_valid:].any() and want[n_valid - 1].any()

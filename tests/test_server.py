@@ -53,6 +53,7 @@ def test_health_and_models(served):
     assert health["hbm_sizing"]["pages"] >= 2
     assert health["platform"] == "cpu" and health["device_count"] >= 1
     assert health["attention"] == "jax"
+    assert "moe_combine" not in health      # no expert layer
     assert health["prefix_cache"] in ("native", "off")
     assert {"id", "bytes_in_use", "peak_bytes_in_use",
             "bytes_limit"} <= set(health["devices"][0])

@@ -158,6 +158,40 @@ def test_served_path_equals_the_plain_reference(async_on, n_prompt):
     assert c["moe_pairs_held_total"] <= c["moe_pairs_routed_total"]
 
 
+def test_a_chunk_wider_than_a_pass_equals_the_plain_reference():
+    """A fresh chunk of 128 rows routes 512 pairs where a pass of this
+    share holds 256: the widths at which a chip's kernel takes only the
+    rows that hold a pair back to their tokens (nn._combine_held), and
+    ``/health`` says whether it does (on a CPU the gathers run)."""
+    import json
+    import threading
+    import urllib.request
+
+    from kaito_tpu.engine.server import make_server
+
+    eng = _mk(max_prefill_tokens=128)
+    prompt = _prompt(150, 2)
+    (req,) = _run(eng, [prompt], 8)
+    seq = prompt + req.output_tokens
+    out = _reference().forward(TINY_MIMO, eng.params, seq, len(prompt) - 1)
+    want = np.asarray(out["target"])[:-1]
+    got = np.asarray(req.output_logprobs)
+    assert np.abs(got - want[:len(got)]).max() < 3e-4
+    assert eng.model.moe_combine == "xla"
+    eng.model.moe_kernel = True
+    assert eng.model.moe_combine == "pallas"
+    eng.model.moe_kernel = False
+    server = make_server(eng, eng.cfg, host="127.0.0.1", port=0)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        url = f"http://127.0.0.1:{server.server_address[1]}/health"
+        health = json.loads(urllib.request.urlopen(url, timeout=30).read())
+    finally:
+        server.shutdown()
+        server.server_close()
+    assert health["moe_combine"] == "xla" and health["attention"] == "jax"
+
+
 def test_prompt_scoring_equals_the_plain_reference():
     eng = _mk()
     prompt = _prompt(70, 4)

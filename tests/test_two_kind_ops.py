@@ -221,7 +221,8 @@ def test_expert_layer_compiles_for_v5e_and_names_its_kernel(one_chip, rows):
     no multiple of the kernel's row tile) and a prefill chunk's 4,096
     (passes of an eighth of the pairs): the grouped matmul is in the
     program under the name the roofline's reader looks for, and reads
-    the stack as it lies (no temporary of a layer's matrices)."""
+    the stack as it lies (no temporary of a layer's matrices); the
+    chunk's un-sort is there as ``moe_combine``."""
     from kaito_tpu.engine import nn
     from kaito_tpu.models.metadata import ModelArch
 
@@ -246,11 +247,15 @@ def test_expert_layer_compiles_for_v5e_and_names_its_kernel(one_chip, rows):
     compiled = jax.jit(layer).lower(
         sd((rows, 4096)), p, sd((rows,), jnp.bool_),
         sd((), jnp.int32)).compile()
-    assert "%gmm" in compiled.as_text()
+    text = compiled.as_text()
+    assert "%gmm" in text
+    # a chunk's pass holds an eighth of the routed pairs and its rows go
+    # back to their tokens through the compact un-sort's kernel; a
+    # decode step's pass holds every pair, and the loop stands
+    assert ("%moe_combine" in text) == (rows == 4096)
     one_layer = 3 * 16 * 4096 * 2048 * 2
-    # (a chunk's eight gathers back to its tokens are 0.54 GB of it)
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer // (
-        8 if rows <= 32 else 1)
+        8 if rows <= 32 else 2)
 
 
 def test_grouped_kernel_equals_the_ragged_dot():
@@ -340,12 +345,15 @@ def test_grouped_kernel_takes_any_number_of_pairs(tokens, top_k, shards):
     assert np.abs(np.asarray(want)).max() > 1e-3
 
 
+@pytest.mark.parametrize("tokens", [512, 640])
 @pytest.mark.parametrize("kernel", [False, True])
-def test_a_share_that_gets_most_pairs_drops_none(kernel):
+def test_a_share_that_gets_most_pairs_drops_none(kernel, tokens):
     """A share's pass computes twice its even share of the pairs; when
     routing sends it more (here every token chooses held experts), the
     passes go on until every held pair is computed: the shares still
-    add up to the whole layer."""
+    add up to the whole layer.  Three passes or more, each through the
+    compact un-sort (its kernel's ring of row copies starts cold in
+    every pass, and from the second on ``y`` is read, not zeros)."""
     from dataclasses import replace
 
     from kaito_tpu.engine import nn
@@ -366,7 +374,7 @@ def test_a_share_that_gets_most_pairs_drops_none(kernel):
          "experts_down": draw(32, 128, 128)}
     # the bias sends three of every token's four pairs to experts 8..11
     p["router_bias"] = p["router_bias"].at[8:11].set(5.0)
-    x = draw(512, 128) * 8
+    x = draw(tokens, 128) * 8
     uncut = nn.moe_mlp_ragged(x, p, whole)
     share = replace(whole, expert_shards=8, expert_shard=2)   # experts 8..11
     held = {k: (v[8:12] if k.startswith("experts") else v)
@@ -385,10 +393,59 @@ def test_a_share_that_gets_most_pairs_drops_none(kernel):
     else:
         y, stats = layer(x, held)
     calls, touched, here, routed = np.asarray(stats).tolist()
-    # 2,048 pairs, 512 a pass: this share holds three quarters of them
-    assert routed == 2048 and here >= 1536 > 2 * 2048 // 8
+    # 2,048 pairs, 512 a pass (2,560 and 640): this share holds three
+    # quarters of them
+    pairs, cap = tokens * 4, tokens
+    assert routed == pairs and here >= 3 * pairs // 4 > 2 * pairs // 8
+    assert -(-here // cap) >= 3
     total = y + nn.moe_mlp_ragged(x, others, rest)
     assert np.abs(np.asarray(total - uncut)).max() < 1e-4
+
+
+@pytest.mark.parametrize("tokens,n_valid,fresh", [
+    (512, 300, True),     # the mask ends inside the third tile of 128
+    (512, None, False),   # a later pass: y is read, not zeros
+    (96, 50, True),       # one tile of fewer than 128 tokens
+    (4096, 2489, True),   # the longest bucket: 32 tiles
+])
+def test_combine_kernel_equals_the_loop(tokens, n_valid, fresh):
+    """The compact un-sort's kernel in interpret mode against the loop
+    over all eight slots, bit for bit, on the same rows: a token that
+    holds all eight of its pairs, a tile of tokens that hold none (and
+    so a ring of row copies that runs across it), rows of padding,
+    fewer pairs in a tile than the ring has slots."""
+    from kaito_tpu.engine import nn
+
+    k, held, experts, E = 8, 16, 256, 256
+    rng = np.random.default_rng(tokens)
+    idx = np.stack([rng.permutation(experts)[:k] for _ in range(tokens)])
+    idx[5] = np.arange(k)
+    if tokens >= 256:
+        idx[128:256] += held * (idx[128:256] < held)
+    here = idx < held
+    if n_valid is not None:
+        here[n_valid:] = False
+    order = np.argsort(np.where(here, idx, held).reshape(-1), kind="stable")
+    place = np.empty(tokens * k, np.int32)
+    place[order] = np.arange(tokens * k)
+    cap = max(256, tokens)
+    live = np.arange(cap) < here.sum()
+    assert live.sum() < cap
+    out = jnp.asarray(rng.standard_normal((cap, E)) * live[:, None],
+                      jnp.float32)
+    y = (jnp.zeros((tokens, E), jnp.float32) if fresh
+         else _t(rng, tokens, E))
+    want = np.asarray(nn._combine_slots(
+        y, out, jnp.asarray(place.reshape(tokens, k)), cap))
+    with pltpu.force_tpu_interpret_mode():      # one program, as above
+        got = np.asarray(jax.jit(lambda y, out, rows, live: nn._combine_held(
+            y, out, rows, live, k, jnp.bool_(fresh)))(
+                y, out, jnp.asarray(order[:cap], jnp.int32),
+                jnp.asarray(live)))
+    assert np.array_equal(got, want)
+    assert not np.array_equal(want, np.asarray(y))
+    if tokens >= 256:
+        assert np.array_equal(want[128:256], np.asarray(y)[128:256])
 
 
 @pytest.mark.parametrize("stacked", [False, True])
