@@ -194,6 +194,65 @@ def decode_case(int8_kv: bool = False) -> KernelCase:
         work=live_rows * 2 + live_pages * 2 * HKV * 4)
 
 
+def mla_decode_case(name: str, rows: int, context: int) -> KernelCase:
+    """The latent decode kernel at JoyAI-LLM-Flash's widths (32 heads
+    against one stream of 512 + 64 at 640 stored lanes, pages of 64):
+    ``rows`` rows of about ``context`` tokens, ragged, one idle, against
+    the XLA path over the same token-flat pool.  ``call_ms`` takes in
+    the two einsums around the kernel."""
+    from kaito_tpu.engine.attention import mla_paged_decode_attention
+    from kaito_tpu.engine.ops.mla_decode_attention import (
+        mla_paged_decode_attention_pallas)
+
+    heads, dn, dr, dl, dv, lanes, pmax = 32, 128, 64, 512, 128, 640, 80
+    P = rows * pmax + 1
+    scale = (dn + dr) ** -0.5
+    keys = jax.random.split(jax.random.PRNGKey(4), 7)
+    q_nope = jax.random.normal(keys[0], (rows, heads, dn), jnp.bfloat16)
+    q_rope = jax.random.normal(keys[1], (rows, heads, dr), jnp.bfloat16)
+    pool = jnp.pad(jax.random.normal(keys[2], (1, P, PS, dl + dr),
+                                     jnp.bfloat16),
+                   ((0, 0), (0, 0), (0, 0), (0, lanes - dl - dr)))
+    wk = (jax.random.normal(keys[3], (dl, heads * dn), jnp.float32)
+          / math.sqrt(dl)).astype(jnp.bfloat16)
+    wv = (jax.random.normal(keys[4], (dl, heads * dv), jnp.float32)
+          / math.sqrt(dl)).astype(jnp.bfloat16)
+    pt = jax.random.permutation(keys[5], jnp.arange(1, P, dtype=jnp.int32)
+                                ).reshape(rows, pmax)
+    lens = jax.random.randint(keys[6], (rows,), context * 3 // 4,
+                              min(context * 5 // 4, pmax * PS), jnp.int32)
+    if rows > 2:
+        lens = lens.at[jnp.asarray([1, rows - 1])].set(
+            jnp.asarray([0, PS], jnp.int32))
+    live = (lens > 0).astype(jnp.float32)[:, None, None]
+    layer = jnp.int32(0)
+
+    def kernel(q_nope, q_rope, pool, pt, lens):
+        q_lat = jnp.einsum("bhd,lhd->bhl", q_nope, wk.reshape(dl, heads, dn),
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate(
+            [q_lat * scale, _f32(q_rope) * scale,
+             jnp.zeros((rows, heads, lanes - dl - dr), jnp.float32)],
+            -1).astype(jnp.bfloat16)
+        out = mla_paged_decode_attention_pallas(q, pool, pt, lens, layer,
+                                                value_lanes=dl)
+        return jnp.einsum("bhl,lhd->bhd", out, wv.reshape(dl, heads, dv),
+                          preferred_element_type=jnp.float32
+                          ).astype(jnp.bfloat16) * live.astype(jnp.bfloat16)
+
+    def reference(q_nope, q_rope, pool, pt, lens):
+        return mla_paged_decode_attention(
+            q_nope, q_rope, pool, pt, lens, wk, wv, scale=scale,
+            kv_lora_rank=dl, layer=layer)
+
+    return KernelCase(
+        name, kernel, reference, (q_nope, q_rope, pool, pt, lens),
+        ATTN_TOL, ATTN_WHY + "; the absorbed query is rounded to bf16 too",
+        mask=live, zero_where_masked=True, unit="live-latent bytes",
+        work=float(jnp.sum(lens)) * (dl + dr) * 2,
+        others={"xla": reference})
+
+
 # flash prefill's geometries: (name, B, T, H, Hkv, D, Dv, window, sink,
 # true_len).  phi-4-mini's, ragged; then MiMo-V2.5's two kinds at its
 # longest bucket (64 query heads on 4 and on 8 KV heads, keys stored at
@@ -207,6 +266,9 @@ PREFILL_GEOMETRIES = {
     "flash_prefill_gqa16": (1, 4096, 64, 4, 256, 128, None, False, (2560,)),
     "flash_prefill_window_sink": (1, 4096, 64, 8, 256, 128, 128, True,
                                   (2560,)),
+    # JoyAI-LLM-Flash's expanded heads: one KV head a query head
+    "flash_prefill_mla32": (1, 4096, 32, 32, 256, 128, None, False,
+                            (3000,)),
 }
 
 
@@ -422,6 +484,9 @@ CASES: dict[str, Callable[[], KernelCase]] = {
     "flash_prefill_gqa16": lambda: prefill_case("flash_prefill_gqa16"),
     "flash_prefill_window_sink":
         lambda: prefill_case("flash_prefill_window_sink"),
+    "flash_prefill_mla32": lambda: prefill_case("flash_prefill_mla32"),
+    "mla_decode_24x3k": lambda: mla_decode_case("mla_decode_24x3k", 24, 3072),
+    "mla_decode_1x4k": lambda: mla_decode_case("mla_decode_1x4k", 1, 4096),
     "flash_prefill_packed": packed_case,
     "gemv_int8": lambda: gemv_case("int8"),
     "gemv_int8_prefetch": lambda: gemv_case("int8", prefetch=True),

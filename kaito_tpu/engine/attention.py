@@ -264,11 +264,40 @@ def mla_prefill_attention(
     return jnp.einsum("bhts,bshd->bthd", p.astype(v.dtype), v)
 
 
+# queries a pass of latent context attention takes (its scores are
+# [B, heads, queries, table positions] in float32)
+_CONTEXT_QUERY_BLOCK = 512
+
+
+def _gather_latent(cache_latent, page_tables, layer, latent_scale,
+                   kv_lora_rank: int, rope_dim: int):
+    """A batch's cached latents through its page tables: (c_kv [B, S,
+    dl], k_rope [B, S, dr]) over the ``S = pmax * ps`` positions the
+    tables name.  ``cache_latent`` is [(Lg,) P, ps, 1, dl+dr], or
+    token-flat as the decode kernel reads a page, [(Lg,) P, ps, lanes]
+    with the lanes past ``dl + dr`` zero (kv_cache.create_kv_cache);
+    int8 pools dequantize by ``latent_scale`` [(Lg,) P, 1]."""
+    flat = cache_latent.ndim == (3 if layer is None else 4)
+    cache_latent, base = _layer_view(cache_latent, layer)
+    lat = cache_latent[base + page_tables]          # [B, pmax, ps, ...]
+    if not flat:
+        lat = lat[:, :, :, 0]
+    if latent_scale is not None:
+        s_flat, _ = _layer_view(latent_scale, layer)
+        sl = s_flat[base + page_tables]                 # [B, pmax, 1]
+        lat = lat.astype(jnp.float32) * sl[..., None]
+    B, pmax, ps, _ = lat.shape
+    lat = lat.reshape(B, pmax * ps, lat.shape[-1])
+    dl = kv_lora_rank
+    return lat[..., :dl], lat[..., dl:dl + rope_dim]
+
+
 @_scoped
 def mla_paged_context_attention(
     q_nope: jax.Array,        # [B, T, H, dn] chunk queries
     q_rope: jax.Array,        # [B, T, H, dr] (roped)
-    cache_latent: jax.Array,  # [P, ps, 1, dl+dr] (chunk latent already written)
+    cache_latent: jax.Array,  # [P, ps, 1, dl+dr] or token-flat [P, ps, lanes]
+                              # (chunk latent already written)
     page_tables: jax.Array,   # [B, pmax]
     start_pos: jax.Array,     # [B] absolute position of q[:, 0]
     true_lens: jax.Array,     # [B] valid NEW tokens in the chunk
@@ -286,45 +315,52 @@ def mla_paged_context_attention(
     paged_context_attention.  Uses the absorption form so per-token K/V
     are never materialized."""
     B, T, H, dn = q_nope.shape
-    ps, _, dtot = cache_latent.shape[-3:]
     dl = kv_lora_rank
-    pmax = page_tables.shape[1]
-    S = pmax * ps
     dv = kv_b_v.shape[1] // H
-
-    cache_latent, base = _layer_view(cache_latent, layer)
-    lat = cache_latent[base + page_tables][:, :, :, 0]  # [B, pmax, ps, dl+dr]
-    if latent_scale is not None:
-        s_flat, _ = _layer_view(latent_scale, layer)
-        sl = s_flat[base + page_tables]                 # [B, pmax, 1]
-        lat = lat.astype(jnp.float32) * sl[..., None]
-    lat = lat.reshape(B, S, dtot)
-    c_kv, k_rope = lat[..., :dl], lat[..., dl:]
-
+    c_kv, k_rope = _gather_latent(cache_latent, page_tables, layer,
+                                  latent_scale, dl, q_rope.shape[-1])
+    S = c_kv.shape[1]
+    c_kv, k_rope = c_kv.astype(jnp.float32), k_rope.astype(jnp.float32)
     wk = kv_b_k.reshape(dl, H, dn)
-    q_lat = jnp.einsum("bthd,lhd->bthl", q_nope, wk,
-                       preferred_element_type=jnp.float32)  # [B, T, H, dl]
-    s = jnp.einsum("bthl,bsl->bhts", q_lat, c_kv.astype(jnp.float32))
-    s = s + jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32),
-                       k_rope.astype(jnp.float32))
-    s = s * scale
-    q_pos = start_pos[:, None] + jnp.arange(T)[None, :]       # [B, T]
+    wv = kv_b_v.reshape(dl, H, dv).astype(jnp.float32)
     k_pos = jnp.arange(S)[None, :]                            # [1, S]
-    mask = k_pos[:, None, :] <= q_pos[:, :, None]             # [B, T, S]
-    mask &= (k_pos < (start_pos + true_lens)[:, None])[:, None, :]
-    s = jnp.where(mask[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out_lat = jnp.einsum("bhts,bsl->bthl", p, c_kv.astype(jnp.float32))
-    wv = kv_b_v.reshape(dl, H, dv)
-    out = jnp.einsum("bthl,lhd->bthd", out_lat, wv.astype(jnp.float32))
-    return out.astype(q_nope.dtype)
+    end = (start_pos + true_lens)[:, None]                    # [B, 1]
+
+    def attend(qn, qr, q_pos):
+        """A block of the chunk's queries ([B, t, H, ...], absolute
+        positions [B, t]) against the whole table."""
+        q_lat = jnp.einsum("bthd,lhd->bthl", qn, wk,
+                           preferred_element_type=jnp.float32)
+        s = jnp.einsum("bthl,bsl->bhts", q_lat, c_kv)
+        s = s + jnp.einsum("bthd,bsd->bhts", qr.astype(jnp.float32), k_rope)
+        mask = (k_pos[:, None, :] <= q_pos[:, :, None]) \
+            & (k_pos < end)[:, None, :]                       # [B, t, S]
+        s = jnp.where(mask[:, None], s * scale, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        out_lat = jnp.einsum("bhts,bsl->bthl", p, c_kv)
+        return jnp.einsum("bthl,lhd->bthd", out_lat, wv).astype(qn.dtype)
+
+    q_pos = start_pos[:, None] + jnp.arange(T)[None, :]       # [B, T]
+    if T <= _CONTEXT_QUERY_BLOCK or T % _CONTEXT_QUERY_BLOCK:
+        return attend(q_nope, q_rope, q_pos)
+    # a block of queries at a time: the float32 scores of every head
+    # over a 4,096-token chunk and a 5,120-position table are 2.5 GiB
+    n = T // _CONTEXT_QUERY_BLOCK
+
+    def blocks(x):
+        return jnp.moveaxis(
+            x.reshape((B, n, _CONTEXT_QUERY_BLOCK) + x.shape[2:]), 1, 0)
+
+    out = jax.lax.map(lambda a: attend(*a),
+                      (blocks(q_nope), blocks(q_rope), blocks(q_pos)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, H, dv)
 
 
 @_scoped
 def mla_paged_decode_attention(
     q_nope: jax.Array,       # [B, H, dn]
     q_rope: jax.Array,       # [B, H, dr]
-    cache_latent: jax.Array,  # [P, ps, 1, dl+dr]
+    cache_latent: jax.Array,  # [P, ps, 1, dl+dr] or token-flat [P, ps, lanes]
     page_tables: jax.Array,  # [B, pmax]
     lengths: jax.Array,      # [B]
     kv_b_k: jax.Array,       # [dl, H*dn]
@@ -344,20 +380,11 @@ def mla_paged_decode_attention(
     memory win).
     """
     B, H, dn = q_nope.shape
-    ps, _, dtot = cache_latent.shape[-3:]
     dl = kv_lora_rank
-    pmax = page_tables.shape[1]
-    S = pmax * ps
     dv = kv_b_v.shape[1] // H
-
-    cache_latent, base = _layer_view(cache_latent, layer)
-    lat = cache_latent[base + page_tables][:, :, :, 0]  # [B, pmax, ps, dl+dr]
-    if latent_scale is not None:
-        s_flat, _ = _layer_view(latent_scale, layer)
-        sl = s_flat[base + page_tables]                 # [B, pmax, 1]
-        lat = lat.astype(jnp.float32) * sl[..., None]
-    lat = lat.reshape(B, S, dtot)
-    c_kv, k_rope = lat[..., :dl], lat[..., dl:]
+    c_kv, k_rope = _gather_latent(cache_latent, page_tables, layer,
+                                  latent_scale, dl, q_rope.shape[-1])
+    S = c_kv.shape[1]
 
     wk = kv_b_k.reshape(dl, H, dn)
     q_lat = jnp.einsum("bhd,lhd->bhl", q_nope, wk,

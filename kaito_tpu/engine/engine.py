@@ -389,6 +389,8 @@ class InferenceEngine:
         self.two_kinds = arch.two_kind_cache
         if self.two_kinds:
             self._refuse_for_two_kinds(mesh)
+        if self.model.is_mla and arch.expert_shards > 1:
+            self._refuse_for_latent_share(mesh)
         if jnp.dtype(cfg.kv_dtype) == jnp.int8 and (
                 cfg.pipeline_parallel > 1 or cfg.sequence_parallel > 1):
             # the staged 6-dim PP pools and the CP ring prefill don't
@@ -475,6 +477,19 @@ class InferenceEngine:
                     "intermediate=%d must all divide); keeping the "
                     "unoverlapped path", ax, tp_sz, emb,
                     arch.num_heads, arch.intermediate_size)
+
+        # a latent-attention model's pool is read by the Pallas decode
+        # kernel where that can run: the attention kernels' platform,
+        # one device, a bf16 pool, and nothing that moves pages in and
+        # out of the pool by their five-dimensional shape (the XLA
+        # paths stand everywhere else: docs/kv-cache.md, "Latent pages")
+        self.latent_kernel = bool(
+            self.model.is_mla and use_pallas and self.mesh is None
+            and self.pp_exec is None
+            and jnp.dtype(cfg.kv_dtype) == jnp.bfloat16
+            and not (cfg.pd_enabled or cfg.kv_pool_enabled
+                     or cfg.host_kv_offload_bytes or cfg.speculative_draft
+                     or cfg.speculative_ngram))
 
         if not cfg.max_model_len:
             cfg.max_model_len = min(self.md.max_model_len, 8192)
@@ -581,6 +596,15 @@ class InferenceEngine:
                         cfg.page_size, arch.attention_layers(1),
                         self.cache.window_pool_bytes / 2**30,
                         self._window_pages_per_seq)
+        if self.model.is_mla:
+            self.sizing_report["latent_pool_bytes"] = self.latent_pool_bytes
+            self.sizing_report["latent_bytes_per_token"] = \
+                self.latent_bytes_per_token
+            logger.info("latent pool: %d B a token stored (%d logical), "
+                        "read by the %s path", self.latent_bytes_per_token,
+                        self.md.kv_bytes_per_token(
+                            jnp.dtype(cfg.kv_dtype).itemsize),
+                        self.attention_path)
         if self.model.has_ssm:
             self.sizing_report["state_pool_bytes"] = \
                 self.cache.state_pool_bytes
@@ -676,7 +700,11 @@ class InferenceEngine:
             logger.warning("prefix caching requested but this model's "
                            "window layers free their pages behind the "
                            "window; serving WITHOUT prefix reuse")
-        elif cfg.enable_prefix_caching and not self.model.is_mla:
+        elif cfg.enable_prefix_caching and self.model.is_mla:
+            logger.warning("prefix caching requested but latent-attention "
+                           "layers have no prefix reuse yet (ROADMAP R5); "
+                           "serving WITHOUT prefix reuse")
+        elif cfg.enable_prefix_caching:
             # the radix tree tracks host-side PAGE IDS only — the same
             # ids index the sharded (TP) or stage-split (PP) pools, so
             # prefix reuse is layout-independent and works under any
@@ -1242,22 +1270,87 @@ class InferenceEngine:
          "pool; prefill_pack 1 serves one-row programs)"),
     )
 
+    def _refuse_settings(self, what: str, mesh, refusals) -> None:
+        """Raise, naming the setting, for a mesh or the first field of
+        ``refusals`` ((field, its off value, why) each) that is on."""
+        if mesh is not None:
+            raise ValueError(f"{what} and is served on one device: no mesh")
+        for field_name, off, why in refusals:
+            if getattr(self.cfg, field_name) != off:
+                raise ValueError(
+                    f"{what} and cannot be served with {why}: "
+                    f"set {field_name} to {off!r}")
+
     def _refuse_for_two_kinds(self, mesh) -> None:
         """Refuse by name, at start, every setting a model with two
         kinds of page cannot be served under yet."""
         what = (f"{self.md.name} keeps a page pool and a page table for "
                 f"its window layers beside the full layers'")
-        if mesh is not None:
-            raise ValueError(f"{what} and is served on one device: no mesh")
-        if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
+        if mesh is None and jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
             raise ValueError(f"{what} and cannot be served with an int8 KV "
                              f"cache (the window pool has no scale "
                              f"tensors): unset kv_dtype")
-        for field_name, off, why in self._TWO_KIND_REFUSALS:
-            if getattr(self.cfg, field_name) != off:
-                raise ValueError(
-                    f"{what} and cannot be served with {why}: "
-                    f"set {field_name} to {off!r}")
+        self._refuse_settings(what, mesh, self._TWO_KIND_REFUSALS)
+
+    # what cannot serve a latent-attention model that holds a share of
+    # its expert layers (docs/kv-cache.md, "Latent pages"), and what
+    # each waits for
+    _LATENT_SHARE_REFUSALS = (
+        ("tensor_parallel", 1, "tensor parallelism (the one latent "
+         "stream all heads share is not sharded)"),
+        ("pipeline_parallel", 1, "pipeline parallelism (the stage "
+         "executor splits whole expert layers)"),
+        ("sequence_parallel", 1, "context-parallel prefill (the ring has "
+         "no latent stream)"),
+        ("expert_parallel", 1, "expert parallelism inside the engine "
+         "(the chip's share of the experts is the model's "
+         "configuration: expert_shards)"),
+        ("host_kv_offload_bytes", 0, "host KV offload (a spilled page's "
+         "shape is not the kernel-read pool's)"),
+        ("pd_enabled", False, "prefill/decode disaggregation (the wire "
+         "carries pages of the five-dimensional pool)"),
+        ("kv_pool_enabled", False, "the cluster KV pool (latent layers "
+         "have no prefix reuse)"),
+        ("speculative_ngram", 0, "n-gram speculation (the verify window "
+         "has no kernel path over a latent pool)"),
+        ("speculative_draft", "", "draft-model speculation (the verify "
+         "window has no kernel path over a latent pool)"),
+        ("prefill_pack", 1, "packed prefill (segment packing has no "
+         "latent path; prefill_pack 1 serves one-row programs)"),
+        ("adapter_slots", 0, "the adapter cache (latent projections "
+         "carry no adapter slots)"),
+    )
+
+    def _refuse_for_latent_share(self, mesh) -> None:
+        """Refuse by name, at start, every setting a latent-attention
+        model that holds a share of its expert layers cannot be served
+        under yet."""
+        self._refuse_settings(
+            f"{self.md.name} caches a latent stream and holds "
+            f"{self.md.arch.experts_held} of {self.md.arch.num_experts} "
+            f"experts a layer", mesh, self._LATENT_SHARE_REFUSALS)
+
+    @property
+    def attention_path(self) -> str:
+        """What reads the cache in a decode step, as ``/health`` names
+        it: ``pallas`` where a kernel does (a latent pool: the kernel-
+        read layout), ``jax`` where XLA gathers the pages."""
+        if self.model.is_mla:
+            return "pallas" if self.latent_kernel else "jax"
+        return self.model.attn_impl
+
+    @property
+    def latent_bytes_per_token(self) -> int:
+        """Bytes a cached token holds in the latent pool across all
+        layers, stored lanes included (0: no latent attention)."""
+        if not self.model.is_mla:
+            return 0
+        return self.md.kv_bytes_per_token(
+            jnp.dtype(self.cfg.kv_dtype).itemsize, stored=self.latent_kernel)
+
+    @property
+    def latent_pool_bytes(self) -> int:
+        return int(self.cache.k.nbytes) if self.model.is_mla else 0
 
     def _refuse_kv_import(self) -> None:
         if self.two_kinds:
@@ -1286,7 +1379,8 @@ class InferenceEngine:
         than its own shard."""
         make = partial(create_kv_cache, self.md.arch, self._num_pages,
                        self.cfg.page_size, jnp.dtype(self.cfg.kv_dtype),
-                       window_pages=self._num_window_pages)
+                       window_pages=self._num_window_pages,
+                       latent_kernel=self.latent_kernel)
         if self.model.has_ssm:
             # one device (_refuse_for_state_pool); the state pool that
             # was there when HBM was measured, or a new one after a
@@ -1514,7 +1608,10 @@ class InferenceEngine:
             page_bytes = full_pb + win_pb * self._window_pages_per_seq \
                 / self.pages_per_seq
         else:
-            bpt = self.md.kv_bytes_per_token(itemsize)
+            # (a kernel-read latent pool holds a token at its stored
+            # lanes; every other pool at its logical shape)
+            bpt = self.md.kv_bytes_per_token(
+                itemsize, stored=getattr(self, "latent_kernel", False))
             page_bytes = bpt * self.cfg.page_size
         if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
             # each page also carries two fp32 scale rows (k + v), one

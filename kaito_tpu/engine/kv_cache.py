@@ -145,7 +145,13 @@ def create_kv_cache(
     page_size: int,
     dtype: jnp.dtype = jnp.bfloat16,
     window_pages: int = 0,
+    latent_kernel: bool = False,
 ) -> KVCache:
+    """``latent_kernel``: a latent-attention model's pool is read by the
+    Pallas decode kernel (``ops/mla_decode_attention.py``) and laid out
+    as that reads a page, token-flat at the stored lanes: [layers,
+    pages, page_size, ``arch.latent_lanes``], the lanes past the latent
+    zero (docs/kv-cache.md, "Latent pages")."""
     if arch.layer_attention is not None:
         # a pair of pools an attention kind, each with its own geometry
         if kv_cache_is_quantized(dtype):
@@ -178,9 +184,18 @@ def create_kv_cache(
     if arch.attention_kind.value == "MLA":
         # MLA caches one latent stream; `k` holds it, `v` is a
         # zero-size placeholder keeping the pytree uniform
+        if latent_kernel:
+            if k_scale is not None:
+                raise ValueError("the latent decode kernel reads a bf16 "
+                                 "pool; an int8 latent pool keeps the "
+                                 "[L, P, ps, 1, dl+dr] layout")
+            shape = shape[:3] + (arch.latent_lanes,)
+        # a shared expert layer's counters ride the cache (moe_stats)
+        stats = (jnp.zeros((4,), jnp.int32)
+                 if arch.num_experts and arch.expert_shards > 1 else None)
         return KVCache(k=jnp.zeros(shape, dtype),
                        v=jnp.zeros(shape[:-1] + (0,), dtype),
-                       k_scale=k_scale, v_scale=v_scale)
+                       k_scale=k_scale, v_scale=v_scale, moe_stats=stats)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    k_scale=k_scale, v_scale=v_scale)
 

@@ -509,6 +509,15 @@ class EngineMetrics:
                       "decoding): what the window pages are held by", r,
                       fn=lambda: sum(1 for s in engine.slots
                                      if s.request is not None))
+            if getattr(engine, "latent_bytes_per_token", 0):
+                # a latent-attention model's pool (docs/kv-cache.md)
+                Gauge("kaito:engine_latent_pool_bytes",
+                      "Bytes of the latent page pool, as stored", r,
+                      fn=lambda: engine.latent_pool_bytes)
+                Gauge("kaito:engine_latent_bytes_per_token",
+                      "Bytes a cached token holds in the latent pool "
+                      "across all layers, stored lanes included", r,
+                      fn=lambda: engine.latent_bytes_per_token)
             if getattr(getattr(engine, "cache", None), "moe_stats",
                        None) is not None:
                 # a shared expert layer's counters over the decode

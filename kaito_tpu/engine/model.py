@@ -221,7 +221,7 @@ class TransformerLM:
         if self.is_mla:
             from dataclasses import replace
 
-            rope_arch = replace(arch, head_dim=arch.qk_rope_head_dim or 64,
+            rope_arch = replace(arch, head_dim=arch.mla_dims[1],
                                 partial_rotary_factor=1.0)
             self._inv_freq_global = nn.rope_frequencies(rope_arch)
         else:
@@ -269,10 +269,7 @@ class TransformerLM:
             H, Hkv, D, Dv = (ak.num_heads, ak.num_kv_heads, ak.head_dim,
                              ak.v_head_dim)
         if self.is_mla:
-            dn = a.qk_nope_head_dim or D
-            dr = a.qk_rope_head_dim or 64
-            dv = a.v_head_dim or D
-            dl = a.kv_lora_rank or 512
+            dn, dr, dl, dv = a.mla_dims
             specs: dict[str, tuple[tuple[int, ...], tuple]] = {
                 "attn_norm": ((E,), ("embed",)),
                 "kv_a": ((E, dl + dr), ("embed", None)),
@@ -422,7 +419,8 @@ class TransformerLM:
 
     def _normal(self, key: jax.Array, shape: tuple) -> jax.Array:
         """A standard-normal draw in the model's type.  A model whose
-        layers name their kinds draws in float32 and rounds: JAX's
+        router carries a fitted correction bias (mimo_v2, the
+        deepseek-v3 family) draws in float32 and rounds: JAX's
         bfloat16 sampler gives 128 distinct values with a mean of
         -0.012 (measured over 16.8M draws), every matrix then carries
         a rank-one part that maps the all-ones direction onto itself
@@ -433,7 +431,7 @@ class TransformerLM:
         same experts, and whether a chip's share holds them is the
         seed's luck (PERF.md section 6, PR 38).  The other models keep
         the draw their tolerances were read with."""
-        if self.kinds is None or self.dtype == jnp.float32:
+        if not self.arch.router_bias or self.dtype == jnp.float32:
             return jax.random.normal(key, shape, self.dtype)
         return jax.random.normal(key, shape, jnp.float32).astype(self.dtype)
 
@@ -605,8 +603,8 @@ class TransformerLM:
     def _scale(self) -> float:
         a = self.arch
         if self.is_mla:
-            base = 1.0 / math.sqrt((a.qk_nope_head_dim or a.head_dim)
-                                   + (a.qk_rope_head_dim or 0))
+            dn, dr, _, _ = a.mla_dims
+            base = 1.0 / math.sqrt(dn + dr)
             # deepseek-yarn: the all-dim mscale lands in the softmax
             # scale (squared — applied to both q and k), while the
             # mscale/mscale_all_dim RATIO rides the rope table
@@ -631,8 +629,18 @@ class TransformerLM:
         cache only [c_kv ; k_rope], expand per-head K/V on use (prefill)
         or absorb projections into the query (decode).
 
-        ``ck`` is the full layer-group latent cache [Lg, P, ps, 1, dl+dr]
-        riding the layer scan as a carry; ``li`` selects this layer.
+        ``ck`` is the full layer-group latent cache riding the layer
+        scan as a carry, [Lg, P, ps, 1, dl+dr], or token-flat at the
+        stored lanes as the decode kernel reads a page, [Lg, P, ps,
+        lanes] (``kv_cache.create_kv_cache``, ``latent_kernel``);
+        ``li`` selects this layer.  Over a token-flat pool decode is the
+        Pallas kernel (``ops/mla_decode_attention.py``: the absorbed
+        query against the live pages, each read once as keys and as
+        values) and a fresh chunk is flash prefill on the expanded
+        heads (keys of dn+dr, values of dv, one KV head a query head:
+        the expanded form costs 2(dn+dr+dv) operations a pair and head,
+        the absorbed form 2(2 dl + dr)); a chunk with earlier context
+        and every other pool keep the XLA paths.
         ``ks``/``vs`` are the group's page-scale pools when the latent
         stream is int8-quantized (None otherwise); only ``ks`` is live —
         MLA has a single cached stream — but both ride the carry so the
@@ -640,9 +648,10 @@ class TransformerLM:
         a = self.arch
         B, T, E = h.shape
         H = a.num_heads
-        dn = a.qk_nope_head_dim or a.head_dim
-        dr = a.qk_rope_head_dim or 64
-        dl = a.kv_lora_rank or 512
+        dn, dr, dl, dv = a.mla_dims
+        rope = partial(nn.apply_rope, inv_freq=self._inv_freq_global,
+                       head_dim=dr, mscale=self._rope_mscale,
+                       interleave=a.rope_interleave)
 
         if "q_a" in p:
             q_lat = nn.rms_norm(nn.linear(h, p["q_a"]), p["q_a_norm"],
@@ -652,22 +661,27 @@ class TransformerLM:
             q = nn.linear(h, p["q"])
         q = q.reshape(B, T, H, dn + dr)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
-        q_rope = nn.apply_rope(q_rope, positions, self._inv_freq_global, dr,
-                               mscale=self._rope_mscale)
+        q_rope = rope(q_rope, positions)
 
         kv = nn.linear(h, p["kv_a"])             # [B, T, dl+dr]
         c_kv = nn.rms_norm(kv[..., :dl], p["kv_a_norm"], a.rms_norm_eps, False)
-        k_rope = nn.apply_rope(kv[..., dl:][:, :, None, :], positions,
-                               self._inv_freq_global, dr,
-                               mscale=self._rope_mscale)[:, :, 0]
+        k_rope = rope(kv[..., dl:][:, :, None, :], positions)[:, :, 0]
         latent = jnp.concatenate([c_kv, k_rope], axis=-1)  # [B, T, dl+dr]
+        # a kernel-read pool is token-flat at its stored lanes
+        kernel_pool = ck is not None and ck.ndim == 4
+        if kernel_pool:
+            latent = jnp.pad(latent, ((0, 0), (0, 0),
+                                      (0, ck.shape[-1] - (dl + dr))))
+        ps = None if ck is None else ck.shape[2]
 
-        if mode == "train":
-            out = attn.mla_prefill_attention(
+        def plain():
+            return attn.mla_prefill_attention(
                 q_nope, q_rope, c_kv, k_rope, p["kv_b_k"], p["kv_b_v"],
                 scale=self._scale, true_len=true_lens)
+
+        if mode == "train":
+            out = plain()
         elif mode == "prefill":
-            ps = ck.shape[-3]
             start = (start_pos if start_pos is not None
                      else jnp.zeros((B,), jnp.int32))
             if ks is not None:
@@ -685,12 +699,12 @@ class TransformerLM:
                     q_nope, q_rope, ck, page_tables, start, true_lens,
                     p["kv_b_k"], p["kv_b_v"], scale=self._scale,
                     kv_lora_rank=dl, layer=li, latent_scale=ks)
+            elif kernel_pool and self.attn_impl == "pallas":
+                out = self._mla_flash_prefill(q_nope, q_rope, c_kv, k_rope,
+                                              p, true_lens)
             else:
-                out = attn.mla_prefill_attention(
-                    q_nope, q_rope, c_kv, k_rope, p["kv_b_k"], p["kv_b_v"],
-                    scale=self._scale, true_len=true_lens)
+                out = plain()
         else:
-            ps = ck.shape[-3]
             if ks is not None:
                 ck, ks = write_decode_tokens_q(
                     ck, ks, latent[:, 0][:, None, :], page_tables,
@@ -699,13 +713,70 @@ class TransformerLM:
                 ck = write_decode_tokens(ck, latent[:, 0][:, None, :],
                                          page_tables, positions[:, 0], ps,
                                          active, layer=li)
-            out = attn.mla_paged_decode_attention(
-                q_nope[:, 0], q_rope[:, 0], ck, page_tables, lengths,
-                p["kv_b_k"], p["kv_b_v"], scale=self._scale,
-                kv_lora_rank=dl, layer=li, latent_scale=ks)[:, None]
-        dv = a.v_head_dim or a.head_dim
+            if kernel_pool and self.attn_impl == "pallas":
+                out = self._mla_decode_kernel(
+                    q_nope[:, 0], q_rope[:, 0], ck, page_tables, lengths,
+                    li, p)[:, None]
+            else:
+                out = attn.mla_paged_decode_attention(
+                    q_nope[:, 0], q_rope[:, 0], ck, page_tables, lengths,
+                    p["kv_b_k"], p["kv_b_v"], scale=self._scale,
+                    kv_lora_rank=dl, layer=li, latent_scale=ks)[:, None]
         attn_out = nn.linear(out.reshape(B, T, H * dv), p["o"])
         return attn_out, ck, cv, ks, vs
+
+    def _mla_flash_prefill(self, q_nope, q_rope, c_kv, k_rope, p, true_lens):
+        """A fresh chunk through flash prefill on the EXPANDED heads:
+        keys ``[k_nope | k_rope]`` at their stored lanes (q padded
+        alike), values of dv, one KV head a query head; temporaries of
+        the prefill program, never cached.  Returns [B, T, H, dv]."""
+        from kaito_tpu.engine.ops.flash_prefill import (
+            flash_prefill_attention)
+
+        B, T, H, dn = q_nope.shape
+        _, dr, _, dv = self.arch.mla_dims
+        with jax.named_scope("mla_expand"):
+            k_nope = nn.linear(c_kv, p["kv_b_k"]).reshape(B, T, H, dn)
+            v = nn.linear(c_kv, p["kv_b_v"]).reshape(B, T, H, dv)
+        zeros = jnp.zeros((B, T, H, stored_key_dim(dn + dr) - (dn + dr)),
+                          k_nope.dtype)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, T, H, dr)),
+             zeros], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope, zeros], axis=-1)
+        return flash_prefill_attention(q, k, v, true_lens,
+                                       jnp.int32(_BIG_WINDOW),
+                                       scale=self._scale)
+
+    def _mla_decode_kernel(self, q_nope, q_rope, pool, page_tables, lengths,
+                           li, p):
+        """Decode over a kernel-read latent pool, in the absorbed form:
+        ``q_nope`` [B, H, dn] into latent space (``mla_absorb``), all
+        heads against the one stream of the live pages
+        (``mla_attention``, the Pallas kernel), the attended latent out
+        through ``W_uv`` (``mla_expand``).  Returns [B, H, dv]."""
+        from kaito_tpu.engine.ops.mla_decode_attention import (
+            mla_paged_decode_attention_pallas)
+
+        B, H, dn = q_nope.shape
+        _, dr, dl, dv = self.arch.mla_dims
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bhd,lhd->bhl", q_nope,
+                               p["kv_b_k"].reshape(dl, H, dn),
+                               preferred_element_type=jnp.float32)
+            q = jnp.concatenate(
+                [q_lat * self._scale,
+                 q_rope.astype(jnp.float32) * self._scale,
+                 jnp.zeros((B, H, pool.shape[-1] - dl - dr), jnp.float32)],
+                axis=-1).astype(pool.dtype)
+        with jax.named_scope("mla_attention"):
+            out_lat = mla_paged_decode_attention_pallas(
+                q, pool, page_tables, lengths, li, value_lanes=dl)
+        with jax.named_scope("mla_expand"):
+            out = jnp.einsum("bhl,lhd->bhd", out_lat,
+                             p["kv_b_v"].reshape(dl, H, dv),
+                             preferred_element_type=jnp.float32)
+        return out.astype(q_nope.dtype)
 
     # ------------------------------------------------------------------
     # Layer body (shared by prefill and decode via mode switch)
@@ -857,8 +928,9 @@ class TransformerLM:
 
         ``kind``: the layer's attention kind, of a model whose layers
         name theirs; ``ck``/``cv``/``page_tables`` are then that kind's
-        pools and table, and the return has a seventh element: ``stats``
-        (int32 [4] or None) with an expert layer's counters added.
+        pools and table, and the return has a seventh element, as a
+        latent-attention layer's has: ``stats`` (int32 [4] or None) with
+        an expert layer's counters added.
 
         ``ssm`` is the mixer's per-slot pools (state [Lg, S, H, P, N],
         convolution tail [Lg, S, K-1, C]) of a model with a state-space
@@ -883,10 +955,21 @@ class TransformerLM:
                 true_lens=true_lens, active=active, start_pos=start_pos)
             if a.parallel_residual:
                 return (x + attn_out + self._mlp(h, p, moe), ck, cv, ks, vs,
-                        ssm)
+                        ssm, stats)
             x = x + attn_out
             h2 = self._norm(x, p, "mlp_norm")
-            return x + self._mlp(h2, p, moe), ck, cv, ks, vs, ssm
+            # an expert layer routes the tokens that are there
+            if mode == "decode":
+                valid = None if active is None else active[:, None]
+            else:
+                valid = jnp.arange(T)[None, :] < true_lens[:, None]
+            want = moe and stats is not None
+            mlp_out = self._mlp(h2, p, moe, valid=valid, with_stats=want,
+                                expert_layer=expert_layer)
+            if want:
+                mlp_out, layer_stats = mlp_out
+                stats = stats + layer_stats
+            return x + mlp_out, ck, cv, ks, vs, ssm, stats
         # collective-compute overlap (docs/multichip.md): DECODE-only,
         # resolved once here — q (column-parallel, below), o and down
         # (row-parallel, further down) all key off the same handle
@@ -1230,7 +1313,15 @@ class TransformerLM:
                 true_lens=true_lens, active=active, remat=remat,
                 start_pos=start_pos)
         serve_lora = params.get("serve_lora") if mode != "train" else None
-        new_k, new_v, new_ks, new_vs, new_ssm = [], [], [], [], []
+        if mode != "train":
+            if self.has_ssm and cache.ssm_state is None:
+                raise ValueError("a model with a state-space mixer serves "
+                                 "from a cache with a state pool "
+                                 "(kv_cache.create_state_pool)")
+            pools = (cache.k, cache.v, cache.k_scale, cache.v_scale,
+                     (cache.ssm_state, cache.ssm_conv)
+                     if cache.ssm_state is not None else None)
+            stats = cache.moe_stats if mode == "decode" else None
         for g in self.groups:
             stack = params[g.name]
             flags = self._window_flags(g.start, g.count)
@@ -1248,27 +1339,18 @@ class TransformerLM:
                 x, _ = jax.lax.scan(body, x, xs)
                 continue
 
-            # The group's page pools ride the scan as a CARRY: writes are
+            # The page pools ride the scan as a CARRY: writes are
             # in-place scatters at a traced layer index and attention
             # gathers straight from the big buffer.  (Threading them as
             # xs/ys sliced + re-stacked the full pool every step — 14 ms
-            # of a 31 ms decode step on a v5e chip.)
-            ck_g = cache.k[g.start:g.start + g.count]
-            cv_g = cache.v[g.start:g.start + g.count]
-            # scale pools (int8 KV mode) ride the same carry; None is a
-            # valid empty pytree leaf so the bf16 scan is unchanged
-            ks_g = (cache.k_scale[g.start:g.start + g.count]
-                    if cache.k_scale is not None else None)
-            vs_g = (cache.v_scale[g.start:g.start + g.count]
-                    if cache.v_scale is not None else None)
-            # the mixer's per-slot pools ride it too (None: no mixer)
-            if self.has_ssm and cache.ssm_state is None:
-                raise ValueError("a model with a state-space mixer serves "
-                                 "from a cache with a state pool "
-                                 "(kv_cache.create_state_pool)")
-            ssm_g = ((cache.ssm_state[g.start:g.start + g.count],
-                      cache.ssm_conv[g.start:g.start + g.count])
-                     if cache.ssm_state is not None else None)
+            # of a 31 ms decode step on a v5e chip.)  The WHOLE pools
+            # ride every group's scan and a group's layers index them
+            # from ``g.start``: a slice of the pool for the second of
+            # two groups (dense layers, then expert layers) would be a
+            # copy of it a program (4.2 GB of a 40-layer latent pool).
+            # The scale pools (int8 KV mode) and the mixer's per-slot
+            # pools ride the same carry; None is a valid empty pytree
+            # leaf so the bf16 scan is unchanged.
             # per-request adapters ride the scan as an extra [L, n, ...]
             # stack (None for groups without one, e.g. MoE)
             lora_g = serve_lora.get(g.name) if serve_lora else None
@@ -1282,25 +1364,38 @@ class TransformerLM:
                     if self.overlap is not None and mode == "decode"
                     else None)
             has_pf = pf_g is not None
+            # a latent-attention model's grouped expert layer keeps its
+            # stacks whole and takes its layer by index (a slice handed
+            # to the grouped-matmul kernel would be a copy of the
+            # layer's matrices), and counts for the decode programs
+            whole = {k: v for k, v in stack.items()
+                     if self.is_mla and g.moe and self.moe_impl == "ragged"
+                     and k.startswith("experts_")}
+            if whole:
+                stack = {k: v for k, v in stack.items() if k not in whole}
 
             def body(carry, xs, moe=g.moe, has_lora=has_lora,
-                     has_pf=has_pf):
-                h, ck_g, cv_g, ks_g, vs_g, ssm_g = carry
+                     has_pf=has_pf, whole=whole, first=g.start):
+                h, ck_g, cv_g, ks_g, vs_g, ssm_g, st = carry
                 items = list(xs)
-                li, p = items[0], items[1]
+                at, p = items[0], items[1]
+                # the layer's index into the pools
+                li = at + first if first else at
                 k = 2
                 lora_l = items[k] if has_lora else None
                 k += int(has_lora)
                 pf_l = items[k] if has_pf else None
                 window = items[-1] if flags is not None else None
-                h, ck_g, cv_g, ks_g, vs_g, ssm_g = self._layer(
-                    h, p, ck_g, cv_g, li, window, moe, mode,
+                out = self._layer(
+                    h, {**p, **whole}, ck_g, cv_g, li, window, moe, mode,
                     positions=positions, page_tables=page_tables,
                     lengths=lengths, true_lens=true_lens, active=active,
                     start_pos=start_pos, lora=lora_l, lora_ids=adapter_ids,
                     ks=ks_g, vs=vs_g, packed=packed, pf=pf_l, ssm=ssm_g,
-                    ssm_rows=ssm_rows)
-                return (h, ck_g, cv_g, ks_g, vs_g, ssm_g), None
+                    ssm_rows=ssm_rows, stats=st,
+                    expert_layer=at if whole else None)
+                # (a latent layer's seventh element: its counters)
+                return out[:6] + (out[6] if self.is_mla else st,), None
 
             # scan length follows the actual stack: pipeline stages pass
             # stage-local views whose leading axis is a fraction of the
@@ -1321,27 +1416,16 @@ class TransformerLM:
                         f"sliding-window pattern ({pat}); per-stage window "
                         f"flags are not implemented")
                 xs = xs + (flags[:Lg],)
-            (x, ck_new, cv_new, ks_new, vs_new, ssm_new), _ = jax.lax.scan(
-                body, (x, ck_g, cv_g, ks_g, vs_g, ssm_g), xs)
-            new_k.append(ck_new)
-            new_v.append(cv_new)
-            new_ks.append(ks_new)
-            new_vs.append(vs_new)
-            new_ssm.append(ssm_new)
+            (x, *pools, stats), _ = jax.lax.scan(body, (x, *pools, stats),
+                                                 xs)
         if mode == "train":
             return x, None
-
-        def _cat(parts):
-            if parts and parts[0] is None:
-                return None
-            return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-
-        st, cv = (_cat([s[i] for s in new_ssm]) if new_ssm[0] is not None
-                  else None for i in (0, 1))
-        cache = KVCache(k=_cat(new_k), v=_cat(new_v),
-                        k_scale=_cat(new_ks), v_scale=_cat(new_vs),
-                        ssm_state=st, ssm_conv=cv)
-        return x, cache
+        k, v, ks, vs, ssm = pools
+        st, cv = ssm if ssm is not None else (None, None)
+        return x, KVCache(k=k, v=v, k_scale=ks, v_scale=vs, ssm_state=st,
+                          ssm_conv=cv,
+                          moe_stats=stats if mode == "decode"
+                          else cache.moe_stats)
 
     def _run_layers_kinds(self, params, cache: Optional[KVCache], x, mode,
                           *, positions, page_tables, lengths, true_lens,

@@ -243,8 +243,12 @@ def rope_attention_factor(arch: ModelArch) -> float:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
-               head_dim: int, mscale=1.0) -> jax.Array:
-    """Rotate the first ``2*len(inv_freq)`` dims of each head.
+               head_dim: int, mscale=1.0, interleave: bool = False
+               ) -> jax.Array:
+    """Rotate the first ``2*len(inv_freq)`` dims of each head: pair
+    ``i`` is dims ``(i, i + half)`` (rotate-half), or with
+    ``interleave`` dims ``(2i, 2i + 1)``, each where it lies (the
+    deepseek-v3 family's published pairing, ``rope_interleave``).
 
     x: [..., seq, heads, head_dim]; positions: [..., seq].  ``mscale``
     multiplies the rotated output (HF's attention_scaling on cos/sin —
@@ -258,8 +262,14 @@ def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
     sin = jnp.sin(angles)[..., :, None, :] * mscale
     x_rot = x[..., :rot].astype(jnp.float32)
     x_pass = x[..., rot:]
-    x1, x2 = jnp.split(x_rot, 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if interleave:
+        x1, x2 = x_rot[..., 0::2], x_rot[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x_rot.shape)
+    else:
+        x1, x2 = jnp.split(x_rot, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
     return jnp.concatenate([out.astype(x.dtype), x_pass], axis=-1)
 
 

@@ -365,7 +365,7 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             "status": "ok",
             **_device_identity(),
             "jax_version": jax.__version__,
-            "attention": engines[0].model.attn_impl,
+            "attention": engines[0].attention_path,
             "prefix_cache": ("native" if all(
                 e.prefix_cache is not None for e in engines) else "off"),
             "devices": [],

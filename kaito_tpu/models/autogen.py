@@ -36,6 +36,7 @@ SUPPORTED_ARCHITECTURES = {
     "FalconH1ForCausalLM",
     "GptOssForCausalLM",
     "MiMoV2ForCausalLM",
+    "JoyAILLMFlashForCausalLM",
 }
 
 
@@ -126,19 +127,8 @@ def arch_from_hf_config(cfg: Mapping) -> ModelArch:
             sliding_window_pattern=2,
         )
 
-    if model_type in ("deepseek_v2", "deepseek_v3"):
-        kw.update(
-            num_experts=int(_first(cfg, "n_routed_experts", default=0)),
-            num_experts_per_tok=int(_first(cfg, "num_experts_per_tok", default=0)),
-            moe_intermediate_size=_first(cfg, "moe_intermediate_size"),
-            num_shared_experts=int(_first(cfg, "n_shared_experts", default=0)),
-            moe_layer_start=int(_first(cfg, "first_k_dense_replace", default=0)),
-            kv_lora_rank=_first(cfg, "kv_lora_rank"),
-            q_lora_rank=_first(cfg, "q_lora_rank"),
-            qk_rope_head_dim=_first(cfg, "qk_rope_head_dim"),
-            qk_nope_head_dim=_first(cfg, "qk_nope_head_dim"),
-            v_head_dim=_first(cfg, "v_head_dim"),
-        )
+    if model_type in ("deepseek_v2", "deepseek_v3", "joyai_llm_flash"):
+        kw.update(_deepseek_fields(cfg, model_type))
 
     if model_type == "falcon":
         if bool(cfg.get("multi_query", False)) and "num_key_value_heads" not in cfg:
@@ -202,6 +192,64 @@ def arch_from_hf_config(cfg: Mapping) -> ModelArch:
                   linear_bias=True)
 
     return ModelArch(**kw)
+
+
+def _deepseek_fields(cfg: Mapping, model_type: str) -> dict:
+    """The DeepSeek-V2/V3 family's keys (``joyai_llm_flash`` publishes
+    the same ones): latent attention, the first ``first_k_dense_replace``
+    layers dense and an expert layer with shared experts after them.
+    The router is read from the keys the family publishes
+    (``scoring_func``, ``topk_method`` ``noaux_tc``: a correction bias
+    that chooses and never weighs, ``routed_scaling_factor``; a config
+    that carries none of them keeps the softmax router of deepseek-v2),
+    the rotary pairing from ``rope_interleave``, and the chip's share
+    of an expert layer from ``expert_shards`` / ``expert_shard`` (this
+    repo's keys, as ``_mimo_v2_fields`` reads them:
+    ``n_routed_experts`` then counts the experts held).  What is not
+    implemented is refused by name."""
+    def refuse(what):
+        raise ValueError(f"{model_type}: {what} is not implemented")
+
+    n_group = int(cfg.get("n_group") or 1)
+    if n_group > 1 and int(cfg.get("topk_group") or n_group) < n_group:
+        refuse(f"group-limited routing (topk_group "
+               f"{cfg.get('topk_group')} of n_group {n_group})")
+    scoring = str(cfg.get("scoring_func") or "softmax")
+    if scoring not in ("softmax", "sigmoid"):
+        refuse(f"scoring_func {scoring!r}")
+    method = str(cfg.get("topk_method") or "greedy")
+    if method not in ("greedy", "noaux_tc"):
+        refuse(f"topk_method {method!r}")
+    if scoring == "sigmoid" and not bool(cfg.get("norm_topk_prob", True)):
+        refuse("sigmoid scores with norm_topk_prob false")
+    if int(cfg.get("moe_layer_freq") or 1) != 1:
+        refuse(f"moe_layer_freq {cfg.get('moe_layer_freq')!r}")
+    scaling = cfg.get("rope_scaling") or {}
+    stype = str(scaling.get("rope_type", scaling.get("type", "default")))
+    if stype.lower() not in ("default", "linear", "yarn", "llama3"):
+        refuse(f"rope_scaling {scaling!r} (no table for it)")
+    shards = int(cfg.get("expert_shards", 1))
+    shard = int(cfg.get("expert_shard", 0))
+    if not 0 <= shard < shards:
+        raise ValueError(f"{model_type}: expert_shard {shard} of {shards}")
+    return dict(
+        num_experts=int(_first(cfg, "n_routed_experts", default=0)) * shards,
+        num_experts_per_tok=int(_first(cfg, "num_experts_per_tok", default=0)),
+        moe_intermediate_size=_first(cfg, "moe_intermediate_size"),
+        num_shared_experts=int(_first(cfg, "n_shared_experts", default=0)),
+        moe_layer_start=int(_first(cfg, "first_k_dense_replace", default=0)),
+        kv_lora_rank=_first(cfg, "kv_lora_rank"),
+        q_lora_rank=_first(cfg, "q_lora_rank"),
+        qk_rope_head_dim=_first(cfg, "qk_rope_head_dim"),
+        qk_nope_head_dim=_first(cfg, "qk_nope_head_dim"),
+        v_head_dim=_first(cfg, "v_head_dim"),
+        rope_interleave=bool(cfg.get("rope_interleave", False)),
+        router_scoring=scoring,
+        router_bias=method == "noaux_tc",
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
+        expert_shards=shards,
+        expert_shard=shard,
+    )
 
 
 def _mimo_v2_fields(cfg: Mapping, layers: int) -> dict:

@@ -98,6 +98,9 @@ class ModelArch:
     qk_rope_head_dim: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
+    # rotary pairs are (2i, 2i+1), as the deepseek-v3 family publishes
+    # them (``rope_interleave``); False: (i, i + half), rotate-half
+    rope_interleave: bool = False
 
     # state-space mixer beside attention in every block (falcon-h1: a
     # Mamba-2 mixer fed the same normed input as attention, the two
@@ -213,6 +216,23 @@ class ModelArch:
         return self.num_layers * per_layer * dtype_bytes
 
     @property
+    def mla_dims(self) -> tuple:
+        """(nope, rope, latent, value) widths of a latent-attention
+        head: what a query head multiplies without rotation, what
+        rotates (the one key part all heads share), the cached latent's
+        rank, and a value head's size."""
+        return (self.qk_nope_head_dim or self.head_dim,
+                self.qk_rope_head_dim or 64,
+                self.kv_lora_rank or 512,
+                self.v_head_dim or self.head_dim)
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes a cached token's latent is stored at where the decode
+        kernel reads the pool (``stored_key_dim``: 576 lies in 640)."""
+        return stored_key_dim(self.kv_cache_dim)
+
+    @property
     def kv_cache_heads(self) -> int:
         """Head count of the KV cache: MLA caches ONE shared latent."""
         return 1 if self.attention_kind == AttentionKind.MLA else self.num_kv_heads
@@ -255,7 +275,9 @@ class ModelArch:
             attn = h * self.num_heads * self.head_dim + 2 * h * self.num_kv_heads * self.head_dim + self.num_heads * self.head_dim * h
         if self.num_experts > 0:
             inter = self.moe_intermediate_size or self.intermediate_size
-            experts = self.num_experts + self.num_shared_experts
+            # (the experts HELD here: all of them unless the layer is
+            # shared between chips)
+            experts = self.experts_held + self.num_shared_experts
             mlp_moe = 3 * h * inter * experts + h * self.num_experts
             dense_layers = self.moe_layer_start
             moe_layers = self.num_layers - dense_layers
@@ -300,15 +322,18 @@ class ModelArch:
             total += 2 * h
         return total
 
-    def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
+    def kv_bytes_per_token(self, dtype_bytes: int = 2,
+                           stored: bool = False) -> int:
         """KV-cache bytes per token across all layers.
 
         GQA formula matches the reference
         (``pkg/model/interface.go:217``): ``2*layers*kv_heads*head_dim*dtype``.
-        MLA caches the compressed latent + rope key instead.
+        MLA caches the compressed latent + rope key instead; ``stored``:
+        at the lanes the kernel-read latent pool lays a token out in
+        (``latent_lanes``).
         """
         if self.attention_kind == AttentionKind.MLA:
-            per_layer = (self.kv_lora_rank or 0) + (self.qk_rope_head_dim or 0)
+            per_layer = self.latent_lanes if stored else self.kv_cache_dim
             return self.num_layers * per_layer * dtype_bytes
         if self.layer_attention is not None:
             # what a token holds while every layer still holds it: a
@@ -356,8 +381,9 @@ class ModelMetadata:
     def max_model_len(self) -> int:
         return self.token_limit or self.arch.max_position_embeddings
 
-    def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
-        return self.arch.kv_bytes_per_token(dtype_bytes)
+    def kv_bytes_per_token(self, dtype_bytes: int = 2,
+                           stored: bool = False) -> int:
+        return self.arch.kv_bytes_per_token(dtype_bytes, stored)
 
     def disk_storage_bytes(self) -> int:
         """Provisioned disk for weights: expand for download+load headroom,

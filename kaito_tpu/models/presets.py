@@ -87,6 +87,14 @@ _DEEPSEEK_V3 = {
     "v_head_dim": 128,
     "max_position_embeddings": 163840,
     "rope_theta": 10000.0,
+    # the router and the rotary pairing the family publishes; its
+    # group limit (n_group 8, topk_group 4) has no path here and is
+    # left out: the top 8 are taken over all 256 (ROADMAP R7)
+    "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc",
+    "norm_topk_prob": True,
+    "routed_scaling_factor": 2.5,
+    "rope_interleave": True,
 }
 _add("deepseek-r1-0528", "deepseek-ai/DeepSeek-R1-0528", _DEEPSEEK_V3, tags=("reasoning",))
 _add("deepseek-v3-0324", "deepseek-ai/DeepSeek-V3-0324", _DEEPSEEK_V3)

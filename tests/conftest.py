@@ -26,8 +26,8 @@ def cpu_devices():
     return devices
 
 
-# Two tests under tests/kbench/ (benchmark files, not a program PR's to
-# edit) pin where BENCHMARK.json's per_layer ends, and the benchmark's
+# Three tests under tests/kbench/ (benchmark files, not a program PR's
+# to edit) pin where BENCHMARK.json's per_layer ends, and the benchmark's
 # contract has every later PR append its entries there (the driver
 # refused PR 38 for putting them anywhere else):
 # - test_kbench_prefill_multi_metric.py finds PR 34's entry as
@@ -38,13 +38,22 @@ def cpu_devices():
 #   five.  Everything else it asserts is held, with the tail compared
 #   as a prefix, in test_kbench_part_metrics.py::
 #   test_pr_34s_and_pr_38s_entries_stand_where_they_stood.
+# - test_kbench_part_metrics.py::
+#   test_the_eleven_entries_are_appended_for_every_cell holds PR 40's
+#   eleven as ``per_layer[-11:]`` and each one's cells equal to the
+#   three there were.  Everything else it asserts is held, the eleven
+#   found by name and each list compared as a prefix, in
+#   test_kbench_joyai_llm_flash.py::
+#   test_pr_40s_eleven_entries_stand_where_they_stood.
 # strict: the day a benchmark PR finds the entries by name these
 # markers fail the tests, and go.
 _PINNED_BY_INDEX = (
     "tests/kbench/test_kbench_prefill_multi_metric.py::"
     "test_the_metric_is_data_on_a_reader_the_benchmark_had",
     "tests/kbench/test_kbench_mimo_v2.py::"
-    "test_pr_34s_entry_stands_where_it_stood")
+    "test_pr_34s_entry_stands_where_it_stood",
+    "tests/kbench/test_kbench_part_metrics.py::"
+    "test_the_eleven_entries_are_appended_for_every_cell")
 
 
 def pytest_collection_modifyitems(items):
