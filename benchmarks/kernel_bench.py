@@ -11,7 +11,7 @@ here carries ``call_ms``, the host's clock round the case called alone
 (the wrapper's transposes included), which is what a kernel change is
 measured by before a cell is run and is no benchmark metric.
 
-Usage:  python benchmarks/kernel_bench.py --parity
+Usage:  python benchmarks/kernel_bench.py --parity [--only name,name]
 
 This is ``chip_smoke.py``'s ``kernels`` leg: one JSON line per case,
 then one naming the device; exit 1 if any case fails to compile or to
@@ -194,12 +194,15 @@ def decode_case(int8_kv: bool = False) -> KernelCase:
         work=live_rows * 2 + live_pages * 2 * HKV * 4)
 
 
-def mla_decode_case(name: str, rows: int, context: int) -> KernelCase:
+def mla_decode_case(name: str, rows: int, context: int,
+                    ragged: float = 0.25) -> KernelCase:
     """The latent decode kernel at JoyAI-LLM-Flash's widths (32 heads
     against one stream of 512 + 64 at 640 stored lanes, pages of 64):
-    ``rows`` rows of about ``context`` tokens, ragged, one idle, against
-    the XLA path over the same token-flat pool.  ``call_ms`` takes in
-    the two einsums around the kernel."""
+    ``rows`` rows of ``context`` tokens give or take ``ragged`` of it,
+    one idle, against the XLA path over the same token-flat pool.
+    ``call_ms`` takes in the two einsums around the kernel.  One row of
+    16 gangs gives the cost a gang, the cell's 24 rows what rows add to
+    it, and 24 rows near ``max_model_len`` the long end."""
     from kaito_tpu.engine.attention import mla_paged_decode_attention
     from kaito_tpu.engine.ops.mla_decode_attention import (
         mla_paged_decode_attention_pallas)
@@ -219,8 +222,9 @@ def mla_decode_case(name: str, rows: int, context: int) -> KernelCase:
           / math.sqrt(dl)).astype(jnp.bfloat16)
     pt = jax.random.permutation(keys[5], jnp.arange(1, P, dtype=jnp.int32)
                                 ).reshape(rows, pmax)
-    lens = jax.random.randint(keys[6], (rows,), context * 3 // 4,
-                              min(context * 5 // 4, pmax * PS), jnp.int32)
+    lens = jax.random.randint(
+        keys[6], (rows,), int(context * (1 - ragged)),
+        min(int(context * (1 + ragged)), pmax * PS), jnp.int32)
     if rows > 2:
         lens = lens.at[jnp.asarray([1, rows - 1])].set(
             jnp.asarray([0, PS], jnp.int32))
@@ -487,6 +491,8 @@ CASES: dict[str, Callable[[], KernelCase]] = {
     "flash_prefill_mla32": lambda: prefill_case("flash_prefill_mla32"),
     "mla_decode_24x3k": lambda: mla_decode_case("mla_decode_24x3k", 24, 3072),
     "mla_decode_1x4k": lambda: mla_decode_case("mla_decode_1x4k", 1, 4096),
+    "mla_decode_24x5k": lambda: mla_decode_case("mla_decode_24x5k", 24, 4864,
+                                                ragged=0.05),
     "flash_prefill_packed": packed_case,
     "gemv_int8": lambda: gemv_case("int8"),
     "gemv_int8_prefetch": lambda: gemv_case("int8", prefetch=True),
@@ -496,12 +502,14 @@ CASES: dict[str, Callable[[], KernelCase]] = {
 }
 
 
-def run_parity() -> int:
-    """The chip smoke's kernels leg.  A case that does not compile is a
-    failed case with the compiler's message, not a skipped one."""
+def run_parity(only: Optional[list] = None) -> int:
+    """The chip smoke's kernels leg (``only``: just those cases).  A
+    case that does not compile is a failed case with the compiler's
+    message, not a skipped one."""
     dev = jax.devices()[0]
     failed = 0
-    for name, build in CASES.items():
+    for name in only or CASES:
+        build = CASES[name]
         try:
             res = parity(build())
         except Exception as e:   # report every kernel, then fail the run
@@ -523,8 +531,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--parity", action="store_true", required=True,
                     help="compile and check every kernel; JSON lines")
-    ap.parse_args()
-    sys.exit(run_parity())
+    ap.add_argument("--only", default="",
+                    help="comma-separated case names; default every case")
+    args = ap.parse_args()
+    sys.exit(run_parity([n for n in args.only.split(",") if n] or None))
 
 
 if __name__ == "__main__":

@@ -30,6 +30,37 @@ multiplied) and are masked by position.  A row of length 0, a slot that
 decodes nothing, copies nothing and writes zeros.  Pages beyond a row's
 length are neither copied nor computed; the cache is never cast.
 
+What a turn overlaps, and what the two sizes are chosen from.  A turn of
+the loop is serial in itself (wait for the gang's copies, scores, mask
+and softmax, values, refill the slot), so what it overlaps is the copy
+engine against all of that: while one gang computes, the other
+``N_BUF - 1`` slots' copies are in flight.  Both sizes were read off the
+chip (a v5e; PERF.md section 6, PR 43), on 24 rows of 2,816 tokens at
+640 stored lanes:
+
+- ``GANG_TOKENS``: a turn's fixed cost (the fill and drain of two
+  dependent products, two cross-lane reductions between them, the
+  loop's branch) is a quarter of a microsecond, and a panel has to
+  stream for several times that to hide it: 512 tokens are 0.8 us of
+  HBM.  At 256 a turn took 0.61 us where its bytes need 0.40;
+  at 384 to 1,024 a call runs at nine tenths of the HBM stream and a
+  larger panel only computes more masked lanes in a row's last gang.
+  The head count does not move the choice: at 16 and 32 heads the call
+  is bound by its copies from 384 tokens on, at 128 heads (where the
+  products, not the copies, bound it) 512 beat 256 by a sixth and 128
+  lost a half.  ``pages_per_gang`` turns the tokens into pages of the
+  pool's page size, and a table narrower than a gang is one gang.
+- ``N_BUF``: with two slots ONE gang's copy is in flight while a gang
+  computes and the copy engine idles between a slot's last read and its
+  refill; with four, three gangs (2.4 us of stream) are queued behind
+  the one computing, which covers a copy's latency.  Eight slots read
+  what four do.  Four slots of 512 tokens are 2.6 MB of VMEM.
+
+Two sub-panels a turn as independent chains, one semaphore and one wait
+a gang, and a refill issued before the fold into ``acc`` were each
+measured at these sizes and bought nothing or lost 3-13%: a call bound
+by its copies has no use for a shorter turn.
+
 ``attention.mla_paged_decode_attention`` is the same contract in plain
 JAX; tests compare the two in interpreter mode.
 """
@@ -44,10 +75,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# slots of the ring, a power of two, each a gang of pages
-N_BUF = 2
-# tokens a gang holds: its panel is [GANG_TOKENS, lanes]
-GANG_TOKENS = 256
+# slots of the ring, a power of two, each a gang of pages: one computes
+# while the copies of the others are in flight
+N_BUF = 4
+# tokens a gang holds: its panel is [GANG_TOKENS, lanes], several times
+# a turn's fixed cost in HBM time
+GANG_TOKENS = 512
 
 
 def _mla_decode_kernel(

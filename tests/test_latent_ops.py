@@ -29,33 +29,51 @@ def _pool(rng, layers, pages, ps, dtype):
     return jnp.asarray(pool, dtype)
 
 
-def _weights(rng, dtype):
-    return (jnp.asarray(rng.standard_normal((DL, H * DN)) / np.sqrt(DL), dtype),
-            jnp.asarray(rng.standard_normal((DL, H * DV)) / np.sqrt(DL), dtype))
+def _weights(rng, dtype, heads=H):
+    return (jnp.asarray(rng.standard_normal((DL, heads * DN)) / np.sqrt(DL),
+                        dtype),
+            jnp.asarray(rng.standard_normal((DL, heads * DV)) / np.sqrt(DL),
+                        dtype))
 
 
 def _absorbed(q_nope, q_rope, wk, dtype):
-    B = q_nope.shape[0]
-    q_lat = jnp.einsum("bhd,lhd->bhl", q_nope, wk.reshape(DL, H, DN),
+    B, heads, _ = q_nope.shape
+    q_lat = jnp.einsum("bhd,lhd->bhl", q_nope, wk.reshape(DL, heads, DN),
                        preferred_element_type=jnp.float32)
     return jnp.concatenate(
         [q_lat * SCALE, q_rope.astype(jnp.float32) * SCALE,
-         jnp.zeros((B, H, LANES - DL - DR), jnp.float32)], -1).astype(dtype)
+         jnp.zeros((B, heads, LANES - DL - DR), jnp.float32)], -1).astype(dtype)
 
 
-@pytest.mark.parametrize("ps,pmax,lengths", [
+@pytest.mark.parametrize("heads,ps,pmax,lengths", [
     # ragged rows, a row of length 1, one that ends on a page boundary,
-    # one that fills its table, and a slot that decodes nothing
-    (16, 6, [1, 16, 0, 37, 96]),
-    # gangs of 256 tokens: rows of less than one gang, exactly one, and
-    # two and a part (the last gang's pages past the row's are stale)
-    (64, 12, [300, 256, 0, 700, 64]),
+    # one that fills its table, and a slot that decodes nothing; the
+    # table is narrower than a gang, so a row is one gang
+    (4, 16, 6, [1, 16, 0, 37, 96]),
+    # gangs of 512 tokens (8 pages of 64): rows of less than one gang,
+    # and of one and a part (the last gang's pages past the row's are
+    # stale)
+    (4, 64, 12, [300, 256, 0, 700, 64]),
     # every row idle but the last: the ring starts cold at row 3
-    (16, 4, [0, 0, 0, 50]),
+    (4, 16, 4, [0, 0, 0, 50]),
+    # fewer gangs in all (two) than the ring has slots: the cold start
+    # runs out of rows and the spare slots start nothing
+    (4, 64, 8, [100, 0, 30]),
+    # gangs of 32 pages of 16: a row that ends exactly on a gang's last
+    # token, on the first token of the next, one short of it, on two
+    # gangs exactly, and an odd number of gangs (three) in a row
+    (4, 16, 72, [512, 513, 511, 1024, 1030]),
+    # idle rows between live ones with the four-slot ring running two
+    # and three rows ahead, and a table the last row fills
+    (4, 16, 40, [600, 0, 0, 40, 0, 530, 0, 640]),
+    # the head counts of the presets (deepseek-v2-lite 16, JoyAI 32,
+    # deepseek-v3 128) over one, two and three gangs
+    *[(heads, 16, 72, [520, 0, 1100, 33]) for heads in (16, 32, 128)],
 ])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 3e-2)])
-def test_decode_kernel_equals_the_xla_path(ps, pmax, lengths, dtype, tol):
+def test_decode_kernel_equals_the_xla_path(heads, ps, pmax, lengths, dtype,
+                                           tol):
     rng = np.random.default_rng(len(lengths) + ps)
     B, L = len(lengths), 2
     pages = B * pmax + 1
@@ -63,9 +81,9 @@ def test_decode_kernel_equals_the_xla_path(ps, pmax, lengths, dtype, tol):
     pt = jnp.asarray(rng.permutation(np.arange(1, pages))[:B * pmax]
                      .reshape(B, pmax), jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
-    q_nope = jnp.asarray(rng.standard_normal((B, H, DN)), dtype)
-    q_rope = jnp.asarray(rng.standard_normal((B, H, DR)), dtype)
-    wk, wv = _weights(rng, dtype)
+    q_nope = jnp.asarray(rng.standard_normal((B, heads, DN)), dtype)
+    q_rope = jnp.asarray(rng.standard_normal((B, heads, DR)), dtype)
+    wk, wv = _weights(rng, dtype, heads)
     layer = jnp.int32(1)
 
     @jax.jit
@@ -73,7 +91,7 @@ def test_decode_kernel_equals_the_xla_path(ps, pmax, lengths, dtype, tol):
         out_lat = mla_paged_decode_attention_pallas(
             _absorbed(q_nope, q_rope, wk, dtype), pool, pt, lens, layer,
             value_lanes=DL, interpret=True)
-        return jnp.einsum("bhl,lhd->bhd", out_lat, wv.reshape(DL, H, DV),
+        return jnp.einsum("bhl,lhd->bhd", out_lat, wv.reshape(DL, heads, DV),
                           preferred_element_type=jnp.float32)
 
     got = np.asarray(kernel(q_nope, q_rope, pool, pt, lens), np.float32)
@@ -218,22 +236,37 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_kernels_compile_for_v5e_at_the_published_widths(one_chip):
-    """24 rows of 32 heads against a [39, P, 64, 640] pool, and flash
-    prefill at every bucket on 32 heads of 256 | 128."""
-    def sds(shape, dtype=jnp.bfloat16):
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    def make(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
 
-    B, heads, lanes, ps, pmax = 24, 32, 640, 64, 80
-    text = jax.jit(lambda q, pool, pt, ln, li:
+
+def _compile_decode(sds, heads):
+    B, lanes, ps, pmax = 24, 640, 64, 80
+    return jax.jit(lambda q, pool, pt, ln, li:
                    mla_paged_decode_attention_pallas(
                        q, pool, pt, ln, li, value_lanes=512)).lower(
         sds((B, heads, lanes)), sds((39, 1600, ps, lanes)),
         sds((B, pmax), jnp.int32), sds((B,), jnp.int32),
         sds((), jnp.int32)).compile().as_text()
-    assert "tpu_custom_call" in text
+
+
+def test_kernels_compile_for_v5e_at_the_published_widths(sds):
+    """24 rows of 32 heads against a [39, P, 64, 640] pool, and flash
+    prefill at every bucket on 32 heads of 256 | 128."""
+    heads = 32
+    assert "tpu_custom_call" in _compile_decode(sds, heads)
     for T in (128, 1024, 4096):
         jax.jit(lambda q, k, v, tl: flash_prefill_attention(
             q, k, v, tl, jnp.int32(1 << 30), scale=0.0722)).lower(
             sds((1, T, heads, 256)), sds((1, T, heads, 256)),
             sds((1, T, heads, 128)), sds((1,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("heads", [16, 128])
+def test_decode_kernel_compiles_for_v5e_at_the_presets_head_counts(sds, heads):
+    """The same gang and ring at deepseek-v2-lite's 16 heads and
+    deepseek-v3's 128: a panel's scores are [heads, 512] float32."""
+    assert "tpu_custom_call" in _compile_decode(sds, heads)
