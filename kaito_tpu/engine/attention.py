@@ -456,3 +456,37 @@ def paged_decode_attention(
     probs = _softmax_with_sink(scores, sink, (1, 2))
     out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v.dtype), v)
     return out.reshape(B, H, Dv)
+
+
+def _lane_slot(num_heads: int, kv_heads: int, pack: int) -> jax.Array:
+    """[H] int32: which of a row's ``pack`` head-wide lane groups holds
+    the KV head of each query head."""
+    return (jnp.arange(num_heads) // (num_heads // kv_heads)) % pack
+
+
+def lane_pack_queries(q: jax.Array, kv_heads: int, pack: int) -> jax.Array:
+    """Queries laid out against pools whose rows hold ``pack`` KV heads
+    side by side (metadata.heads_per_lane_row): ``q`` [..., H, D] ->
+    [..., H, pack * D], a query head's D numbers in the lane group of
+    its KV head and zeros in the others.  Against such a row, read as
+    one KV head of ``pack * D`` lanes shared by ``pack`` times the
+    query heads, the score is the score against the head's own key
+    exactly (the other heads' keys meet zeros)."""
+    H, D = q.shape[-2:]
+    onehot = jax.nn.one_hot(_lane_slot(H, kv_heads, pack), pack,
+                            dtype=q.dtype)                       # [H, pack]
+    return (q[..., :, None, :] * onehot[:, :, None]).reshape(
+        q.shape[:-1] + (pack * D,))
+
+
+def lane_unpack_outputs(out: jax.Array, kv_heads: int,
+                        pack: int) -> jax.Array:
+    """The other half of ``lane_pack_queries``: attention over packed
+    rows gives every query head the weighted sum of whole rows, [..., H,
+    pack * Dv]; its own KV head's values are one lane group of that."""
+    H = out.shape[-2]
+    Dv = out.shape[-1] // pack
+    o = out.reshape(out.shape[:-1] + (pack, Dv))
+    slot = _lane_slot(H, kv_heads, pack).reshape(
+        (1,) * (o.ndim - 3) + (H, 1, 1))
+    return jnp.take_along_axis(o, slot, axis=-2)[..., 0, :]

@@ -43,7 +43,8 @@ from kaito_tpu.engine.config import EngineConfig
 from kaito_tpu.engine.devprof import phase_scope
 from kaito_tpu.engine.grammar import GrammarCache, GrammarSlot, GrammarTable
 from kaito_tpu.engine.kv_cache import (KVCache, NULL_PAGE, create_kv_cache,
-                                       create_state_pool,
+                                       create_conv_state_pool,
+                                      create_state_pool,
                                        kv_cache_is_quantized,
                                        scale_bytes_per_page)
 from kaito_tpu.engine.model import TransformerLM
@@ -380,7 +381,7 @@ class InferenceEngine:
             self.model.moe_impl = ("dense" if cfg.expert_parallel > 1
                                    else "ragged")
         self.tokenizer = load_tokenizer(self.md.hf_id, arch.vocab_size)
-        if self.model.has_ssm:
+        if self.model.has_state:
             self._refuse_for_state_pool(mesh)
         # an expert layer's grouped matmuls: the Pallas kernel where the
         # attention kernels are, on one chip (engine/nn.py); on a mesh
@@ -565,8 +566,7 @@ class InferenceEngine:
         # the per-slot recurrent-state pool of a model with a
         # state-space mixer (docs/kv-cache.md) is allocated BEFORE HBM
         # is measured: the pages get what it leaves
-        self._state_pool = create_state_pool(arch, cfg.max_num_seqs,
-                                             self.dtype)
+        self._state_pool = self._make_state_pool()
         self.sizing_report: dict = {}
         self._num_window_pages = 0
         num_pages = cfg.max_pages or self._derive_max_pages()
@@ -605,12 +605,20 @@ class InferenceEngine:
                         self.md.kv_bytes_per_token(
                             jnp.dtype(cfg.kv_dtype).itemsize),
                         self.attention_path)
-        if self.model.has_ssm:
+        if self.model.has_state:
             self.sizing_report["state_pool_bytes"] = \
                 self.cache.state_pool_bytes
             logger.info("state pool: %d slots x %d layers, %s "
-                        "(%.2f GiB)", cfg.max_num_seqs, arch.num_layers,
+                        "(%.2f GiB)", cfg.max_num_seqs,
+                        arch.conv_layers or arch.num_layers,
                         self.dtype.name, self.cache.state_pool_bytes / 2**30)
+        if self.model.has_conv:
+            # pages for the attention layers alone, a row of conv state
+            # for the rest (docs/kv-cache.md, "A row of conv state")
+            self.sizing_report["kv_bytes_per_token"] = \
+                self.md.kv_bytes_per_token(jnp.dtype(cfg.kv_dtype).itemsize)
+            self.sizing_report["state_bytes_per_row"] = \
+                arch.state_bytes_per_seq(self.dtype.itemsize)
         self.adapter_index: dict[str, int] = {}
         self.adapters_merged = False
         self.adapter_cache = None
@@ -687,9 +695,10 @@ class InferenceEngine:
         if self.pp_exec is not None:
             self.params = self.pp_exec.stage_params(self.params)
         self.prefix_cache = None
-        if cfg.enable_prefix_caching and self.model.has_ssm:
+        if cfg.enable_prefix_caching and self.model.has_state:
             # a page of a shared prefix carries keys and values but no
-            # recurrent state: reuse waits for state snapshots
+            # recurrent state (a radix-tree node no conv state): reuse
+            # waits for state snapshots
             logger.warning("prefix caching requested but this model keeps "
                            "a recurrent state no page carries; serving "
                            "WITHOUT prefix reuse")
@@ -1229,14 +1238,33 @@ class InferenceEngine:
          "token's state update cannot be rolled back)"),
     )
 
+    # what a row of conv state (lfm2) refuses beside those: its layers
+    # run as a schedule of scans by kind, which has no segment-packed
+    # form (and no int8 pages: _refuse_for_state_pool)
+    _CONV_STATE_REFUSALS = (
+        ("prefill_pack", 1, "packed prefill (a schedule of layer kinds "
+         "has no segment-packed scan; prefill_pack 1 serves one-row "
+         "programs)"),
+    )
+
     def _refuse_for_state_pool(self, mesh) -> None:
         """Refuse by name, at start, every setting a model with a
-        state-space mixer cannot be served under yet."""
+        state pool (a state-space mixer's, or rows of conv state)
+        cannot be served under yet."""
         if mesh is not None:
             raise ValueError(
                 f"{self.md.name} keeps a per-slot recurrent state beside "
                 f"its KV pages and is served on one device: no mesh")
-        for field_name, off, what in self._STATE_POOL_REFUSALS:
+        refusals = self._STATE_POOL_REFUSALS
+        if self.model.has_conv:
+            if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
+                raise ValueError(
+                    f"{self.md.name} keeps a per-slot recurrent state "
+                    f"beside its KV pages and cannot be served with an "
+                    f"int8 KV cache (its token-flat pools have no scale "
+                    f"tensors): unset kv_dtype")
+            refusals = refusals + self._CONV_STATE_REFUSALS
+        for field_name, off, what in refusals:
             if getattr(self.cfg, field_name) != off:
                 raise ValueError(
                     f"{self.md.name} keeps a per-slot recurrent state "
@@ -1334,9 +1362,15 @@ class InferenceEngine:
     def attention_path(self) -> str:
         """What reads the cache in a decode step, as ``/health`` names
         it: ``pallas`` where a kernel does (a latent pool: the kernel-
-        read layout), ``jax`` where XLA gathers the pages."""
+        read layout), ``jax`` where XLA gathers the pages; ``+conv``
+        behind either where the model's other layers are short
+        convolutions."""
         if self.model.is_mla:
             return "pallas" if self.latent_kernel else "jax"
+        if self.model.has_conv:
+            # most layers mix their tokens by a short convolution (XLA)
+            # and read a row of conv state, not pages
+            return self.model.attn_impl + "+conv"
         return self.model.attn_impl
 
     @property
@@ -1358,7 +1392,7 @@ class InferenceEngine:
                 f"{self.md.name} keeps a second page pool for its window "
                 f"layers: imported KV pages carry none of it, so a "
                 f"request with KV cannot be admitted")
-        if self.model.has_ssm:
+        if self.model.has_state:
             raise ValueError(
                 f"{self.md.name} keeps a per-slot recurrent state beside "
                 f"its KV pages: imported KV pages carry none of it, so a "
@@ -1366,10 +1400,22 @@ class InferenceEngine:
 
     def _state_rows(self, idxs) -> Optional[jax.Array]:
         """The slots' rows of the state pool, for a prefill program (None
-        for a model with no mixer: the argument compiles away)."""
-        if not self.model.has_ssm:
+        for a model with no pool: the argument compiles away)."""
+        if not self.model.has_state:
             return None
         return jnp.asarray(np.asarray(idxs, np.int32))
+
+    def _make_state_pool(self) -> dict:
+        """The zeroed state pool as the cache's fields: a state-space
+        mixer's state and convolution tail, or the short-convolution
+        layers' rows of conv state; {} for a model with neither."""
+        arch, slots = self.md.arch, self.cfg.max_num_seqs
+        if self.model.has_conv:
+            return {"conv_state": create_conv_state_pool(arch, slots,
+                                                         self.dtype)}
+        state, conv = create_state_pool(arch, slots, self.dtype)
+        return {} if state is None else {"ssm_state": state,
+                                         "ssm_conv": conv}
 
     def _fresh_cache(self) -> KVCache:
         """Zeroed page pool, laid out for the active parallelism mode.
@@ -1381,16 +1427,13 @@ class InferenceEngine:
                        self.cfg.page_size, jnp.dtype(self.cfg.kv_dtype),
                        window_pages=self._num_window_pages,
                        latent_kernel=self.latent_kernel)
-        if self.model.has_ssm:
+        if self.model.has_state:
             # one device (_refuse_for_state_pool); the state pool that
             # was there when HBM was measured, or a new one after a
             # failed step took it
-            pool, self._state_pool = self._state_pool, (None, None)
-            if pool[0] is None:
-                pool = create_state_pool(self.md.arch, self.cfg.max_num_seqs,
-                                         self.dtype)
-            return dataclasses.replace(make(), ssm_state=pool[0],
-                                       ssm_conv=pool[1])
+            pool, self._state_pool = self._state_pool, None
+            return dataclasses.replace(make(),
+                                       **(pool or self._make_state_pool()))
         if self.pp_exec is not None:
             return self.pp_exec.stage_cache(make())
         if self.mesh is None:
@@ -2037,8 +2080,8 @@ class InferenceEngine:
     @property
     def state_rows_in_use(self) -> int:
         """Rows of the recurrent-state pool that hold a sequence's state
-        (a slot with a request; 0 for a model with no mixer)."""
-        if not self.model.has_ssm:
+        (a slot with a request; 0 for a model with no such pool)."""
+        if not self.model.has_state:
             return 0
         return sum(1 for s in self.slots if s.request is not None)
 
@@ -2936,7 +2979,7 @@ class InferenceEngine:
                 kv_pages_used=(self.allocator.num_pages - 1
                                - self.allocator.available),
                 **({"state_rows": self.state_rows_in_use}
-                   if self.model.has_ssm else {}),
+                   if self.model.has_state else {}),
                 # two kinds of page: what the schedule freed behind the
                 # window in this step, and what is held
                 **({"window_pages_freed":
@@ -3162,7 +3205,7 @@ class InferenceEngine:
         slot.prefilling = True
         slot.prefill_pos = cached
         slot.prefill_tokens = tokens
-        if self.model.has_ssm:
+        if self.model.has_state:
             self.counters["state_resets_total"] += 1
             if req.preemptions:
                 self.counters["state_recomputes_total"] += 1
@@ -3702,7 +3745,7 @@ class InferenceEngine:
         # (batch-axis per bucket for MLA, which has no packed kernel),
         # context chunks batch per bucket
         # (a state-space mixer has no segment-packed scan either)
-        no_pack = self.model.is_mla or self.model.has_ssm
+        no_pack = self.model.is_mla or self.model.has_state
         groups: list[tuple[tuple, list]] = []
         index: dict[tuple, int] = {}
         for p in picks:

@@ -70,6 +70,14 @@ class KVCache:
     # 1,000; no page carries any of it.  None for every other model.
     ssm_state: Optional[jax.Array] = None
     ssm_conv: Optional[jax.Array] = None
+    # A row of conv state (docs/kv-cache.md): a model some of whose
+    # layers mix tokens by a short convolution and not by attention
+    # (lfm2) keeps for those layers, per decode slot, the last
+    # conv_kernel-1 inputs of the convolution, [conv layers, slots,
+    # conv_kernel-1, hidden], in the type the model is served in; those
+    # layers have no page, and the page pools hold the attention layers
+    # alone.  None for every other model.
+    conv_state: Optional[jax.Array] = None
     # Two kinds of page (docs/kv-cache.md): a model whose window layers
     # have a geometry of their own (mimo_v2) keeps those layers' keys
     # and values in a second pair of pools, addressed through a second
@@ -110,7 +118,9 @@ class KVCache:
 
     @property
     def state_pool_bytes(self) -> int:
-        """Bytes of the per-slot recurrent-state pool (0: no mixer)."""
+        """Bytes of the per-slot state pool (0: a model with none)."""
+        if self.conv_state is not None:
+            return int(self.conv_state.nbytes)
         if self.ssm_state is None:
             return 0
         return int(self.ssm_state.nbytes + self.ssm_conv.nbytes)
@@ -139,6 +149,15 @@ def create_state_pool(arch: ModelArch, slots: int, dtype: jnp.dtype):
                       dtype))
 
 
+def create_conv_state_pool(arch: ModelArch, slots: int, dtype: jnp.dtype):
+    """Zeroed rows of conv state for ``slots`` decode slots, or None
+    for a model with no short-convolution layer."""
+    if not arch.conv_layers:
+        return None
+    return jnp.zeros((arch.conv_layers, slots, arch.conv_kernel - 1,
+                      arch.hidden_size), dtype)
+
+
 def create_kv_cache(
     arch: ModelArch,
     num_pages: int,
@@ -160,9 +179,12 @@ def create_kv_cache(
 
         def pools(kind, pages):
             layers, heads, dk, dv = arch.kv_page_geometry(kind)
-            return (jnp.zeros((layers, pages, page_size * heads,
-                               stored_key_dim(dk)), dtype),
-                    jnp.zeros((layers, pages, page_size * heads, dv), dtype))
+            # (heads narrower than a lane tile lie ``n`` to a row)
+            n = arch.kv_heads_per_row(kind)
+            rows = page_size * heads // n
+            return (jnp.zeros((layers, pages, rows, n * stored_key_dim(dk)),
+                              dtype),
+                    jnp.zeros((layers, pages, rows, n * dv), dtype))
 
         k, v = pools(0, num_pages)
         wk, wv = pools(1, window_pages) if arch.two_kind_cache \

@@ -472,7 +472,13 @@ class EngineMetrics:
                   "prompt or one chunk", r,
                   fn=lambda: engine.counters.get(
                       "prefill_turns_single_total", 0))
-            if getattr(getattr(engine, "model", None), "has_ssm", False):
+            if getattr(getattr(engine, "model", None), "has_conv", False):
+                # rows of conv state (docs/kv-cache.md)
+                Gauge("kaito:engine_conv_state_pool_bytes",
+                      "Bytes of the short-convolution layers' state pool "
+                      "(the last inputs of every slot and conv layer)",
+                      r, fn=lambda: engine.cache.state_pool_bytes)
+            if getattr(getattr(engine, "model", None), "has_state", False):
                 # the second kind of state in the cache (docs/kv-cache.md)
                 Gauge("kaito:engine_state_pool_bytes",
                       "Bytes of the per-slot recurrent-state pool (mixer "

@@ -46,8 +46,8 @@ from kaito_tpu.engine.kv_cache import (KVCache, write_decode_tokens,
                                        write_decode_tokens_q,
                                        write_prefill_tokens,
                                        write_prefill_tokens_q)
-from kaito_tpu.models.metadata import (AttentionKind, ModelArch,
-                                       stored_key_dim)
+from kaito_tpu.models.metadata import (MIXER_CONV, AttentionKind, ModelArch,
+                                       heads_per_lane_row, stored_key_dim)
 
 VOCAB_ALIGN = 128
 _BIG_WINDOW = 1 << 30
@@ -64,11 +64,11 @@ def _name_salt(name: str) -> int:
 
 @dataclass(frozen=True)
 class LayerGroup:
-    name: str          # "dense" | "moe"; "<full|window>_<dense|moe>" by kind
+    name: str          # "dense" | "moe"; "<full|window|conv>_<dense|moe>" by kind
     start: int
     count: int
     moe: bool
-    kind: int = 0      # attention kind of the stack (0 full, 1 window)
+    kind: int = 0      # the stack's mixer (0 full, 1 window, 2 short conv)
 
 
 @dataclass(frozen=True)
@@ -88,12 +88,20 @@ class AttnKind:
     def k_dim(self) -> int:
         return stored_key_dim(self.head_dim)
 
+    @property
+    def pack(self) -> int:
+        """KV heads that share a 128-lane row of the kind's pools
+        (metadata.heads_per_lane_row; 1: a head is a row)."""
+        return heads_per_lane_row(self.head_dim, self.v_head_dim,
+                                  self.num_kv_heads)
+
 
 @dataclass(frozen=True)
 class LayerRun:
     """Consecutive layers of one stack: ``count`` layers from
     ``stack_start`` of ``params[stack]``, whose attention kind's page
-    pool holds them from ``cache_start``."""
+    pool (a short-convolution layer's state pool) holds them from
+    ``cache_start``."""
     stack: str
     stack_start: int
     count: int
@@ -103,25 +111,30 @@ class LayerRun:
 
 
 def attention_kinds(arch: ModelArch) -> tuple:
-    """The attention kinds of a model whose layers name theirs."""
-    return (
-        AttnKind(0, arch.num_heads, arch.num_kv_heads, arch.head_dim,
-                 arch.v_head_dim or arch.head_dim, None, arch.full_sink),
-        AttnKind(1, arch.swa_num_heads, arch.swa_num_kv_heads,
-                 arch.swa_head_dim, arch.swa_v_head_dim or arch.swa_head_dim,
-                 arch.sliding_window, arch.swa_sink))
+    """The attention kinds of a model whose layers name theirs: the
+    full kind, and the window kind where some layer is of it."""
+    full = AttnKind(0, arch.num_heads, arch.num_kv_heads, arch.head_dim,
+                    arch.v_head_dim or arch.head_dim, None, arch.full_sink)
+    if not arch.two_kind_cache:
+        return (full,)
+    return (full,
+            AttnKind(1, arch.swa_num_heads, arch.swa_num_kv_heads,
+                     arch.swa_head_dim,
+                     arch.swa_v_head_dim or arch.swa_head_dim,
+                     arch.sliding_window, arch.swa_sink))
 
 
 def _layer_schedule(arch: ModelArch):
     """(stacks, runs) of a model whose layers name their kinds: layers
-    of one attention kind and one FFN kind share a stack, in layer
-    order; a run is a stretch of consecutive layers of one stack."""
+    of one mixer and one FFN kind share a stack, in layer order; a run
+    is a stretch of consecutive layers of one stack."""
     experts = arch.layer_experts or (0,) * arch.num_layers
     members: dict = {}
     runs: list = []
-    seen = [0, 0]
+    seen = [0, 0, 0]
     for kind, moe in zip(arch.layer_attention, experts):
-        name = ("window" if kind else "full") + ("_moe" if moe else "_dense")
+        name = ("full", "window", "conv")[kind] + ("_moe" if moe
+                                                   else "_dense")
         at = len(members.setdefault(name, []))
         members[name].append((kind, bool(moe)))
         last = runs[-1] if runs else None
@@ -199,6 +212,11 @@ class TransformerLM:
         # (docs/kv-cache.md), and prefix reuse, PD and speculation
         # are refused by the engine
         self.has_ssm = arch.ssm_state > 0
+        # short-convolution layers (lfm2): their last inputs a slot are
+        # the state pool's rows, and no page (docs/kv-cache.md, "A row
+        # of conv state"); ``has_state``: the cache holds a state pool
+        self.has_conv = arch.conv_layers > 0
+        self.has_state = self.has_ssm or self.has_conv
         # Pallas grouped matmul, and the Pallas un-sort of a shared
         # expert layer's prefill (set by the engine)
         self.moe_kernel = False
@@ -236,11 +254,11 @@ class TransformerLM:
             from dataclasses import replace
 
             # one table a kind: the window kind has its own theta
-            self._kind_inv_freq = (
-                self._inv_freq_global,
+            self._kind_inv_freq = (self._inv_freq_global,) + tuple(
                 nn.rope_frequencies(replace(
-                    arch, head_dim=arch.swa_head_dim,
-                    rope_theta=arch.swa_rope_theta, rope_scaling=None)))
+                    arch, head_dim=k.head_dim,
+                    rope_theta=arch.swa_rope_theta, rope_scaling=None))
+                for k in self.kinds[1:])
 
     def _rope_select(self, positions):
         """(inv_freq, mscale) for the global table — per-position
@@ -264,13 +282,23 @@ class TransformerLM:
         E, H, Hkv, D, I = (a.hidden_size, a.num_heads, a.num_kv_heads,
                            a.head_dim, a.intermediate_size)
         Dv = D
-        if self.kinds is not None:
+        conv = self.kinds is not None and kind == MIXER_CONV
+        if self.kinds is not None and not conv:
             ak = self.kinds[kind]
             H, Hkv, D, Dv = (ak.num_heads, ak.num_kv_heads, ak.head_dim,
                              ak.v_head_dim)
-        if self.is_mla:
-            dn, dr, dl, dv = a.mla_dims
+        if conv:
+            # a gated short convolution: [B | C | u] in, the taps
+            # (``conv_w[k]`` weighs the input k tokens back), out
             specs: dict[str, tuple[tuple[int, ...], tuple]] = {
+                "attn_norm": ((E,), ("embed",)),
+                "conv_in": ((E, 3 * E), ("embed", None)),
+                "conv_w": ((a.conv_kernel, E), (None, None)),
+                "conv_out": ((E, E), (None, "embed")),
+            }
+        elif self.is_mla:
+            dn, dr, dl, dv = a.mla_dims
+            specs = {
                 "attn_norm": ((E,), ("embed",)),
                 "kv_a": ((E, dl + dr), ("embed", None)),
                 "kv_a_norm": ((dl,), (None,)),
@@ -296,7 +324,7 @@ class TransformerLM:
             }
             if self.kinds is not None and self.kinds[kind].sink:
                 specs["sink"] = ((H,), ("heads",))
-        if a.qkv_bias or a.linear_bias:
+        if (a.qkv_bias or a.linear_bias) and not conv:
             specs.update({
                 "q_bias": ((H * D,), ("heads",)),
                 "k_bias": ((Hkv * D,), ("kv_heads",)),
@@ -304,7 +332,7 @@ class TransformerLM:
             })
         if a.linear_bias:
             specs["o_bias"] = ((E,), ("embed",))
-        if a.qk_norm:
+        if a.qk_norm and not conv:
             specs["q_norm"] = ((D,), (None,))
             specs["k_norm"] = ((D,), (None,))
         if a.norm_type == "layernorm":
@@ -386,7 +414,9 @@ class TransformerLM:
             layer: dict = {}
             for name, (shape, _) in self._layer_specs(g.moe, g.kind).items():
                 full = (g.count,) + shape
-                if name in ("sink", "router_bias"):
+                if name in ("sink", "router_bias", "conv_w") or (
+                        name in ("q_norm", "k_norm")
+                        and self.kinds is not None):
                     init = self._kind_draw(
                         name, jax.random.fold_in(keys[1 + gi],
                                                  _name_salt(name)), full)
@@ -475,10 +505,18 @@ class TransformerLM:
         window plus a standard normal, so the sink's column takes tenths
         of a window layer's probability (scores are of order one, and a
         sink at that scale against 128 of them would take a hundredth).
-        ``router_bias``: zero here, fitted by ``_balanced_router``."""
+        ``router_bias``: zero here, fitted by ``_balanced_router``.
+        ``conv_w``: every tap N(0, 1/sqrt(taps)), so the carried inputs
+        weigh as much as the newest and a dropped state moves the
+        logits.  ``q_norm``/``k_norm`` of a model whose layers name
+        their kinds: 1 + N(0, 0.1), so a dropped QK norm moves them."""
+        z = jax.random.normal(key, shape, jnp.float32)
+        if name == "conv_w":
+            return (z / math.sqrt(shape[-2])).astype(self.dtype)
+        if name in ("q_norm", "k_norm"):
+            return (1.0 + 0.1 * z).astype(self.dtype)
         if name != "sink":
             return jnp.zeros(shape, self.dtype)
-        z = jax.random.normal(key, shape, jnp.float32)
         return (math.log(max(self.arch.sliding_window or 2, 2))
                 + z).astype(self.dtype)
 
@@ -559,6 +597,16 @@ class TransformerLM:
 
     def param_count(self, params: dict) -> int:
         return sum(x.size for x in jax.tree.leaves(params))
+
+    def stack_layers(self, g: LayerGroup) -> list:
+        """The model's layers that stack ``g`` holds, in the stack's
+        order (a checkpoint names tensors by layer)."""
+        if self.kinds is None:
+            return list(range(g.start, g.start + g.count))
+        experts = self.arch.layer_experts or (0,) * self.arch.num_layers
+        return [l for l, (kind, moe) in enumerate(
+            zip(self.arch.layer_attention, experts))
+            if (kind, bool(moe)) == (g.kind, g.moe)]
 
     # ------------------------------------------------------------------
     # Flags / rope tables
@@ -859,6 +907,9 @@ class TransformerLM:
             v = v.reshape(B, T, kind.num_kv_heads, kind.v_head_dim)
             if a.attention_value_scale is not None:
                 v = v * jnp.asarray(a.attention_value_scale, v.dtype)
+            if a.qk_norm:
+                q = nn.rms_norm(q, p["q_norm"], a.rms_norm_eps, a.norm_offset)
+                k = nn.rms_norm(k, p["k_norm"], a.rms_norm_eps, a.norm_offset)
             inv_freq = self._kind_inv_freq[kind.index]
             q = nn.apply_rope(q, positions, inv_freq, kind.head_dim)
             k = nn.apply_rope(k, positions, inv_freq, kind.head_dim)
@@ -977,9 +1028,18 @@ class TransformerLM:
         q, k_new, v_new = self._attn_qkv(h, p, positions, window,
                                          lora=lora, lora_ids=lora_ids,
                                          overlap=ov, kind=kind)
-        # (a model whose layers name their kind keeps token-flat pools)
-        flat_heads = None if kind is None else kind.num_kv_heads
+        # (a model whose layers name their kind keeps token-flat pools;
+        # heads narrower than a lane tile lie ``pack`` to a row of them,
+        # written as rows and read through queries laid to match:
+        # attention.lane_pack_queries)
+        pack = 1 if kind is None else kind.pack
+        flat_heads = None if kind is None else kind.num_kv_heads // pack
         ps = ck.shape[-3] if kind is None else ck.shape[-2] // flat_heads
+        k_w, v_w, q_c = k_new, v_new, q
+        if pack > 1:
+            k_w = k_new.reshape(B, T, flat_heads, pack * kind.head_dim)
+            v_w = v_new.reshape(B, T, flat_heads, pack * kind.v_head_dim)
+            q_c = attn.lane_pack_queries(q, kind.num_kv_heads, pack)
         # a sink bias a head (window layers of mimo_v2): one more
         # column of the softmax, probability and no value
         sink = p["sink"].astype(jnp.float32) if "sink" in p else None
@@ -1059,17 +1119,20 @@ class TransformerLM:
                 cv, vs = write_prefill_tokens_q(cv, vs, v_new, page_tables,
                                                 start, true_lens, ps, layer=li)
             else:
-                ck = write_prefill_tokens(ck, k_new, page_tables, start,
+                ck = write_prefill_tokens(ck, k_w, page_tables, start,
                                           true_lens, ps, layer=li)
-                cv = write_prefill_tokens(cv, v_new, page_tables, start,
+                cv = write_prefill_tokens(cv, v_w, page_tables, start,
                                           true_lens, ps, layer=li)
             if start_pos is not None:
                 # chunk attends over cached context + itself (prefix reuse)
                 out = attn.paged_context_attention(
-                    q, ck, cv, page_tables, start, true_lens,
+                    q_c, ck, cv, page_tables, start, true_lens,
                     scale=self._scale, sliding_window=window,
                     logit_softcap=a.attn_logit_softcap, layer=li,
                     k_scale=ks, v_scale=vs, sink=sink, kv_heads=flat_heads)
+                if pack > 1:
+                    out = attn.lane_unpack_outputs(out, kind.num_kv_heads,
+                                                   pack)
             elif self.attn_impl == "pallas":
                 from kaito_tpu.engine.ops.flash_prefill import (
                     flash_prefill_attention)
@@ -1103,9 +1166,9 @@ class TransformerLM:
                                                positions[:, 0], ps, active,
                                                layer=li)
             else:
-                ck = write_decode_tokens(ck, k_new[:, 0], page_tables,
+                ck = write_decode_tokens(ck, k_w[:, 0], page_tables,
                                          positions[:, 0], ps, active, layer=li)
-                cv = write_decode_tokens(cv, v_new[:, 0], page_tables,
+                cv = write_decode_tokens(cv, v_w[:, 0], page_tables,
                                          positions[:, 0], ps, active, layer=li)
             if self.attn_impl == "pallas":
                 from kaito_tpu.engine.ops.decode_attention import (
@@ -1124,7 +1187,7 @@ class TransformerLM:
 
                 # q [B, H, D]; pools [Lg, P, ps, Hkv, D]; sink [H];
                 # scales [Lg, P, Hkv]
-                args = (q[:, 0], ck, cv, page_tables, lengths,
+                args = (q_c[:, 0], ck, cv, page_tables, lengths,
                         jnp.asarray(win, jnp.int32), li)
                 head_dims = (1, 3, 3, None, None, None, None)
                 if sink is not None:
@@ -1137,10 +1200,12 @@ class TransformerLM:
                                              head_dims, 1)
             else:
                 out = attn.paged_decode_attention(
-                    q[:, 0], ck, cv, page_tables, lengths, scale=self._scale,
+                    q_c[:, 0], ck, cv, page_tables, lengths, scale=self._scale,
                     sliding_window=window, logit_softcap=a.attn_logit_softcap,
                     layer=li, k_scale=ks, v_scale=vs, sink=sink,
                     kv_heads=flat_heads)
+            if pack > 1:
+                out = attn.lane_unpack_outputs(out, kind.num_kv_heads, pack)
             out = out[:, None]
         if kind is not None:
             o_in = out.reshape(B, T, kind.num_heads * kind.v_head_dim)
@@ -1186,24 +1251,77 @@ class TransformerLM:
         x = x + attn_out
         h2 = self._norm(x, p, "mlp_norm")
         if kind is not None:
-            # an expert layer routes the tokens that are there: the rows
-            # that decode, a prompt's own positions
-            if mode == "decode":
-                valid = None if active is None else active[:, None]
-            else:
-                valid = jnp.arange(T)[None, :] < true_lens[:, None]
-            want = moe and stats is not None
-            mlp_out = self._mlp(h2, p, moe, valid=valid, with_stats=want,
-                                expert_layer=expert_layer)
-            if want:
-                mlp_out, layer_stats = mlp_out
-                stats = stats + layer_stats
-            return x + mlp_out, ck, cv, ks, vs, ssm, stats
+            x, stats = self._ffn_by_kind(x, h2, p, moe, mode, true_lens,
+                                         active, stats, expert_layer)
+            return x, ck, cv, ks, vs, ssm, stats
         mlp_out = self._mlp(h2, p, moe, lora=lora, lora_ids=lora_ids,
                             overlap=ov, pf_down=(pf or {}).get("down"))
         if a.pre_post_norm:
             mlp_out = self._norm(mlp_out, p, "post_mlp_norm")
         return x + mlp_out, ck, cv, ks, vs, ssm
+
+    def _ffn_by_kind(self, x, h2, p, moe, mode, true_lens, active, stats,
+                     expert_layer):
+        """The FFN half of a block whose layer names its kinds: ``x``
+        the residual, ``h2`` its normed copy.  An expert layer routes
+        the tokens that are there: the rows that decode, a prompt's own
+        positions.  Returns (x, stats)."""
+        if mode == "decode":
+            valid = None if active is None else active[:, None]
+        else:
+            valid = jnp.arange(x.shape[1])[None, :] < true_lens[:, None]
+        want = moe and stats is not None
+        mlp_out = self._mlp(h2, p, moe, valid=valid, with_stats=want,
+                            expert_layer=expert_layer)
+        if want:
+            mlp_out, layer_stats = mlp_out
+            stats = stats + layer_stats
+        return x + mlp_out, stats
+
+    def _conv_layer(self, x, p, pool, li, moe, mode, *, true_lens, active,
+                    start_pos, rows, stats=None, expert_layer=None):
+        """One block whose mixer is a gated short convolution (lfm2):
+        ``[B | C | u] = h W_in``, ``v = B * u``, a causal depthwise
+        convolution of ``conv_kernel`` taps over ``v``
+        (``nn.short_conv``), ``(C * c) W_out``; then the FFN.  Returns
+        (x, pool, stats).
+
+        ``pool`` is the state pool [conv layers, slots, taps - 1,
+        hidden] in the model's type, or None for a forward pass with no
+        cache (every sequence from its start); ``li`` this layer's row
+        of it.  What a sequence carries is the last ``taps - 1`` values
+        of ``v``.  Prefill reads and writes the rows ``rows`` ([B] slot
+        indices): a chunk at position 0 starts from zeros, which is
+        what resets a reused slot's row, a later chunk from what the
+        chunk before left.  Decode shifts every row that ``active``
+        names, in place, and leaves the others bit for bit."""
+        a = self.arch
+        B, T, E = x.shape
+        h = self._norm(x, p, "attn_norm")
+        gate_b, gate_c, u = jnp.split(nn.linear(h, p["conv_in"]), 3, axis=-1)
+        v = gate_b * u
+        if mode == "decode":
+            carried = pool[li]
+        elif pool is None or start_pos is None:
+            carried = jnp.zeros((B, a.conv_kernel - 1, E), v.dtype)
+        else:
+            carried = jnp.where((start_pos > 0)[:, None, None],
+                                pool[li, rows], 0)
+        c, seen = nn.short_conv(v, carried, p["conv_w"])
+        if mode == "decode":
+            new = seen[:, 1:].astype(pool.dtype)
+            if active is not None:
+                new = jnp.where(active[:, None, None], new, carried)
+            pool = pool.at[li].set(new)
+        elif pool is not None:
+            pool = pool.at[li, rows].set(nn.short_conv_carry(
+                seen, true_lens, a.conv_kernel).astype(pool.dtype))
+        y = (gate_c.astype(jnp.float32) * c).astype(self.dtype)
+        x = x + nn.linear(y, p["conv_out"])
+        h2 = self._norm(x, p, "mlp_norm")
+        x, stats = self._ffn_by_kind(x, h2, p, moe, mode, true_lens, active,
+                                     stats, expert_layer)
+        return x, pool, stats
 
     def _ssm_mixer(self, h, p, pools, li, mode, *, true_lens, active,
                    start_pos, rows):
@@ -1311,7 +1429,7 @@ class TransformerLM:
                 params, cache, x, mode, positions=positions,
                 page_tables=page_tables, lengths=lengths,
                 true_lens=true_lens, active=active, remat=remat,
-                start_pos=start_pos)
+                start_pos=start_pos, state_rows=ssm_rows)
         serve_lora = params.get("serve_lora") if mode != "train" else None
         if mode != "train":
             if self.has_ssm and cache.ssm_state is None:
@@ -1429,31 +1547,41 @@ class TransformerLM:
 
     def _run_layers_kinds(self, params, cache: Optional[KVCache], x, mode,
                           *, positions, page_tables, lengths, true_lens,
-                          active, remat, start_pos):
+                          active, remat, start_pos, state_rows=None):
         """The layers of a model whose layers name their kinds: the
         schedule's runs in order, each a scan over its stretch of its
         stack (the stack rides as a loop invariant and the body takes
         its layer by index: a slice of a stack would be a copy of it).
-        ``page_tables`` is [B, 2, pages]: a table an attention kind, the
-        full kind's first; a kind's pools ride the scans of its runs."""
+        ``page_tables`` is [B, 2, pages] where window layers keep a pool
+        of their own: a table an attention kind, the full kind's first
+        ([B, pages] otherwise); a kind's pools ride the scans of its
+        runs, and the short-convolution layers' state pool
+        (``cache.conv_state``, rows ``state_rows`` at prefill) theirs."""
         if mode not in ("train", "prefill", "decode"):
             raise NotImplementedError(
                 f"layers that name their attention kind have no "
                 f"{mode!r} path")
-        if mode != "train" and cache.wk is None:
+        two_tables = self.arch.two_kind_cache
+        if mode != "train" and two_tables and cache.wk is None:
             raise ValueError("a model with window layers of their own "
                              "geometry serves from a cache with a window "
                              "pool (kv_cache.create_kv_cache)")
+        if mode != "train" and self.has_conv and cache.conv_state is None:
+            raise ValueError("a model with short-convolution layers serves "
+                             "from a cache with rows of conv state "
+                             "(kv_cache.create_conv_state_pool)")
         # ("train": the cache-free forward pass that scores a prompt)
         pools = None if mode == "train" else \
             [(cache.k, cache.v), (cache.wk, cache.wv)]
+        conv_pool = None if mode == "train" else cache.conv_state
         # an expert layer's counters are kept for the decode programs
         # (what the per-layer metrics read)
         stats = cache.moe_stats if mode == "decode" else None
         for run in self.runs:
             stack = params[run.stack]
-            kind = self.kinds[run.kind]
-            window = kind.window
+            conv = run.kind == MIXER_CONV
+            kind = None if conv else self.kinds[run.kind]
+            window = None if conv else kind.window
             # an expert layer's stacks stay whole and the layer goes by
             # index: a slice handed to the grouped-matmul kernel would
             # be a copy of the layer's matrices
@@ -1469,6 +1597,11 @@ class TransformerLM:
 
             if mode == "train":
                 def one(h, p, at, kind=kind, window=window, moe=run.moe):
+                    if kind is None:
+                        return self._conv_layer(
+                            h, p, None, None, moe, mode, true_lens=true_lens,
+                            active=None, start_pos=None, rows=None,
+                            expert_layer=at)[0]
                     return self._layer_train(
                         h, p, window, moe, positions=positions,
                         true_lens=true_lens, kind=kind, expert_layer=at)
@@ -1478,7 +1611,25 @@ class TransformerLM:
                 for i in range(run.count):
                     x = one(x, *take(i))
                 continue
-            table = page_tables[:, run.kind]
+            if conv:
+                def conv_step(carry, i, run=run, take=take):
+                    h, pool, st = carry
+                    p, at = take(i)
+                    return self._conv_layer(
+                        h, p, pool, run.cache_start + i, run.moe, mode,
+                        true_lens=true_lens, active=active,
+                        start_pos=start_pos, rows=state_rows, stats=st,
+                        expert_layer=at), None
+
+                if run.count == 1:
+                    (x, conv_pool, stats), _ = conv_step(
+                        (x, conv_pool, stats), jnp.int32(0))
+                else:
+                    (x, conv_pool, stats), _ = jax.lax.scan(
+                        conv_step, (x, conv_pool, stats),
+                        jnp.arange(run.count, dtype=jnp.int32))
+                continue
+            table = page_tables[:, run.kind] if two_tables else page_tables
             ck, cv = pools[run.kind]
 
             def step(carry, i, run=run, kind=kind, window=window,
@@ -1504,7 +1655,7 @@ class TransformerLM:
             return x, None
         return x, dataclasses.replace(
             cache, k=pools[0][0], v=pools[0][1], wk=pools[1][0],
-            wv=pools[1][1],
+            wv=pools[1][1], conv_state=conv_pool,
             moe_stats=stats if mode == "decode" else cache.moe_stats)
 
     def _layer_train(self, x, p, window, moe, *, positions, true_lens,
@@ -1602,10 +1753,9 @@ class TransformerLM:
         B, T = tokens.shape
         rel = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         positions = rel if start_pos is None else rel + start_pos[:, None]
-        if self.has_ssm and state_rows is None:
-            raise ValueError("a model with a state-space mixer prefills "
-                             "into its slots' rows of the state pool: "
-                             "state_rows is required")
+        if self.has_state and state_rows is None:
+            raise ValueError("a model with a state pool prefills into its "
+                             "slots' rows of it: state_rows is required")
         x = self._embed(params, tokens)
         x, cache = self._run_layers(
             params, cache, x, "prefill", positions=positions,

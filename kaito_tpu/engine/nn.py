@@ -642,6 +642,38 @@ def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
     return y, stats
 
 
+def short_conv(v: jax.Array, carried: jax.Array, taps: jax.Array):
+    """The causal depthwise convolution of a gated short-convolution
+    layer (lfm2), in every form it is served in: ``v`` [B, T, C] the
+    chunk's inputs, ``carried`` [B, K-1, C] the K-1 inputs before the
+    chunk, oldest first (zeros at a sequence's start: a fresh prompt;
+    the row of the state pool: a later chunk, and a decode step, which
+    is a chunk of one), ``taps`` [K, C] with ``taps[k]`` the weight of
+    the input k tokens back.  Returns (``c`` [B, T, C] float32 with
+    ``c[t] = sum_k taps[k] * v[t-k]``, ``seen`` [B, K-1+T, C]: the
+    carried inputs and then the chunk's, what ``short_conv_carry``
+    takes the next state from)."""
+    with jax.named_scope("short_conv"):
+        K, T = taps.shape[0], v.shape[1]
+        seen = jnp.concatenate([carried.astype(v.dtype), v], axis=1)
+        w = taps.astype(jnp.float32)
+        c = sum(w[k] * seen[:, K - 1 - k:K - 1 - k + T].astype(jnp.float32)
+                for k in range(K))
+    return c, seen
+
+
+def short_conv_carry(seen: jax.Array, true_lens: jax.Array,
+                     taps: int) -> jax.Array:
+    """What a sequence carries past a chunk of ``true_lens`` [B] valid
+    tokens: the last ``taps - 1`` inputs it has seen, [B, taps-1, C]
+    (``seen`` as ``short_conv`` returns it; a chunk of no token leaves
+    what was carried)."""
+    with jax.named_scope("short_conv"):
+        at = true_lens.astype(jnp.int32)[:, None] + jnp.arange(
+            taps - 1, dtype=jnp.int32)[None, :]
+        return jnp.take_along_axis(seen, at[..., None], axis=1)
+
+
 def softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
     if not cap:
         return x

@@ -373,6 +373,11 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         combine = engines[0].model.moe_combine
         if combine:
             body["moe_combine"] = combine
+        arch = engines[0].md.arch
+        if arch.conv_layers:
+            # what mixes the layers' tokens, where not attention alone
+            body["mixers"] = {"conv": arch.conv_layers,
+                              "full_attention": arch.attention_layers(0)}
         for d in jax.local_devices():
             stats = d.memory_stats() or {}
             body["devices"].append({

@@ -52,6 +52,23 @@ _GEMMA_OVERRIDES = {
 }
 
 
+# lfm2 / lfm2_moe: the block's two norms, the short convolution's
+# projections, the attention's own names for its output projection and
+# its QK norms, and a dense FFN numbered as mixtral numbers an expert
+_LFM2_OVERRIDES = {
+    "attn_norm": ("operator_norm.weight", False),
+    "mlp_norm": ("ffn_norm.weight", False),
+    "conv_in": ("conv.in_proj.weight", True),
+    "conv_out": ("conv.out_proj.weight", True),
+    "o": ("self_attn.out_proj.weight", True),
+    "q_norm": ("self_attn.q_layernorm.weight", False),
+    "k_norm": ("self_attn.k_layernorm.weight", False),
+    "gate": ("feed_forward.w1.weight", True),
+    "up": ("feed_forward.w3.weight", True),
+    "down": ("feed_forward.w2.weight", True),
+}
+
+
 def _reader(directory: str) -> tuple[Callable[[str], Optional[np.ndarray]], list[str]]:
     from safetensors import safe_open
 
@@ -119,7 +136,11 @@ def assemble_params(model: TransformerLM,
         embed = np.concatenate([embed, np.zeros((pad, embed.shape[1]),
                                                 embed.dtype)])
     params["embed"] = put("", "embed", embed)
-    params["final_norm"] = put("", "final_norm", get("norm.weight"))
+    final_norm = get("norm.weight", required=False)
+    if final_norm is None:
+        # lfm2 names its last norm after what it follows
+        final_norm = get("embedding_norm.weight")
+    params["final_norm"] = put("", "final_norm", final_norm)
     fnb = get("norm.bias", required=False)
     if fnb is not None:
         params["final_norm_bias"] = put("", "final_norm_bias", fnb)
@@ -136,17 +157,25 @@ def assemble_params(model: TransformerLM,
     layer_map = dict(_LAYER_MAP)
     if arch.pre_post_norm:
         layer_map.update(_GEMMA_OVERRIDES)
+    if arch.conv_kernel:
+        layer_map.update(_LFM2_OVERRIDES)
 
     for g in model.groups:
         specs = model._layer_specs(g.moe, g.kind)
         stack: dict[str, list] = {}
-        for li in range(g.start, g.start + g.count):
+        for li in model.stack_layers(g):
             fused_qkv = None
             for our_key in specs:
                 if "lora" in our_key:
                     continue
                 entry = layer_map.get(our_key)
                 tensor = None
+                if our_key == "conv_w":
+                    # a depthwise Conv1d's weight [channels, 1, taps],
+                    # its last tap on the newest input: ours is [taps,
+                    # channels] with tap k on the input k tokens back
+                    w = get(f"layers.{li}.conv.conv.weight")
+                    tensor = np.ascontiguousarray(w[:, 0, ::-1].T)
                 if entry is not None:
                     suffix, transpose = entry
                     tensor = get(f"layers.{li}.{suffix}", required=False)
@@ -207,11 +236,15 @@ def _read_moe_tensor(get, arch, li: int, our_key: str):
     """Load-side MoE mapping: router / stacked experts / shared experts
     from either HF naming convention; None when absent."""
     if our_key == "router":
-        for suffix in ("block_sparse_moe.gate.weight", "mlp.gate.weight"):
+        for suffix in ("block_sparse_moe.gate.weight", "mlp.gate.weight",
+                       "feed_forward.gate.weight"):
             t = get(f"layers.{li}.{suffix}", required=False)
             if t is not None:
                 return t.T                          # [X, H] -> [H, X]
         return None
+    if our_key == "router_bias":
+        # (lfm2_moe's name for the bias that chooses and never weighs)
+        return get(f"layers.{li}.feed_forward.expert_bias", required=False)
     if our_key in _MOE_EXPERT_SUFFIXES:
         mix, qwen = _MOE_EXPERT_SUFFIXES[our_key]
         per_expert = []
@@ -220,6 +253,10 @@ def _read_moe_tensor(get, arch, li: int, our_key: str):
                     required=False)
             if t is None:
                 t = get(f"layers.{li}.mlp.experts.{e}.{qwen}.weight",
+                        required=False)
+            if t is None:
+                # lfm2_moe numbers an expert's matrices as mixtral does
+                t = get(f"layers.{li}.feed_forward.experts.{e}.{mix}.weight",
                         required=False)
             if t is None:
                 return None

@@ -13,7 +13,8 @@ from __future__ import annotations
 
 from typing import Mapping, Optional
 
-from kaito_tpu.models.metadata import ModelArch, ModelMetadata
+from kaito_tpu.models.metadata import (MIXER_CONV, MIXER_FULL, ModelArch,
+                                       ModelMetadata)
 
 # Architectures we can instantiate in the engine.  The analogue of the
 # reference's vLLM arch allowlist (presets/workspace/models/
@@ -37,6 +38,8 @@ SUPPORTED_ARCHITECTURES = {
     "GptOssForCausalLM",
     "MiMoV2ForCausalLM",
     "JoyAILLMFlashForCausalLM",
+    "Lfm2ForCausalLM",
+    "Lfm2MoeForCausalLM",
 }
 
 
@@ -187,11 +190,82 @@ def arch_from_hf_config(cfg: Mapping) -> ModelArch:
     if model_type == "mimo_v2":
         kw.update(_mimo_v2_fields(cfg, layers))
 
+    if model_type in ("lfm2", "lfm2_moe"):
+        kw.update(_lfm2_fields(cfg, model_type, layers, kw))
+
     if model_type == "phi":
         kw.update(gated_mlp=False, parallel_residual=True, norm_type="layernorm",
                   linear_bias=True)
 
     return ModelArch(**kw)
+
+
+def _lfm2_fields(cfg: Mapping, model_type: str, layers: int, kw: dict) -> dict:
+    """LFM2 (``lfm2``) and LFM2-MoE (``lfm2_moe``): ``layer_types``
+    names each layer's mixer, ``conv`` a gated short convolution of
+    ``conv_L_cache`` taps a channel and ``full_attention`` GQA with an
+    RMSNorm on every query and key head before the rotary embedding;
+    the head is tied to the embedding.  ``lfm2_moe``: the first
+    ``num_dense_layers`` FFNs are dense and the rest a sigmoid-routed
+    expert layer whose ``use_expert_bias`` bias chooses and never
+    weighs; ``lfm2``: every FFN dense, of the width its ``block_*`` keys
+    give.  What is not implemented is refused by name."""
+    def refuse(what):
+        raise ValueError(f"{model_type}: {what} is not implemented")
+
+    types = tuple(cfg.get("layer_types") or ())
+    if len(types) != layers:
+        raise ValueError(f"{model_type}: layer_types ({len(types)}) must "
+                         f"name each of the {layers} layers")
+    known = {"conv": MIXER_CONV, "full_attention": MIXER_FULL}
+    for t in types:
+        if t not in known:
+            refuse(f"a layer_types entry {t!r}")
+    if bool(cfg.get("conv_bias", False)):
+        refuse("conv_bias true")
+    taps = int(cfg.get("conv_L_cache", 3))
+    if taps < 2:
+        refuse(f"conv_L_cache {taps}")
+    scaling = cfg.get("rope_scaling") or {}
+    if str(scaling.get("rope_type", scaling.get("type", "default"))) \
+            != "default":
+        refuse(f"rope_scaling {scaling!r}")
+    out = dict(
+        rms_norm_eps=float(_first(cfg, "norm_eps", "rms_norm_eps",
+                                  default=1e-5)),
+        rope_scaling=None,
+        qkv_bias=False,
+        qk_norm=True,
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+        layer_attention=tuple(known[t] for t in types),
+        conv_kernel=taps,
+    )
+    if model_type == "lfm2":
+        inter = int(_first(cfg, "block_ff_dim", "intermediate_size",
+                           default=kw["intermediate_size"]))
+        if bool(cfg.get("block_auto_adjust_ff_dim", False)):
+            inter = int(2 * inter / 3)
+            if cfg.get("block_ffn_dim_multiplier") is not None:
+                inter = int(float(cfg["block_ffn_dim_multiplier"]) * inter)
+            mult = int(cfg.get("block_multiple_of", 256))
+            inter = mult * ((inter + mult - 1) // mult)
+        out.update(intermediate_size=inter, layer_experts=(0,) * layers)
+        return out
+    if not bool(cfg.get("norm_topk_prob", True)):
+        refuse("norm_topk_prob false")
+    if not bool(cfg.get("use_expert_bias", True)):
+        refuse("use_expert_bias false")
+    dense = int(cfg.get("num_dense_layers", 0))
+    out.update(
+        layer_experts=tuple(int(l >= dense) for l in range(layers)),
+        num_experts=int(cfg.get("num_experts", 0)),
+        num_experts_per_tok=int(cfg.get("num_experts_per_tok", 0)),
+        moe_intermediate_size=_first(cfg, "moe_intermediate_size"),
+        router_scoring="sigmoid",
+        router_bias=True,
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
+    )
+    return out
 
 
 def _deepseek_fields(cfg: Mapping, model_type: str) -> dict:

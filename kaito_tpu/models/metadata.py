@@ -29,6 +29,24 @@ def stored_key_dim(head_dim: int) -> int:
     return -(-head_dim // 128) * 128
 
 
+def heads_per_lane_row(head_dim: int, v_head_dim: int, kv_heads: int) -> int:
+    """KV heads of one token that share a 128-lane row of a token-flat
+    page pool.  A head narrower than a lane tile would lie in a tile of
+    its own, half of it padding (a 64-wide head: the TPU lays a
+    [rows, 64] bf16 array out in 128 lanes, and Mosaic refuses to copy
+    a 64-lane slice of it), so a whole number of such heads is stored
+    side by side: 8 heads of 64 are 4 rows of 128, byte for byte what
+    [8, 64] is.  1: a head is a row (docs/kv-cache.md)."""
+    if head_dim != v_head_dim or not 0 < head_dim < 128 or 128 % head_dim:
+        return 1
+    n = 128 // head_dim
+    return n if kv_heads % n == 0 else 1
+
+
+# ``ModelArch.layer_attention``: what mixes a layer's tokens
+MIXER_FULL, MIXER_WINDOW, MIXER_CONV = 0, 1, 2
+
+
 class AttentionKind(str, enum.Enum):
     """Attention family — drives the KV bytes/token formula (reference:
     ``presets/workspace/generator/generator.go:620`` calculateKVCacheTokenSize)."""
@@ -126,15 +144,21 @@ class ModelArch:
     lm_head_multiplier: Optional[float] = None
 
     # layers of more than one kind in one model (mimo_v2: docs/kv-cache.md,
-    # "Two kinds of page").  ``layer_attention[l]`` is 0 for a full layer
-    # (num_heads / num_kv_heads / head_dim / v_head_dim / rope_theta
-    # above) and 1 for a window layer, which has its own head counts and
-    # sizes, its own rope theta, a causal window of ``sliding_window``
-    # positions and, with ``swa_sink``, a learnable sink bias a head;
-    # ``layer_experts[l]`` is 0 for a dense FFN and 1 for an expert
-    # layer.  None: every layer is of the one kind the fields above give.
+    # "Two kinds of page"; lfm2: "A row of conv state").
+    # ``layer_attention[l]`` says what mixes layer l's tokens: 0 a full
+    # attention layer (num_heads / num_kv_heads / head_dim / v_head_dim
+    # / rope_theta above), 1 a window layer, which has its own head
+    # counts and sizes, its own rope theta, a causal window of
+    # ``sliding_window`` positions and, with ``swa_sink``, a learnable
+    # sink bias a head, 2 a gated short convolution (lfm2: no attention
+    # and no page; ``conv_kernel`` taps a channel over ``hidden_size``
+    # channels, and the last ``conv_kernel - 1`` inputs a sequence in
+    # the state pool); ``layer_experts[l]`` is 0 for a dense FFN and 1
+    # for an expert layer.  None: every layer is of the one kind the
+    # fields above give.
     layer_attention: Optional[tuple] = None
     layer_experts: Optional[tuple] = None
+    conv_kernel: int = 0
     swa_num_heads: int = 0
     swa_num_kv_heads: int = 0
     swa_head_dim: int = 0
@@ -163,13 +187,25 @@ class ModelArch:
     @property
     def two_kind_cache(self) -> bool:
         """Window layers with a page pool and a page table of their own."""
-        return bool(self.layer_attention) and any(self.layer_attention)
+        return MIXER_WINDOW in (self.layer_attention or ())
 
     def attention_layers(self, kind: int) -> int:
-        """How many layers are of attention kind ``kind`` (0 full, 1 window)."""
+        """How many layers' mixer is of kind ``kind`` (0 full attention,
+        1 window attention, 2 short convolution)."""
         if self.layer_attention is None:
             return self.num_layers if kind == 0 else 0
         return sum(1 for k in self.layer_attention if k == kind)
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is a short convolution (no page)."""
+        return self.attention_layers(MIXER_CONV)
+
+    def kv_heads_per_row(self, kind: int) -> int:
+        """KV heads a 128-lane row of kind ``kind``'s token-flat pools
+        holds (``heads_per_lane_row``)."""
+        _, heads, dk, dv = self.kv_page_geometry(kind)
+        return heads_per_lane_row(dk, dv, heads)
 
     def kv_page_geometry(self, kind: int) -> tuple:
         """(layers, kv heads, k head dim, v head dim) of kind ``kind``'s
@@ -207,8 +243,12 @@ class ModelArch:
     def state_bytes_per_seq(self, dtype_bytes: int = 2) -> int:
         """Bytes of recurrent state one sequence holds across all
         layers, whatever its length: the mixer's state and the
-        convolution's tail, in the type the model is served in (0 for a
-        model with no mixer)."""
+        convolution's tail, or the short-convolution layers' last
+        inputs, in the type the model is served in (0 for a model with
+        neither)."""
+        if self.conv_layers:
+            return (self.conv_layers * (self.conv_kernel - 1)
+                    * self.hidden_size * dtype_bytes)
         if not self.ssm_state:
             return 0
         per_layer = (self.ssm_inner * self.ssm_state
@@ -303,15 +343,21 @@ class ModelArch:
         total = embed + h
         experts = self.layer_experts or (0,) * self.num_layers
         for kind, moe in zip(self.layer_attention, experts):
-            if kind:
-                H, Hkv = self.swa_num_heads, self.swa_num_kv_heads
-                dk, dv = self.swa_head_dim, self.swa_v_head_dim or self.swa_head_dim
-                sink = H if self.swa_sink else 0
+            if kind == MIXER_CONV:
+                # [B | C | u] in, out, and the taps
+                total += h * 3 * h + h * h + self.conv_kernel * h
             else:
-                H, Hkv = self.num_heads, self.num_kv_heads
-                dk, dv = self.head_dim, self.v_head_dim or self.head_dim
-                sink = H if self.full_sink else 0
-            total += h * H * dk + h * Hkv * (dk + dv) + H * dv * h + sink
+                if kind:
+                    H, Hkv = self.swa_num_heads, self.swa_num_kv_heads
+                    dk = self.swa_head_dim
+                    dv = self.swa_v_head_dim or dk
+                    sink = H if self.swa_sink else 0
+                else:
+                    H, Hkv = self.num_heads, self.num_kv_heads
+                    dk, dv = self.head_dim, self.v_head_dim or self.head_dim
+                    sink = H if self.full_sink else 0
+                total += h * H * dk + h * Hkv * (dk + dv) + H * dv * h + sink
+                total += 2 * dk if self.qk_norm else 0
             if moe:
                 inter = self.moe_intermediate_size or self.intermediate_size
                 total += 3 * h * inter * self.experts_held \

@@ -26,7 +26,7 @@ def cpu_devices():
     return devices
 
 
-# Three tests under tests/kbench/ (benchmark files, not a program PR's
+# Five tests under tests/kbench/ (benchmark files, not a program PR's
 # to edit) pin where BENCHMARK.json's per_layer ends, and the benchmark's
 # contract has every later PR append its entries there (the driver
 # refused PR 38 for putting them anywhere else):
@@ -45,6 +45,15 @@ def cpu_devices():
 #   found by name and each list compared as a prefix, in
 #   test_kbench_joyai_llm_flash.py::
 #   test_pr_40s_eleven_entries_stand_where_they_stood.
+# - test_kbench_joyai_llm_flash.py::
+#   test_the_cell_reports_what_the_issue_lists holds PR 42's three as
+#   ``per_layer[-3:]``, and ::test_pr_40s_eleven_entries_stand_where_
+#   they_stood each of the eleven's cells behind the first three equal
+#   to PR 42's one (PR 44 appended five entries and a fifth cell).
+#   Everything else the two assert is held, the entries found by name
+#   and the lists compared as prefixes, in test_kbench_lfm2_moe.py::
+#   test_pr_42s_three_entries_stand_where_they_stood and ::
+#   test_pr_40s_eleven_entries_stand_where_they_stood.
 # strict: the day a benchmark PR finds the entries by name these
 # markers fail the tests, and go.
 _PINNED_BY_INDEX = (
@@ -53,7 +62,11 @@ _PINNED_BY_INDEX = (
     "tests/kbench/test_kbench_mimo_v2.py::"
     "test_pr_34s_entry_stands_where_it_stood",
     "tests/kbench/test_kbench_part_metrics.py::"
-    "test_the_eleven_entries_are_appended_for_every_cell")
+    "test_the_eleven_entries_are_appended_for_every_cell",
+    "tests/kbench/test_kbench_joyai_llm_flash.py::"
+    "test_the_cell_reports_what_the_issue_lists",
+    "tests/kbench/test_kbench_joyai_llm_flash.py::"
+    "test_pr_40s_eleven_entries_stand_where_they_stood")
 
 
 def pytest_collection_modifyitems(items):

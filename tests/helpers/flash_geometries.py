@@ -1,4 +1,4 @@
-"""The attention geometries the benchmark's three configurations send
+"""The attention geometries the benchmark's configurations send
 through flash prefill, shared by the tile arithmetic's test
 (tests/test_flash_prefill.py) and the test that hands the same tiles to
 Mosaic (tests/test_two_kind_ops.py).  bf16 on the chip."""
@@ -10,4 +10,7 @@ SERVED = {
     # keys of 192 stored at 256 lanes
     "mimo-v2.5-full": (64, 4, 256, 128, False),
     "mimo-v2.5-window": (64, 8, 256, 128, True),
+    # heads of 64: half a lane tile (a fresh chunk's q, k and v go to
+    # the kernel as they are; the pools pair the heads up)
+    "lfm2-8b-a1b": (32, 8, 64, 64, False),
 }

@@ -186,6 +186,42 @@ def test_kernels_compile_for_v5e_at_the_published_widths(one_chip, layers,
     _compile_flash(sd, 4096, H, Hkv, D, Dv, sink_on)
 
 
+def test_decode_kernel_compiles_for_v5e_over_lane_packed_heads(one_chip):
+    """LFM2-8B-A1B's attention layers at the cell's 32 rows: 8 KV heads
+    of 64 lie two to a 128-lane row of the token-flat pools ([3, P, 64
+    x 4, 128]), which the kernel reads as 4 KV heads of 128 under
+    queries laid into their own head's lanes (attention.
+    lane_pack_queries); one head a row of 64 lanes is what Mosaic
+    refuses (a 64-lane slice of a 128-lane tile)."""
+    from kaito_tpu.engine import attention as A
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, H, Hkv, D, ps, P, pack = 32, 32, 8, 64, 64, 2600, 2
+
+    def decode(q, ck, cv, pt, ln, win, li, rows):
+        out = paged_decode_attention_pallas(
+            A.lane_pack_queries(q, Hkv, pack), ck, cv, pt, ln, win,
+            scale=0.125, layer=li, kv_heads=rows)
+        return A.lane_unpack_outputs(out, Hkv, pack)
+
+    rest = (sd((B, 80), jnp.int32), sd((B,), jnp.int32), sd((), jnp.int32),
+            sd((), jnp.int32))
+    pool = sd((3, P, ps * Hkv // pack, pack * D))
+    compiled = jax.jit(partial(decode, rows=Hkv // pack)).lower(
+        sd((B, H, D)), pool, pool, *rest).compile()
+    assert "attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 3 * P * ps * Hkv * D * 2 // 8
+    plain = sd((3, P, ps * Hkv, D))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda q, ck, cv, *r: paged_decode_attention_pallas(
+            q, ck, cv, *r[:3], scale=0.125, layer=r[3],
+            kv_heads=Hkv)).lower(sd((B, H, D)), plain, plain,
+                                 *rest).compile()
+
+
 def _compile_flash(sd, T, H, Hkv, D, Dv, sink_on):
     def flash(q, k, v, tl, win, *s):
         return flash_prefill_attention(q, k, v, tl, win, scale=0.07,
