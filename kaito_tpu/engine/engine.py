@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kaito_tpu.engine import nn
 from kaito_tpu.engine.config import EngineConfig
 from kaito_tpu.engine.devprof import phase_scope
 from kaito_tpu.engine.grammar import GrammarCache, GrammarSlot, GrammarTable
@@ -499,6 +500,11 @@ class InferenceEngine:
         self.buckets = tuple(sorted(
             {b for b in cfg.prefill_buckets if b < cfg.max_model_len}
             | {cfg.max_model_len}))
+        self.moe_tiles = self._expert_tiles()
+        if self.moe_tiles:
+            logger.info("expert layer: grouped-matmul tiles (m, k, n) by "
+                        "[K, N]: decode %s, prefill %s",
+                        self.moe_tiles["decode"], self.moe_tiles["prefill"])
         if cfg.quantization:
             from kaito_tpu.engine.quant import (QUANT_SCHEMES,
                                                 supports_quantization)
@@ -1357,6 +1363,23 @@ class InferenceEngine:
             f"{self.md.name} caches a latent stream and holds "
             f"{self.md.arch.experts_held} of {self.md.arch.num_experts} "
             f"experts a layer", mesh, self._LATENT_SHARE_REFUSALS)
+
+    def _expert_tiles(self) -> Optional[dict]:
+        """The grouped-matmul kernel's (m, k, n) tiles by the experts'
+        [K, N], as ``/health`` lists them under ``moe_tiles``: for a
+        decode step of every slot and for the longest prefill chunk
+        (``nn.expert_tiles``: chosen from the shapes when a program is
+        traced).  None where no such kernel runs: no grouped expert
+        layer, a CPU or a mesh, quantized stacks (XLA's ragged dot)."""
+        if not (self.model.moe_combine and self.model.moe_kernel) \
+                or self.cfg.quantization:
+            return None
+        cfg, arch = self.cfg, self.md.arch
+        chunk = self._bucket(min(max(cfg.max_prefill_tokens, cfg.page_size),
+                                 cfg.max_model_len))
+        itemsize = self.dtype.itemsize
+        return {"decode": nn.expert_tiles(arch, cfg.max_num_seqs, itemsize),
+                "prefill": nn.expert_tiles(arch, chunk, itemsize)}
 
     @property
     def attention_path(self) -> str:

@@ -420,12 +420,6 @@ def route_tokens(logits: jax.Array, arch: ModelArch,
     return idx, weights
 
 
-# (m, k, n) tiles of the grouped-matmul kernel: a held expert's matrix
-# is read in 2 MiB tiles, once a call whatever the rows it gets
-_GMM_TILE_K = 2048
-_GMM_TILE_N = 512
-
-
 def _grouped_matmul(lhs: jax.Array, w, group_sizes: jax.Array,
                     expert_of_row: jax.Array, kernel: bool,
                     layer=None) -> jax.Array:
@@ -452,7 +446,8 @@ def _grouped_matmul(lhs: jax.Array, w, group_sizes: jax.Array,
                 group_sizes.astype(jnp.int32), (layer * held,))
             return gmm(lhs, w.reshape((n_layers * held,) + w.shape[2:]),
                        sizes, preferred_element_type=jnp.float32,
-                       tiling=_gmm_tile(lhs, w))
+                       tiling=_gmm_tile(lhs.shape[0], *w.shape[-2:],
+                                        lhs.dtype.itemsize))
         w = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, layer, 0, keepdims=False), w)
     if is_qtensor(w):
@@ -473,12 +468,18 @@ def _grouped_matmul(lhs: jax.Array, w, group_sizes: jax.Array,
 
         return gmm(lhs, w, group_sizes.astype(jnp.int32),
                    preferred_element_type=jnp.float32,
-                   tiling=_gmm_tile(lhs, w))
+                   tiling=_gmm_tile(lhs.shape[0], *w.shape[-2:],
+                                        lhs.dtype.itemsize))
     return jax.lax.ragged_dot(lhs, w, group_sizes,
                               preferred_element_type=jnp.float32)
 
 
 _GMM_TILE_M = 128
+# What one set of the grouped-matmul kernel's tiles may hold of VMEM
+# (``_gmm_vmem_bytes``).  A Mosaic call gets 16 MiB on a v5e unless it
+# asks for more, and megablox's ``gmm`` cannot ask: the budget leaves a
+# quarter of that to what the compiler keeps beside the tiles.
+_GMM_VMEM_BUDGET = 12 << 20
 
 
 def _gmm_rows(rows: int) -> int:
@@ -489,9 +490,77 @@ def _gmm_rows(rows: int) -> int:
     return -(-rows // tile) * tile
 
 
-def _gmm_tile(lhs: jax.Array, w: jax.Array) -> tuple:
-    return (min(lhs.shape[0], _GMM_TILE_M), min(w.shape[-2], _GMM_TILE_K),
-            min(w.shape[-1], _GMM_TILE_N))
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """VMEM one tile set of the kernel needs: the pipeline keeps two
+    buffers each of the lhs [tm, tk], rhs [tk, tn] (``itemsize`` bytes
+    an element) and float32 out [tm, tn] tiles, the next copied while
+    this one multiplies, beside the float32 accumulator."""
+    return (2 * (itemsize * (tm * tk + tk * tn) + 4 * tm * tn)
+            + 4 * tm * tn)
+
+
+def _gmm_tile(rows: int, k: int, n: int, itemsize: int = 2) -> tuple:
+    """The kernel's (m, k, n) tiles for ``rows`` x [k, n] experts of
+    ``itemsize`` bytes an element, from those numbers alone (PERF.md
+    section 6, PR 45: the table these rules are read off).
+
+    m: 128, or all of fewer rows.  k and n: the whole dimension or a
+    multiple of 128 that divides it (a tile that does not is multiplied
+    whole and its overhang masked: half of 1,792's fourth 512), under
+    ``_GMM_VMEM_BUDGET``.  K stays whole where an n-tile no narrower
+    than before fits beside it: with one k-tile an expert's [K, tn]
+    stays in VMEM over all the row tiles of its group, where a split K
+    copies it again for every row tile, and the accumulator is written
+    once.  A K too long for that is cut to its largest divisor up to
+    2,048.  Then n is the widest divisor that fits: the fewer tiles an
+    expert's matrix is read in, the fewer grid steps pay their fixed
+    cost, and a prefill's rows are read once an n-tile.
+
+    What every call had before, ``(min(K, 2048), min(N, 512))``, stays
+    in two cases: where no divisor of N is as wide as that, and for a
+    call of at most two row tiles whose K and N the 2 MiB tile divides
+    (no group there spans row tiles enough to use a resident [K, tn]
+    twice, a copy of 2 MiB hides a grid step's fixed cost, and every
+    wider tile measured slower: MiMo's 4,096 x 2,048 at 256 rows)."""
+    tm = min(rows, _GMM_TILE_M)
+    before = (min(k, 2048), min(n, 512))
+    if rows <= 2 * _GMM_TILE_M and k % 2048 == 0 and n % 512 == 0:
+        return (tm,) + before
+
+    def divisors(x):
+        """``x`` and the multiples of 128 that divide it, widest first."""
+        return [x] + [t for t in range((x - 1) // 128 * 128, 127, -128)
+                      if x % t == 0]
+
+    def widest(tk):
+        return next((tn for tn in divisors(n) if _gmm_vmem_bytes(
+            tm, tk, tn, itemsize) <= _GMM_VMEM_BUDGET), 0)
+
+    tk = k if widest(k) >= before[1] else next(
+        (t for t in divisors(k) if t <= 2048), before[0])
+    tn = widest(tk)
+    return (tm, tk, tn) if tn >= before[1] else (tm,) + before
+
+
+def _pass_rows(arch: ModelArch, tokens: int) -> int:
+    """Sorted pairs one pass of the expert layer computes: every pair
+    when the layer is whole, twice the even share of a layer that
+    ``expert_shards`` chips share."""
+    pairs = tokens * arch.num_experts_per_tok
+    if arch.expert_shards == 1:
+        return pairs
+    return min(pairs, max(256, 2 * pairs // arch.expert_shards))
+
+
+def expert_tiles(arch: ModelArch, tokens: int, itemsize: int) -> dict:
+    """The tiles ``moe_mlp_ragged(kernel=True)`` runs a step of
+    ``tokens`` tokens with over experts of ``itemsize`` bytes an
+    element, ``{"KxN": [m, k, n]}`` for the gate and up matrices and
+    for the down one: what ``/health`` lists."""
+    rows = _gmm_rows(_pass_rows(arch, tokens))
+    E, inter = arch.hidden_size, arch.moe_intermediate_size
+    return {f"{k}x{n}": list(_gmm_tile(rows, k, n, itemsize))
+            for k, n in ((E, inter), (inter, E))}
 
 
 def _combine_slots(y: jax.Array, out: jax.Array, rel: jax.Array,
@@ -585,8 +654,7 @@ def moe_mlp_ragged(x: jax.Array, p: dict, arch: ModelArch, *,
     starts = ends - group_sizes
     flat_w = weights.reshape(-1)
     pairs = T * k
-    cap = pairs if arch.expert_shards == 1 else min(
-        pairs, max(256, 2 * pairs // arch.expert_shards))
+    cap = _pass_rows(arch, T)
     n_rows = _gmm_rows(cap) if kernel else cap
     compact = False
     if kernel and cap < pairs:

@@ -373,6 +373,8 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         combine = engines[0].model.moe_combine
         if combine:
             body["moe_combine"] = combine
+        if engines[0].moe_tiles:
+            body["moe_tiles"] = engines[0].moe_tiles
         arch = engines[0].md.arch
         if arch.conv_layers:
             # what mixes the layers' tokens, where not attention alone

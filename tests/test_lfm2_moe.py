@@ -401,6 +401,7 @@ def test_health_surface_and_metrics():
     assert health["mixers"] == {"conv": 5, "full_attention": 2}
     assert health["hbm_sizing"]["state_bytes_per_row"] == 5 * 2 * 256 * 4
     assert health["moe_combine"] == "xla"
+    assert "moe_tiles" not in health    # XLA's ragged dot has no tiles
 
 
 def test_a_model_with_no_conv_layer_has_no_such_family():

@@ -166,3 +166,123 @@ def test_compact_unsort_equals_the_loop_bit_for_bit(monkeypatch, tokens,
         assert not want[128:256].any()
     if n_valid is not None:
         assert not want[n_valid:].any() and want[n_valid - 1].any()
+
+
+def _divides_or_whole(tile, dim):
+    return tile == dim or (tile % 128 == 0 and dim % tile == 0)
+
+
+@pytest.mark.parametrize("rows", [16, 128, 256, 16384])
+@pytest.mark.parametrize("k,n,want", [
+    (2048, 1792, (2048, 896)),      # lfm2: two n-tiles, none half empty
+    (1792, 2048, (1792, 1024)),
+    (2048, 768, (2048, 768)),       # joyai: an expert's matrix one tile
+    (768, 2048, (768, 2048)),
+    (4096, 2048, (4096, 512)),      # mimo's prefill: K whole, the rhs
+    (2048, 4096, (2048, 1024)),     # stays put over a group's row tiles
+    (4096, 14336, (4096, 512)),     # mixtral
+    (14336, 4096, (2048, 1024)),    # a K no tile holds whole is split
+    (2048, 1400, (2048, 512)),      # no multiple of 128 divides 1,400:
+    (2048, 1408, (2048, 512)),      # nor one as wide as before 1,408
+    (96, 64, (96, 64)),             # narrower than a tile: whole
+])
+def test_grouped_kernel_tiles_follow_the_shapes(rows, k, n, want):
+    """The grouped matmul's tiles for the widths served and in the
+    presets: k and n divide their dimension (or are what every call
+    had before, where no multiple of 128 does), m is the rows or 128,
+    and a tile set stays under the VMEM budget written beside it."""
+    tm, tk, tn = nn._gmm_tile(rows, k, n)
+    assert tm == min(rows, 128) and rows % tm == 0
+    before = (min(k, 2048), min(n, 512))
+    assert (tk, tn) == before or (
+        _divides_or_whole(tk, k) and _divides_or_whole(tn, n)
+        and tn >= before[1])
+    assert nn._gmm_vmem_bytes(tm, tk, tn, 2) <= nn._GMM_VMEM_BUDGET < 16 << 20
+    # a call of at most two row tiles keeps the 2 MiB tile where it
+    # divides both dimensions (what the chip measured fastest there)
+    if rows <= 256 and k % 2048 == 0 and n % 512 == 0:
+        assert (tk, tn) == before
+    elif rows >= 128:           # fewer rows leave room for a wider n
+        assert (tk, tn) == want
+
+
+@pytest.mark.parametrize("hidden,inter,tiles", [
+    (256, 384, {"256x384": [64, 256, 384], "384x256": [64, 384, 256]}),
+    (384, 256, {"384x256": [64, 384, 256], "256x384": [64, 256, 384]}),
+])
+def test_grouped_kernel_at_a_width_no_power_of_two(monkeypatch, hidden,
+                                                   inter, tiles):
+    """The kernel interpreted at tiles of 384, three times 128, against
+    XLA's ragged dot through the expert layer, and the tiles it ran
+    with are the ones ``expert_tiles`` names for ``/health``."""
+    import importlib
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from kaito_tpu.models.metadata import ModelArch
+
+    arch = ModelArch(
+        vocab_size=64, hidden_size=hidden, num_layers=1, num_heads=2,
+        num_kv_heads=2, head_dim=16, intermediate_size=64, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=inter)
+    rng = np.random.default_rng(hidden)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32) / 8
+
+    p = {"router": draw(hidden, 8) * 8,
+         "experts_gate": draw(2, 8, hidden, inter),
+         "experts_up": draw(2, 8, hidden, inter),
+         "experts_down": draw(2, 8, inter, hidden)}
+    x = draw(32, hidden) * 8
+    assert nn.expert_tiles(arch, 32, 4) == tiles
+    ran = {}
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    kernel = megablox.gmm
+
+    def recorded(lhs, rhs, *a, tiling, **kw):
+        ran["%dx%d" % rhs.shape[-2:]] = list(tiling)
+        return kernel(lhs, rhs, *a, tiling=tiling, **kw)
+
+    monkeypatch.setattr(megablox, "gmm", recorded)
+    want = nn.moe_mlp_ragged(x, p, arch, layer=jnp.int32(1))
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.jit(lambda x, p, at: nn.moe_mlp_ragged(
+            x, p, arch, kernel=True, layer=at))(x, p, jnp.int32(1))
+    assert np.abs(np.asarray(got - want)).max() < 1e-4
+    assert np.abs(np.asarray(want)).max() > 1e-2
+    assert ran == tiles
+
+
+@pytest.mark.parametrize("kernel,quantization,listed", [
+    (True, None, True),
+    (False, None, False),       # a CPU, a mesh: XLA's ragged dot
+    (True, "int8", False),      # quantized stacks take it too
+])
+def test_engine_lists_the_tiles_it_runs(kernel, quantization, listed):
+    """What ``/health`` gets under ``moe_tiles``: the tiles of a decode
+    step of every slot and of the longest prefill chunk, by the
+    experts' [K, N]; nothing where the kernel does not run."""
+    from types import SimpleNamespace
+
+    from kaito_tpu.engine.engine import InferenceEngine
+    from kaito_tpu.models.metadata import ModelArch
+
+    arch = ModelArch(
+        vocab_size=64, hidden_size=2048, num_layers=1, num_heads=2,
+        num_kv_heads=2, head_dim=16, intermediate_size=64, num_experts=32,
+        num_experts_per_tok=4, moe_intermediate_size=1792)
+    eng = SimpleNamespace(
+        model=SimpleNamespace(moe_combine="xla", moe_kernel=kernel),
+        cfg=SimpleNamespace(quantization=quantization, max_num_seqs=32,
+                            max_prefill_tokens=4096, page_size=64,
+                            max_model_len=8192),
+        md=SimpleNamespace(arch=arch), dtype=jnp.dtype(jnp.bfloat16),
+        _bucket=lambda n: n)
+    tiles = InferenceEngine._expert_tiles(eng)
+    if not listed:
+        assert tiles is None
+        return
+    one = {"2048x1792": [128, 2048, 896], "1792x2048": [128, 1792, 1024]}
+    assert tiles == {"decode": one, "prefill": one}

@@ -294,6 +294,30 @@ def test_expert_layer_compiles_for_v5e_and_names_its_kernel(one_chip, rows):
         8 if rows <= 32 else 2)
 
 
+@pytest.mark.parametrize("k,n,held", [
+    (2048, 1792, 32), (1792, 2048, 32),     # lfm2-8b-a1b: tiles of 896
+    (2048, 768, 16), (768, 2048, 16),       # joyai-llm-flash: one tile
+    (4096, 14336, 2), (14336, 4096, 2),     # mixtral: K whole, K split
+])
+@pytest.mark.parametrize("rows", [128, 4096])
+def test_grouped_kernel_tiles_compile_for_v5e(one_chip, k, n, held, rows):
+    """The tiles ``nn._gmm_tile`` picks at the widths served and in the
+    presets are tiles Mosaic takes within the VMEM a call gets: the
+    budget's arithmetic held against the compiler's own verdict."""
+    from kaito_tpu.engine import nn
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(lhs, w, sizes, expert_of_row, at):
+        return nn._grouped_matmul(lhs, w, sizes, expert_of_row, True, at)
+
+    text = jax.jit(call).lower(
+        sd((rows, k)), sd((2, held, k, n)), sd((held,), jnp.int32),
+        sd((rows,), jnp.int32), sd((), jnp.int32)).compile().as_text()
+    assert "%gmm" in text
+
+
 def test_grouped_kernel_equals_the_ragged_dot():
     """The Pallas grouped matmul in interpret mode against XLA's ragged
     dot, through the expert layer: the whole stack by index, an expert
