@@ -556,6 +556,19 @@ class InferenceEngine:
                     sum(x.nbytes for x in jax.tree.leaves(self.params))
                     / 2**30)
 
+        # a latent-attention model's ``kv_b_k`` / ``kv_b_v`` as decode
+        # reads them, named on /health: the Pallas kernel's absorb and
+        # expand products batch over heads, so beside a kernel-read
+        # pool the two stacks are held head-major too, made here once
+        # and BEFORE pool sizing, which counts every resident leaf
+        self.latent_weights = None
+        if self.latent_kernel:
+            self.params = jax.block_until_ready(
+                self.model.latent_head_major(self.params))
+            self.latent_weights = "head_major"
+        elif self.model.is_mla:
+            self.latent_weights = "as_drawn"
+
         # draft-model speculation (docs/speculative.md): the draft and
         # its private KV pool come up BEFORE target-pool sizing so the
         # derived page count reads the HBM actually left over

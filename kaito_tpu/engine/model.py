@@ -161,6 +161,14 @@ def _layer_groups(arch: ModelArch) -> tuple[LayerGroup, ...]:
     return (LayerGroup("dense", 0, arch.num_layers, False),)
 
 
+def _head_major(w: jax.Array, heads: int) -> jax.Array:
+    """A latent layer's ``kv_b_k`` or ``kv_b_v``, [..., dl, H*d] as
+    ``init_params`` draws it, in the form the absorbed products batch
+    over: [..., H, d, dl]."""
+    *lead, dl, hd = w.shape
+    return jnp.moveaxis(w.reshape(*lead, dl, heads, hd // heads), -3, -1)
+
+
 def _prefetch_stack(stack: dict):
     """Layer-ahead slabs for the comm-overlap decode scan
     (docs/multichip.md): the QUANTIZED o/down planes rolled one layer
@@ -595,6 +603,30 @@ class TransformerLM:
             }
         return axes
 
+    def latent_head_major(self, params: dict) -> dict:
+        """The tree with every latent layer group's ``kv_b_k`` and
+        ``kv_b_v`` held a second time head-major, as ``kv_b_k_hm`` [n,
+        H, dn, dl] and ``kv_b_v_hm`` [n, H, dv, dl]: what the decode
+        kernel's absorb and expand products and flash prefill's
+        expansion read (a product batched over heads wants the head
+        axis major, and given the stacks as drawn the compiler
+        transposes both whole, once a program).  Made once, on the
+        engine's load path, from the tree as ``init_params`` draws it,
+        which stays as it is: the XLA paths read the flat form.  One
+        program makes the pair, so nothing but the pair stays resident
+        behind it (the pool is sized from what the device reports in
+        use).  The head axis is the one ``_layer_specs`` shards by
+        ``heads``."""
+        H = self.arch.num_heads
+        flat = {name: {k: sub[k] for k in ("kv_b_k", "kv_b_v")}
+                for name, sub in params.items()
+                if isinstance(sub, dict) and "kv_b_k" in sub}
+        made = jax.jit(lambda stacks: {
+            name: {k + "_hm": _head_major(w, H) for k, w in sub.items()}
+            for name, sub in stacks.items()})(flat)
+        return {name: {**sub, **made[name]} if name in made else sub
+                for name, sub in params.items()}
+
     def param_count(self, params: dict) -> int:
         return sum(x.size for x in jax.tree.leaves(params))
 
@@ -702,11 +734,23 @@ class TransformerLM:
                        interleave=a.rope_interleave)
 
         if "q_a" in p:
-            q_lat = nn.rms_norm(nn.linear(h, p["q_a"]), p["q_a_norm"],
-                                a.rms_norm_eps, False)
-            q = nn.linear(q_lat, p["q_b"])
+            x = nn.rms_norm(nn.linear(h, p["q_a"]), p["q_a_norm"],
+                            a.rms_norm_eps, False)
+            q = nn.linear(x, p["q_b"])
         else:
-            q = nn.linear(h, p["q"])
+            x = h
+            q = nn.linear(x, p["q"])
+        if (dn + dr) % 128 and B * T < x.shape[-1]:
+            # a query head that is no whole number of 128-lane tiles
+            # (192 = 128 | 64): as in ``_attn_qkv``, the split into
+            # heads re-lays the activations out; without the barrier
+            # the compiler transposes the whole stack of weights
+            # instead, once a program, and slices the copy a layer.
+            # Taken where the rows are fewer than the weight's own
+            # (decode, a short chunk): past that the activations are
+            # the larger of the two and the weight's re-lay, a layer at
+            # a time there, is the cheaper
+            q = jax.lax.optimization_barrier(q)
         q = q.reshape(B, T, H, dn + dr)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
         q_rope = rope(q_rope, positions)
@@ -773,6 +817,16 @@ class TransformerLM:
         attn_out = nn.linear(out.reshape(B, T, H * dv), p["o"])
         return attn_out, ck, cv, ks, vs
 
+    def _kv_b_head_major(self, p: dict) -> tuple:
+        """A layer's ``kv_b_k`` and ``kv_b_v`` as [H, d, dl], what both
+        kernels' products read: the pair the engine holds so
+        (``latent_head_major``), else made of the flat form in the
+        program, where the compiler copies them as it sees fit."""
+        if "kv_b_k_hm" in p:
+            return p["kv_b_k_hm"], p["kv_b_v_hm"]
+        H = self.arch.num_heads
+        return _head_major(p["kv_b_k"], H), _head_major(p["kv_b_v"], H)
+
     def _mla_flash_prefill(self, q_nope, q_rope, c_kv, k_rope, p, true_lens):
         """A fresh chunk through flash prefill on the EXPANDED heads:
         keys ``[k_nope | k_rope]`` at their stored lanes (q padded
@@ -784,8 +838,9 @@ class TransformerLM:
         B, T, H, dn = q_nope.shape
         _, dr, _, dv = self.arch.mla_dims
         with jax.named_scope("mla_expand"):
-            k_nope = nn.linear(c_kv, p["kv_b_k"]).reshape(B, T, H, dn)
-            v = nn.linear(c_kv, p["kv_b_v"]).reshape(B, T, H, dv)
+            wk, wv = self._kv_b_head_major(p)
+            k_nope = jnp.einsum("btl,hdl->bthd", c_kv, wk)
+            v = jnp.einsum("btl,hdl->bthd", c_kv, wv)
         zeros = jnp.zeros((B, T, H, stored_key_dim(dn + dr) - (dn + dr)),
                           k_nope.dtype)
         k = jnp.concatenate(
@@ -808,9 +863,9 @@ class TransformerLM:
 
         B, H, dn = q_nope.shape
         _, dr, dl, dv = self.arch.mla_dims
+        wk, wv = self._kv_b_head_major(p)
         with jax.named_scope("mla_absorb"):
-            q_lat = jnp.einsum("bhd,lhd->bhl", q_nope,
-                               p["kv_b_k"].reshape(dl, H, dn),
+            q_lat = jnp.einsum("bhd,hdl->bhl", q_nope, wk,
                                preferred_element_type=jnp.float32)
             q = jnp.concatenate(
                 [q_lat * self._scale,
@@ -821,8 +876,7 @@ class TransformerLM:
             out_lat = mla_paged_decode_attention_pallas(
                 q, pool, page_tables, lengths, li, value_lanes=dl)
         with jax.named_scope("mla_expand"):
-            out = jnp.einsum("bhl,lhd->bhd", out_lat,
-                             p["kv_b_v"].reshape(dl, H, dv),
+            out = jnp.einsum("bhl,hdl->bhd", out_lat, wv,
                              preferred_element_type=jnp.float32)
         return out.astype(q_nope.dtype)
 

@@ -375,6 +375,8 @@ class OpenAIHandler(BaseHTTPRequestHandler):
             body["moe_combine"] = combine
         if engines[0].moe_tiles:
             body["moe_tiles"] = engines[0].moe_tiles
+        if engines[0].latent_weights:
+            body["latent_weights"] = engines[0].latent_weights
         arch = engines[0].md.arch
         if arch.conv_layers:
             # what mixes the layers' tokens, where not attention alone

@@ -165,3 +165,221 @@ def test_deepseek_v3_full_arch_constructs():
     assert specs["router"][0] == (7168, 256)
     axes = model.param_logical_axes()
     assert "moe" in axes and "dense" in axes
+
+
+# ----------------------------------------------------------------------
+# The attention weights are multiplied where they lie (PR 47): a query
+# head of 192 = 128 | 64 lanes takes a barrier on its activations, the
+# absorbed products read kv_b_k / kv_b_v head-major, and the tree that
+# init_params draws stays as it is.
+# ----------------------------------------------------------------------
+
+def _latent_cfg(width: int) -> dict:
+    """joyai_llm_flash's shape at a tiny size with its published head
+    widths: q heads of [128 | width - 128], values of 128."""
+    return dict(
+        architectures=["JoyAILLMFlashForCausalLM"],
+        model_type="joyai_llm_flash", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_hidden_layers=3, num_attention_heads=2,
+        num_key_value_heads=2, head_dim=width - 128, kv_lora_rank=128,
+        q_lora_rank=48, qk_head_dim=width, qk_nope_head_dim=128,
+        qk_rope_head_dim=width - 128, v_head_dim=128, rope_theta=32000000,
+        rope_interleave=True, rope_scaling=None, attention_bias=False,
+        rms_norm_eps=1e-6, hidden_act="silu", max_position_embeddings=2048,
+        tie_word_embeddings=False, first_k_dense_replace=1, moe_layer_freq=1,
+        moe_intermediate_size=32, n_routed_experts=4, expert_shards=4,
+        expert_shard=0, n_shared_experts=1, num_experts_per_tok=4,
+        norm_topk_prob=True, scoring_func="sigmoid", n_group=1, topk_group=1,
+        topk_method="noaux_tc", routed_scaling_factor=2.5, ep_size=1,
+        num_nextn_predict_layers=1)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+@pytest.mark.parametrize("width", [192, 256])
+def test_both_head_widths_equal_a_float32_evaluation_of_the_tree(width, path):
+    """Prefill and two decode steps, through the XLA paths over the
+    five-dimensional pool and through both kernels (interpreted) over
+    the kernel-read pool with the head-major weights beside the drawn
+    ones, against the plain reference's float32 evaluation of the tree
+    as ``init_params`` draws it."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    cfg = _latent_cfg(width)
+    arch = arch_from_hf_config(cfg)
+    kernel = path == "kernel"
+    model = TransformerLM(arch, dtype=jnp.float32,
+                          attn_impl="pallas" if kernel else "jax")
+    model.moe_impl = "ragged"
+    drawn = model.init_params(jax.random.PRNGKey(3))
+    params = model.latent_head_major(drawn) if kernel else drawn
+    cache = create_kv_cache(arch, 12, PS, jnp.float32, latent_kernel=kernel)
+    n = 100
+    seq = np.random.default_rng(width).integers(1, 500, n + 2)
+    toks = jnp.asarray(np.concatenate([seq[:n], np.zeros(128 - n, int)])[None],
+                       jnp.int32)
+    pt = jnp.asarray(np.arange(1, 10)[None], jnp.int32)
+
+    @jax.jit
+    def served(params, cache):
+        cache, l0, _ = model.prefill(params, cache, toks,
+                                     jnp.asarray([n], jnp.int32), pt)
+        step = []
+        for i in (n, n + 1):
+            cache, l = model.decode(params, cache,
+                                    jnp.asarray(seq[i:i + 1], jnp.int32),
+                                    jnp.asarray([i], jnp.int32), pt)
+            step.append(l)
+        return jax.nn.log_softmax(jnp.concatenate([l0, *step]), axis=-1)
+
+    if kernel:
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(served(params, cache))
+    else:
+        got = np.asarray(served(params, cache))
+    from test_latent_engine import _reference
+
+    want = _reference().forward(cfg, drawn, [int(t) for t in seq], n - 1)
+    target = np.asarray(want["target"])
+    assert np.abs(got[0, seq[n]] - target[0]) < 3e-4
+    assert np.abs(got[1, seq[n + 1]] - target[1]) < 3e-4
+    assert np.abs(got.max(axis=-1) - np.asarray(want["top"])).max() < 3e-4
+
+
+def _latent_shapes(width: int):
+    arch = arch_from_hf_config(_latent_cfg(width))
+    model = TransformerLM(arch, dtype=jnp.float32)
+    model.moe_impl = "ragged"
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: create_kv_cache(arch, 8, PS, jnp.float32))
+    return model, params, cache
+
+
+def _decode_jaxpr(width: int, rows: int) -> str:
+    model, params, cache = _latent_shapes(width)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    return str(jax.make_jaxpr(model.decode)(params, cache, i32(rows),
+                                            i32(rows), i32(rows, 4)))
+
+
+def _prefill_jaxpr(width: int, tokens: int) -> str:
+    model, params, cache = _latent_shapes(width)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    return str(jax.make_jaxpr(model.prefill)(params, cache, i32(1, tokens),
+                                             i32(1), i32(1, 4)))
+
+
+def _qkv_jaxpr(head_dim: int) -> str:
+    """``_attn_qkv`` of a model whose layers name their kinds, at keys
+    of ``head_dim`` (MiMo's are 192)."""
+    from test_two_kind_engine import TINY_MIMO
+
+    arch = arch_from_hf_config(dict(TINY_MIMO, head_dim=head_dim,
+                                    swa_head_dim=head_dim))
+    model = TransformerLM(arch, dtype=jnp.float32)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    g = model.groups[0]
+    p = {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype)
+         for k, v in params[g.name].items()}
+    return str(jax.make_jaxpr(
+        lambda x, p, pos: model._attn_qkv(x, p, pos, None,
+                                          kind=model.kinds[g.kind]))(
+        jax.ShapeDtypeStruct((2, 1, arch.hidden_size), jnp.float32), p,
+        jax.ShapeDtypeStruct((2, 1), jnp.int32)))
+
+
+@pytest.mark.parametrize("jaxpr,args,barrier", [
+    # a 192-wide latent query head at decode rows: the activations pay
+    (_decode_jaxpr, (192, 4), True),
+    # whole tiles: nothing to re-lay, the program is what it was
+    (_decode_jaxpr, (256, 4), False),
+    # a short chunk, fewer rows than q_b has (48 here): still the
+    # activations
+    (_prefill_jaxpr, (192, 32), True),
+    # a chunk with more rows than q_b: the weight is the smaller of
+    # the two, and its re-lay is left to the compiler
+    (_prefill_jaxpr, (192, 64), False),
+    (_prefill_jaxpr, (256, 32), False),
+    # the same pin for ``_attn_qkv``: MiMo's 192-wide keys, and heads
+    # of at most one tile
+    (_qkv_jaxpr, (192,), True),
+    (_qkv_jaxpr, (24,), False),
+])
+def test_a_barrier_stands_where_a_head_is_no_whole_number_of_tiles(
+        jaxpr, args, barrier):
+    assert ("optimization_barrier" in jaxpr(*args)) == barrier
+
+
+def test_head_major_weights_are_derived_and_the_drawn_tree_stands():
+    """``init_params`` gives the tree the benchmark's reference reads by
+    name; the head-major pair is added beside it, from it."""
+    arch = arch_from_hf_config(_latent_cfg(192))
+    model = TransformerLM(arch, dtype=jnp.float32)
+    drawn = model.init_params(jax.random.PRNGKey(0))
+    assert sorted(drawn) == ["dense", "embed", "final_norm", "lm_head", "moe"]
+    latent = {"attn_norm": (64,), "kv_a": (64, 192), "kv_a_norm": (128,),
+              "kv_b_k": (128, 256), "kv_b_v": (128, 256), "o": (256, 64),
+              "q_a": (64, 48), "q_a_norm": (48,), "q_b": (48, 384)}
+    for name, count in (("dense", 1), ("moe", 2)):
+        assert {k: v.shape[1:] for k, v in drawn[name].items()
+                if k in latent} == latent
+        assert all(v.shape[0] == count for v in drawn[name].values())
+    held = model.latent_head_major(drawn)
+    for name in ("dense", "moe"):
+        assert set(held[name]) - set(drawn[name]) == {"kv_b_k_hm",
+                                                      "kv_b_v_hm"}
+        assert all(held[name][k] is v for k, v in drawn[name].items())
+        for flat, hm in (("kv_b_k", "kv_b_k_hm"), ("kv_b_v", "kv_b_v_hm")):
+            w = np.asarray(drawn[name][flat])            # [n, dl, H*d]
+            assert held[name][hm].shape == (w.shape[0], 2, 128, 128)
+            np.testing.assert_array_equal(
+                np.asarray(held[name][hm])[:, 1, 5, :], w[:, :, 128 + 5])
+    assert all(held[k] is drawn[k] for k in ("embed", "final_norm", "lm_head"))
+
+
+@pytest.mark.parametrize("kw,form", [
+    (dict(), "as_drawn"),
+    # the kernel-read pool (a TPU's path, asked for by hand here):
+    # decode would read the pair head-major
+    (dict(use_pallas=True, dtype="bfloat16", kv_dtype="bfloat16"),
+     "head_major"),
+])
+def test_health_names_the_form_of_the_latent_weights(kw, form):
+    import json
+    import threading
+    import urllib.request
+
+    from kaito_tpu.engine.config import EngineConfig
+    from kaito_tpu.engine.engine import InferenceEngine
+    from kaito_tpu.engine.server import make_server
+    from kaito_tpu.models.autogen import metadata_from_hf_config
+
+    md = metadata_from_hf_config("kaito-tpu/tiny-latent-192-test",
+                                 _latent_cfg(192), name="tiny-latent-192-test")
+    eng = InferenceEngine(EngineConfig(**{**dict(
+        model=md.name, max_model_len=256, page_size=PS, max_num_seqs=2,
+        dtype="float32", kv_dtype="float32", prefill_buckets=(32, 64),
+        max_prefill_tokens=64, prefill_pack=1, seed=5), **kw}), metadata=md)
+    assert eng.latent_weights == form
+    assert ("kv_b_k_hm" in eng.params["moe"]) == (form == "head_major")
+    # what the pool is sized after: every resident leaf, the pair too
+    drawn = jax.eval_shape(eng.model.init_params, jax.random.PRNGKey(0))
+    assert set(drawn["moe"]) <= set(eng.params["moe"])
+    server = make_server(eng, eng.cfg, host="127.0.0.1", port=0)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        health = json.loads(urllib.request.urlopen(
+            f"http://127.0.0.1:{server.server_address[1]}/health",
+            timeout=30).read())
+    finally:
+        server.shutdown()
+        server.server_close()
+    assert health["latent_weights"] == form
+
+
+def test_a_model_without_latent_attention_names_no_such_form():
+    from kaito_tpu.engine.config import EngineConfig
+    from kaito_tpu.engine.engine import InferenceEngine
+
+    eng = InferenceEngine(EngineConfig(model="tiny-llama-test",
+                                       max_model_len=64, max_num_seqs=2))
+    assert eng.latent_weights is None
