@@ -93,7 +93,7 @@ structured:
 obs:
 	$(PYTHON) -m pytest tests/test_tracing.py tests/test_metrics_format.py \
 	  tests/test_slo.py tests/test_itl_slo.py tests/test_controllers.py \
-	  tests/test_fleet.py tests/test_prefill_pack.py tests/test_devprof.py \
+	  tests/test_fleet.py tests/test_devprof.py \
 	  tests/test_comm_overlap.py tests/test_kv_tier.py -q -m "not slow"
 
 # device-time attribution suite (docs/observability.md "Device-time
@@ -167,16 +167,11 @@ asyncloop:
 	KAITO_ASYNC_DISPATCH=1 $(PYTHON) -m pytest \
 	  tests/test_async_dispatch.py tests/test_decode_run_ahead.py -q
 
-# packed multi-sequence prefill (docs/prefill.md): token-budget
-# scheduler + segment-packed dispatch bit-equivalence, packed flash
-# kernel segment-mask parity, then the chunked-prefill engine tier
-# once more with KAITO_PREFILL_PACK=8 forced so the packed path can't
-# rot behind its auto default
+# prefill scheduling (docs/prefill.md): the serial turn, chunked
+# prefill through the engine, the flash kernel's parity
 prefill:
-	$(PYTHON) -m pytest tests/test_prefill_pack.py \
-	  tests/test_flash_prefill.py -q
-	KAITO_PREFILL_PACK=8 $(PYTHON) -m pytest \
-	  tests/test_chunked_prefill.py -q
+	$(PYTHON) -m pytest tests/test_scheduler.py \
+	  tests/test_chunked_prefill.py tests/test_flash_prefill.py -q
 
 # the real server at a real model's widths on the chip (one chip
 # process at a time; needs a TPU).  Rehearse on the CPU first:

@@ -325,41 +325,6 @@ def prefill_case(name: str = "flash_prefill") -> KernelCase:
         unit="live causal FLOPs", work=2.0 * Hq * (Dk + Dv) * live)
 
 
-def packed_case() -> KernelCase:
-    import numpy as np
-
-    from kaito_tpu.engine.attention import packed_prefill_attention
-    from kaito_tpu.engine.ops.flash_prefill import flash_prefill_packed
-
-    # the engine's shape: one row, a 512-token budget, a handful of
-    # prompts of uneven length and a padded tail
-    T, seg_lens = 512, (100, 150, 37, 200)
-    scale = D ** -0.5
-    segs = np.full((1, T), -1, np.int32)
-    poss = np.zeros((1, T), np.int32)
-    off = 0
-    for si, n in enumerate(seg_lens):
-        segs[0, off:off + n] = si
-        poss[0, off:off + n] = np.arange(n)
-        off += n
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(4), 3)
-    q = jax.random.normal(kq, (1, T, H, D), jnp.bfloat16)
-    k = jax.random.normal(kk, (1, T, HKV, D), jnp.bfloat16)
-    v = jax.random.normal(kv, (1, T, HKV, D), jnp.bfloat16)
-    win = jnp.asarray(BIG_WINDOW, jnp.int32)
-    mask = jnp.asarray(segs >= 0, jnp.float32)[:, :, None, None]
-    return KernelCase(
-        "flash_prefill_packed",
-        lambda q, k, v, sg, ps_: flash_prefill_packed(
-            q, k, v, sg, ps_, win, scale=scale),
-        lambda q, k, v, sg, ps_: packed_prefill_attention(
-            q, k, v, sg, ps_, scale=scale),
-        (q, k, v, jnp.asarray(segs), jnp.asarray(poss)),
-        ATTN_TOL, ATTN_WHY, mask=mask,
-        unit="causal FLOPs",
-        work=sum(4.0 * H * D * n * n / 2 for n in seg_lens))
-
-
 def gemv_case(scheme: str, prefetch: bool = False) -> KernelCase:
     from kaito_tpu.engine.ops.quant_matmul import (dequant_matmul_jax,
                                                    kernel_plan, prefetch_ok,
@@ -493,7 +458,6 @@ CASES: dict[str, Callable[[], KernelCase]] = {
     "mla_decode_1x4k": lambda: mla_decode_case("mla_decode_1x4k", 1, 4096),
     "mla_decode_24x5k": lambda: mla_decode_case("mla_decode_24x5k", 24, 4864,
                                                 ragged=0.05),
-    "flash_prefill_packed": packed_case,
     "gemv_int8": lambda: gemv_case("int8"),
     "gemv_int8_prefetch": lambda: gemv_case("int8", prefetch=True),
     "gemv_int4": lambda: gemv_case("int4"),

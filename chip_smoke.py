@@ -9,9 +9,9 @@ child.  Legs, in order:
 
 - ``serve``    one chip, phi-4-mini-instruct at full depth and width,
                random weights from the seed.  Five kinds of request
-               (see ``run_requests``) cover fresh flash prefill, packed
-               prefill, chunked context prefill, fused and batched
-               decode, SSE streaming and a prefix-cache hit.
+               (see ``run_requests``) cover fresh flash prefill, a
+               turn of several prompts, chunked context prefill, fused
+               and batched decode, SSE streaming and a prefix-cache hit.
 - ``kernels``  ``benchmarks/kernel_bench.py --parity``: every Pallas
                kernel compiled for the chip against its pure-JAX
                reference.
@@ -62,8 +62,8 @@ EXPECT = {
     "cpu": {"attention": "jax", "hbm_source": "seq-cap"},
 }
 # the kernels on the default bf16 serving path; every other kernel must
-# match too, but these three are what the serve leg just ran
-MAIN_PATH_KERNELS = ("decode_bf16", "flash_prefill", "flash_prefill_packed")
+# match too, but these two are what the serve leg just ran
+MAIN_PATH_KERNELS = ("decode_bf16", "flash_prefill")
 # and what the serve leg's model does not run: the decode kernel of a
 # model with a state-space mixer beside attention (its state pool's
 # rows, empty ones included), and flash prefill at MiMo-V2.5's two
@@ -308,8 +308,8 @@ def run_requests(srv: Server, replicas: int) -> dict:
     ttft = srv.metrics().get("kaito:time_to_first_token_seconds_sum", 0.0)
     out["seconds_to_first_token"] = round(t_sent - srv.t_launch + ttft, 1)
 
-    # (b) eight ~100-200-token prompts at once, 64 out each: packed
-    # prefill kernel, batched decode
+    # (b) eight ~100-200-token prompts at once, 64 out each: prefill
+    # turns of several prompts, batched decode
     prompts = [text(rng, rng.randint(100, 200)) for _ in range(8)]
     errors = []
 

@@ -124,49 +124,6 @@ def prefill_attention(
 
 
 @_scoped
-def packed_prefill_attention(
-    q: jax.Array,            # [B, T, H, D]
-    k: jax.Array,            # [B, T, Hkv, D]
-    v: jax.Array,            # [B, T, Hkv, D]
-    seg_ids: jax.Array,      # [B, T] int32 segment id per token (-1 = pad)
-    positions: jax.Array,    # [B, T] int32 position WITHIN the segment
-    *,
-    scale: float,
-    sliding_window: Optional[jax.Array] = None,
-    logit_softcap: Optional[float] = None,
-) -> jax.Array:
-    """Causal self-attention over a segment-packed row.
-
-    Many fresh prompts share one padded row: tokens of segment ``s``
-    attend only to earlier tokens of the SAME segment (segment-id
-    causal masking), so one bucket's MXU work covers the whole pack.
-    Pad tokens carry ``seg_id == -1`` and attend to nothing; their
-    output rows are garbage the caller never gathers.
-    """
-    B, T, H, D = q.shape
-    groups = H // k.shape[2]
-    k = _gqa_expand(k, groups)
-    v = _gqa_expand(v, groups)
-    scores = jnp.einsum("bthd,bshd->bhts", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if logit_softcap:
-        scores = jnp.tanh(scores / logit_softcap) * logit_softcap
-    seg_q = seg_ids[:, :, None]                               # [B, T, 1]
-    seg_k = seg_ids[:, None, :]                               # [B, 1, T]
-    pos_q = positions[:, :, None]
-    pos_k = positions[:, None, :]
-    # same segment + within-segment causality; positions are strictly
-    # increasing inside a segment so pos_k <= pos_q also implies packed
-    # index order
-    mask = (seg_q == seg_k) & (seg_q >= 0) & (pos_k <= pos_q)
-    if sliding_window is not None:
-        mask &= pos_k > pos_q - sliding_window
-    scores = jnp.where(mask[:, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v)
-
-
-@_scoped
 def paged_context_attention(
     q: jax.Array,            # [B, T, H, D] chunk queries
     cache_k: jax.Array,      # [P, ps, Hkv, D] (chunk KV already written)

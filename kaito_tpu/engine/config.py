@@ -23,15 +23,6 @@ class EngineConfig:
     max_pages: int = 0                   # 0 = derive from HBM budget
     max_prefill_tokens: int = 512        # prefill chunk budget per step
     prefill_interleave: int = 2          # decode steps between prefill chunks
-    # packed multi-sequence prefill (docs/prefill.md): the per-step
-    # prefill budget above becomes an AGGREGATE token budget spread over
-    # a PACK of staged slots (segment packing for fresh prompts,
-    # batch-axis packing for same-bucket context chunks), so concurrent
-    # arrivals stop serializing at batch 1.  0 = auto (pack up to
-    # max_num_seqs); 1 is the serial round-robin scheduler: one-row
-    # programs only, as many whole staged prompts a turn as its chunk
-    # budget holds.
-    prefill_pack: int = 0
     prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048, 4096)
     dtype: str = "bfloat16"
     # KV page-pool dtype: "bfloat16" | "float32" | "int8".  int8 stores

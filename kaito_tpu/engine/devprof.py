@@ -61,8 +61,7 @@ BUCKETS = ("matmul", "attention", "collective", "copy", "other", "idle")
 #: spec.py / pd.py).  The scope string survives tracing into HLO
 #: ``metadata.op_name``, which is how a device slice lands in a phase
 #: here.
-PHASES = ("decode", "prefill", "prefill_packed", "verify", "draft",
-          "kv_import")
+PHASES = ("decode", "prefill", "verify", "draft", "kv_import")
 
 _PHASE_RE = re.compile(r"kaito/([a-z_]+)")
 

@@ -43,7 +43,7 @@ from kaito_tpu.engine import nn
 from kaito_tpu.engine.config import EngineConfig
 from kaito_tpu.engine.devprof import phase_scope
 from kaito_tpu.engine.grammar import GrammarCache, GrammarSlot, GrammarTable
-from kaito_tpu.engine.kv_cache import (KVCache, NULL_PAGE, create_kv_cache,
+from kaito_tpu.engine.kv_cache import (KVCache, create_kv_cache,
                                        create_conv_state_pool,
                                       create_state_pool,
                                        kv_cache_is_quantized,
@@ -985,15 +985,13 @@ class InferenceEngine:
                 ("replay_stall", "replay_stall",
                  "Per step: wall less thread CPU time inside the replay "
                  "alone: the interpreter lock or the OS"))}
-        # prefill scheduling (docs/prefill.md): sequences per packed
-        # dispatch, or per turn of the serial scheduler, and
-        # staged-to-first-dispatch wait — the two numbers that say
+        # prefill scheduling (docs/prefill.md): prompts a prefill turn
+        # and staged-to-first-dispatch wait — the two numbers that say
         # whether concurrent arrivals are admitted together or still
         # one an iteration
         self.prefill_pack_hist = Histogram(
             "kaito:engine_prefill_pack_size",
-            "Sequences per packed prefill dispatch, or per prefill turn "
-            "of the serial scheduler", None,
+            "Prompts a prefill turn", None,
             buckets=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 24.0, 32.0))
         self.prefill_wait_hist = Histogram(
             "kaito:prefill_queue_wait_seconds",
@@ -1257,15 +1255,6 @@ class InferenceEngine:
          "token's state update cannot be rolled back)"),
     )
 
-    # what a row of conv state (lfm2) refuses beside those: its layers
-    # run as a schedule of scans by kind, which has no segment-packed
-    # form (and no int8 pages: _refuse_for_state_pool)
-    _CONV_STATE_REFUSALS = (
-        ("prefill_pack", 1, "packed prefill (a schedule of layer kinds "
-         "has no segment-packed scan; prefill_pack 1 serves one-row "
-         "programs)"),
-    )
-
     def _refuse_for_state_pool(self, mesh) -> None:
         """Refuse by name, at start, every setting a model with a
         state pool (a state-space mixer's, or rows of conv state)
@@ -1274,7 +1263,6 @@ class InferenceEngine:
             raise ValueError(
                 f"{self.md.name} keeps a per-slot recurrent state beside "
                 f"its KV pages and is served on one device: no mesh")
-        refusals = self._STATE_POOL_REFUSALS
         if self.model.has_conv:
             if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
                 raise ValueError(
@@ -1282,8 +1270,7 @@ class InferenceEngine:
                     f"beside its KV pages and cannot be served with an "
                     f"int8 KV cache (its token-flat pools have no scale "
                     f"tensors): unset kv_dtype")
-            refusals = refusals + self._CONV_STATE_REFUSALS
-        for field_name, off, what in refusals:
+        for field_name, off, what in self._STATE_POOL_REFUSALS:
             if getattr(self.cfg, field_name) != off:
                 raise ValueError(
                     f"{self.md.name} keeps a per-slot recurrent state "
@@ -1313,8 +1300,6 @@ class InferenceEngine:
          "has no two-table path)"),
         ("speculative_draft", "", "draft-model speculation (the verify "
          "window has no two-table path)"),
-        ("prefill_pack", 1, "packed prefill (segment packing writes one "
-         "pool; prefill_pack 1 serves one-row programs)"),
     )
 
     def _refuse_settings(self, what: str, mesh, refusals) -> None:
@@ -1362,8 +1347,6 @@ class InferenceEngine:
          "has no kernel path over a latent pool)"),
         ("speculative_draft", "", "draft-model speculation (the verify "
          "window has no kernel path over a latent pool)"),
-        ("prefill_pack", 1, "packed prefill (segment packing has no "
-         "latent path; prefill_pack 1 serves one-row programs)"),
         ("adapter_slots", 0, "the adapter cache (latent projections "
          "carry no adapter slots)"),
     )
@@ -1979,31 +1962,6 @@ class InferenceEngine:
                 return cache, logits
 
             fn = prefill_cp
-            self._prefill_fns[key] = fn
-        return fn
-
-    def _prefill_packed_fn(self):
-        """Segment-packed prefill dispatch (docs/prefill.md): S fresh
-        prompts concatenated into one padded row.  One jitted callable
-        covers every (bucket, pack-size) combination — jax.jit retraces
-        per shape like the batch axis does."""
-        key = "pack"
-        fn = self._prefill_fns.get(key)
-        if fn is None:
-            model = self.model
-
-            @partial(jax.jit, donate_argnums=(1,))
-            @phase_scope("prefill_packed")
-            def prefill_packed(params, cache, tokens, seg_ids, positions,
-                               tok_pages, last_idx, pack_pages, tok_pgslot,
-                               adapter_ids):
-                cache, logits, _ = model.prefill_packed(
-                    params, cache, tokens, seg_ids, positions, tok_pages,
-                    last_idx, pack_pages=pack_pages, tok_pgslot=tok_pgslot,
-                    adapter_ids=adapter_ids)
-                return cache, logits
-
-            fn = prefill_packed
             self._prefill_fns[key] = fn
         return fn
 
@@ -2997,8 +2955,8 @@ class InferenceEngine:
                 extra["drain"] = ",".join(self._step_drains)
                 self._step_drains.clear()
             if self._prefill_pack_note:
-                # largest prefill pack dispatched this step — the
-                # /debug/timeline annotation for packed rounds
+                # most prompts a prefill turn of this step took — the
+                # /debug/timeline annotation for multi-prompt turns
                 extra["prefill_pack"] = self._prefill_pack_note
                 self._prefill_pack_note = 0
             self.timeline.add(
@@ -3518,27 +3476,6 @@ class InferenceEngine:
                     n_pages=n_use, export=exp,
                     nbytes=meta_nbytes(exp.meta)))
 
-    def _advance_prefills(self) -> bool:
-        """Advance staged prefills by one scheduler round.
-
-        ``prefill_pack > 1`` (the default resolves to ``max_num_seqs``)
-        spreads the per-step token budget over a PACK of staged slots
-        (docs/prefill.md); ``prefill_pack == 1`` is the serial
-        round-robin scheduler, one-row programs only
-        (_advance_prefill_single).  Pipeline parallelism keeps the
-        serial path — its prefill runs through the stage executor,
-        which has no packed route."""
-        pack = int(self.cfg.prefill_pack)
-        if pack <= 0:
-            pack = int(os.environ.get("KAITO_PREFILL_PACK", "0") or "0")
-        if pack <= 0:
-            pack = self.cfg.max_num_seqs
-        if self.pp_exec is not None:
-            pack = 1
-        if pack <= 1:
-            return self._advance_prefill_single()
-        return self._advance_prefill_pack(pack)
-
     def _fail_prefill(self, i: int, e: Exception) -> None:
         """Fail the request staged in slot ``i`` over a prefill error
         and free the slot without committing its pages."""
@@ -3572,8 +3509,8 @@ class InferenceEngine:
         return max(cfg.max_prefill_tokens, cfg.page_size) \
             * max(1, steps // every)
 
-    def _advance_prefill_single(self) -> bool:
-        """One prefill turn of the serial scheduler (docs/prefill.md).
+    def _advance_prefills(self) -> bool:
+        """One prefill turn (docs/prefill.md).
 
         Staged slots are served round-robin.  The first pick is always
         taken and runs ONE bounded chunk, as it always did.  When it
@@ -3702,304 +3639,6 @@ class InferenceEngine:
         if slot.prefill_pos >= n:
             self._complete_prefills([(i, n)], logits)
         return True
-
-    def _advance_prefill_pack(self, pack_limit: int) -> bool:
-        """Token-budget prefill scheduling (docs/prefill.md).
-
-        Picks a PACK of staged slots — strict QoS priority, then
-        admission order — whose chunks fill ``max_prefill_tokens`` as an
-        AGGREGATE budget, and runs them in as few dispatches as
-        possible: fresh-complete prompts are segment-packed into one
-        row per adapter (one bucket's MXU work covers the whole group),
-        context chunks batch on the batch axis per bucket, and CP-long
-        prompts keep their dedicated single-shot ring dispatch.  The
-        budget bounds decode ITL exactly as the serial path did; a
-        single-slot group dispatches through the same jitted family as
-        the serial scheduler, so light traffic is numerically untouched.
-        """
-        staged = [i for i, s in enumerate(self.slots)
-                  if s.request is not None and s.prefilling
-                  and not s.importing]
-        if not staged:
-            return False
-        staged.sort(key=lambda i: (-self.slots[i].request.priority,
-                                   self.slots[i].seq))
-        budget = max(self.cfg.max_prefill_tokens, self.cfg.page_size)
-        left = budget
-        picks: list[tuple[int, int, int, int]] = []  # (slot, pos, take, n)
-        cp_pick = None
-        for i in staged:
-            if len(picks) >= pack_limit or left <= 0:
-                break
-            slot = self.slots[i]
-            n = len(slot.prefill_tokens)
-            pos = slot.prefill_pos
-            use_cp = (self.model.cp is not None and pos == 0
-                      and n >= self.cfg.cp_min_tokens
-                      and self._bucket(n) % dict(
-                          self.model.cp[0].shape)["sequence"] == 0)
-            if use_cp:
-                # the ring shards the memory the budget was bounding; it
-                # runs ALONE — first in priority order, or next round
-                if not picks:
-                    cp_pick = i
-                break
-            take = min(n - pos, left)
-            if take <= 0:
-                break
-            if take < n - pos and picks and take < self.cfg.page_size:
-                # sub-page tail of the budget: leave it whole for the
-                # next round instead of fragmenting a long prompt
-                break
-            picks.append((i, pos, take, n))
-            left -= take
-        if cp_pick is not None:
-            return self._dispatch_prefill_cp(cp_pick)
-        if not picks:
-            return False
-
-        # a fault scoped to one request fails that request and not its
-        # pack-mates: the failpoint fires per row BEFORE rows share a
-        # dispatch; a failure of a dispatch itself (below) is the
-        # group's, which is its real domain
-        sound = []
-        for p in picks:
-            req = self.slots[p[0]].request
-            try:
-                FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
-            except Exception as e:
-                logger.exception("prefill failed for %s", req.req_id)
-                self._fail_prefill(p[0], e)
-                continue
-            sound.append(p)
-        if not sound:
-            return True
-        picks = sound
-
-        # group into dispatches, preserving priority order of first
-        # members: fresh-complete prompts segment-pack per adapter
-        # (batch-axis per bucket for MLA, which has no packed kernel),
-        # context chunks batch per bucket
-        # (a state-space mixer has no segment-packed scan either)
-        no_pack = self.model.is_mla or self.model.has_state
-        groups: list[tuple[tuple, list]] = []
-        index: dict[tuple, int] = {}
-        for p in picks:
-            i, pos, take, n = p
-            if pos == 0 and take == n:
-                gk = (("fresh", self._bucket(take)) if no_pack
-                      else ("seg", int(self.slot_adapters[i])))
-            else:
-                gk = ("ctx", self._bucket(take))
-            if gk in index:
-                groups[index[gk]][1].append(p)
-            else:
-                index[gk] = len(groups)
-                groups.append((gk, [p]))
-
-        did = False
-        completed = []   # (slot_idx, n, logits, row)
-        for gk, rows in groups:
-            t0 = time.monotonic()
-            part = self.phases.part
-            try:
-                with self.phases.phase("engine.prefill.dispatch"):
-                    with part("host.args"):
-                        if gk[0] == "seg" and len(rows) > 1:
-                            fn, args = self._prefill_packed_args(rows)
-                        elif gk[0] == "ctx":
-                            fn, args = self._prefill_ctx_args(rows)
-                        else:
-                            # single fresh prompt or MLA fresh bucket:
-                            # the serial scheduler's own jitted family,
-                            # batched
-                            fn, args = self._prefill_fresh_args(rows)
-                    with part("host.launch"):
-                        self.cache, logits = fn(self.params, self.cache,
-                                                *args)
-                    del args
-            except Exception as e:
-                logger.exception("prefill dispatch failed (%d slots)",
-                                 len(rows))
-                for (i, _, _, _) in rows:
-                    self._fail_prefill(i, e)
-                self._recover_cache_if_poisoned()
-                return True
-            dur = time.monotonic() - t0
-            self.counters["prefill_steps_total"] += 1
-            self.counters["prefill_tokens_total"] += sum(
-                take for (_, _, take, _) in rows)
-            self.prefill_pack_hist.observe(float(len(rows)))
-            self._prefill_pack_note = max(self._prefill_pack_note,
-                                          len(rows))
-            for row, (i, pos, take, n) in enumerate(rows):
-                slot = self.slots[i]
-                req = slot.request
-                wait = 0.0
-                if not slot.prefill_t0:
-                    slot.prefill_t0 = t0
-                    slot.prefill_base = pos
-                    if slot.staged_t0:
-                        wait = max(0.0, t0 - slot.staged_t0)
-                    self.prefill_wait_hist.observe(wait)
-                self.tracer.record(
-                    "prefill.chunk", req.trace_id, t0, dur, pos=pos,
-                    tokens=take, bucket=self._bucket(take), slot=i,
-                    cp=False, pack=len(rows), queue_wait=round(wait, 6))
-                slot.prefill_pos = pos + take
-                if slot.prefill_pos >= n:
-                    completed.append((i, n, logits, row))
-            did = True
-
-        if completed:
-            if len(completed) == 1:
-                i, n, logits, row = completed[0]
-                rows_l = logits[row:row + 1]
-            else:
-                rows_l = jnp.concatenate(
-                    [lg[r:r + 1] for (_, _, lg, r) in completed], axis=0)
-            # every sequence completing in the round, in ONE program
-            # over the gathered rows
-            self._complete_prefills([(i, n) for (i, n, _, _) in completed],
-                                    rows_l)
-        return did
-
-    def _dispatch_prefill_cp(self, i: int) -> bool:
-        """Single-slot context-parallel dispatch from the pack path —
-        the same route `_advance_prefill_single` takes for CP prompts."""
-        slot = self.slots[i]
-        req = slot.request
-        n = len(slot.prefill_tokens)
-        bucket = self._bucket(n)
-        t0 = time.monotonic()
-        try:
-            part = self.phases.part
-            with self.phases.phase("engine.prefill.dispatch"):
-                with part("host.args"):
-                    ctoks = np.zeros((1, bucket), np.int32)
-                    ctoks[0, :n] = slot.prefill_tokens
-                    aid = jnp.asarray(self.slot_adapters[i:i + 1])
-                    FAILPOINTS.fire("engine.prefill", req_id=req.req_id)
-                    fn = self._prefill_cp_fn(bucket)
-                    args = (jnp.asarray(ctoks), jnp.asarray([n], np.int32),
-                            jnp.asarray(self.page_tables[i][None]), aid)
-                with part("host.launch"):
-                    self.cache, logits = fn(self.params, self.cache, *args)
-                del args
-        except Exception as e:
-            logger.exception("prefill failed for %s", req.req_id)
-            self._fail_prefill(i, e)
-            self._recover_cache_if_poisoned()
-            return True
-        self.counters["prefill_steps_total"] += 1
-        self.counters["prefill_tokens_total"] += n
-        self.prefill_pack_hist.observe(1.0)
-        wait = 0.0
-        if not slot.prefill_t0:
-            slot.prefill_t0 = t0
-            slot.prefill_base = 0
-            if slot.staged_t0:
-                wait = max(0.0, t0 - slot.staged_t0)
-            self.prefill_wait_hist.observe(wait)
-        self.tracer.record("prefill.chunk", req.trace_id, t0,
-                           time.monotonic() - t0, pos=0, tokens=n,
-                           bucket=bucket, slot=i, cp=True, pack=1,
-                           queue_wait=round(wait, 6))
-        slot.prefill_pos = n
-        self._complete_prefills([(i, n)], logits)
-        return True
-
-    def _prefill_fresh_args(self, rows):
-        """Batch-axis dispatch of fresh-complete prompts sharing one
-        bucket: tokens [B, bucket] with per-row true_lens/page tables —
-        `model.prefill` was already row-wise, the serial scheduler just
-        never passed B > 1.  Returns the program and its arguments
-        behind (params, cache); the caller launches it."""
-        bucket = self._bucket(max(n for (_, _, _, n) in rows))
-        B = len(rows)
-        ctoks = np.zeros((B, bucket), np.int32)
-        tls = np.zeros((B,), np.int32)
-        pts = np.zeros((B,) + self.page_tables[0].shape, np.int32)
-        aids = np.zeros((B,), np.int32)
-        for j, (i, _, _, n) in enumerate(rows):
-            ctoks[j, :n] = self.slots[i].prefill_tokens
-            tls[j] = n
-            pts[j] = self.page_tables[i]
-            aids[j] = self.slot_adapters[i]
-        return self._prefill_fn(bucket), (
-            jnp.asarray(ctoks), jnp.asarray(tls), jnp.asarray(pts),
-            jnp.asarray(aids), self._state_rows([r[0] for r in rows]))
-
-    def _prefill_ctx_args(self, rows):
-        """Batch-axis dispatch of context chunks sharing one bucket:
-        per-row start_pos, each chunk attending over its own paged
-        history (cached prefix + earlier chunks).  Returns the program
-        and its arguments, as _prefill_fresh_args does."""
-        bucket = self._bucket(max(take for (_, _, take, _) in rows))
-        B = len(rows)
-        ctoks = np.zeros((B, bucket), np.int32)
-        tls = np.zeros((B,), np.int32)
-        sps = np.zeros((B,), np.int32)
-        pts = np.zeros((B,) + self.page_tables[0].shape, np.int32)
-        aids = np.zeros((B,), np.int32)
-        for j, (i, pos, take, _) in enumerate(rows):
-            ctoks[j, :take] = self.slots[i].prefill_tokens[pos:pos + take]
-            tls[j] = take
-            sps[j] = pos
-            pts[j] = self.page_tables[i]
-            aids[j] = self.slot_adapters[i]
-        return self._prefill_ctx_fn(bucket), (
-            jnp.asarray(ctoks), jnp.asarray(tls), jnp.asarray(pts),
-            jnp.asarray(sps), jnp.asarray(aids),
-            self._state_rows([r[0] for r in rows]))
-
-    def _prefill_packed_args(self, rows):
-        """Sequence-axis segment packing: concatenate S fresh prompts
-        (same adapter) into ONE padded row with per-token segment ids,
-        positions and page targets, so short prompts share one bucket's
-        MXU work instead of each padding a batch-1 row (docs/prefill.md).
-        Returns the program, whose last-token logits [S, V] are in pack
-        order, and its arguments, as _prefill_fresh_args does."""
-        ps = self.cfg.page_size
-        total = sum(take for (_, _, take, _) in rows)
-        T = self._bucket(total)
-        S = len(rows)
-        int8 = self.cache.k_scale is not None
-        toks = np.zeros((1, T), np.int32)
-        segs = np.full((1, T), -1, np.int32)
-        poss = np.zeros((1, T), np.int32)
-        tok_pages = np.full((T,), NULL_PAGE, np.int32)
-        last_idx = np.zeros((S,), np.int32)
-        pack_pages = tok_pgslot = None
-        if int8:
-            # pad the page span to a budget-derived constant so the jit
-            # trace is keyed only by (bucket, pack size)
-            budget = max(self.cfg.max_prefill_tokens, self.cfg.page_size)
-            npg_max = budget // ps + S + 1
-            pack_pages = np.full((npg_max,), NULL_PAGE, np.int32)
-            tok_pgslot = np.full((T,), npg_max, np.int32)  # OOB -> dropped
-        off = 0
-        pg = 0
-        for si, (i, _, take, _) in enumerate(rows):
-            toks[0, off:off + take] = self.slots[i].prefill_tokens
-            segs[0, off:off + take] = si
-            rel = np.arange(take, dtype=np.int32)
-            poss[0, off:off + take] = rel
-            table = self.page_tables[i]
-            tok_pages[off:off + take] = table[rel // ps]
-            if int8:
-                npg = (take + ps - 1) // ps
-                pack_pages[pg:pg + npg] = table[:npg]
-                tok_pgslot[off:off + take] = pg + rel // ps
-                pg += npg
-            last_idx[si] = off + take - 1
-            off += take
-        aid = jnp.asarray(self.slot_adapters[rows[0][0]:rows[0][0] + 1])
-        return self._prefill_packed_fn(), (
-            jnp.asarray(toks), jnp.asarray(segs), jnp.asarray(poss),
-            jnp.asarray(tok_pages), jnp.asarray(last_idx),
-            jnp.asarray(pack_pages) if int8 else None,
-            jnp.asarray(tok_pgslot) if int8 else None, aid)
 
     # what makes the host read a completed prefill's first token back
     # at once (docs/decode-loop.md): it must see the token before the

@@ -331,69 +331,6 @@ def write_prefill_tokens(
     return cache_layer.at[layer, page_idx.reshape(-1), offset.reshape(-1)].set(flat)
 
 
-def write_packed_prefill_tokens(
-    cache_layer: jax.Array,       # [num_pages, ps, Hkv, D] or, with
-                                  # ``layer``, the stacked group [Lg, P, ps, Hkv, D]
-    new: jax.Array,               # [1, T, Hkv, D] segment-packed row
-    tok_pages: jax.Array,         # [T] int32 page per token (pad -> null page)
-    offsets: jax.Array,           # [T] int32 slot within the page
-    layer: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Scatter a SEGMENT-PACKED prefill row into its pages.
-
-    Many fresh prompts share one packed row (``model.prefill_packed``);
-    each token carries its own page index and in-page offset, computed
-    host-side from its segment's page table, so one flat scatter lands
-    every segment's KV in that segment's own pages.  Pad tokens point
-    at the null page."""
-    flat = new[0]                                                 # [T, Hkv, D]
-    if layer is None:
-        return cache_layer.at[tok_pages, offsets].set(flat)
-    return cache_layer.at[layer, tok_pages, offsets].set(flat)
-
-
-def write_packed_prefill_tokens_q(
-    cache_layer: jax.Array,       # int8 [Lg, P, ps, Hkv, D] (or unstacked)
-    scale_layer: jax.Array,       # fp32 [Lg, P, Hkv] (or [P, Hkv])
-    new: jax.Array,               # [1, T, Hkv, D] segment-packed row
-    pack_pages: jax.Array,        # [n_pg] int32 pages of the pack (pad -> null)
-    tok_pgslot: jax.Array,        # [T] int32 index into pack_pages (n_pg = drop)
-    offsets: jax.Array,           # [T] int32 slot within the page
-    layer: Optional[jax.Array] = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Quantizing counterpart of :func:`write_packed_prefill_tokens`.
-
-    Same rescale-on-grow fold as :func:`write_prefill_tokens_q`, but the
-    page span is the union of every segment's pages (``pack_pages``) and
-    each token addresses its page through ``tok_pgslot``.  Segments are
-    fresh (start at position 0), so a page's absmax fold sees exactly
-    the same tokens as the serial per-sequence write — the grown scales
-    and codes come out identical.  Pad tokens carry ``tok_pgslot ==
-    n_pg`` (out of bounds -> dropped) and are excluded from the fold."""
-    n_pg = pack_pages.shape[0]
-    lidx = (layer,) if layer is not None else ()
-    pages = cache_layer[lidx + (pack_pages,)]      # [n_pg, ps, Hkv, D]
-    old = scale_layer[lidx + (pack_pages,)]        # [n_pg, Hkv]
-
-    new32 = new[0].astype(jnp.float32)                            # [T, Hkv, D]
-    tokmax = jnp.max(jnp.abs(new32), axis=-1)                     # [T, Hkv]
-    onehot = tok_pgslot[:, None] == jnp.arange(n_pg)[None, :]     # [T, n_pg]
-    cand = jnp.max(
-        jnp.where(onehot[..., None], tokmax[:, None, :], 0.0),
-        axis=0) / 127.0                                           # [n_pg, Hkv]
-    s_new = jnp.maximum(old, cand)
-    merged = _requantize(pages, old, s_new)
-
-    s_tok = s_new[jnp.clip(tok_pgslot, 0, n_pg - 1)]              # [T, Hkv]
-    q_tok = jnp.clip(jnp.round(new32 / _safe(s_tok)[..., None]), -127, 127)
-    merged = merged.at[tok_pgslot, offsets].set(q_tok)
-    merged = merged.astype(cache_layer.dtype)
-
-    cache_layer = cache_layer.at[lidx + (pack_pages,)].set(merged)
-    scale_layer = scale_layer.at[lidx + (pack_pages,)].set(s_new)
-    return cache_layer, scale_layer
-
-
 def write_decode_tokens(
     cache_layer: jax.Array,       # [num_pages, ps, Hkv, D] or, with
                                   # ``layer``, the stacked group [Lg, P, ps, Hkv, D];

@@ -41,8 +41,6 @@ from jax.sharding import PartitionSpec as P
 from kaito_tpu.engine import attention as attn
 from kaito_tpu.engine import nn
 from kaito_tpu.engine.kv_cache import (KVCache, write_decode_tokens,
-                                       write_packed_prefill_tokens,
-                                       write_packed_prefill_tokens_q,
                                        write_decode_tokens_q,
                                        write_prefill_tokens,
                                        write_prefill_tokens_q)
@@ -1026,7 +1024,7 @@ class TransformerLM:
     def _layer(self, x, p, ck, cv, li, window, moe, mode, *,
                positions, page_tables, lengths, true_lens, active,
                start_pos=None, lora=None, lora_ids=None,
-               ks=None, vs=None, packed=None, pf=None, ssm=None,
+               ks=None, vs=None, pf=None, ssm=None,
                ssm_rows=None, kind: Optional[AttnKind] = None, stats=None,
                expert_layer=None):
         """One transformer block. Returns (x, ck, cv, ks, vs, ssm).
@@ -1128,42 +1126,6 @@ class TransformerLM:
                     causal=True, sliding_window=window,
                     logit_softcap=a.attn_logit_softcap,
                     head_axis=head_axis, q_tile=q_tile)
-        elif mode == "prefill_packed":
-            # Segment-packed prefill: many fresh prompts share this row;
-            # each token carries its own page target (host-computed from
-            # its segment's page table) and attention masks by segment id
-            # (docs/prefill.md).  ``positions`` are within-segment, which
-            # for fresh prompts ARE the absolute positions — so RoPE,
-            # page offsets and the sliding window all line up with the
-            # serial path.
-            seg_ids, tok_pages, pack_pages, tok_pgslot = packed
-            offsets = (positions[0] % ps).astype(jnp.int32)
-            if ks is not None:
-                ck, ks = write_packed_prefill_tokens_q(
-                    ck, ks, k_new, pack_pages, tok_pgslot, offsets, layer=li)
-                cv, vs = write_packed_prefill_tokens_q(
-                    cv, vs, v_new, pack_pages, tok_pgslot, offsets, layer=li)
-            else:
-                ck = write_packed_prefill_tokens(ck, k_new, tok_pages,
-                                                 offsets, layer=li)
-                cv = write_packed_prefill_tokens(cv, v_new, tok_pages,
-                                                 offsets, layer=li)
-            if self.attn_impl == "pallas":
-                from kaito_tpu.engine.ops.flash_prefill import (
-                    flash_prefill_packed)
-
-                win = window if window is not None else jnp.int32(_BIG_WINDOW)
-                out = self._pallas_attention(
-                    partial(flash_prefill_packed, scale=self._scale,
-                            softcap=a.attn_logit_softcap),
-                    (q, k_new, v_new, seg_ids, positions,
-                     jnp.asarray(win, jnp.int32)),
-                    (2, 2, 2, None, None, None), 2)
-            else:
-                out = attn.packed_prefill_attention(
-                    q, k_new, v_new, seg_ids, positions, scale=self._scale,
-                    sliding_window=window,
-                    logit_softcap=a.attn_logit_softcap)
         elif mode == "prefill":
             start = (start_pos if start_pos is not None
                      else jnp.zeros((B,), jnp.int32))
@@ -1477,7 +1439,7 @@ class TransformerLM:
     def _run_layers(self, params, cache: Optional[KVCache], x, mode, *,
                     positions, page_tables, lengths, true_lens, active,
                     remat: bool = False, start_pos=None, adapter_ids=None,
-                    packed=None, ssm_rows=None):
+                    ssm_rows=None):
         if self.kinds is not None:
             return self._run_layers_kinds(
                 params, cache, x, mode, positions=positions,
@@ -1563,7 +1525,7 @@ class TransformerLM:
                     positions=positions, page_tables=page_tables,
                     lengths=lengths, true_lens=true_lens, active=active,
                     start_pos=start_pos, lora=lora_l, lora_ids=adapter_ids,
-                    ks=ks_g, vs=vs_g, packed=packed, pf=pf_l, ssm=ssm_g,
+                    ks=ks_g, vs=vs_g, pf=pf_l, ssm=ssm_g,
                     ssm_rows=ssm_rows, stats=st,
                     expert_layer=at if whole else None)
                 # (a latent layer's seventh element: its counters)
@@ -1819,36 +1781,6 @@ class TransformerLM:
         x = self._norm(x, params, "final_norm")
         last = jnp.take_along_axis(
             x, (true_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        return cache, self._logits(params, last), last
-
-    def prefill_packed(self, params, cache: KVCache, tokens, seg_ids,
-                       positions, tok_pages, last_idx, pack_pages=None,
-                       tok_pgslot=None, adapter_ids=None):
-        """Segment-packed prefill: S fresh prompts concatenated into ONE
-        padded row share a single dispatch (docs/prefill.md).
-
-        tokens/seg_ids/positions: [1, T] — per-token segment id (-1 =
-        pad) and within-segment position; tok_pages: [T] page per token
-        (bf16 KV); pack_pages [n_pg] + tok_pgslot [T] address the int8
-        scale fold; last_idx: [S] packed index of each segment's final
-        token.  All segments must share one adapter (``adapter_ids`` is
-        the usual [B] row vector with B=1).  Returns (cache, last_logits
-        [S, vocab], last_hidden [S, E]).
-        """
-        if self.is_mla or self.has_ssm or self.kinds is not None:
-            raise NotImplementedError(
-                "segment-packed prefill is not implemented for MLA "
-                "attention, for a state-space mixer, nor for layers "
-                "that name their attention kind; the engine batches "
-                "such fresh prompts on the batch axis instead")
-        x = self._embed(params, tokens)
-        x, cache = self._run_layers(
-            params, cache, x, "prefill_packed", positions=positions,
-            page_tables=None, lengths=None, true_lens=None, active=None,
-            adapter_ids=adapter_ids,
-            packed=(seg_ids, tok_pages, pack_pages, tok_pgslot))
-        x = self._norm(x, params, "final_norm")
-        last = x[0, last_idx]                               # [S, E]
         return cache, self._logits(params, last), last
 
     def prefill_cp(self, params, cache: KVCache, tokens, true_lens,

@@ -32,9 +32,6 @@ probabilities are cast to the values' type for the second product.
 Same contract as engine.attention.prefill_attention (GQA, true_len,
 sliding window, softcap, values narrower than keys, a sink bias a
 head); tests compare the two in interpreter mode.
-
-``flash_prefill_packed`` (segment-packed rows) keeps the older walk: a
-grid over query heads, one head's 128 queries against 128 keys a step.
 """
 
 from __future__ import annotations
@@ -48,19 +45,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 
-# Both kernels hold one KV head's whole K and V in VMEM, and the grid
+# The kernel holds one KV head's whole K and V in VMEM, and the grid
 # pipeline double-buffers each: 4 * T * D * itemsize bytes, 1 KiB per
 # token at D=128 in bf16.  A v5e's default scoped-VMEM limit is 16 MiB:
 # 12 MiB of K/V (T=12,288) compiles there and 16 MiB is refused by
-# Mosaic ("Ran out of memory in memory space vmem"), so longer chunks
-# are refused here, by name.
-_KV_VMEM_BUDGET = 12 << 20
-# What flash_prefill_attention may plan for of those 16 MiB, K and V,
-# tiles and float32 temporaries together (_vmem_need); the rest is the
-# compiler's own scratch.
+# Mosaic ("Ran out of memory in memory space vmem").  What the kernel
+# may plan for of those 16 MiB, K and V, tiles and float32 temporaries
+# together (_vmem_need); the rest is the compiler's own scratch.  A
+# chunk that needs more is refused by name (_check_fits_vmem).
 _VMEM_BUDGET = 14 << 20
 # Rows (query heads of a KV head x query positions) and keys a step:
 # rows enough to stream through each K tile the MXU loads, keys enough
@@ -71,15 +64,6 @@ _VMEM_BUDGET = 14 << 20
 # 0.76-0.90 anywhere in 512-1,024 x 128-512 (PERF.md section 6, PR 39).
 _MAX_ROWS = 512
 _MAX_BLOCK_K = 512
-
-
-def _check_kv_fits_vmem(T: int, D: int, dtype, Dv: Optional[int] = None) -> None:
-    need = 2 * T * (D + (D if Dv is None else Dv)) * jnp.dtype(dtype).itemsize
-    if need > _KV_VMEM_BUDGET:
-        raise ValueError(
-            f"flash prefill keeps a head's K and V in VMEM: a {T}-token "
-            f"chunk needs {need >> 20} MiB of the {_KV_VMEM_BUDGET >> 20} "
-            f"MiB budget; prefill it in smaller chunks")
 
 
 def _vmem_need(T: int, D: int, Dv: int, dtype, rows: int, bk: int) -> int:
@@ -208,66 +192,6 @@ def _flash_kernel(
         o_ref[0] = out.reshape(G, Bq, Dv).astype(o_ref.dtype)
 
 
-def _flash_packed_kernel(
-    window_ref,        # [1] SMEM (scalar prefetch)
-    seg_ref,           # [1, T] VMEM int32 segment ids (-1 = pad)
-    pos_ref,           # [1, T] VMEM int32 within-segment positions
-    q_ref,             # [1, 1, Bq, D] VMEM (pre-scaled)
-    k_ref,             # [1, 1, T, D] VMEM
-    v_ref,             # [1, 1, T, D] VMEM
-    o_ref,             # [1, 1, Bq, D] VMEM
-    *,
-    block_k: int,
-    softcap: Optional[float],
-):
-    qi = pl.program_id(2)
-    window = window_ref[0]
-
-    q = q_ref[0, 0]                          # [Bq, D]
-    Bq, D = q.shape
-    q_start = qi * Bq
-    # Segments are contiguous and ordered within the packed row, so no
-    # key past the current q block's end can be a same-segment-earlier
-    # token: the causal block skip survives packing unchanged.
-    num_k_blocks = pl.cdiv(q_start + Bq, block_k)
-
-    seg_q = seg_ref[0, pl.ds(q_start, Bq)].reshape(Bq, 1)
-    pos_q = pos_ref[0, pl.ds(q_start, Bq)].reshape(Bq, 1)
-
-    def body(ki, carry):
-        m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(ki * block_k, block_k), :]   # [Bk, D]
-        v = v_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [Bq, Bk]
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        seg_k = seg_ref[0, pl.ds(ki * block_k, block_k)].reshape(1, block_k)
-        pos_k = pos_ref[0, pl.ds(ki * block_k, block_k)].reshape(1, block_k)
-        # same segment + within-segment causal + sliding window; pads
-        # carry seg -1 and never match a valid query's segment.  Fully
-        # masked leading blocks self-heal: once the first valid entry
-        # lands, alpha = exp(-inf - m_new) zeroes the garbage partials.
-        valid = (seg_k == seg_q) & (seg_q >= 0) & (pos_k <= pos_q) \
-            & (pos_k > pos_q - window)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * alpha + pv
-
-    m0 = jnp.full((Bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Bq, 1), jnp.float32)
-    acc0 = jnp.zeros((Bq, D), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_k_blocks, body, (m0, l0, acc0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
 def _scoped(fn):
     # trace-time marker for the device profiler's bucket classifier
     # (engine/devprof.py): every HLO op emitted here carries
@@ -277,69 +201,6 @@ def _scoped(fn):
         with jax.named_scope("attention"):
             return fn(*args, **kwargs)
     return wrapper
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("scale", "softcap", "block_q", "block_k", "interpret"))
-@_scoped
-def flash_prefill_packed(
-    q: jax.Array,            # [B, T, H, D] segment-packed row(s)
-    k: jax.Array,            # [B, T, Hkv, D]
-    v: jax.Array,
-    seg_ids: jax.Array,      # [B, T] int32 (-1 = pad)
-    positions: jax.Array,    # [B, T] int32 within-segment positions
-    window: jax.Array,       # [] int32 (huge == global)
-    *,
-    scale: float,
-    softcap: Optional[float] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
-) -> jax.Array:
-    """Segment-packed variant of :func:`flash_prefill_attention`: many
-    fresh prompts share one padded row, masked to attend only within
-    their own segment (same contract as
-    engine.attention.packed_prefill_attention)."""
-    B, T, H, D = q.shape
-    Hkv = k.shape[2]
-    G = H // Hkv
-    bq = min(block_q, T)
-    bk = min(block_k, T)
-    if T % bq or T % bk:
-        raise ValueError(f"chunk length {T} must be a multiple of the "
-                         f"block sizes ({bq}, {bk})")
-    _check_kv_fits_vmem(T, D, k.dtype)
-    grid = (B, H, T // bq)
-
-    qt = (q * scale).astype(q.dtype).transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, T), lambda b, h, t, *_: (b, 0)),
-            pl.BlockSpec((1, T), lambda b, h, t, *_: (b, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, t, *_: (b, h, t, 0)),
-            pl.BlockSpec((1, 1, T, D), lambda b, h, t, *_: (b, h // G, 0, 0)),
-            pl.BlockSpec((1, 1, T, D), lambda b, h, t, *_: (b, h // G, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, t, *_: (b, h, t, 0)),
-    )
-    kernel = functools.partial(_flash_packed_kernel, block_k=bk,
-                               softcap=softcap)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(jnp.reshape(window, (1,)), seg_ids.astype(jnp.int32),
-      positions.astype(jnp.int32), qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
 
 
 @functools.partial(

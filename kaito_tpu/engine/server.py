@@ -2303,14 +2303,8 @@ def main(argv=None):
                          "(0 disables; reference contract "
                          "inference_api.py:503-556)")
     ap.add_argument("--max-queue-len", type=int, default=256)
-    ap.add_argument("--prefill-pack", type=int,
-                    default=int(os.environ.get("KAITO_PREFILL_PACK", "0")),
-                    help="max staged sequences packed into one prefill "
-                         "round under the shared token budget "
-                         "(docs/prefill.md); 0 = auto (up to "
-                         "max-num-seqs), 1 = serial round-robin "
-                         "(one-row programs; a turn takes the whole "
-                         "staged prompts its chunk budget holds)")
+    ap.add_argument("--prefill-pack", type=int, default=1,
+                    help=argparse.SUPPRESS)
     ap.add_argument("--qos-config",
                     default=os.environ.get("KAITO_QOS_CONFIG", ""),
                     help="multi-tenant QoS classes as inline JSON or "
@@ -2460,7 +2454,6 @@ def main(argv=None):
             args.kaito_kv_cache_cpu_memory_utilization
             * os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")),
         max_queue_len=args.max_queue_len,
-        prefill_pack=args.prefill_pack,
         qos_config=args.qos_config,
         max_pages=args.max_pages,
         speculative_ngram=args.speculative_ngram,
@@ -2486,6 +2479,9 @@ def main(argv=None):
         cfg = load_config_file(cfg, args.kaito_config_file)
 
     logging.basicConfig(level=logging.INFO)
+    if args.prefill_pack != 1:
+        logger.warning("--prefill-pack %d: prefill packing was removed; "
+                       "the serial scheduler serves", args.prefill_pack)
     # probes/Prometheus must not flap during the minutes-long weight
     # load + compile: serve a loading stub on the real port until the
     # engine exists (reference inference_api.py:265-415)

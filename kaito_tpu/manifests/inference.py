@@ -154,30 +154,6 @@ def parse_structured_output_annotation(text: str) -> Optional[dict]:
     return out
 
 
-def parse_prefill_pack_annotation(text: str) -> Optional[int]:
-    """Parse the ``kaito-tpu.io/prefill-pack`` Workspace annotation
-    (docs/prefill.md).  Empty input returns None — the server keeps its
-    default (auto packing up to max-num-seqs).  Accepts a non-negative
-    integer: 0 = auto, 1 = serial legacy scheduler, N > 1 caps the pack
-    size.  Raises ValueError on anything else; the workspace controller
-    calls this at plan time so a bad annotation becomes a PlanFailed
-    condition instead of a crash-looping pod.  jax-free on purpose:
-    the controller imports it."""
-    text = (text or "").strip()
-    if not text:
-        return None
-    try:
-        pack = int(text)
-    except ValueError:
-        raise ValueError(
-            f"prefill-pack annotation must be a non-negative integer, "
-            f"got {text!r}") from None
-    if pack < 0:
-        raise ValueError("prefill-pack annotation must be >= 0 "
-                         "(0 = auto, 1 = serial scheduler)")
-    return pack
-
-
 def parse_devprof_annotation(text: str) -> Optional[float]:
     """Parse the ``kaito-tpu.io/devprof`` Workspace annotation
     (docs/observability.md): the device-profiler sampling interval in
@@ -382,13 +358,6 @@ def build_engine_command(
     qos = ws.metadata.annotations.get("kaito-tpu.io/qos", "")
     if qos:
         args += ["--qos-config", qos]
-    # packed prefill (docs/prefill.md): auto is the server default, so
-    # only an explicit annotation renders — absent keeps the pod
-    # command byte-identical
-    pack = parse_prefill_pack_annotation(
-        ws.metadata.annotations.get("kaito-tpu.io/prefill-pack", ""))
-    if pack is not None:
-        args += ["--prefill-pack", str(pack)]
     # cluster KV pool (docs/kv-pool.md): opt-in per workspace; the
     # controller mirrors the same annotation onto the EPP deployment so
     # holder adverts and fetch hints switch on together

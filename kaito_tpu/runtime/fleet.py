@@ -125,12 +125,12 @@ _ENGINE_COUNTERS = {
     "kaito:kv_tier_hits_total": "kv_tier_hits_total",
     "kaito:kv_tier_spills_total": "kv_tier_spills_total",
     "kaito:kv_tier_evictions_total": "kv_tier_evictions_total",
-    # packed prefill (docs/prefill.md): histogram _sum/_count fold into
+    # prefill turns (docs/prefill.md): histogram _sum/_count fold into
     # plain counters (a fleet-level histogram merge would need every
-    # bucket edge; mean pack size + dispatch rate answer the capacity
+    # bucket edge; mean prompts a turn + turn rate answer the capacity
     # question), plus the prompt-token counter for tokens/s
     "kaito:prompt_tokens_total": "prompt_tokens_total",
-    "kaito:engine_prefill_pack_size_sum": "prefill_packed_seqs_total",
+    "kaito:engine_prefill_pack_size_sum": "prefill_turn_prompts_total",
     "kaito:engine_prefill_pack_size_count": "prefill_dispatches_total",
     "kaito:prefill_queue_wait_seconds_sum": "prefill_wait_seconds_total",
     "kaito:prefill_queue_wait_seconds_count": "prefill_waits_total",
@@ -683,7 +683,7 @@ class FleetTelemetry:
                 "adapter_loads_total", "adapter_evictions_total",
                 "adapter_hits_total",
                 "grammar_hits_total", "grammar_misses_total",
-                "prompt_tokens_total", "prefill_packed_seqs_total",
+                "prompt_tokens_total", "prefill_turn_prompts_total",
                 "prefill_dispatches_total", "prefill_wait_seconds_total",
                 "prefill_waits_total",
                 "forwarded_total", "received_total"]
@@ -876,14 +876,13 @@ class FleetTelemetry:
             "grammar_cache_hit_rate": (
                 gr_hit / (gr_hit + gr_miss)
                 if gr_hit + gr_miss > 0 else 0.0),
-            # packed prefill (docs/prefill.md): prompt tokens/s,
-            # prefill dispatches/s, mean sequences per dispatch (the
-            # packing win — 1.0 means serial), and mean staged->first-
-            # dispatch queue wait (the TTFT component packing attacks)
+            # prefill turns (docs/prefill.md): prompt tokens/s,
+            # turns/s, mean prompts a turn (1.0: arrivals never taken
+            # together), and mean staged->first-dispatch queue wait
             "prefill_tokens_rate": rate("prompt_tokens_rate"),
             "prefill_dispatch_rate": rate("prefill_dispatches_rate"),
             "prefill_pack_mean": (
-                rate("prefill_packed_seqs_rate")
+                rate("prefill_turn_prompts_rate")
                 / rate("prefill_dispatches_rate")
                 if rate("prefill_dispatches_rate") > 0 else 0.0),
             "prefill_queue_wait_mean": (
@@ -1197,12 +1196,11 @@ class FleetTelemetry:
               "Fleet prompt-token prefill rate", r,
               labels=("kind", "name"), fn=family("prefill_tokens_rate"))
         Gauge("kaito:fleet_prefill_dispatches_per_s",
-              "Fleet prefill dispatch rate (a packed round, or a turn of "
-              "the serial scheduler, counts once)", r,
+              "Fleet prefill turn rate (a turn counts once, however "
+              "many prompts it takes)", r,
               labels=("kind", "name"), fn=family("prefill_dispatch_rate"))
         Gauge("kaito:fleet_prefill_pack_mean",
-              "Mean sequences per packed prefill dispatch, or per turn "
-              "of the serial scheduler, across the fleet (1.0 = "
+              "Mean prompts a prefill turn across the fleet (1.0 = "
               "arrivals never taken together)", r,
               labels=("kind", "name"), fn=family("prefill_pack_mean"))
         Gauge("kaito:fleet_prefill_queue_wait_mean",

@@ -24,7 +24,7 @@ from kaito_tpu.engine.engine import InferenceEngine, SamplingParams
 BASE = dict(model="tiny-llama-test", max_model_len=256, page_size=16,
             max_num_seqs=4, dtype="float32", kv_dtype="float32",
             prefill_buckets=(32, 64, 128), decode_run_ahead=4,
-            fused_under_load=4, prefill_pack=1)
+            fused_under_load=4)
 N_REQUESTS = 14
 STOPPED = 5                    # the request that gets a stop id
 SAMPLED = (1, 2)               # temperature 0.8, top-k 40, seeded
@@ -642,21 +642,24 @@ def test_the_first_token_families_exist_where_the_loop_runs(sustained):
     assert "engine_first_token" not in off
 
 
-def test_the_packed_path_defers_every_row_of_a_round():
-    """Without the prefill-pack 1 annotation several prompts complete
-    in one round: one program over the gathered rows, one readback,
-    every row deferred."""
+def test_every_prompt_of_a_multi_prompt_turn_is_deferred():
+    """Four staged prompts go in one turn: a first-token program a
+    prompt, queued behind its prefill, none read back inside the turn,
+    every one deferred."""
     prompts = [[3 + i, 5 + i, 7 + i, 9 + i] for i in range(4)]
     out = []
     for async_on in (False, True):
-        eng = _mk(async_on, prefill_pack=0)
+        eng = _mk(async_on)
         reqs = [eng.submit(p, _greedy(12 + 3 * i, logprobs=True))
                 for i, p in enumerate(prompts)]
         if async_on:
             eng.step()
-            (staged, tok, _lp, _t0), = eng._first_pending
-            assert len(staged) == 4 and np.asarray(tok).shape == (4,)
-            assert all(eng.active[i] for i, _, _ in staged)
+            assert (eng.prefill_pack_hist._total,
+                    eng.prefill_pack_hist._sum) == (1, 4.0)
+            staged = [i for rows, *_ in eng._first_pending
+                      for i, _, _ in rows]
+            assert sorted(staged) == [0, 1, 2, 3]
+            assert all(eng.active[i] for i in staged)
         _run(eng, reqs)
         out.append((eng, reqs))
     (_, ref), (eng, reqs) = out
@@ -665,4 +668,4 @@ def test_the_packed_path_defers_every_row_of_a_round():
         assert a.output_logprobs == b.output_logprobs
     assert eng.counters["first_tokens_deferred_total"] == 4
     assert not any(eng.first_token_blocking.values())
-    assert eng.first_token_resolve_hist._total == 1
+    assert eng.first_token_resolve_hist._total == 4

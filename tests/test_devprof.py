@@ -75,7 +75,7 @@ def test_classify_fusion_names_embedding_collectives():
 
 def test_phase_of():
     assert phase_of("jit(step)/kaito/decode/dot_general") == "decode"
-    assert phase_of("a/kaito/prefill_packed/b") == "prefill_packed"
+    assert phase_of("a/kaito/prefill/b") == "prefill"
     assert phase_of("kaito/kv_import") == "kv_import"
     assert phase_of("jit(step)/decode/dot") is None      # no kaito/ scope
     assert phase_of("kaito/unknown_phase") is None

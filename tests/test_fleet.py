@@ -144,12 +144,12 @@ kaito:prefill_queue_wait_seconds_count 8
 
 
 def test_prefill_pack_series_parse_rate_and_aggregate():
-    """Packed-prefill telemetry (docs/prefill.md): the histogram's
+    """Prefill-turn telemetry (docs/prefill.md): the histogram's
     _sum/_count fold as counters, rate like any other, and aggregate
     into the fleet pack-mean / queue-wait-mean gauge fields."""
     vals = parse_replica_metrics(PREFILL_PAYLOAD)
     assert vals["prompt_tokens_total"] == 4096.0
-    assert vals["prefill_packed_seqs_total"] == 30.0
+    assert vals["prefill_turn_prompts_total"] == 30.0
     assert vals["prefill_dispatches_total"] == 10.0
     assert vals["prefill_wait_seconds_total"] == pytest.approx(0.4)
     assert vals["prefill_waits_total"] == 8.0
@@ -159,7 +159,7 @@ def test_prefill_pack_series_parse_rate_and_aggregate():
     clock = Clock()
     ft = FleetTelemetry(Store(), time_fn=clock)
     prev = ReplicaSample(ts=clock() - 10.0,
-                         values={"prefill_packed_seqs_total": 0.0,
+                         values={"prefill_turn_prompts_total": 0.0,
                                  "prefill_dispatches_total": 0.0,
                                  "prefill_wait_seconds_total": 0.0,
                                  "prefill_waits_total": 0.0,
@@ -167,7 +167,7 @@ def test_prefill_pack_series_parse_rate_and_aggregate():
                                  "uptime_s": 50.0})
     rates = ft._rates(prev, vals, clock())
     assert rates["prompt_tokens_rate"] == pytest.approx(409.6)
-    assert rates["prefill_packed_seqs_rate"] == pytest.approx(3.0)
+    assert rates["prefill_turn_prompts_rate"] == pytest.approx(3.0)
     assert rates["prefill_dispatches_rate"] == pytest.approx(1.0)
 
     key = ("InferenceSet", "default", "pack")

@@ -63,7 +63,7 @@ def _mk(async_on=False, md=MD, **kw):
     base = dict(model=md.name, max_model_len=256, page_size=PAGE,
                 max_num_seqs=4, dtype="float32", kv_dtype="float32",
                 prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
-                prefill_pack=1, decode_run_ahead=4, async_dispatch=async_on,
+                decode_run_ahead=4, async_dispatch=async_on,
                 seed=5)
     base.update(kw)
     return InferenceEngine(EngineConfig(**base), metadata=md)
@@ -347,7 +347,6 @@ def test_kernel_read_pool_serves_the_same_logits():
     (dict(kv_pool_enabled=True), "cluster KV pool"),
     (dict(speculative_ngram=3), "n-gram speculation"),
     (dict(speculative_draft="tiny-llama-test"), "draft-model speculation"),
-    (dict(prefill_pack=0), "packed prefill"),
     (dict(adapter_slots=2), "adapter cache"),
 ])
 def test_refuses_by_name_what_a_latent_share_cannot_serve(kw, word):

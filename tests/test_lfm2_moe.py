@@ -61,7 +61,7 @@ def _mk(async_on=False, **kw):
     base = dict(model="tiny-lfm2-test", max_model_len=256, page_size=PAGE,
                 max_num_seqs=4, dtype="float32", kv_dtype="float32",
                 prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
-                prefill_pack=1, decode_run_ahead=4, async_dispatch=async_on,
+                decode_run_ahead=4, async_dispatch=async_on,
                 seed=5)
     base.update(kw)
     return InferenceEngine(EngineConfig(**base), metadata=MD)
@@ -425,7 +425,6 @@ def test_a_model_with_no_conv_layer_has_no_such_family():
     (dict(kv_pool_enabled=True), "the cluster KV pool"),
     (dict(speculative_ngram=3), "n-gram speculation"),
     (dict(speculative_draft="tiny-llama-test"), "draft-model speculation"),
-    (dict(prefill_pack=4), "packed prefill"),
     (dict(kv_dtype="int8"), "int8 KV cache"),
 ])
 def test_refusals_at_start_by_name(kw, names):
@@ -439,9 +438,6 @@ def test_a_mesh_and_imported_pages_are_refused_by_name():
     with pytest.raises(ValueError, match="imported KV pages carry none"):
         eng.submit_with_kv(_prompt(20, 1), 3, {}, b"",
                            SamplingParams(max_tokens=2))
-    with pytest.raises(NotImplementedError, match="segment-packed prefill"):
-        eng.model.prefill_packed(eng.params, eng.cache, None, None, None,
-                                 None, None)
     with pytest.raises(ValueError, match="state pool"):
         eng.model.prefill(eng.params, eng.cache, jnp.zeros((1, 32), jnp.int32),
                           jnp.asarray([3]), jnp.zeros((1, 16), jnp.int32))

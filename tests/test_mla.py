@@ -358,7 +358,7 @@ def test_health_names_the_form_of_the_latent_weights(kw, form):
     eng = InferenceEngine(EngineConfig(**{**dict(
         model=md.name, max_model_len=256, page_size=PS, max_num_seqs=2,
         dtype="float32", kv_dtype="float32", prefill_buckets=(32, 64),
-        max_prefill_tokens=64, prefill_pack=1, seed=5), **kw}), metadata=md)
+        max_prefill_tokens=64, seed=5), **kw}), metadata=md)
     assert eng.latent_weights == form
     assert ("kv_b_k_hm" in eng.params["moe"]) == (form == "head_major")
     # what the pool is sized after: every resident leaf, the pair too

@@ -6,6 +6,11 @@ matters, mirroring how the reference's vLLM scheduler is unit-tested at
 the step level rather than by wall-clock.
 """
 
+import dataclasses
+import functools
+import importlib
+import json
+
 import numpy as np
 import pytest
 
@@ -121,13 +126,12 @@ def test_preemption_with_prefix_cache_reuses_committed_pages():
 
 
 # ---------------------------------------------------------------------------
-# the serial scheduler's prefill turn (prefill_pack=1, docs/prefill.md):
-# whole staged prompts while they fit the turn's budget, each through
-# the one-row programs
+# the prefill turn (docs/prefill.md): whole staged prompts while they
+# fit the turn's budget, each through the one-row programs
 # ---------------------------------------------------------------------------
 
 def _serial(async_on=False, **kw):
-    cfg = dict(BASE, prefill_pack=1, async_dispatch=async_on,
+    cfg = dict(BASE, async_dispatch=async_on,
                decode_run_ahead=4, fused_under_load=4)
     cfg.update(kw)
     return InferenceEngine(EngineConfig(**cfg))
@@ -300,3 +304,151 @@ def test_the_turn_counters_are_exposed():
     assert "kaito:engine_prefill_turns_multi_total 1" in text
     assert "kaito:engine_prefill_turns_single_total 0" in text
     assert "kaito:engine_prefill_pack_size_sum 2" in text
+
+
+# ---------------------------------------------------------------------------
+# what sharing a turn must not change: every prompt decodes what it
+# decodes when it is served alone, whatever shares its turn
+# ---------------------------------------------------------------------------
+
+# two short prompts, one past the 32 bucket, one just past the 64
+MIXED = [_tokens(n, m) for n, m in ((9, 3), (21, 5), (34, 7), (65, 11))]
+
+
+def _together(eng, prompts, n=8):
+    return _finish(eng, [eng.submit(list(p), _greedy(n)) for p in prompts])
+
+
+@functools.lru_cache(maxsize=None)
+def _mixed_alone(async_on):
+    """What each of MIXED decodes on an engine that serves nothing
+    else: one after the other, no prefix cache."""
+    eng = _serial(async_on)
+    return [_together(eng, [p])[0] for p in MIXED]
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_mixed_lengths_in_one_turn_decode_what_each_decodes_alone(async_on):
+    """Prompts of 9, 21, 34 and 65 tokens, three buckets, one turn:
+    four one-row calls, the prompts' own tokens and no more, and the
+    greedy streams of each served alone."""
+    eng = _serial(async_on)
+    assert _together(eng, MIXED) == _mixed_alone(async_on)
+    assert _turns(eng) == (1, 0)
+    assert eng.counters["prefill_steps_total"] == len(MIXED)
+    assert eng.counters["prefill_tokens_total"] == sum(map(len, MIXED))
+    h = eng.prefill_pack_hist
+    assert (h._total, h._sum) == (1, float(len(MIXED)))
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+@pytest.mark.parametrize("chunk,steps,turns", [(512, 2, (1, 0)),
+                                               (16, 3, (0, 3))])
+def test_a_turn_takes_the_prompts_its_chunk_holds_whole(chunk, steps, turns,
+                                                        async_on):
+    """One one-row dispatch a prompt or chunk, and as many prompts a
+    turn as its chunk budget holds whole (docs/prefill.md): both at
+    512, one at 16, where the second prompt (21 tokens) is chunked.
+    The streams are the same either way."""
+    eng = _serial(async_on, max_prefill_tokens=chunk)
+    assert _together(eng, MIXED[:2]) == _mixed_alone(async_on)[:2]
+    assert eng.counters["prefill_steps_total"] == steps
+    assert _turns(eng) == turns
+    assert eng.prefill_pack_hist._sum == steps
+    assert eng.prefill_pack_hist._total == sum(turns)
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_an_abort_between_two_prompts_of_a_turn_leaves_the_rest(async_on):
+    """The second of three prompts of one turn is aborted after the
+    first is prefilled and before its own call: the turn goes on, the
+    aborted request retires at its first emit, and the other two
+    decode what they decode alone."""
+    eng = _serial(async_on)
+    reqs = [eng.submit(list(p), _greedy(8)) for p in MIXED[:3]]
+    chunk = eng._prefill_serial_chunk
+
+    def abort_after_the_first(i, turn):
+        ok = chunk(i, turn)
+        if i == 0:
+            eng.abort(reqs[1])
+        return ok
+
+    eng._prefill_serial_chunk = abort_after_the_first
+    eng.step()
+    assert _chunks(eng) == [(i, 0, len(p), 3)
+                            for i, p in enumerate(MIXED[:3])]
+    out = _finish(eng, reqs)
+    alone = _mixed_alone(async_on)
+    assert [out[0], out[2]] == [alone[0], alone[2]]
+    assert reqs[1].finish_reason is not None
+    assert len(out[1]) < 8 and out[1] == alone[1][:len(out[1])]
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_admission_by_priority_decides_who_a_turn_serves_first(async_on):
+    """A turn serves the staged slots round-robin; the order of
+    admission into the slots is the QoS classes' (engine/qos.py).  At
+    a budget of one prompt a turn the guaranteed tenant's prompt is
+    prefilled first even when it was submitted last."""
+    qos = json.dumps({
+        "classes": {"guaranteed": {"priority": 100, "weight": 8},
+                    "best-effort": {"priority": 0, "weight": 1}},
+        "tenants": {"acme": "guaranteed"},
+        "default_class": "best-effort",
+    })
+    eng = _serial(async_on, qos_config=qos, max_prefill_tokens=32)
+    be = eng.submit(_tokens(30, 3), _greedy(4), tenant="free")
+    gt = eng.submit(_tokens(30, 5), _greedy(4), tenant="acme")
+    _finish(eng, [be, gt])
+    assert [c[3] for c in _chunks(eng)] == [1, 1]
+    assert gt.first_token_time <= be.first_token_time
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_the_turn_histograms_round_trip_through_the_exposition(async_on):
+    eng = _serial(async_on)
+    _together(eng, MIXED[:3], n=4)
+    for hist, name in ((eng.prefill_pack_hist,
+                        "kaito:engine_prefill_pack_size"),
+                       (eng.prefill_wait_hist,
+                        "kaito:prefill_queue_wait_seconds")):
+        lines = list(hist.collect())
+        assert f"# TYPE {name} histogram" in lines
+        count = sum_ = None
+        for ln in lines:
+            if ln.startswith(f"{name}_count"):
+                count = float(ln.split()[-1])
+            elif ln.startswith(f"{name}_sum"):
+                sum_ = float(ln.split()[-1])
+        assert count is not None and count > 0
+        assert sum_ is not None and sum_ >= 0.0
+    assert "# HELP kaito:engine_prefill_pack_size Prompts a prefill turn" \
+        in list(eng.prefill_pack_hist.collect())
+    # the step timeline names the turn that took several prompts
+    packs = [e for e in eng.timeline.records() if e.get("prefill_pack")]
+    assert packs and max(e["prefill_pack"] for e in packs) == 3
+
+
+@pytest.mark.parametrize("module", [
+    "test_latent_engine",       # a latent stream, a share of the experts
+    "test_two_kind_engine",     # a page pool a kind of attention layer
+    "test_lfm2_moe",            # a row of conv state a slot
+    "test_ssm_engine",          # a state-space mixer beside attention
+])
+def test_every_kind_of_model_starts_with_no_prefill_setting(module):
+    """There is one prefill scheduler and no setting that selects it:
+    the tiny engine of every kind of layer starts from a configuration
+    that names none, and a turn takes two staged prompts."""
+    assert not [f.name for f in dataclasses.fields(EngineConfig)
+                if "pack" in f.name]
+    md = importlib.import_module(f"tests.{module}").MD
+    eng = InferenceEngine(EngineConfig(
+        model=md.name, max_model_len=256, page_size=16, max_num_seqs=4,
+        dtype="float32", kv_dtype="float32", prefill_buckets=(32, 64, 128),
+        seed=5), metadata=md)
+    for m in (3, 5):
+        eng.submit([(m * i) % 500 + 2 for i in range(12)], _greedy(3))
+    eng.step()
+    assert _turns(eng) == (1, 0)
+    assert [c[1:] for c in _chunks(eng)] == [(0, 12, 2), (0, 12, 2)]

@@ -159,6 +159,66 @@ def test_config_file_merge(tmp_path):
     assert cfg.served_model_name == "foo"
 
 
+def _config_built(argv, monkeypatch):
+    """The EngineConfig ``main`` hands the engine it starts."""
+    from kaito_tpu.engine import server
+    from kaito_tpu.utils import platform
+
+    class Built(Exception):
+        pass
+
+    def engine(cfg):
+        raise Built(cfg)
+
+    monkeypatch.setattr(platform, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(server, "start_loading_stub", lambda host, port: None)
+    monkeypatch.setattr(server, "InferenceEngine", engine)
+    with pytest.raises(Built) as e:
+        server.main(["--model", "tiny-llama-test"] + argv)
+    return e.value.args[0]
+
+
+@pytest.mark.parametrize("argv,warns", [([], False),
+                                        (["--prefill-pack", "1"], False),
+                                        (["--prefill-pack", "0"], True)])
+def test_prefill_pack_is_parsed_and_ignored(argv, warns, monkeypatch, caplog):
+    """The benchmark's configurations still pass the flag (ROADMAP
+    D16): whatever it says, the engine is the one no flag starts, and
+    a value that used to ask for packing is told so once."""
+    with caplog.at_level("WARNING", logger="kaito_tpu.engine.server"):
+        cfg = _config_built(argv, monkeypatch)
+    assert cfg == _config_built([], monkeypatch)
+    assert not hasattr(cfg, "prefill_pack")
+    said = [r for r in caplog.records if "prefill packing was removed"
+            in r.getMessage()]
+    assert len(said) == int(warns)
+
+
+def test_a_prefill_pack_annotation_renders_no_flag():
+    """A Workspace that still carries ``kaito-tpu.io/prefill-pack`` is
+    served as if it did not: the pod's command is that of no
+    annotation."""
+    from kaito_tpu.api import (InferenceSpec, ObjectMeta, ResourceSpec,
+                               Workspace)
+    from kaito_tpu.manifests.inference import build_engine_command
+    from kaito_tpu.models.registry import get_model_by_name
+    from kaito_tpu.parallel.plan import plan_parallelism
+    from kaito_tpu.sku.catalog import CHIP_CATALOG
+
+    md = get_model_by_name("llama-3.1-8b-instruct")
+    plan = plan_parallelism(md, CHIP_CATALOG["v5e"], workload="serve",
+                            max_model_len=2048)
+    ws = Workspace(
+        ObjectMeta(name="packed",
+                   annotations={"kaito-tpu.io/prefill-pack": "4"}),
+        resource=ResourceSpec(instance_type="ct5lp-hightpu-4t"),
+        inference=InferenceSpec(preset="llama-3.1-8b-instruct"))
+    cmd = build_engine_command(ws, md, plan)
+    assert "--prefill-pack" not in cmd
+    ws.metadata.annotations = {}
+    assert cmd == build_engine_command(ws, md, plan)
+
+
 def test_adapter_discovery(tmp_path):
     from kaito_tpu.engine.server import discover_adapters
 

@@ -190,12 +190,13 @@ def test_preempted_sequence_resumes_to_the_same_logits():
     assert eng.counters["state_recomputes_total"] == 1
 
 
-def test_batched_fresh_prompts_under_the_default_packing():
-    """The default prefill packing batches such prompts on the batch
-    axis (no segment-packed scan): two at once equal each alone."""
-    eng = _mk(prefill_pack=4, max_prefill_tokens=128)
+def test_two_prompts_of_one_turn_equal_each_alone():
+    """Two fresh prompts prefilled in one turn, each on its own row of
+    the state pool, decode what each decodes alone."""
+    eng = _mk(max_prefill_tokens=128)
     prompts = [_prompt(20, 7), _prompt(27, 8)]
     both = _run(eng, prompts, 8)
+    assert eng.counters["prefill_turns_multi_total"] == 1
     serial = _mk()
     for p, r in zip(prompts, both):
         assert r.output_tokens == _run(serial, [p], 8)[0].output_tokens
@@ -203,13 +204,13 @@ def test_batched_fresh_prompts_under_the_default_packing():
 
 @pytest.mark.parametrize("async_on", [False, True])
 def test_a_serial_turn_prefills_every_staged_prompt_that_fits(async_on):
-    """The serial scheduler's turn (prefill_pack=1, docs/prefill.md)
-    with a mixer: three staged fresh prompts within one chunk of 128
-    are prefilled by one step(), each a one-row call on its own row of
-    the state pool; every emitted logprob is the full forward's, and
-    the tokens are those of turns held to one prompt."""
+    """A prefill turn (docs/prefill.md) with a mixer: three staged
+    fresh prompts within one chunk of 128 are prefilled by one step(),
+    each a one-row call on its own row of the state pool; every emitted
+    logprob is the full forward's, and the tokens are those of turns
+    held to one prompt."""
     prompts = [_prompt(20, 31), _prompt(27, 32), _prompt(33, 33)]
-    eng = _mk(async_on, prefill_pack=1, max_prefill_tokens=128)
+    eng = _mk(async_on, max_prefill_tokens=128)
     reqs = [eng.submit(list(p), SamplingParams(
         max_tokens=10, temperature=0.0, ignore_eos=True, logprobs=1))
         for p in prompts]
@@ -224,7 +225,7 @@ def test_a_serial_turn_prefills_every_staged_prompt_that_fits(async_on):
         if all(r.finish_reason for r in reqs):
             break
         eng.step()
-    one = _mk(async_on, prefill_pack=1, max_prefill_tokens=128)
+    one = _mk(async_on, max_prefill_tokens=128)
     one._prefill_turn_budget = lambda: 0     # the first pick, no more
     ref = _run(one, prompts, 10)
     assert one.counters["prefill_turns_multi_total"] == 0
@@ -242,7 +243,7 @@ def test_a_serial_turn_never_splits_a_prompt_and_chunks_go_alone(async_on):
     prompts of 12 and 14 wait whole and share the turn between its
     first chunk and its second."""
     prompts = [_prompt(90, 41), _prompt(12, 42), _prompt(14, 43)]
-    eng = _mk(async_on, prefill_pack=1)
+    eng = _mk(async_on)
     reqs = _run(eng, prompts, 8)
     chunks = [(s.attrs["slot"], s.attrs["pos"], s.attrs["tokens"],
                s.attrs["pack"]) for s in eng.tracer.spans()
@@ -360,13 +361,6 @@ def test_kv_import_refused_at_the_request():
     with pytest.raises(ValueError, match="imported KV pages carry none"):
         eng.submit_with_kv_prefix(_prompt(20, 1), {}, [], 16,
                                   SamplingParams(max_tokens=2))
-
-
-def test_packed_prefill_refuses_by_name():
-    eng = _mk()
-    with pytest.raises(NotImplementedError, match="state-space mixer"):
-        eng.model.prefill_packed(eng.params, eng.cache, None, None, None,
-                                 None, None)
 
 
 def test_estimator_counts_a_sequences_state_row():

@@ -61,7 +61,7 @@ def _mk(async_on=False, **kw):
     base = dict(model="tiny-mimo-v2-test", max_model_len=256, page_size=PAGE,
                 max_num_seqs=4, dtype="float32", kv_dtype="float32",
                 prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
-                prefill_pack=1, decode_run_ahead=4, async_dispatch=async_on,
+                decode_run_ahead=4, async_dispatch=async_on,
                 seed=5)
     base.update(kw)
     return InferenceEngine(EngineConfig(**base), metadata=MD)
@@ -251,7 +251,6 @@ def test_preemption_returns_both_tables_pages():
     (dict(kv_pool_enabled=True), "cluster KV pool"),
     (dict(speculative_ngram=3), "n-gram speculation"),
     (dict(speculative_draft="tiny-llama-test"), "draft-model speculation"),
-    (dict(prefill_pack=0), "packed prefill"),
     (dict(kv_dtype="int8"), "int8 KV"),
 ])
 def test_refuses_by_name_what_two_kinds_of_page_cannot_serve(kw, word):
