@@ -45,6 +45,7 @@ from kaito_tpu.engine.devprof import phase_scope
 from kaito_tpu.engine.grammar import GrammarCache, GrammarSlot, GrammarTable
 from kaito_tpu.engine.kv_cache import (KVCache, create_kv_cache,
                                        create_conv_state_pool,
+                                       create_delta_state_pool,
                                       create_state_pool,
                                        kv_cache_is_quantized,
                                        scale_bytes_per_page)
@@ -629,11 +630,13 @@ class InferenceEngine:
                 self.cache.state_pool_bytes
             logger.info("state pool: %d slots x %d layers, %s "
                         "(%.2f GiB)", cfg.max_num_seqs,
-                        arch.conv_layers or arch.num_layers,
+                        arch.conv_layers or arch.gdn_layers
+                        or arch.num_layers,
                         self.dtype.name, self.cache.state_pool_bytes / 2**30)
-        if self.model.has_conv:
-            # pages for the attention layers alone, a row of conv state
-            # for the rest (docs/kv-cache.md, "A row of conv state")
+        if self.model.has_conv or self.model.has_gdn:
+            # pages for the attention layers alone, a row of state for
+            # the rest (docs/kv-cache.md, "A row of conv state", "A row
+            # of matrix state")
             self.sizing_report["kv_bytes_per_token"] = \
                 self.md.kv_bytes_per_token(jnp.dtype(cfg.kv_dtype).itemsize)
             self.sizing_report["state_bytes_per_row"] = \
@@ -1263,7 +1266,7 @@ class InferenceEngine:
             raise ValueError(
                 f"{self.md.name} keeps a per-slot recurrent state beside "
                 f"its KV pages and is served on one device: no mesh")
-        if self.model.has_conv:
+        if self.model.has_conv or self.model.has_gdn:
             if jnp.dtype(self.cfg.kv_dtype) == jnp.int8:
                 raise ValueError(
                     f"{self.md.name} keeps a per-slot recurrent state "
@@ -1383,13 +1386,17 @@ class InferenceEngine:
         it: ``pallas`` where a kernel does (a latent pool: the kernel-
         read layout), ``jax`` where XLA gathers the pages; ``+conv``
         behind either where the model's other layers are short
-        convolutions."""
+        convolutions, ``+delta`` where they are gated delta rules."""
         if self.model.is_mla:
             return "pallas" if self.latent_kernel else "jax"
         if self.model.has_conv:
             # most layers mix their tokens by a short convolution (XLA)
             # and read a row of conv state, not pages
             return self.model.attn_impl + "+conv"
+        if self.model.has_gdn:
+            # most layers keep a matrix a head (the Pallas state update
+            # where attention's kernel is) and read no page
+            return self.model.attn_impl + "+delta"
         return self.model.attn_impl
 
     @property
@@ -1426,9 +1433,14 @@ class InferenceEngine:
 
     def _make_state_pool(self) -> dict:
         """The zeroed state pool as the cache's fields: a state-space
-        mixer's state and convolution tail, or the short-convolution
-        layers' rows of conv state; {} for a model with neither."""
+        mixer's state and convolution tail, the short-convolution
+        layers' rows of conv state, or the delta-rule layers' rows of
+        matrix state and their convolutions' tails; {} for a model with
+        none of them."""
         arch, slots = self.md.arch, self.cfg.max_num_seqs
+        if self.model.has_gdn:
+            state, tail = create_delta_state_pool(arch, slots, self.dtype)
+            return {"delta_state": state, "conv_state": tail}
         if self.model.has_conv:
             return {"conv_state": create_conv_state_pool(arch, slots,
                                                          self.dtype)}

@@ -78,6 +78,15 @@ class KVCache:
     # layers have no page, and the page pools hold the attention layers
     # alone.  None for every other model.
     conv_state: Optional[jax.Array] = None
+    # A row of matrix state (docs/kv-cache.md): a model some of whose
+    # layers mix tokens by a gated delta rule (olmo_hybrid) keeps for
+    # those layers, per decode slot, a key x value matrix a head,
+    # [delta layers, slots, key dim, heads * value dim] (keys on
+    # sublanes, the heads' values side by side on lanes: whole lane
+    # tiles with no padding), and in ``conv_state`` the last inputs of
+    # the convolutions in front of it, [delta layers, slots, taps - 1,
+    # channels]; those layers have no page.  None for every other model.
+    delta_state: Optional[jax.Array] = None
     # Two kinds of page (docs/kv-cache.md): a model whose window layers
     # have a geometry of their own (mimo_v2) keeps those layers' keys
     # and values in a second pair of pools, addressed through a second
@@ -119,11 +128,9 @@ class KVCache:
     @property
     def state_pool_bytes(self) -> int:
         """Bytes of the per-slot state pool (0: a model with none)."""
-        if self.conv_state is not None:
-            return int(self.conv_state.nbytes)
-        if self.ssm_state is None:
-            return 0
-        return int(self.ssm_state.nbytes + self.ssm_conv.nbytes)
+        return sum(int(pool.nbytes) for pool in (
+            self.ssm_state, self.ssm_conv, self.conv_state,
+            self.delta_state) if pool is not None)
 
 
 def kv_cache_is_quantized(dtype) -> bool:
@@ -156,6 +163,18 @@ def create_conv_state_pool(arch: ModelArch, slots: int, dtype: jnp.dtype):
         return None
     return jnp.zeros((arch.conv_layers, slots, arch.conv_kernel - 1,
                       arch.hidden_size), dtype)
+
+
+def create_delta_state_pool(arch: ModelArch, slots: int, dtype: jnp.dtype):
+    """Zeroed (matrix state, convolution tail) pools of a model with
+    delta-rule layers for ``slots`` decode slots, in the model's type
+    (the step programs compute in float32 and round once, where a state
+    is written back)."""
+    L = arch.gdn_layers
+    return (jnp.zeros((L, slots, arch.gdn_key_dim,
+                       arch.gdn_heads * arch.gdn_value_dim), dtype),
+            jnp.zeros((L, slots, arch.gdn_conv - 1, arch.gdn_conv_dim),
+                      dtype))
 
 
 def create_kv_cache(

@@ -17,7 +17,11 @@ attention layers with their own head counts, dense and expert FFNs)
 differ in parameter SHAPES, so they are stacked by kind and run as a
 schedule of scans over those stacks (``_layer_schedule``,
 ``_run_layers_kinds``); each attention kind has a page pool and a page
-table of its own (docs/kv-cache.md).
+table of its own (docs/kv-cache.md).  A layer whose mixer is no
+attention has no page and keeps a row of the state pool a slot: a gated
+short convolution its last inputs (lfm2: ``_conv_layer``), a gated
+delta rule a matrix a head and its convolutions' last inputs
+(olmo_hybrid: ``_gdn_layer``, engine/ops/gdn.py).
 
 This replaces the model zoo the reference gets for free from vLLM
 (SURVEY.md §2.2, §7 step 3); parameters are plain pytrees whose logical
@@ -44,8 +48,9 @@ from kaito_tpu.engine.kv_cache import (KVCache, write_decode_tokens,
                                        write_decode_tokens_q,
                                        write_prefill_tokens,
                                        write_prefill_tokens_q)
-from kaito_tpu.models.metadata import (MIXER_CONV, AttentionKind, ModelArch,
-                                       heads_per_lane_row, stored_key_dim)
+from kaito_tpu.models.metadata import (MIXER_CONV, MIXER_GDN, AttentionKind,
+                                       ModelArch, heads_per_lane_row,
+                                       stored_key_dim)
 
 VOCAB_ALIGN = 128
 _BIG_WINDOW = 1 << 30
@@ -62,11 +67,11 @@ def _name_salt(name: str) -> int:
 
 @dataclass(frozen=True)
 class LayerGroup:
-    name: str          # "dense" | "moe"; "<full|window|conv>_<dense|moe>" by kind
+    name: str          # "dense" | "moe"; "<full|window|conv|gdn>_<dense|moe>" by kind
     start: int
     count: int
     moe: bool
-    kind: int = 0      # the stack's mixer (0 full, 1 window, 2 short conv)
+    kind: int = 0      # the stack's mixer (metadata.MIXER_*)
 
 
 @dataclass(frozen=True)
@@ -98,8 +103,8 @@ class AttnKind:
 class LayerRun:
     """Consecutive layers of one stack: ``count`` layers from
     ``stack_start`` of ``params[stack]``, whose attention kind's page
-    pool (a short-convolution layer's state pool) holds them from
-    ``cache_start``."""
+    pool (a short-convolution or delta-rule layer's state pool) holds
+    them from ``cache_start``."""
     stack: str
     stack_start: int
     count: int
@@ -129,10 +134,10 @@ def _layer_schedule(arch: ModelArch):
     experts = arch.layer_experts or (0,) * arch.num_layers
     members: dict = {}
     runs: list = []
-    seen = [0, 0, 0]
+    seen = [0, 0, 0, 0]
     for kind, moe in zip(arch.layer_attention, experts):
-        name = ("full", "window", "conv")[kind] + ("_moe" if moe
-                                                   else "_dense")
+        name = ("full", "window", "conv", "gdn")[kind] + ("_moe" if moe
+                                                          else "_dense")
         at = len(members.setdefault(name, []))
         members[name].append((kind, bool(moe)))
         last = runs[-1] if runs else None
@@ -222,7 +227,11 @@ class TransformerLM:
         # the state pool's rows, and no page (docs/kv-cache.md, "A row
         # of conv state"); ``has_state``: the cache holds a state pool
         self.has_conv = arch.conv_layers > 0
-        self.has_state = self.has_ssm or self.has_conv
+        # delta-rule layers (olmo_hybrid): a matrix a head and the
+        # convolutions' last inputs a slot, and no page ("A row of
+        # matrix state")
+        self.has_gdn = arch.gdn_layers > 0
+        self.has_state = self.has_ssm or self.has_conv or self.has_gdn
         # Pallas grouped matmul, and the Pallas un-sort of a shared
         # expert layer's prefill (set by the engine)
         self.moe_kernel = False
@@ -238,6 +247,11 @@ class TransformerLM:
             self.kinds = attention_kinds(arch)
             self.groups, self.runs = _layer_schedule(arch)
         else:
+            if arch.norm_after or arch.qk_norm_whole or not arch.rotary:
+                raise NotImplementedError(
+                    "norms after the operators, a QK norm over the whole "
+                    "projection and attention with no rotary embedding "
+                    "are implemented for layers that name their kinds")
             self.groups = _layer_groups(arch)
         self.vocab_padded = -(-arch.vocab_size // VOCAB_ALIGN) * VOCAB_ALIGN
         # rope tables are concrete constants; computing them lazily inside
@@ -248,6 +262,9 @@ class TransformerLM:
             rope_arch = replace(arch, head_dim=arch.mla_dims[1],
                                 partial_rotary_factor=1.0)
             self._inv_freq_global = nn.rope_frequencies(rope_arch)
+        elif not arch.rotary:
+            # no rotary embedding: no table
+            self._inv_freq_global = None
         else:
             self._inv_freq_global = nn.rope_frequencies(arch)
         # attention_factor only reads rope_scaling/max_pos, which the
@@ -256,7 +273,7 @@ class TransformerLM:
         # longrope (phi-3 family): per-position short/long table switch
         self._longrope = None if self.is_mla else nn.longrope_tables(arch)
         self._inv_freq_local = self._make_inv_freq_local()
-        if self.kinds is not None:
+        if self.kinds is not None and arch.rotary:
             from dataclasses import replace
 
             # one table a kind: the window kind has its own theta
@@ -288,15 +305,38 @@ class TransformerLM:
         E, H, Hkv, D, I = (a.hidden_size, a.num_heads, a.num_kv_heads,
                            a.head_dim, a.intermediate_size)
         Dv = D
-        conv = self.kinds is not None and kind == MIXER_CONV
+        gdn = self.kinds is not None and kind == MIXER_GDN
+        # (a short convolution or a delta rule: none of attention's
+        # projections, biases or QK norm)
+        conv = gdn or (self.kinds is not None and kind == MIXER_CONV)
         if self.kinds is not None and not conv:
             ak = self.kinds[kind]
             H, Hkv, D, Dv = (ak.num_heads, ak.num_kv_heads, ak.head_dim,
                              ak.v_head_dim)
-        if conv:
+        if gdn:
+            # a gated delta rule: [q | k | v | out gate] in (whole lane
+            # tiles: with the two gates' 2 x 30 columns beside them the
+            # compiler re-laid the stack out once a program, 762 MiB),
+            # the gates a head [a | b], the taps over [q | k | v]
+            # (``gdn_conv_w[K-1]`` on the newest input), the decay's A
+            # and step bias a head, the gated norm's gain a value lane,
+            # out
+            Hd, C, inner = a.gdn_heads, a.gdn_conv_dim, \
+                a.gdn_heads * a.gdn_value_dim
+            specs: dict[str, tuple[tuple[int, ...], tuple]] = {
+                "attn_norm": ((E,), ("embed",)),
+                "gdn_in": ((E, C + inner), ("embed", None)),
+                "gdn_gates": ((E, 2 * Hd), ("embed", None)),
+                "gdn_conv_w": ((a.gdn_conv, C), (None, None)),
+                "gdn_a_log": ((Hd,), (None,)),
+                "gdn_dt_bias": ((Hd,), (None,)),
+                "gdn_norm": ((a.gdn_value_dim,), (None,)),
+                "gdn_out": ((inner, E), (None, "embed")),
+            }
+        elif conv:
             # a gated short convolution: [B | C | u] in, the taps
             # (``conv_w[k]`` weighs the input k tokens back), out
-            specs: dict[str, tuple[tuple[int, ...], tuple]] = {
+            specs = {
                 "attn_norm": ((E,), ("embed",)),
                 "conv_in": ((E, 3 * E), ("embed", None)),
                 "conv_w": ((a.conv_kernel, E), (None, None)),
@@ -339,8 +379,10 @@ class TransformerLM:
         if a.linear_bias:
             specs["o_bias"] = ((E,), ("embed",))
         if a.qk_norm and not conv:
-            specs["q_norm"] = ((D,), (None,))
-            specs["k_norm"] = ((D,), (None,))
+            # (one gain a head's lane, or one a lane of the projection)
+            whole = a.qk_norm_whole
+            specs["q_norm"] = ((H * D if whole else D,), (None,))
+            specs["k_norm"] = ((Hkv * D if whole else D,), (None,))
         if a.norm_type == "layernorm":
             specs["attn_norm_bias"] = ((E,), ("embed",))
         if not a.parallel_residual:
@@ -420,14 +462,15 @@ class TransformerLM:
             layer: dict = {}
             for name, (shape, _) in self._layer_specs(g.moe, g.kind).items():
                 full = (g.count,) + shape
-                if name in ("sink", "router_bias", "conv_w") or (
+                if name in ("sink", "router_bias", "conv_w", "gdn_conv_w",
+                            "gdn_norm") or (
                         name in ("q_norm", "k_norm")
                         and self.kinds is not None):
                     init = self._kind_draw(
                         name, jax.random.fold_in(keys[1 + gi],
                                                  _name_salt(name)), full)
                 elif name in ("ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_conv",
-                            "ssm_conv_bias"):
+                            "ssm_conv_bias", "gdn_a_log", "gdn_dt_bias"):
                     init = self._ssm_draw(
                         name, jax.random.fold_in(keys[1 + gi],
                                                  _name_salt(name)), full)
@@ -465,9 +508,11 @@ class TransformerLM:
         to every token: greedy decoding falls onto a handful of
         attractor tokens whatever the prompt, every row routes to the
         same experts, and whether a chip's share holds them is the
-        seed's luck (PERF.md section 6, PR 38).  The other models keep
-        the draw their tolerances were read with."""
-        if not self.arch.router_bias or self.dtype == jnp.float32:
+        seed's luck (PERF.md section 6, PR 38).  A model with
+        delta-rule layers draws so too.  The other models keep the draw
+        their tolerances were read with."""
+        if not (self.arch.router_bias or self.arch.gdn_layers) \
+                or self.dtype == jnp.float32:
             return jax.random.normal(key, shape, self.dtype)
         return jax.random.normal(key, shape, jnp.float32).astype(self.dtype)
 
@@ -514,12 +559,13 @@ class TransformerLM:
         ``router_bias``: zero here, fitted by ``_balanced_router``.
         ``conv_w``: every tap N(0, 1/sqrt(taps)), so the carried inputs
         weigh as much as the newest and a dropped state moves the
-        logits.  ``q_norm``/``k_norm`` of a model whose layers name
-        their kinds: 1 + N(0, 0.1), so a dropped QK norm moves them."""
+        logits (``gdn_conv_w``: the same).  ``q_norm``/``k_norm`` of a
+        model whose layers name their kinds and ``gdn_norm``: 1 + N(0,
+        0.1), so a dropped norm moves them."""
         z = jax.random.normal(key, shape, jnp.float32)
-        if name == "conv_w":
+        if name in ("conv_w", "gdn_conv_w"):
             return (z / math.sqrt(shape[-2])).astype(self.dtype)
-        if name in ("q_norm", "k_norm"):
+        if name in ("q_norm", "k_norm", "gdn_norm"):
             return (1.0 + 0.1 * z).astype(self.dtype)
         if name != "sink":
             return jnp.zeros(shape, self.dtype)
@@ -573,11 +619,13 @@ class TransformerLM:
     def _ssm_draw(self, name: str, key: jax.Array, shape: tuple):
         """The mixer's small parameters by Mamba-2's conventions: A in
         1..16, the step dt (softplus of its bias) log-uniform between
-        1e-3 and 1e-1, D one, and a convolution with a bias."""
+        1e-3 and 1e-1, D one, and a convolution with a bias.  A
+        delta-rule layer's decay (``gdn_a_log``, ``gdn_dt_bias``) is
+        initialised the same way."""
         f32 = jnp.float32
-        if name == "ssm_a_log":
+        if name in ("ssm_a_log", "gdn_a_log"):
             v = jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0))
-        elif name == "ssm_dt_bias":
+        elif name in ("ssm_dt_bias", "gdn_dt_bias"):
             dt = jnp.exp(jax.random.uniform(key, shape, f32, math.log(1e-3),
                                             math.log(1e-1)))
             v = dt + jnp.log(-jnp.expm1(-dt))       # softplus's inverse
@@ -947,24 +995,31 @@ class TransformerLM:
         if a.key_multiplier is not None:
             k = k * jnp.asarray(a.key_multiplier, k.dtype)
         if kind is not None:
-            if kind.k_dim != kind.head_dim:
+            if kind.k_dim != kind.head_dim or a.qk_norm_whole:
                 # a head that is no whole number of 128-lane tiles: the
                 # projections stay plain matrix products on the weights
                 # as they lie, and the split into heads re-lays the
                 # activations out; without the barrier the compiler
-                # re-lays the weights out instead, every step
+                # re-lays the weights out instead, every step (under a
+                # QK norm over the whole projection: the values' stack,
+                # once a program and a slice of the copy a step)
                 q, k, v = jax.lax.optimization_barrier((q, k, v))
+            if a.qk_norm and a.qk_norm_whole:
+                # one norm over the whole projection, before the split
+                q = nn.rms_norm(q, p["q_norm"], a.rms_norm_eps, a.norm_offset)
+                k = nn.rms_norm(k, p["k_norm"], a.rms_norm_eps, a.norm_offset)
             q = q.reshape(B, T, kind.num_heads, kind.head_dim)
             k = k.reshape(B, T, kind.num_kv_heads, kind.head_dim)
             v = v.reshape(B, T, kind.num_kv_heads, kind.v_head_dim)
             if a.attention_value_scale is not None:
                 v = v * jnp.asarray(a.attention_value_scale, v.dtype)
-            if a.qk_norm:
+            if a.qk_norm and not a.qk_norm_whole:
                 q = nn.rms_norm(q, p["q_norm"], a.rms_norm_eps, a.norm_offset)
                 k = nn.rms_norm(k, p["k_norm"], a.rms_norm_eps, a.norm_offset)
-            inv_freq = self._kind_inv_freq[kind.index]
-            q = nn.apply_rope(q, positions, inv_freq, kind.head_dim)
-            k = nn.apply_rope(k, positions, inv_freq, kind.head_dim)
+            if a.rotary:
+                inv_freq = self._kind_inv_freq[kind.index]
+                q = nn.apply_rope(q, positions, inv_freq, kind.head_dim)
+                k = nn.apply_rope(k, positions, inv_freq, kind.head_dim)
             pad = kind.k_dim - kind.head_dim
             if pad:
                 widths = ((0, 0), (0, 0), (0, 0), (0, pad))
@@ -1050,7 +1105,9 @@ class TransformerLM:
         bf16 mode."""
         a = self.arch
         B, T, E = x.shape
-        h = self._norm(x, p, "attn_norm")
+        # (``norm_after``: the operator reads the residual stream as it
+        # is and its OUTPUT is normed, by the same two gains)
+        h = x if a.norm_after else self._norm(x, p, "attn_norm")
         if self.is_mla:
             attn_out, ck, cv, ks, vs = self._mla_attention(
                 h, p, ck, cv, li, ks, vs, mode, positions=positions,
@@ -1264,24 +1321,32 @@ class TransformerLM:
 
         if a.pre_post_norm:
             attn_out = self._norm(attn_out, p, "post_attn_norm")
-        x = x + attn_out
-        h2 = self._norm(x, p, "mlp_norm")
         if kind is not None:
-            x, stats = self._ffn_by_kind(x, h2, p, moe, mode, true_lens,
+            x, stats = self._ffn_by_kind(x, attn_out, p, moe, mode, true_lens,
                                          active, stats, expert_layer)
             return x, ck, cv, ks, vs, ssm, stats
+        x = x + attn_out
+        h2 = self._norm(x, p, "mlp_norm")
         mlp_out = self._mlp(h2, p, moe, lora=lora, lora_ids=lora_ids,
                             overlap=ov, pf_down=(pf or {}).get("down"))
         if a.pre_post_norm:
             mlp_out = self._norm(mlp_out, p, "post_mlp_norm")
         return x + mlp_out, ck, cv, ks, vs, ssm
 
-    def _ffn_by_kind(self, x, h2, p, moe, mode, true_lens, active, stats,
+    def _ffn_by_kind(self, x, mixed, p, moe, mode, true_lens, active, stats,
                      expert_layer):
-        """The FFN half of a block whose layer names its kinds: ``x``
-        the residual, ``h2`` its normed copy.  An expert layer routes
-        the tokens that are there: the rows that decode, a prompt's own
-        positions.  Returns (x, stats)."""
+        """The rest of a block whose layer names its kinds, from its
+        mixer's output ``mixed``: the residual add and the FFN, each
+        with the block's norm where the architecture has it (in front
+        of the FFN, or, ``norm_after``, on the mixer's and the FFN's
+        outputs).  An expert layer routes the tokens that are there: the
+        rows that decode, a prompt's own positions.  Returns (x,
+        stats)."""
+        after = self.arch.norm_after
+        if after:
+            mixed = self._norm(mixed, p, "attn_norm")
+        x = x + mixed
+        h2 = x if after else self._norm(x, p, "mlp_norm")
         if mode == "decode":
             valid = None if active is None else active[:, None]
         else:
@@ -1292,6 +1357,8 @@ class TransformerLM:
         if want:
             mlp_out, layer_stats = mlp_out
             stats = stats + layer_stats
+        if after:
+            mlp_out = self._norm(mlp_out, p, "mlp_norm")
         return x + mlp_out, stats
 
     def _conv_layer(self, x, p, pool, li, moe, mode, *, true_lens, active,
@@ -1333,11 +1400,119 @@ class TransformerLM:
             pool = pool.at[li, rows].set(nn.short_conv_carry(
                 seen, true_lens, a.conv_kernel).astype(pool.dtype))
         y = (gate_c.astype(jnp.float32) * c).astype(self.dtype)
-        x = x + nn.linear(y, p["conv_out"])
-        h2 = self._norm(x, p, "mlp_norm")
-        x, stats = self._ffn_by_kind(x, h2, p, moe, mode, true_lens, active,
-                                     stats, expert_layer)
+        x, stats = self._ffn_by_kind(x, nn.linear(y, p["conv_out"]), p, moe,
+                                     mode, true_lens, active, stats,
+                                     expert_layer)
         return x, pool, stats
+
+    def _gdn_layer(self, x, p, pools, li, moe, mode, *, true_lens, active,
+                   start_pos, rows, stats=None, expert_layer=None):
+        """One block whose mixer is a gated delta rule (olmo_hybrid's
+        ``linear_attention``; ops/gdn.py has the recurrence): ``[q | k |
+        v | z] = h W_in``, ``[a | b] = h W_gates``; q, k and v through a
+        causal depthwise
+        convolution of ``gdn_conv`` taps and SiLU; a head's q and k
+        L2-normalised, q scaled by ``dk ** -0.5``; ``beta = scale *
+        sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``; the
+        recurrence; ``RMSNorm(o) * silu(z)`` a head, out.  Then the
+        FFN.  Returns (x, pools, stats).
+
+        ``pools`` is (matrix state [delta layers, slots, dk, H * dv],
+        convolution tail [delta layers, slots, taps - 1, channels]), or
+        None for a forward pass with no cache (every sequence from a
+        zero state); ``li`` this layer's row of them.  The three served
+        forms are this one function of (the chunk, the carried state
+        and tail): prefill reads and writes the rows ``rows`` ([B] slot
+        indices), a chunk at position 0 from zeros, which is what
+        resets a reused slot's row, a later chunk from what the chunk
+        before left; decode, a chunk of one, updates every row that
+        ``active`` names, in place, and leaves the others bit for bit
+        (``rows`` is then ``ssm.live_rows(active)``).  Everything is
+        computed in float32 and rounded once, where a state is
+        written."""
+        from kaito_tpu.engine.ops import gdn as G
+        from kaito_tpu.engine.ops import ssm as S
+
+        a = self.arch
+        B, T, _ = x.shape
+        H, dk, dv, C = (a.gdn_heads, a.gdn_key_dim, a.gdn_value_dim,
+                        a.gdn_conv_dim)
+        inner = H * dv
+        f32 = jnp.float32
+        h = x if a.norm_after else self._norm(x, p, "attn_norm")
+        proj = nn.linear(h, p["gdn_in"])
+        qkv = proj[..., :C].astype(f32)
+        z = proj[..., C:].astype(f32)
+        gates = nn.linear(h, p["gdn_gates"]).astype(f32)         # [a | b]
+        g = -jnp.exp(p["gdn_a_log"].astype(f32)) * jax.nn.softplus(
+            gates[..., :H] + p["gdn_dt_bias"].astype(f32))
+        beta = a.gdn_beta_scale * jax.nn.sigmoid(gates[..., H:])
+        w = p["gdn_conv_w"].astype(f32)
+
+        def heads(c):
+            q, k = (c[..., i * H * dk:(i + 1) * H * dk].reshape(
+                c.shape[:-1] + (H, dk)) for i in (0, 1))
+            v = c[..., 2 * H * dk:].reshape(c.shape[:-1] + (H, dv))
+            q, k = (t * jax.lax.rsqrt(jnp.sum(jnp.square(t), axis=-1,
+                                              keepdims=True) + 1e-6)
+                    for t in (q, k))
+            return q * dk ** -0.5, k, v
+
+        if mode == "decode":
+            st, cv = pools
+            tail = cv[li]
+            with jax.named_scope("gdn_conv"):
+                conv, new_tail = S.conv_step(qkv[:, 0], tail.astype(f32), w)
+                new_tail = new_tail.astype(cv.dtype)
+                if active is not None:
+                    new_tail = jnp.where(active[:, None, None], new_tail,
+                                         tail)
+                cv = cv.at[li].set(new_tail)
+                q, k, v = heads(jax.nn.silu(conv))
+            if self.attn_impl == "pallas":
+                st, o = G.gdn_state_update(st, li, rows[0], rows[1], q, k, v,
+                                           g[:, 0], beta[:, 0])
+                if active is not None:
+                    o = jnp.where(active[:, None, None], o, 0.0)
+            else:
+                st, o = G.gdn_state_update_jax(st, li, q, k, v, g[:, 0],
+                                               beta[:, 0], active)
+            o = o[:, None]                                       # [S, 1, H, dv]
+            pools = (st, cv)
+        else:
+            if pools is None or start_pos is None:
+                tail0 = jnp.zeros((B, a.gdn_conv - 1, C), f32)
+                s0 = jnp.zeros((B, H, dk, dv), f32)
+            else:
+                keep = start_pos > 0
+                tail0 = jnp.where(keep[:, None, None],
+                                  pools[1][li, rows].astype(f32), 0.0)
+                s0 = jnp.where(
+                    keep[:, None, None, None],
+                    G.from_pool_layout(pools[0][li, rows].astype(f32), H),
+                    0.0)
+            with jax.named_scope("gdn_conv"):
+                q, k, v = heads(jax.nn.silu(S.causal_conv(qkv, tail0, w)))
+            valid = (jnp.arange(T)[None, :] < true_lens[:, None])[..., None]
+            o, s_last = G.gdn_chunked_scan(
+                q, k, v, jnp.where(valid, g, 0.0),
+                jnp.where(valid, beta, 0.0), s0)
+            if pools is not None:
+                st, cv = pools
+                st = st.at[li, rows].set(
+                    G.pool_layout(s_last).astype(st.dtype))
+                cv = cv.at[li, rows].set(
+                    S.conv_tail(qkv, tail0, true_lens).astype(cv.dtype))
+                pools = (st, cv)
+        # the gated norm: RMSNorm over a head's values, one gain a lane
+        # shared by the heads, then the gate
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                              + a.rms_norm_eps) * p["gdn_norm"].astype(f32)
+        y = o.reshape(B, T, inner) * jax.nn.silu(z)
+        x, stats = self._ffn_by_kind(
+            x, nn.linear(y.astype(self.dtype), p["gdn_out"]), p, moe, mode,
+            true_lens, active, stats, expert_layer)
+        return x, pools, stats
 
     def _ssm_mixer(self, h, p, pools, li, mode, *, true_lens, active,
                    start_pos, rows):
@@ -1572,7 +1747,9 @@ class TransformerLM:
         of their own: a table an attention kind, the full kind's first
         ([B, pages] otherwise); a kind's pools ride the scans of its
         runs, and the short-convolution layers' state pool
-        (``cache.conv_state``, rows ``state_rows`` at prefill) theirs."""
+        (``cache.conv_state``, rows ``state_rows`` at prefill) or the
+        delta-rule layers' (``cache.delta_state`` with the convolutions'
+        tails in ``cache.conv_state``) theirs."""
         if mode not in ("train", "prefill", "decode"):
             raise NotImplementedError(
                 f"layers that name their attention kind have no "
@@ -1586,16 +1763,23 @@ class TransformerLM:
             raise ValueError("a model with short-convolution layers serves "
                              "from a cache with rows of conv state "
                              "(kv_cache.create_conv_state_pool)")
+        if mode != "train" and self.has_gdn and cache.delta_state is None:
+            raise ValueError("a model with delta-rule layers serves from a "
+                             "cache with rows of matrix state "
+                             "(kv_cache.create_delta_state_pool)")
         # ("train": the cache-free forward pass that scores a prompt)
         pools = None if mode == "train" else \
             [(cache.k, cache.v), (cache.wk, cache.wv)]
         conv_pool = None if mode == "train" else cache.conv_state
+        delta_pool = None if mode == "train" else cache.delta_state
         # an expert layer's counters are kept for the decode programs
         # (what the per-layer metrics read)
         stats = cache.moe_stats if mode == "decode" else None
         for run in self.runs:
             stack = params[run.stack]
-            conv = run.kind == MIXER_CONV
+            gdn = run.kind == MIXER_GDN
+            # (a run that reads a row of the state pool and no page)
+            conv = gdn or run.kind == MIXER_CONV
             kind = None if conv else self.kinds[run.kind]
             window = None if conv else kind.window
             # an expert layer's stacks stay whole and the layer goes by
@@ -1612,7 +1796,13 @@ class TransformerLM:
                 return {**p, **whole}, (at if whole else None)
 
             if mode == "train":
-                def one(h, p, at, kind=kind, window=window, moe=run.moe):
+                def one(h, p, at, kind=kind, window=window, moe=run.moe,
+                        gdn=gdn):
+                    if gdn:
+                        return self._gdn_layer(
+                            h, p, None, None, moe, mode, true_lens=true_lens,
+                            active=None, start_pos=None, rows=None,
+                            expert_layer=at)[0]
                     if kind is None:
                         return self._conv_layer(
                             h, p, None, None, moe, mode, true_lens=true_lens,
@@ -1628,22 +1818,30 @@ class TransformerLM:
                     x = one(x, *take(i))
                 continue
             if conv:
-                def conv_step(carry, i, run=run, take=take):
+                def conv_step(carry, i, run=run, take=take, gdn=gdn):
                     h, pool, st = carry
                     p, at = take(i)
-                    return self._conv_layer(
+                    layer = self._gdn_layer if gdn else self._conv_layer
+                    return layer(
                         h, p, pool, run.cache_start + i, run.moe, mode,
                         true_lens=true_lens, active=active,
                         start_pos=start_pos, rows=state_rows, stats=st,
                         expert_layer=at), None
 
+                # (a delta-rule layer's pool: its matrix state and the
+                # convolutions' tails)
+                pool = (delta_pool, conv_pool) if gdn else conv_pool
                 if run.count == 1:
-                    (x, conv_pool, stats), _ = conv_step(
-                        (x, conv_pool, stats), jnp.int32(0))
+                    (x, pool, stats), _ = conv_step(
+                        (x, pool, stats), jnp.int32(0))
                 else:
-                    (x, conv_pool, stats), _ = jax.lax.scan(
-                        conv_step, (x, conv_pool, stats),
+                    (x, pool, stats), _ = jax.lax.scan(
+                        conv_step, (x, pool, stats),
                         jnp.arange(run.count, dtype=jnp.int32))
+                if gdn:
+                    delta_pool, conv_pool = pool
+                else:
+                    conv_pool = pool
                 continue
             table = page_tables[:, run.kind] if two_tables else page_tables
             ck, cv = pools[run.kind]
@@ -1671,7 +1869,7 @@ class TransformerLM:
             return x, None
         return x, dataclasses.replace(
             cache, k=pools[0][0], v=pools[0][1], wk=pools[1][0],
-            wv=pools[1][1], conv_state=conv_pool,
+            wv=pools[1][1], conv_state=conv_pool, delta_state=delta_pool,
             moe_stats=stats if mode == "decode" else cache.moe_stats)
 
     def _layer_train(self, x, p, window, moe, *, positions, true_lens,
@@ -1679,7 +1877,7 @@ class TransformerLM:
         """Transformer block without KV-cache plumbing (training)."""
         a = self.arch
         B, T, E = x.shape
-        h = self._norm(x, p, "attn_norm")
+        h = x if a.norm_after else self._norm(x, p, "attn_norm")
         if self.is_mla:
             attn_out, _, _, _, _ = self._mla_attention(
                 h, p, None, None, None, None, None, "train",
@@ -1697,11 +1895,9 @@ class TransformerLM:
                 true_len=true_lens,
                 sink=p["sink"].astype(jnp.float32) if "sink" in p else None)
             o_in = out.reshape(B, T, kind.num_heads * kind.v_head_dim)
-            x = x + nn.linear(o_in, p["o"])
-            h2 = self._norm(x, p, "mlp_norm")
-            valid = jnp.arange(T)[None, :] < true_lens[:, None]
-            return x + self._mlp(h2, p, moe, valid=valid,
-                                 expert_layer=expert_layer)
+            return self._ffn_by_kind(x, nn.linear(o_in, p["o"]), p, moe,
+                                     "train", true_lens, None, None,
+                                     expert_layer)[0]
         if self.ring is not None and window is None:
             # sequence-parallel exact attention over the mesh ring;
             # training batches are packed dense (loss masks handle pads)
@@ -1879,7 +2075,7 @@ class TransformerLM:
         if active is not None:
             lengths = jnp.where(active, lengths, 0)
         ssm_rows = None
-        if self.has_ssm and self.attn_impl == "pallas":
+        if (self.has_ssm or self.has_gdn) and self.attn_impl == "pallas":
             # once a step, for every layer's call of the kernel
             from kaito_tpu.engine.ops.ssm import live_rows
 

@@ -52,14 +52,15 @@ _HI = jax.lax.Precision.HIGHEST
 # ----------------------------------------------------------------------
 
 def causal_conv(x: jax.Array, tail: jax.Array, w: jax.Array,
-                b: jax.Array) -> jax.Array:
+                b: jax.Array = None) -> jax.Array:
     """Depthwise causal convolution.  x: [B, T, C]; tail: [B, K-1, C],
     the K-1 inputs before x[:, 0] (zeros at a sequence's start);
-    w: [K, C], w[K-1] on the current input; b: [C].  Returns [B, T, C]."""
+    w: [K, C], w[K-1] on the current input; b: [C] or None (no bias).
+    Returns [B, T, C]."""
     K = w.shape[0]
     T = x.shape[1]
     full = jnp.concatenate([tail, x], axis=1)
-    out = b[None, None, :]
+    out = 0.0 if b is None else b[None, None, :]
     for k in range(K):
         out = out + w[k][None, None, :] * full[:, k:k + T]
     return out
@@ -75,11 +76,15 @@ def conv_tail(x: jax.Array, tail: jax.Array, true_lens: jax.Array
     return jnp.take_along_axis(full, idx[:, :, None], axis=1)
 
 
-def conv_step(x: jax.Array, tail: jax.Array, w: jax.Array, b: jax.Array):
-    """One token.  x: [S, C]; tail: [S, K-1, C].  Returns the
-    convolution's output [S, C] and the tail after this token."""
+def conv_step(x: jax.Array, tail: jax.Array, w: jax.Array,
+              b: jax.Array = None):
+    """One token.  x: [S, C]; tail: [S, K-1, C]; b: [C] or None.
+    Returns the convolution's output [S, C] and the tail after this
+    token."""
     window = jnp.concatenate([tail, x[:, None, :]], axis=1)   # [S, K, C]
-    out = b[None, :] + jnp.sum(w[None] * window, axis=1)
+    out = jnp.sum(w[None] * window, axis=1)
+    if b is not None:
+        out = b[None, :] + out
     return out, window[:, 1:]
 
 

@@ -378,9 +378,11 @@ class OpenAIHandler(BaseHTTPRequestHandler):
         if engines[0].latent_weights:
             body["latent_weights"] = engines[0].latent_weights
         arch = engines[0].md.arch
-        if arch.conv_layers:
+        if arch.conv_layers or arch.gdn_layers:
             # what mixes the layers' tokens, where not attention alone
-            body["mixers"] = {"conv": arch.conv_layers,
+            other = ({"conv": arch.conv_layers} if arch.conv_layers
+                     else {"gated_delta_rule": arch.gdn_layers})
+            body["mixers"] = {**other,
                               "full_attention": arch.attention_layers(0)}
         for d in jax.local_devices():
             stats = d.memory_stats() or {}

@@ -69,6 +69,34 @@ _LFM2_OVERRIDES = {
 }
 
 
+# olmo_hybrid: the block's two norms stand after the operator and the
+# MLP (and are named for it), and a delta-rule layer's small tensors;
+# its projections and taps are fused by ``_olmo_hybrid_fused``
+_OLMO_HYBRID_OVERRIDES = {
+    "attn_norm": ("post_attention_layernorm.weight", False),
+    "mlp_norm": ("post_feedforward_layernorm.weight", False),
+    "gdn_a_log": ("linear_attn.A_log", False),
+    "gdn_dt_bias": ("linear_attn.dt_bias", False),
+    "gdn_norm": ("linear_attn.o_norm.weight", False),
+    "gdn_out": ("linear_attn.o_proj.weight", True),
+}
+
+
+def _olmo_hybrid_fused(get, li: int, our_key: str):
+    """A delta-rule layer's fused tensors from the family's names:
+    ``gdn_in`` = [W_q | W_k | W_v | W_g] and ``gdn_gates`` = [W_a |
+    W_b] (each ``x @ W``), ``gdn_conv_w`` = the three depthwise Conv1d
+    weights [channels, 1, taps] side by side as [taps, channels], the
+    last tap on the newest input in both."""
+    pre = f"layers.{li}.linear_attn."
+    if our_key in ("gdn_in", "gdn_gates"):
+        return np.concatenate(
+            [get(f"{pre}{n}_proj.weight").T
+             for n in ("qkvg" if our_key == "gdn_in" else "ab")], axis=1)
+    return np.concatenate(
+        [get(f"{pre}{n}_conv1d.weight")[:, 0, :].T for n in "qkv"], axis=1)
+
+
 def _reader(directory: str) -> tuple[Callable[[str], Optional[np.ndarray]], list[str]]:
     from safetensors import safe_open
 
@@ -159,6 +187,8 @@ def assemble_params(model: TransformerLM,
         layer_map.update(_GEMMA_OVERRIDES)
     if arch.conv_kernel:
         layer_map.update(_LFM2_OVERRIDES)
+    if arch.gdn_layers:
+        layer_map.update(_OLMO_HYBRID_OVERRIDES)
 
     for g in model.groups:
         specs = model._layer_specs(g.moe, g.kind)
@@ -176,6 +206,8 @@ def assemble_params(model: TransformerLM,
                     # channels] with tap k on the input k tokens back
                     w = get(f"layers.{li}.conv.conv.weight")
                     tensor = np.ascontiguousarray(w[:, 0, ::-1].T)
+                if our_key in ("gdn_in", "gdn_gates", "gdn_conv_w"):
+                    tensor = _olmo_hybrid_fused(get, li, our_key)
                 if entry is not None:
                     suffix, transpose = entry
                     tensor = get(f"layers.{li}.{suffix}", required=False)

@@ -13,8 +13,8 @@ from __future__ import annotations
 
 from typing import Mapping, Optional
 
-from kaito_tpu.models.metadata import (MIXER_CONV, MIXER_FULL, ModelArch,
-                                       ModelMetadata)
+from kaito_tpu.models.metadata import (MIXER_CONV, MIXER_FULL, MIXER_GDN,
+                                       ModelArch, ModelMetadata)
 
 # Architectures we can instantiate in the engine.  The analogue of the
 # reference's vLLM arch allowlist (presets/workspace/models/
@@ -40,6 +40,7 @@ SUPPORTED_ARCHITECTURES = {
     "JoyAILLMFlashForCausalLM",
     "Lfm2ForCausalLM",
     "Lfm2MoeForCausalLM",
+    "OlmoHybridForCausalLM",
 }
 
 
@@ -193,11 +194,69 @@ def arch_from_hf_config(cfg: Mapping) -> ModelArch:
     if model_type in ("lfm2", "lfm2_moe"):
         kw.update(_lfm2_fields(cfg, model_type, layers, kw))
 
+    if model_type == "olmo_hybrid":
+        kw.update(_olmo_hybrid_fields(cfg, layers))
+
     if model_type == "phi":
         kw.update(gated_mlp=False, parallel_residual=True, norm_type="layernorm",
                   linear_bias=True)
 
     return ModelArch(**kw)
+
+
+def _olmo_hybrid_fields(cfg: Mapping, layers: int) -> dict:
+    """Olmo-Hybrid (``olmo_hybrid``): ``layer_types`` names each
+    layer's mixer, ``linear_attention`` a gated delta rule (the
+    ``linear_*`` keys: heads, key and value sizes, the taps of the
+    convolutions in front, ``linear_allow_neg_eigval``: beta times 2)
+    and ``full_attention`` MHA with one RMSNorm over the whole query
+    and the whole key projection and NO rotary embedding
+    (``rope_parameters.rope_theta`` null); the family's reordered block
+    norm (after the operator and after the MLP alone).  What is not
+    implemented is refused by name."""
+    def refuse(what):
+        raise ValueError(f"olmo_hybrid: {what} is not implemented")
+
+    types = tuple(cfg.get("layer_types") or ())
+    if len(types) != layers:
+        raise ValueError(f"olmo_hybrid: layer_types ({len(types)}) must "
+                         f"name each of the {layers} layers")
+    known = {"linear_attention": MIXER_GDN, "full_attention": MIXER_FULL}
+    for t in types:
+        if t not in known:
+            refuse(f"a layer_types entry {t!r}")
+    heads = int(cfg["linear_num_value_heads"])
+    if int(cfg.get("linear_num_key_heads", heads)) != heads:
+        refuse(f"linear_num_key_heads {cfg['linear_num_key_heads']} != "
+               f"linear_num_value_heads {heads}")
+    rope = cfg.get("rope_parameters") or {}
+    theta = rope.get("rope_theta", cfg.get("rope_theta"))
+    if theta is not None:
+        # (until a checkpoint with one is tested against its reference)
+        refuse(f"a rotary embedding (rope_theta {theta!r})")
+    if bool(cfg.get("attention_bias", False)):
+        refuse("attention_bias true")
+    taps = int(cfg.get("linear_conv_kernel_dim", 4))
+    if taps < 2:
+        refuse(f"linear_conv_kernel_dim {taps}")
+    return dict(
+        rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        rotary=False,
+        rope_scaling=None,
+        qkv_bias=False,
+        qk_norm=True,
+        qk_norm_whole=True,
+        norm_after=True,
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        layer_attention=tuple(known[t] for t in types),
+        layer_experts=(0,) * layers,
+        gdn_heads=heads,
+        gdn_key_dim=int(cfg["linear_key_head_dim"]),
+        gdn_value_dim=int(cfg["linear_value_head_dim"]),
+        gdn_conv=taps,
+        gdn_beta_scale=2.0 if bool(cfg.get("linear_allow_neg_eigval",
+                                           False)) else 1.0,
+    )
 
 
 def _lfm2_fields(cfg: Mapping, model_type: str, layers: int, kw: dict) -> dict:

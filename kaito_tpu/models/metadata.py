@@ -44,7 +44,7 @@ def heads_per_lane_row(head_dim: int, v_head_dim: int, kv_heads: int) -> int:
 
 
 # ``ModelArch.layer_attention``: what mixes a layer's tokens
-MIXER_FULL, MIXER_WINDOW, MIXER_CONV = 0, 1, 2
+MIXER_FULL, MIXER_WINDOW, MIXER_CONV, MIXER_GDN = 0, 1, 2, 3
 
 
 class AttentionKind(str, enum.Enum):
@@ -82,16 +82,25 @@ class ModelArch:
     norm_type: str = "rmsnorm"        # rmsnorm | layernorm
     norm_offset: bool = False         # gemma: weight = 1 + w
     pre_post_norm: bool = False       # gemma-2/3: extra post-attn/post-mlp norms
+    # olmo-2/3, olmo_hybrid: the block's two norms stand AFTER the
+    # operator and the MLP and nowhere else, x + norm(op(x)); both read
+    # the residual stream as it is
+    norm_after: bool = False
     parallel_residual: bool = False   # falcon/phi-2: x + attn(n(x)) + mlp(n(x))
     linear_bias: bool = False         # phi-2: biases on all projections
 
-    # rotary embedding
+    # rotary embedding (``rotary`` false: none; olmo_hybrid's attention
+    # layers see positions through the recurrent layers below them)
+    rotary: bool = True
     rope_theta: float = 10000.0
     partial_rotary_factor: float = 1.0
     rope_scaling: Optional[dict] = None   # {"rope_type": "llama3"|"linear"|"yarn", ...}
 
     # attention details
     qk_norm: bool = False             # gemma-3 / qwen-3: RMSNorm on q and k heads
+    # olmo-2/3, olmo_hybrid: the QK norm is ONE norm over the whole
+    # projection (all heads' lanes), before the split into heads
+    qk_norm_whole: bool = False
     qkv_bias: bool = False            # qwen2
     attn_logit_softcap: Optional[float] = None   # gemma-2
     final_logit_softcap: Optional[float] = None  # gemma-2
@@ -153,12 +162,23 @@ class ModelArch:
     # sink bias a head, 2 a gated short convolution (lfm2: no attention
     # and no page; ``conv_kernel`` taps a channel over ``hidden_size``
     # channels, and the last ``conv_kernel - 1`` inputs a sequence in
-    # the state pool); ``layer_experts[l]`` is 0 for a dense FFN and 1
-    # for an expert layer.  None: every layer is of the one kind the
-    # fields above give.
+    # the state pool), 3 a gated delta rule (olmo_hybrid's
+    # ``linear_attention``: no page; ``gdn_heads`` heads with keys of
+    # ``gdn_key_dim`` and values of ``gdn_value_dim``, each a causal
+    # depthwise convolution of ``gdn_conv`` taps in front, beta times
+    # ``gdn_beta_scale``; a sequence keeps a ``gdn_key_dim x
+    # gdn_value_dim`` matrix a head and the convolution's last inputs in
+    # the state pool: "A row of matrix state"); ``layer_experts[l]`` is
+    # 0 for a dense FFN and 1 for an expert layer.  None: every layer is
+    # of the one kind the fields above give.
     layer_attention: Optional[tuple] = None
     layer_experts: Optional[tuple] = None
     conv_kernel: int = 0
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_beta_scale: float = 1.0
     swa_num_heads: int = 0
     swa_num_kv_heads: int = 0
     swa_head_dim: int = 0
@@ -191,7 +211,7 @@ class ModelArch:
 
     def attention_layers(self, kind: int) -> int:
         """How many layers' mixer is of kind ``kind`` (0 full attention,
-        1 window attention, 2 short convolution)."""
+        1 window attention, 2 short convolution, 3 gated delta rule)."""
         if self.layer_attention is None:
             return self.num_layers if kind == 0 else 0
         return sum(1 for k in self.layer_attention if k == kind)
@@ -200,6 +220,25 @@ class ModelArch:
     def conv_layers(self) -> int:
         """Layers whose mixer is a short convolution (no page)."""
         return self.attention_layers(MIXER_CONV)
+
+    @property
+    def gdn_layers(self) -> int:
+        """Layers whose mixer is a gated delta rule (no page)."""
+        return self.attention_layers(MIXER_GDN)
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels of a delta-rule layer's convolution: [q | k | v]."""
+        return self.gdn_heads * (2 * self.gdn_key_dim + self.gdn_value_dim)
+
+    def gdn_state_bytes(self, state_bytes: int = 2,
+                        dtype_bytes: int = 2) -> tuple:
+        """(matrix state, convolution tail) bytes a sequence holds in
+        ONE delta-rule layer: the state in ``state_bytes`` a number,
+        the tail in the type the model is served in."""
+        return (self.gdn_heads * self.gdn_key_dim * self.gdn_value_dim
+                * state_bytes,
+                (self.gdn_conv - 1) * self.gdn_conv_dim * dtype_bytes)
 
     def kv_heads_per_row(self, kind: int) -> int:
         """KV heads a 128-lane row of kind ``kind``'s token-flat pools
@@ -240,12 +279,18 @@ class ModelArch:
         """Width of the mixer's input projection: [z | x | B | C | dt]."""
         return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
 
-    def state_bytes_per_seq(self, dtype_bytes: int = 2) -> int:
+    def state_bytes_per_seq(self, dtype_bytes: int = 2,
+                            state_bytes: Optional[int] = None) -> int:
         """Bytes of recurrent state one sequence holds across all
         layers, whatever its length: the mixer's state and the
-        convolution's tail, or the short-convolution layers' last
-        inputs, in the type the model is served in (0 for a model with
-        neither)."""
+        convolution's tail, the short-convolution layers' last inputs,
+        or the delta-rule layers' matrix state (``state_bytes`` a
+        number where it is held in another type than the rest) and
+        convolution tail, in the type the model is served in (0 for a
+        model with none of them)."""
+        if self.gdn_layers:
+            return self.gdn_layers * sum(self.gdn_state_bytes(
+                state_bytes or dtype_bytes, dtype_bytes))
         if self.conv_layers:
             return (self.conv_layers * (self.conv_kernel - 1)
                     * self.hidden_size * dtype_bytes)
@@ -346,6 +391,14 @@ class ModelArch:
             if kind == MIXER_CONV:
                 # [B | C | u] in, out, and the taps
                 total += h * 3 * h + h * h + self.conv_kernel * h
+            elif kind == MIXER_GDN:
+                # q, k, v with their taps, the output gate and W_o, the
+                # two gates a head (a, b), A_log, dt_bias, the gated
+                # norm's one gain a value lane
+                Hd, dv = self.gdn_heads, self.gdn_value_dim
+                total += (h * self.gdn_conv_dim
+                          + self.gdn_conv * self.gdn_conv_dim
+                          + 2 * h * Hd * dv + 2 * h * Hd + 2 * Hd + dv)
             else:
                 if kind:
                     H, Hkv = self.swa_num_heads, self.swa_num_kv_heads
@@ -357,7 +410,10 @@ class ModelArch:
                     dk, dv = self.head_dim, self.v_head_dim or self.head_dim
                     sink = H if self.full_sink else 0
                 total += h * H * dk + h * Hkv * (dk + dv) + H * dv * h + sink
-                total += 2 * dk if self.qk_norm else 0
+                if self.qk_norm and self.qk_norm_whole:
+                    total += (H + Hkv) * dk
+                elif self.qk_norm:
+                    total += 2 * dk
             if moe:
                 inter = self.moe_intermediate_size or self.intermediate_size
                 total += 3 * h * inter * self.experts_held \

@@ -13,4 +13,6 @@ SERVED = {
     # heads of 64: half a lane tile (a fresh chunk's q, k and v go to
     # the kernel as they are; the pools pair the heads up)
     "lfm2-8b-a1b": (32, 8, 64, 64, False),
+    # as many KV heads as query heads: a group of one
+    "olmo-hybrid-7b": (30, 30, 128, 128, False),
 }

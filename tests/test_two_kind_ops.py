@@ -222,6 +222,47 @@ def test_decode_kernel_compiles_for_v5e_over_lane_packed_heads(one_chip):
                                  *rest).compile()
 
 
+def test_decode_kernel_compiles_for_v5e_at_a_group_of_one(one_chip):
+    """Olmo-Hybrid-7B's attention layers at the cell's 32 rows: 30 KV
+    heads of 128 under 30 query heads, pools [2, P, 64 x 30, 128]; the
+    query block is [30, 128] and a page's score panel [30, 1920]."""
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, H, D, ps, P = 32, 30, 128, 64, 2600
+    pool = sd((2, P, ps * H, D))
+    compiled = jax.jit(
+        lambda q, ck, cv, pt, ln, win, li: paged_decode_attention_pallas(
+            q, ck, cv, pt, ln, win, scale=D ** -0.5, layer=li,
+            kv_heads=H)).lower(
+        sd((B, H, D)), pool, pool, sd((B, 80), jnp.int32),
+        sd((B,), jnp.int32), sd((), jnp.int32), sd((), jnp.int32)).compile()
+    assert "attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * P * ps * H * D * 2 // 8
+
+
+def test_delta_rule_kernel_compiles_for_v5e_at_the_published_widths(one_chip):
+    """Olmo-Hybrid-7B's state pool at the cell's 32 slots: six layers'
+    rows of [96, 30 x 192] bfloat16 (45 whole lane tiles, the heads in
+    pairs of three), aliased in and out: no temporary of a layer's
+    rows, and the kernel is in the program under the name the
+    roofline's reader looks for."""
+    from kaito_tpu.engine.ops.gdn import gdn_state_update
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, dk, dv = 32, 30, 96, 192
+    compiled = jax.jit(gdn_state_update, donate_argnums=(0,)).lower(
+        sd((6, S, dk, H * dv), jnp.bfloat16), sd((), jnp.int32),
+        sd((S,), jnp.int32), sd((1,), jnp.int32), sd((S, H, dk)),
+        sd((S, H, dk)), sd((S, H, dv)), sd((S, H)), sd((S, H))).compile()
+    assert "gdn_state_update" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < S * dk * H * dv * 2
+
+
 def _compile_flash(sd, T, H, Hkv, D, Dv, sink_on):
     def flash(q, k, v, tl, win, *s):
         return flash_prefill_attention(q, k, v, tl, win, scale=0.07,
