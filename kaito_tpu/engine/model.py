@@ -788,14 +788,11 @@ class TransformerLM:
             q = nn.linear(x, p["q"])
         if (dn + dr) % 128 and B * T < x.shape[-1]:
             # a query head that is no whole number of 128-lane tiles
-            # (192 = 128 | 64): as in ``_attn_qkv``, the split into
-            # heads re-lays the activations out; without the barrier
-            # the compiler transposes the whole stack of weights
-            # instead, once a program, and slices the copy a layer.
-            # Taken where the rows are fewer than the weight's own
-            # (decode, a short chunk): past that the activations are
-            # the larger of the two and the weight's re-lay, a layer at
-            # a time there, is the cheaper
+            # (192 = 128 | 64), at fewer rows than the weight has: the
+            # split into heads re-lays the activations and not the
+            # stack of weights (docs/kv-cache.md, "The attention
+            # weights are multiplied where they lie": the rule and its
+            # bound on rows, for this place and ``_attn_qkv``'s two)
             q = jax.lax.optimization_barrier(q)
         q = q.reshape(B, T, H, dn + dr)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
@@ -996,13 +993,13 @@ class TransformerLM:
             k = k * jnp.asarray(a.key_multiplier, k.dtype)
         if kind is not None:
             if kind.k_dim != kind.head_dim or a.qk_norm_whole:
-                # a head that is no whole number of 128-lane tiles: the
-                # projections stay plain matrix products on the weights
-                # as they lie, and the split into heads re-lays the
-                # activations out; without the barrier the compiler
-                # re-lays the weights out instead, every step (under a
-                # QK norm over the whole projection: the values' stack,
-                # once a program and a slice of the copy a step)
+                # keys wider than the head (no whole number of 128-lane
+                # tiles), or a QK norm over the whole projection (the
+                # values' stack would be copied once a program and
+                # sliced a step): the split into heads re-lays the
+                # activations and not the weights, here at every width
+                # (docs/kv-cache.md, "The attention weights are
+                # multiplied where they lie")
                 q, k, v = jax.lax.optimization_barrier((q, k, v))
             if a.qk_norm and a.qk_norm_whole:
                 # one norm over the whole projection, before the split
@@ -1025,6 +1022,13 @@ class TransformerLM:
                 widths = ((0, 0), (0, 0), (0, 0), (0, pad))
                 q, k = jnp.pad(q, widths), jnp.pad(k, widths)
             return q, k, v
+        if B * T < x.shape[-1]:
+            # fewer rows than the weight has (decode, a short chunk):
+            # the split into heads, and the rotary embedding's cut of a
+            # head's rotated lanes into halves, re-lay the activations
+            # and not the weight stacks (docs/kv-cache.md, "The
+            # attention weights are multiplied where they lie")
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
         q = q.reshape(B, T, a.num_heads, a.head_dim)
         k = k.reshape(B, T, a.num_kv_heads, a.head_dim)
         v = v.reshape(B, T, a.num_kv_heads, a.head_dim)

@@ -268,23 +268,47 @@ def _prefill_jaxpr(width: int, tokens: int) -> str:
                                              i32(1), i32(1, 4)))
 
 
-def _qkv_jaxpr(head_dim: int) -> str:
-    """``_attn_qkv`` of a model whose layers name their kinds, at keys
-    of ``head_dim`` (MiMo's are 192)."""
-    from test_two_kind_engine import TINY_MIMO
-
-    arch = arch_from_hf_config(dict(TINY_MIMO, head_dim=head_dim,
-                                    swa_head_dim=head_dim))
+def _attn_qkv_jaxpr(arch, batch: int, tokens: int) -> str:
+    """``_attn_qkv`` of the first group's layer (under its kind, where
+    the layers name theirs) at ``batch * tokens`` rows."""
     model = TransformerLM(arch, dtype=jnp.float32)
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     g = model.groups[0]
     p = {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype)
          for k, v in params[g.name].items()}
+    kind = model.kinds[g.kind] if arch.layer_attention is not None else None
     return str(jax.make_jaxpr(
-        lambda x, p, pos: model._attn_qkv(x, p, pos, None,
-                                          kind=model.kinds[g.kind]))(
-        jax.ShapeDtypeStruct((2, 1, arch.hidden_size), jnp.float32), p,
-        jax.ShapeDtypeStruct((2, 1), jnp.int32)))
+        lambda x, p, pos: model._attn_qkv(x, p, pos, None, kind=kind))(
+        jax.ShapeDtypeStruct((batch, tokens, arch.hidden_size), jnp.float32),
+        p, jax.ShapeDtypeStruct((batch, tokens), jnp.int32)))
+
+
+def _qkv_jaxpr(head_dim: int) -> str:
+    """``_attn_qkv`` of a model whose layers name their kinds, at keys
+    of ``head_dim`` (MiMo's are 192)."""
+    from test_two_kind_engine import TINY_MIMO
+
+    return _attn_qkv_jaxpr(
+        arch_from_hf_config(dict(TINY_MIMO, head_dim=head_dim,
+                                 swa_head_dim=head_dim)), 2, 1)
+
+
+def _plain_arch(rotary: float):
+    """A tiny decoder whose layers name no kind (``_attn_qkv``'s plain
+    branch), with biases on q, k and v: phi-like where three quarters
+    of a head are rotated, and with the whole head rotated."""
+    return arch_from_hf_config({
+        "architectures": ["LlamaForCausalLM"], "model_type": "llama",
+        "vocab_size": 256, "hidden_size": 64, "num_hidden_layers": 2,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "intermediate_size": 128, "max_position_embeddings": 256,
+        "attention_bias": True, "partial_rotary_factor": rotary})
+
+
+def _plain_qkv_jaxpr(rotary: float, batch: int, tokens: int) -> str:
+    """The plain branch at ``batch * tokens`` rows against a hidden
+    size of 64."""
+    return _attn_qkv_jaxpr(_plain_arch(rotary), batch, tokens)
 
 
 @pytest.mark.parametrize("jaxpr,args,barrier", [
@@ -303,10 +327,73 @@ def _qkv_jaxpr(head_dim: int) -> str:
     # of at most one tile
     (_qkv_jaxpr, (192,), True),
     (_qkv_jaxpr, (24,), False),
+    # the plain branch (no kinds: phi-4-mini's 96 rotated lanes of 128,
+    # falcon-h1's whole head) goes by rows alone: decode rows and a
+    # chunk shorter than the hidden size (64 here) re-lay the
+    # activations, rows equal to it or past it are the compiler's
+    (_plain_qkv_jaxpr, (0.75, 8, 1), True),
+    (_plain_qkv_jaxpr, (1.0, 8, 1), True),
+    (_plain_qkv_jaxpr, (0.75, 1, 32), True),
+    (_plain_qkv_jaxpr, (1.0, 1, 48), True),
+    (_plain_qkv_jaxpr, (0.75, 1, 64), False),
+    (_plain_qkv_jaxpr, (1.0, 1, 64), False),
+    (_plain_qkv_jaxpr, (0.75, 2, 64), False),
+    (_plain_qkv_jaxpr, (1.0, 64, 1), False),
 ])
 def test_a_barrier_stands_where_a_head_is_no_whole_number_of_tiles(
         jaxpr, args, barrier):
     assert ("optimization_barrier" in jaxpr(*args)) == barrier
+
+
+@pytest.mark.parametrize("rotary,batch,tokens,overlap", [
+    (0.75, 8, 1, False),
+    (1.0, 8, 1, False),
+    (0.75, 1, 32, False),
+    (1.0, 2, 24, False),
+    # the column-parallel q through the all-gather ring feeds the same
+    # sum (two virtual devices)
+    (0.75, 8, 1, True),
+])
+def test_the_plain_branchs_barrier_changes_no_value(
+        rotary, batch, tokens, overlap, monkeypatch):
+    """q, k and v with a LoRA delta and a bias in the sum: the jitted
+    call with the barrier and the jitted call with the barrier taken
+    out agree to the last bit; the eager call, whose sums no compiler
+    fuses, to a rounding of either."""
+    model = TransformerLM(_plain_arch(rotary), dtype=jnp.float32)
+    model.lora_scaling = 0.5
+    stack = model.init_params(jax.random.PRNGKey(1))[model.groups[0].name]
+    p = {k: v[1] for k, v in stack.items()}
+    rng = np.random.default_rng(7)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    for name in ("q", "k", "v"):
+        p[name + "_bias"] = normal(*p[name + "_bias"].shape)
+        p[name + "_lora_a"] = normal(64, 4)
+        p[name + "_lora_b"] = normal(4, p[name].shape[1])
+    x = normal(batch, tokens, 64)
+    pos = jnp.broadcast_to(jnp.arange(tokens, dtype=jnp.int32) + 3,
+                           (batch, tokens))
+    handle = None
+    if overlap:
+        from jax.sharding import Mesh
+
+        handle = (Mesh(np.array(jax.devices()[:2]), ("tensor",)), "tensor")
+
+    def qkv():
+        # a new function a trace: jit keeps its traces by function
+        return lambda x, p, pos: model._attn_qkv(x, p, pos, None,
+                                                 overlap=handle)
+
+    assert "optimization_barrier" in str(jax.make_jaxpr(qkv())(x, p, pos))
+    held = jax.jit(qkv())(x, p, pos)
+    eager = qkv()(x, p, pos)
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda t: t)
+    assert "optimization_barrier" not in str(jax.make_jaxpr(qkv())(x, p, pos))
+    free = jax.jit(qkv())(x, p, pos)
+    for a, b, c in zip(held, free, eager):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_head_major_weights_are_derived_and_the_drawn_tree_stands():
