@@ -43,10 +43,18 @@ from trafficgen import schedule, words                  # noqa: E402
 
 WARM_SEED = 0x3A97          # warm-up traffic is the same in every run
 POLL_PERIOD_S = 0.5
-# /stop_profile returns when the trace is written: about 10 s for each
-# second traced on a v5e host (31, 82 and 139 s for 3, 8 and 14 s; PR 26),
-# while the server goes on serving
+# /stop_profile returns when the trace is written, while the server goes
+# on serving: the export's seconds follow the file's size, 1.3-2.4 s a MB
+# by cell and host over the traced runs of PRs 50-55 (PERF.md section 7), and a mix's
+# trace_seconds is sized so that its largest cell's stop fits this limit
 PROFILER_TIMEOUT_S = 300.0
+# the slowest export on record (phi4mini-batch's parent run of PR 51's call
+# a: 68.6 MB in 162.5 s, 2.37 s a MB) and the share of the limit a mix's
+# span may cost at that rate (tests/kbench/test_kbench_trace_window.py
+# holds every file under traffic/ to it)
+EXPORT_S_PER_MB = 2.4
+TRACE_BUDGET_SHARE = 0.75
+STOP_WARN_SHARE = 2 / 3     # a stop over this share of the limit is said
 LATE_SHARE = 0.10           # generator lateness worth a warning, of the mean gap
 
 
@@ -150,12 +158,43 @@ def trace_window(post, begin_s: float, span_s: float, window_s: float,
     if late > 0:
         raise BenchError(
             f"/start_profile, asked at {asked - t0:.1f}s, returned at "
-            f"{started - t0:.1f}s: {span_s:.0f}s of trace from there would "
+            f"{started - t0:.1f}s: {span_s:g}s of trace from there would "
             f"end {late:.1f}s after the {window_s:.0f}s window")
     if status != 200:
         raise BenchError(f"/stop_profile answered {status}")
     return {"start_s": started - t0, "stop_s": stopping - t0,
             "start_took_s": started - asked, "stop_took_s": stopped - stopping}
+
+
+def trace_middle(post, seconds: float, span_s: float, traced: dict,
+                 **clock) -> None:
+    """The tracer thread's work: ``span_s`` in the middle of the window,
+    its times into ``traced``; a bracket that failed is left there under
+    ``error`` for ``run`` to raise (a stop that outlasts
+    ``PROFILER_TIMEOUT_S`` is ``Server.request``'s ``TimeoutError``).
+    ``loadgen.py`` is starting up meanwhile, so its clock's zero is read
+    from its result afterwards."""
+    traced["t0_unix"] = time.time()
+    try:
+        traced.update(trace_window(post, max(0.0, (seconds - span_s) / 2),
+                                   span_s, seconds, **clock))
+    except (BenchError, OSError) as e:
+        traced["error"] = f"the profiler's bracket failed: {e}"
+
+
+def stop_warning(cell: str, cost: dict, span_s: float) -> str:
+    """What a traced run says of a stop that came near the limit, before
+    a faster cell's longer export fails there; empty up to
+    ``STOP_WARN_SHARE`` of it."""
+    share = cost["stop_profile_s"] / PROFILER_TIMEOUT_S
+    if share <= STOP_WARN_SHARE:
+        return ""
+    return (f"WARNING: {cell}: /stop_profile took "
+            f"{cost['stop_profile_s']:.1f}s, {share:.0%} of the "
+            f"{PROFILER_TIMEOUT_S:.0f}s it may, for "
+            f"{cost['xplane_bytes'] / 1e6:.1f} MB and the mix's trace_seconds "
+            f"{span_s:g}: the span wants shortening in a benchmark PR "
+            "(kbench/README.md, the trace's budget)")
 
 
 def reduce_trace(profile_dir: str, work_dir: str, into: dict) -> None:
@@ -245,6 +284,7 @@ def run(args, t_start: float) -> int:
     weight_seed = args.seed % (2 ** 31 - 1)
     rate = float(settings.get("rate_rps", 0.0))
     concurrency = clients_of(cfg, mix)
+    span = float(mix.get("trace_seconds", 3.0))     # of the window, traced
     chips = 1 if on_cpu else cell["chips"]
     if mix["loop"] == "open" and rate <= 0:
         raise BenchError(f"cell {cell['name']} has no rate: run the sweep "
@@ -285,24 +325,17 @@ def run(args, t_start: float) -> int:
 
         before = srv.metrics()
 
-        def tracer():
-            # the middle of the window; loadgen.py is starting up, so its
-            # clock's zero is read from its result afterwards
-            span = float(mix.get("trace_seconds", 3.0))
-            traced["t0_unix"] = time.time()
-            try:
-                traced.update(trace_window(
-                    lambda path: srv.request(path, {}, PROFILER_TIMEOUT_S)[0],
-                    max(0.0, (args.seconds - span) / 2), span, args.seconds))
-            except (BenchError, OSError) as e:
-                traced["error"] = f"the profiler's bracket failed: {e}"
+        def profiler(path):
+            return srv.request(path, {}, PROFILER_TIMEOUT_S)[0]
 
         def during(proc):
             t0 = time.monotonic()
             thread = None
             if args.trace and not on_cpu:
                 # a CPU has no device plane to trace: the rehearsal takes none
-                thread = threading.Thread(target=tracer, daemon=True)
+                thread = threading.Thread(
+                    target=trace_middle, daemon=True,
+                    args=(profiler, args.seconds, span, traced))
                 thread.start()
             while proc.poll() is None and args.trace:
                 if time.monotonic() - t0 < args.seconds:
@@ -392,6 +425,9 @@ def run(args, t_start: float) -> int:
             cost = dict(reduced["cost"], start_profile_s=traced["start_took_s"],
                         stop_profile_s=traced["stop_took_s"])
             log("the trace cost " + json.dumps(cost))
+            near = stop_warning(cell["name"], cost, span)
+            if near:
+                log(near)
             device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
             out["breakdown"] = {
                 "device_ops": [[n, s] for n, s in sorted(

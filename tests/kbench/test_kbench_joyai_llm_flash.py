@@ -156,7 +156,9 @@ def test_the_configuration_is_the_published_one_but_for_its_cut():
     assert mix["prompt"]["unique"] == {"dist": "uniform", "min": 1024,
                                        "max": 4096}
     assert mix["output"] == {"dist": "uniform", "min": 256, "max": 768}
-    assert mix["trace_seconds"] <= 14 and mix["drain_timeout_s"] == 180
+    # what this model's export fits into the profiler call's limit with
+    # (test_kbench_trace_window.py holds every mix's budget)
+    assert mix["trace_seconds"] == 2.5 and mix["drain_timeout_s"] == 180
 
 
 def test_the_top_level_keys_are_the_catalog_rows_but_for_reduced():

@@ -181,7 +181,9 @@ def test_the_configuration_is_the_published_one_but_for_its_cut():
     mix = Manifest().traffic("batch-long-t14")
     plain = Manifest().traffic("batch-long")
     assert {k for k in mix if mix[k] != plain[k]} == {
-        "trace_seconds", "trace_seconds_why"}
+        "trace_seconds", "trace_seconds_why",
+        # what a traced second costs each mix's largest cell (PR 55)
+        "trace_mb_per_s", "trace_mb_per_s_from"}
     assert (plain["trace_seconds"], mix["trace_seconds"]) == (10, 14)
     assert mix["check"] == {"prompt_lens": [150, 1100, 4500],
                             "decode_tokens": 24}
