@@ -26,8 +26,24 @@ definition (``gdn_recurrence``), token by token, at beta up to 2:
       T    = (I + A)^-1                                forward substitution
       W    = T diag(beta) (K * e^gamma)      U = T diag(beta) V
       V'   = U - W S_0
-      O    = (Q * e^gamma) S_0 + ((Q K^T) * M) V'      M_ij = e^{gamma_i - gamma_j}, i >= j
+      O    = (Q * e^gamma) S_0 + ((Q K^T) * D) V'      D_ij = e^{gamma_i - gamma_j}, i >= j
       S_C  = e^{gamma_C} S_0 + sum_j e^{gamma_C - gamma_j} k_j v'_j^T
+           = M S_0 + N      M = diag(e^{gamma_C}) - K_end^T W    N = K_end^T U
+                            K_end = K * e^{gamma_C - gamma}
+
+  Only two things in this are serial, and only they sit in a loop.
+  ``T`` is unit lower triangular and is had in blocks of ``GDN_SUB``
+  rows: a diagonal block's inverse a row at a time (row ``i`` is
+  ``e_i - A[i, :i] T[:i]``; ``GDN_SUB - 1`` steps, for every block of
+  every chunk and head at once), then the blocks under the diagonal a
+  block row at a time, ``T[r, :r] = -T_rr (A[r, :r] T[:r, :r])``
+  (``chunk / GDN_SUB - 1`` pairs of products).  And a chunk's state
+  follows from the chunk before it: ``M`` and ``N`` are computed for
+  every chunk at once, the loop over the chunks carries
+  ``S <- M_c S + N_c``, one product a step, and hands out ``S`` at each
+  chunk's start; ``V'`` and ``O`` then follow for every chunk at once.
+  At the default chunk that is 31 + 3 steps, then one product a chunk
+  (benchmarks/gdn_scan.py times the forms against each other).
 
   XLA einsums in float32 under the scope ``gdn_scan``, from an initial
   state to a final one; a padded position has ``g`` 0 and ``beta`` 0
@@ -60,8 +76,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 _HI = jax.lax.Precision.HIGHEST
 
-# tokens a chunk of the prefill scan holds
-GDN_CHUNK = 64
+# tokens a chunk of the prefill scan holds: its products put a chunk's
+# tokens on the rows of the matrix unit, which 128 fill
+GDN_CHUNK = 128
+# rows a diagonal block of the chunk's triangular inverse holds: at 16
+# the scan alone is 4% faster and a prefill program costs a start 0.5 s
+# more to meet (seven block rows of operations to trace and load, not
+# three; PERF.md section 6, PR 56)
+GDN_SUB = 32
 
 
 def gdn_recurrence(q, k, v, g, beta, s0):
@@ -89,6 +111,52 @@ def gdn_chunked_scan(q, k, v, g, beta, s0, chunk: int = GDN_CHUNK):
         return _chunked_scan(q, k, v, g, beta, s0, chunk)
 
 
+def _lane_product(X, Y):
+    """[i, j, n] x [j, k, n] -> [i, k, n]: a matrix product a lane."""
+    return jnp.sum(X[:, :, None, :] * Y[None, :, :, :], axis=1)
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1`` for ``A`` [..., C, C] strictly lower triangular,
+    in blocks of ``GDN_SUB`` rows (a ``C`` that is no whole number of
+    them is one block of its own size: a sequence shorter than a chunk,
+    which no prefill program is).
+
+    Laid out [row, column, batch] with every matrix of the batch on a
+    lane, so that a step is arithmetic on whole registers where a
+    product of 1 x 32 by 32 x 32 a matrix would leave the matrix unit
+    idle.  The diagonal blocks by forward substitution, all at once:
+    row ``i`` of a block's inverse is ``e_i - A[i, :i] T[:i]`` (rows
+    ``i`` and on of ``T`` still hold the identity, and ``A[i, j]`` is 0
+    there).  Then the blocks under them, a block row at a time:
+    ``T[r, :r] = -T_rr (A[r, :r] T[:r, :r])``."""
+    *lead, C, _ = A.shape
+    sub = GDN_SUB if C % GDN_SUB == 0 else C
+    nb = C // sub
+    At = jnp.moveaxis(A.reshape(-1, C, C), 0, -1)            # [C, C, n]
+    n = At.shape[-1]
+    Ad = jnp.concatenate([At[r * sub:(r + 1) * sub, r * sub:(r + 1) * sub]
+                          for r in range(nb)], axis=-1)      # [sub,sub,nb*n]
+    eye = jnp.broadcast_to(jnp.eye(sub, dtype=A.dtype)[:, :, None], Ad.shape)
+
+    def row(i, Td):
+        a_i = jax.lax.dynamic_index_in_dim(Ad, i, axis=0, keepdims=False)
+        e_i = jax.lax.dynamic_index_in_dim(eye, i, axis=0, keepdims=False)
+        new = e_i - jnp.sum(a_i[:, None, :] * Td, axis=0)
+        return jax.lax.dynamic_update_index_in_dim(Td, new, i, axis=0)
+
+    Td = jax.lax.fori_loop(1, sub, row, eye)
+    Td = [Td[..., r * n:(r + 1) * n] for r in range(nb)]
+    Tm = Td[0]
+    for r in range(1, nb):
+        below = -_lane_product(
+            Td[r], _lane_product(At[r * sub:(r + 1) * sub, :r * sub], Tm))
+        Tm = jnp.concatenate([
+            jnp.pad(Tm, [(0, 0), (0, sub), (0, 0)]),
+            jnp.concatenate([below, Td[r]], axis=1)], axis=0)
+    return jnp.moveaxis(Tm, -1, 0).reshape(*lead, C, C)
+
+
 def _chunked_scan(q, k, v, g, beta, s0, chunk):
     b, T, H, dk = q.shape
     dv = v.shape[-1]
@@ -110,19 +178,7 @@ def _chunked_scan(q, k, v, g, beta, s0, chunk):
     kk = jnp.einsum("bchid,bchjd->bchij", k, k, precision=_HI)
     A = jnp.where(ci[:, None] > ci[None, :],
                   beta[..., :, None] * kk * decay, 0.0)
-
-    # T = (I + A)^-1, unit lower triangular, a row at a time: row i is
-    # e_i - A[i, :i] T[:i] (rows i and on of T still hold the identity,
-    # and A[i, j] is 0 there)
-    eye = jnp.broadcast_to(jnp.eye(C, dtype=jnp.float32), A.shape)
-
-    def row(i, Tm):
-        a_i = jax.lax.dynamic_index_in_dim(A, i, axis=-2, keepdims=True)
-        e_i = jax.lax.dynamic_index_in_dim(eye, i, axis=-2, keepdims=True)
-        new = e_i - jnp.einsum("bchij,bchjk->bchik", a_i, Tm, precision=_HI)
-        return jax.lax.dynamic_update_index_in_dim(Tm, new, i, axis=-2)
-
-    Tm = jax.lax.fori_loop(1, C, row, eye)
+    Tm = _unit_lower_inverse(A)
     eg = jnp.exp(gamma)[..., None]
     W = jnp.einsum("bchij,bchjd->bchid", Tm, beta[..., None] * k * eg,
                    precision=_HI)
@@ -133,20 +189,24 @@ def _chunked_scan(q, k, v, g, beta, s0, chunk):
     to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None]     # [b,nc,H,C,1]
     k_end = k * to_end
     chunk_decay = jnp.exp(gamma[..., -1])                    # [b,nc,H]
+    # a chunk takes the state at its start to the one at its end
+    # linearly, S_C = M S_0 + N: both for every chunk at once, so that
+    # the loop is left one product a chunk
+    M = jnp.eye(dk, dtype=jnp.float32) * chunk_decay[..., None, None] \
+        - jnp.einsum("bchjk,bchjd->bchkd", k_end, W, precision=_HI)
+    N = jnp.einsum("bchjk,bchjv->bchkv", k_end, U, precision=_HI)
 
     def carry(S, inp):
-        W_c, U_c, qk_c, qin_c, kend_c, d_c = inp
-        v_new = U_c - jnp.einsum("bhik,bhkv->bhiv", W_c, S, precision=_HI)
-        o = jnp.einsum("bhik,bhkv->bhiv", qin_c, S, precision=_HI) \
-            + jnp.einsum("bhij,bhjv->bhiv", qk_c, v_new, precision=_HI)
-        S = S * d_c[..., None, None] \
-            + jnp.einsum("bhjk,bhjv->bhkv", kend_c, v_new, precision=_HI)
-        return S, o
+        M_c, N_c = inp
+        return jnp.einsum("bhkj,bhjv->bhkv", M_c, S, precision=_HI) + N_c, S
 
-    s_last, o = jax.lax.scan(
-        carry, s0, tuple(jnp.moveaxis(x, 1, 0)
-                         for x in (W, U, qk, q_in, k_end, chunk_decay)))
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)            # [b,nc,C,H,dv]
+    s_last, starts = jax.lax.scan(
+        carry, s0, (jnp.moveaxis(M, 1, 0), jnp.moveaxis(N, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)                      # [b,nc,H,dk,dv]
+    v_new = U - jnp.einsum("bchik,bchkv->bchiv", W, starts, precision=_HI)
+    o = jnp.einsum("bchik,bchkv->bchiv", q_in, starts, precision=_HI) \
+        + jnp.einsum("bchij,bchjv->bchiv", qk, v_new, precision=_HI)
+    o = jnp.moveaxis(o, 2, 3)                                # [b,nc,C,H,dv]
     return o.reshape(b, nc * C, H, dv)[:, :T], s_last
 
 

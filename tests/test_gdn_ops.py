@@ -1,6 +1,7 @@
 """The gated delta rule's three forms (kaito_tpu/engine/ops/gdn.py)
 against the definition, token by token, at beta up to 2: the chunked
-scan that prefill runs, the ``jax.numpy`` decode step a CPU serves, and
+scan that prefill runs (its blocked triangular inverse and its two
+loops too), the ``jax.numpy`` decode step a CPU serves, and
 the Pallas decode kernel in interpret mode at the smallest shape that
 crosses a lane tile (tests/test_two_kind_ops.py compiles it for a
 described v5e at the published widths)."""
@@ -70,6 +71,108 @@ def test_two_chunks_carry_the_state():
     got = jnp.concatenate([o1, o2], axis=1)
     assert float(jnp.abs(got - whole_o).max()) < 2e-5
     assert float(jnp.abs(s2 - whole_s).max()) < 2e-5
+
+
+def _lower_A(C, dk=8, seed=5, n=6):
+    """``n`` matrices ``A`` as a chunk of ``C`` tokens makes them
+    (float64): normalised keys, decays between a thousandth and 1.6 a
+    token, as many under 0.04 as over it, beta up to 2 and 2 itself at
+    every seventh token."""
+    rng = np.random.default_rng(seed)
+    k = rng.standard_normal((n, C, dk))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    gamma = np.cumsum(-np.exp(rng.uniform(np.log(1e-3), np.log(1.6),
+                                          (n, C))), axis=-1)
+    beta = rng.uniform(0.0, 2.0, (n, C))
+    beta[:, ::7] = 2.0
+    A = beta[..., None] * (k @ np.swapaxes(k, -1, -2)) \
+        * np.exp(gamma[..., :, None] - gamma[..., None, :])
+    return np.tril(A, -1)
+
+
+def _row_recurrence(A):
+    """Forward substitution over the whole matrix, in float64: row i of
+    ``(I + A)^-1`` is ``e_i - A[i, :i] T[:i]``."""
+    T = np.broadcast_to(np.eye(A.shape[-1]), A.shape).copy()
+    for i in range(1, A.shape[-1]):
+        T[..., i, :] -= np.einsum("nj,njk->nk", A[..., i, :i], T[..., :i, :])
+    return T
+
+
+@pytest.mark.parametrize("C", [128, 64, 32, 16, 24])
+def test_the_blocked_inverse_is_the_inverse(C):
+    """Blocks of 32 rows (24 and 16 are no whole number of them and go
+    as one block) against ``inv(I + A)`` and against the row recurrence over
+    the whole matrix.  float32 against float64: forward substitution
+    loses a rounding (6e-8) a term of a row's sum, on entries of order
+    1; this reads 1.1e-7 to 1.6e-7 of the largest entry at every
+    size."""
+    A = _lower_A(C)
+    got = np.asarray(jax.jit(G._unit_lower_inverse)(
+        jnp.asarray(A, jnp.float32).reshape(2, 3, C, C))).reshape(A.shape)
+    inv = np.linalg.inv(np.eye(C) + A)
+    rows = _row_recurrence(A)
+    top = np.abs(inv).max()
+    assert top >= 1.0 and np.abs(A).max() > 0.5
+    assert np.abs(rows - inv).max() < 1e-12 * top
+    assert np.abs(got - inv).max() < 1e-6 * top
+    assert np.abs(got - rows).max() < 1e-6 * top
+    # unit lower triangular, exactly
+    assert (np.triu(got, 1) == 0).all()
+    assert (np.diagonal(got, axis1=-2, axis2=-1) == 1).all()
+
+
+@pytest.mark.parametrize("T,chunk", [(2048, 128), (1024, 64)])
+def test_sixteen_chunks_at_the_published_head_shape(T, chunk):
+    """Heads of 96 keys and 192 values from a state that is not zero,
+    16 chunks: the carry multiplies 16 transition matrices ``M`` in a
+    row.  A token's transition ``e^g (I - beta k k^T)`` has no
+    eigenvalue outside [-1, 1] at beta <= 2, so ``M`` stretches
+    nothing and the roundings add and do not compound: one product of
+    96 terms is off by about ``sqrt(96) * 6e-8 = 6e-7`` of the state's
+    largest entry (4-5 here), 16 of them in a row by at most 5e-5 and,
+    adding as a random walk, by about 1e-5: the limit is the other
+    cases' 2e-5 (a CPU reads 1.7e-6 and 5.0e-6 on the state and under
+    1e-6 on the output; the chip, whose float32 products are six
+    bfloat16 passes, 0.9e-5 to 4.3e-5 of 2.1-2.8 over 12 to 32 chunks
+    of 30 heads, benchmarks/gdn_scan.py).  A dropped term is of the
+    order of the state itself."""
+    q, k, v, g, beta, s0 = _inputs(1, T, 3, 96, 192, seed=6)
+    want_o, want_s = jax.jit(G.gdn_recurrence)(q, k, v, g, beta, s0)
+    got_o, got_s = jax.jit(G.gdn_chunked_scan, static_argnames="chunk")(
+        q, k, v, g, beta, s0, chunk=chunk)
+    assert float(jnp.abs(want_s).max()) > 1.0
+    assert float(jnp.abs(got_o - want_o).max()) < 2e-5
+    assert float(jnp.abs(got_s - want_s).max()) < 2e-5
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for x in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_only_what_is_serial_is_in_a_loop():
+    """The scan's two loops at 256 tokens: the inverse's row loop makes
+    31 trips (blocks of 32 rows, not the chunk's 127) with no matrix
+    product in it, and the loop over the chunks holds exactly one (the
+    state's own recurrence), so work put back into a serial loop fails
+    here and not only in a benchmark."""
+    q, k, v, g, beta, s0 = _inputs(1, 256, 2, 8, 16)
+    jaxpr = jax.make_jaxpr(G.gdn_chunked_scan)(q, k, v, g, beta, s0).jaxpr
+    names = [e.primitive.name for e in _equations(jaxpr)]
+    assert "while" not in names
+    loops = {e.params["length"]: [x.primitive.name
+                                  for x in _equations(e.params["jaxpr"].jaxpr)]
+             for e in _equations(jaxpr) if e.primitive.name == "scan"}
+    assert sorted(loops) == [256 // G.GDN_CHUNK, G.GDN_SUB - 1] == [2, 31]
+    assert loops[31].count("dot_general") == 0
+    assert loops[2].count("dot_general") == 1
 
 
 def _decode_case(seed=3):
